@@ -19,10 +19,6 @@ _sys.path.insert(0, _os.path.join(_os.path.dirname(_os.path.abspath(__file__)), 
 
 import argparse
 
-from maggy_tpu.util import apply_platform_env
-
-apply_platform_env()  # honor JAX_PLATFORMS even if a TPU plugin pre-registered
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -96,9 +96,8 @@ def child_main(argv: Optional[List[str]] = None) -> int:
 
     wit = _witness.install() if _witness.enabled_by_env() else None
 
-    from maggy_tpu import OptimizationConfig, Searchspace, experiment, util
+    from maggy_tpu import OptimizationConfig, Searchspace, experiment
 
-    util.apply_platform_env()
     config = OptimizationConfig(
         name="driver_soak", num_trials=args.trials,
         optimizer="randomsearch",

@@ -197,7 +197,7 @@ class OptimizationConfig(LagomConfig):
     # across trials whose program identity matches (model config, mesh
     # topology, strategy, input shapes, swept-optimizer family), so a
     # repeat-shape trial's time-to-first-metric drops from a fresh XLA
-    # trace+compile (20-40 s on TPU) to near dispatch cost. State VALUES
+    # trace+compile to near dispatch cost. State VALUES
     # are always recomputed per trial — only memory and executables are
     # reused — and resumed/promoted trials never consume retired buffers.
     # False restores the build-per-trial behavior bit-for-bit.

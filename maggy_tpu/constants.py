@@ -42,9 +42,9 @@ REGISTRATION_TIMEOUT_S = 600.0
 FORK_AFFINITY_HOLD_S = float(os.environ.get(
     "MAGGY_TPU_FORK_AFFINITY_HOLD_S", "0.5"))
 # Bound between an elastic RESIZE request and the respawned runner's
-# REGISTER. A respawn that wedges before registering (e.g. a stale device
-# claim at backend init) never heartbeats, so heartbeat-loss detection
-# cannot see it — this is its liveness bound.
+# REGISTER. A respawn that hangs before registering (e.g. in backend
+# init, while another process still holds its chips) never heartbeats, so
+# heartbeat-loss detection cannot see it — this is its liveness bound.
 RESIZE_RESPAWN_TIMEOUT_S = 120.0
 RENDEZVOUS_TIMEOUT_S = 60.0
 # Request retry budget. Env-overridable (MAGGY_TPU_CLIENT_MAX_RETRIES)

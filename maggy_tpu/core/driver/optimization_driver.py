@@ -761,10 +761,10 @@ class OptimizationDriver(Driver):
     def periodic_check(self) -> None:
         """Server event-loop hook: bound resize-respawn registration.
 
-        A respawn that wedges BEFORE registering (stale device claim at
-        backend init) never heartbeats, so heartbeat-loss detection cannot
-        see it — and with the last-runner retire rule the pool may have
-        nobody else polling. Expired respawns are killed via the pool,
+        A respawn that hangs BEFORE registering (in backend init, while
+        another process still holds its chips) never heartbeats, so
+        heartbeat-loss detection cannot see it — and with the last-runner
+        retire rule the pool may have nobody else polling. Expired respawns are killed via the pool,
         which turns a silent infinite wait into a loud runner failure the
         driver surfaces. An expired entry whose process was still QUEUED
         for chips (kill_worker finds nothing) merely loses its in-flight

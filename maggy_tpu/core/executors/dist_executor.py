@@ -52,7 +52,6 @@ class DistExecutor:
 
     def __call__(self, partition_id: int) -> None:
         env = EnvSing.get_instance()
-        util.apply_platform_env()
         util.enable_compile_cache()
         task_attempt = int(os.environ.get("MAGGY_TPU_TASK_ATTEMPT", "0"))
         reporter = Reporter(

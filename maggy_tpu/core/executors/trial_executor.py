@@ -116,7 +116,6 @@ class TrialExecutor:
     def __call__(self, partition_id: int) -> None:
         env = EnvSing.get_instance()
         exp_dir = self.exp_dir
-        util.apply_platform_env()
         # Shared persistent XLA cache: successive trials (and sibling runner
         # processes) with recurring shapes skip recompilation (SURVEY.md
         # §7.3 "compile-cache churn").

@@ -136,9 +136,6 @@ def lagom(train_fn: Callable, config: LagomConfig = None, **kwargs) -> Any:
     experiments concurrently over one shared runner fleet, use
     ``lagom_submit``."""
     config = _build_config(config, kwargs)
-    # Honor JAX_PLATFORMS even when a TPU plugin was registered before this
-    # process's env could win (see util.apply_platform_env).
-    util.apply_platform_env()
     env = EnvSing.get_instance()
     sub = _begin_run(config, env, exclusive=True)
     driver = None
@@ -180,7 +177,6 @@ def lagom_submit(train_fn: Callable, config: LagomConfig = None, *,
     # RunAdoptionError through the handle — a resubmitted tenant after a
     # driver crash recovers its run from the journal like lagom() does
     # (docs/developer.md "Crash-only recovery").
-    util.apply_platform_env()
     handle = fleet.submit(train_fn, config, priority=priority, weight=weight,
                           min_runners=min_runners, max_runners=max_runners,
                           name=name)

@@ -1,45 +1,67 @@
 """Native (C++) control-plane codec, loaded via ctypes.
 
-Builds `framing.cpp` into `_maggy_native.so` with g++ on first import (cached
-next to the source); every entry point has a pure-Python fallback so the
-framework works without a toolchain. See framing.cpp for what/why.
+Builds `framing.cpp` with g++ on first use into a shared object NAMED BY THE
+SOURCE'S CONTENT HASH (`_maggy_native.<sha256 prefix>.so`, git-ignored, next
+to the source), so a binary can only ever be loaded by the source it was
+built from — a tree copied with a stale or foreign `.so` rebuilds instead of
+trusting it. Every entry point has a pure-Python fallback so the framework
+works without a toolchain; a failed build says so once. See framing.cpp for
+what/why.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
 import hashlib
 import hmac as _py_hmac
 import os
 import subprocess
 import threading
+import warnings
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "framing.cpp")
-_SO = os.path.join(_HERE, "_maggy_native.so")
 
 _lib = None
 _lock = threading.Lock()
 _build_attempted = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, "_maggy_native.{}.so".format(digest))
+
+
+def _build(so: str) -> bool:
     # Compile to a per-pid temp path then rename: os.rename is atomic, so
     # concurrent runner processes never dlopen a partially written .so.
-    tmp = "{}.tmp.{}".format(_SO, os.getpid())
+    tmp = os.path.join(_HERE, ".build.{}.{}".format(
+        os.getpid(), os.path.basename(so)))
     try:
         subprocess.run(
             ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp],
             check=True, capture_output=True, timeout=120,
         )
-        os.replace(tmp, _SO)
-        return True
-    except Exception:  # noqa: BLE001 - no toolchain -> python fallback
+        os.replace(tmp, so)
+    except (OSError, subprocess.SubprocessError) as e:
         try:
             os.unlink(tmp)
         except OSError:
             pass
+        warnings.warn(
+            "native codec not built ({!r}); using the pure-Python codec"
+            .format(getattr(e, "stderr", None) or e), stacklevel=3)
         return False
+    # Binaries of other source versions (and the pre-hash name) are dead.
+    for stale in glob.glob(os.path.join(_HERE, "_maggy_native*.so")):
+        if stale != so:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
+    return True
 
 
 def get_lib():
@@ -48,14 +70,15 @@ def get_lib():
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
+        so = _so_path()
+        if not os.path.exists(so):
             if _build_attempted:
                 return None
             _build_attempted = True
-            if not _build():
+            if not _build(so):
                 return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError:
             return None
         lib.maggy_hmac_sha256.argtypes = [

@@ -48,22 +48,6 @@ def make_mesh(mesh_shape: Dict[str, int], devices: Optional[Sequence] = None):
     return jax.sharding.Mesh(arr, tuple(shape.keys()))
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions: the public spelling when
-    present, else ``jax.experimental.shard_map`` with ``check_vma``
-    mapped to its older ``check_rep`` name."""
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
-
-
 def slice_mesh(chip_ids: Sequence[int], mesh_shape: Dict[str, int]):
     """Named mesh over a slice of the global device inventory, by device
     index. This is the gang-scheduling mesh constructor: the driver

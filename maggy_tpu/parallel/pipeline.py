@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from maggy_tpu.parallel.mesh import shard_map as version_shard_map
-
 
 def pipeline_apply(
     stage_fn: Callable,
@@ -95,7 +93,7 @@ def pipeline_apply(
         x_spec = P(None, data_axes, *([None] * (x_mb.ndim - 2)))
     else:
         x_spec = P()
-    out = version_shard_map(
+    out = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(stage_spec, x_spec), out_specs=x_spec,
         check_vma=False,
@@ -274,7 +272,7 @@ def pipeline_1f1b_grads(
         tgt_spec = P(None, data_axes, *([None] * (t_mb.ndim - 2)))
     else:
         mb_spec, tgt_spec = P(), P()
-    return version_shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(stage_spec, mb_spec, tgt_spec),
         out_specs=(P(), stage_spec),

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 
 from maggy_tpu.ops.attention import (NEG_INF, flash_block_bwd,
                                      flash_block_fwd)
-from maggy_tpu.parallel.mesh import shard_map as version_shard_map
 
 
 # ------------------------------------------------------------------ xla path
@@ -271,7 +270,7 @@ def ring_attention(q, k, v, mesh, axis_name: str = "seq",
     else:
         def fn(qb, kb, vb):
             return _ring_xla_shard(qb, kb, vb, axis_name, n, causal)
-    out = version_shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh, in_specs=(qspec, qspec, qspec), out_specs=qspec,
         check_vma=False,
     )(q, k, v)

@@ -26,8 +26,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from maggy_tpu.parallel.mesh import shard_map as version_shard_map
-
 
 def ulysses_attention(q, k, v, mesh, axis_name: str = "seq",
                       causal: bool = True, impl: str = "auto",
@@ -88,7 +86,7 @@ def ulysses_attention(q, k, v, mesh, axis_name: str = "seq",
         return heads_to_seq(out.astype(q_l.dtype))
 
     spec = P(None, axis_name, None, None)
-    return version_shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

@@ -1,8 +1,8 @@
 """Unified telemetry: metrics registry, trial-span tracing, event journal.
 
-The subsystem VERDICT round 5 asked for: the paper's scheduling-efficiency
-claim (early-stop a trial, hand the freed runner new work with near-zero
-gap) becomes a queryable artifact instead of ad-hoc timers. Three pieces:
+The paper's scheduling-efficiency claim (early-stop a trial, hand the freed
+runner new work with near-zero gap) becomes a queryable artifact instead of
+ad-hoc timers. Three pieces:
 
 - ``MetricsRegistry`` (metrics.py): counters / gauges / fixed-bound
   histograms, thread-safe, snapshot-able to plain dicts.

@@ -2,9 +2,9 @@
 
 ROADMAP item 3. Every trial used to pay a fresh XLA trace+compile (and a
 fresh sharded init) for a program byte-identical to the previous trial's —
-a 20-40 s time-to-first-metric stall on TPU that dwarfs the ~2.6 ms
-hand-off PR 4 bought. The fix is the pjit idiom ("Scalable Training of
-Language Models using JAX pjit and TPUv4", PAPERS.md): program identity is
+a time-to-first-metric stall that dwarfs the hand-off PR 4 shortened. The
+fix is the pjit idiom ("Scalable Training of Language Models using JAX pjit
+and TPUv4", PAPERS.md): program identity is
 pinned by *shapes and mesh topology*, not hyperparameter values, so a
 runner that keeps the compiled program resident (Podracer-style persistent
 actors) only recompiles when the program actually changes.

@@ -1,6 +1,9 @@
-"""Auto-dispatch resilience: the one-time flash compile probe and the
-MAGGY_TPU_NO_FLASH kill switch must route attention to the XLA reference
-instead of bricking every model when the Pallas path is unavailable."""
+"""Attention dispatch: which path `multi_head_attention` takes is decided
+by the backend, the shapes and the MAGGY_TPU_NO_FLASH kill switch alone.
+On a TPU backend, shapes that tile take the Pallas kernel compiled (never
+interpreted), and a kernel that fails to build is an error, not a silent
+switch to the XLA reference (tests/test_flash_compile.py holds the compile
+guarantee)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -9,20 +12,13 @@ import pytest
 import maggy_tpu.ops.attention as att
 
 
-@pytest.fixture(autouse=True)
-def reset_probe(monkeypatch):
-    monkeypatch.setattr(att, "_FLASH_PROBE", None)
-    yield
-    att._FLASH_PROBE = None
-
-
 def _qkv():
     rng = np.random.default_rng(0)
     return tuple(jnp.asarray(rng.normal(size=(1, 128, 2, 128)), jnp.float32)
                  for _ in range(3))
 
 
-class TestDispatchResilience:
+class TestDispatch:
     def test_kill_switch_forces_reference(self, monkeypatch):
         monkeypatch.setenv("MAGGY_TPU_NO_FLASH", "1")
         monkeypatch.setattr(att, "_tpu_backend", lambda: True)
@@ -36,51 +32,44 @@ class TestDispatchResilience:
         ref = att.attention_reference(q, k, v, causal=True)
         assert float(jnp.abs(out - ref).max()) < 1e-6
 
-    def test_probe_failure_falls_back_with_warning(self, monkeypatch):
+    def test_tpu_backend_takes_the_kernel_compiled(self, monkeypatch):
+        monkeypatch.setattr(att, "_tpu_backend", lambda: True)
+        seen = {}
+
+        def stub(q, k, v, mask, causal, blk_q, blk_k, interpret):
+            seen["interpret"] = interpret
+            return att.attention_reference(q, k, v, causal=causal)
+
+        monkeypatch.setattr(att, "flash_attention", stub)
+        att.multi_head_attention(*_qkv(), causal=True)
+        assert seen == {"interpret": False}
+
+    def test_kernel_build_failure_is_an_error(self, monkeypatch):
         monkeypatch.setattr(att, "_tpu_backend", lambda: True)
 
         def boom(*a, **k):
             raise RuntimeError("Mosaic lowering failed")
 
         monkeypatch.setattr(att, "flash_attention", boom)
-        q, k, v = _qkv()
-        with pytest.warns(UserWarning, match="failed to COMPILE"):
-            out = att.multi_head_attention(q, k, v, causal=True)
+        with pytest.raises(RuntimeError, match="Mosaic lowering failed"):
+            att.multi_head_attention(*_qkv(), causal=True)
+
+    def test_shapes_that_do_not_tile_take_the_reference(self, monkeypatch):
+        monkeypatch.setattr(att, "_tpu_backend", lambda: True)
+        monkeypatch.setattr(
+            att, "flash_attention",
+            lambda *a, **k: pytest.fail("kernel called for S=96"))
+        rng = np.random.default_rng(0)
+        q, k, v = (jnp.asarray(rng.normal(size=(1, 96, 2, 128)), jnp.float32)
+                   for _ in range(3))
+        out = att.multi_head_attention(q, k, v, causal=True)
         ref = att.attention_reference(q, k, v, causal=True)
         assert float(jnp.abs(out - ref).max()) < 1e-6
-        # Probe result is cached: second call must not warn again.
-        import warnings as _w
 
-        with _w.catch_warnings():
-            _w.simplefilter("error")
-            att.multi_head_attention(q, k, v, causal=True)
-
-    def test_probe_success_is_cached(self, monkeypatch):
-        # The probe path lowers a jit of flash_attention; on CPU the real
-        # kernel only works in interpret mode, so substitute a pass-through
-        # and count invocations: 1 probe + 2 dispatches.
-        monkeypatch.setattr(att, "_tpu_backend", lambda: True)
-        calls = {"n": 0}
-
-        def stub(q, k, v, *a, **kw):
-            calls["n"] += 1
-            return att.attention_reference(q, k, v)
-
-        monkeypatch.setattr(att, "flash_attention", stub)
+    def test_force_flash_interprets_off_tpu(self):
+        """force='flash' on the CPU backend runs the kernel in interpret
+        mode (how the algorithm is tested without a chip)."""
         q, k, v = _qkv()
-        att.multi_head_attention(q, k, v, causal=True)
-        after_first = calls["n"]
-        assert att._FLASH_PROBE is True
-        att.multi_head_attention(q, k, v, causal=True)
-        # The cached probe does not re-run: exactly one more kernel call.
-        assert calls["n"] == after_first + 1
-
-    def test_force_flash_bypasses_probe(self, monkeypatch):
-        """force='flash' ignores a failed probe result — it must surface
-        the real kernel (and its real error), not the silent fallback."""
-        monkeypatch.setattr(att, "_FLASH_PROBE", False)
-        q, k, v = _qkv()
-        # CPU backend -> force='flash' runs the kernel in interpret mode.
         out = att.multi_head_attention(q, k, v, causal=True, force="flash")
         ref = att.attention_reference(q, k, v, causal=True)
         assert float(jnp.abs(out - ref).max()) < 1e-4

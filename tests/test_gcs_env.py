@@ -83,7 +83,7 @@ class TestRegistry:
 
 
 class TestObjectStoreResume:
-    """Resume against a RENAME-LESS backend (VERDICT r4 item 7): GCS has no
+    """Resume against a RENAME-LESS backend: GCS has no
     atomic tmp+rename, so the driver's torn-artifact tolerance — not
     LocalEnv's os.replace — is what guarantees old-or-nothing semantics on
     object stores. Drive a full interrupt/tear/resume cycle entirely

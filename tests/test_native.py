@@ -84,6 +84,44 @@ class TestNativeCodec:
             assert result <= len(buf)
 
 
+class TestArtifactFollowsSource:
+    """The shared object is named by framing.cpp's content hash, so a
+    copied tree can never load a binary built from another source."""
+
+    def test_loaded_binary_is_named_by_the_source_hash(self):
+        with open(native._SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        assert native.is_native()
+        assert native._so_path().endswith(
+            "_maggy_native.{}.so".format(digest))
+        import os
+
+        assert os.path.exists(native._so_path())
+
+    def test_changed_source_does_not_trust_the_old_binary(
+            self, tmp_path, monkeypatch):
+        import shutil
+
+        src = tmp_path / "framing.cpp"
+        shutil.copy(native._SRC, src)
+        monkeypatch.setattr(native, "_SRC", str(src))
+        before = native._so_path()
+        src.write_text(src.read_text() + "\n// edited\n")
+        assert native._so_path() != before
+
+    def test_failed_build_says_so_and_falls_back(self, tmp_path, monkeypatch):
+        (tmp_path / "framing.cpp").write_text("this is not C++")
+        monkeypatch.setattr(native, "_HERE", str(tmp_path))
+        monkeypatch.setattr(native, "_SRC", str(tmp_path / "framing.cpp"))
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_build_attempted", False)
+        with pytest.warns(UserWarning, match="native codec not built"):
+            assert native.get_lib() is None
+        key = b"fallback"
+        assert native.hmac_sha256(key, b"m") == \
+            hmac.new(key, b"m", hashlib.sha256).digest()
+
+
 class TestNativeTfrecord:
     def test_crc32c_matches_python_table(self):
         from maggy_tpu import native

@@ -786,77 +786,6 @@ class TestResizeWatchCreditLeak:
         assert pool.pending_respawn(0) is True
 
 
-class TestBenchOrphanRemediation:
-    """ADVICE #3: a bench_ marker alone must not get a process killed —
-    the marker must differ from OUR run and be gone from disk."""
-
-    def setup_method(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_mod", os.path.join(os.path.dirname(__file__), "..",
-                                      "bench.py"))
-        self.bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(self.bench)
-
-    def test_marker_parsing(self):
-        env = b"PATH=/bin\x00MAGGY_TPU_BASE_DIR=/tmp/bench_abc\x00X=1"
-        assert self.bench._marker_base_dir(env) == "/tmp/bench_abc"
-        assert self.bench._marker_base_dir(b"PATH=/bin") is None
-
-    def test_own_run_never_killable(self, tmp_path):
-        base = str(tmp_path / "bench_mine")
-        os.makedirs(base)
-        assert self.bench._is_killable_orphan_marker(base, my_base=base) is False
-
-    def test_live_concurrent_run_never_killable(self, tmp_path):
-        theirs = str(tmp_path / "bench_theirs")
-        os.makedirs(theirs)  # on disk, no owner record: conservative
-        mine = str(tmp_path / "bench_mine")
-        assert self.bench._is_killable_orphan_marker(
-            theirs, my_base=mine) is False
-
-    def test_dir_with_live_owner_never_killable(self, tmp_path):
-        theirs = str(tmp_path / "bench_theirs")
-        os.makedirs(theirs)
-        # OUR (pid, starttime) plays the live owner.
-        pid = os.getpid()
-        with open(os.path.join(theirs, ".bench_owner"), "w") as f:
-            f.write("{} {}".format(pid, self.bench._proc_starttime(pid)))
-        assert self.bench._is_killable_orphan_marker(
-            theirs, my_base=str(tmp_path / "bench_mine")) is False
-
-    def test_sigkilled_runs_dir_is_killable_once_owner_dead(self, tmp_path):
-        # The run's tmpdir survived (atexit never ran) but its owner pid
-        # is gone: positively over -> its orphans are reclaimable.
-        theirs = str(tmp_path / "bench_theirs")
-        os.makedirs(theirs)
-        with open(os.path.join(theirs, ".bench_owner"), "w") as f:
-            f.write("4194200 12345")  # beyond pid_max here: never alive
-        assert self.bench._is_killable_orphan_marker(
-            theirs, my_base=str(tmp_path / "bench_mine")) is True
-
-    def test_recycled_owner_pid_reads_as_dead(self, tmp_path):
-        # Same pid, different process incarnation (starttime mismatch):
-        # the minting owner is gone, its dir is reclaimable.
-        theirs = str(tmp_path / "bench_theirs")
-        os.makedirs(theirs)
-        with open(os.path.join(theirs, ".bench_owner"), "w") as f:
-            f.write("{} 1".format(os.getpid()))  # our pid, bogus starttime
-        assert self.bench._is_killable_orphan_marker(
-            theirs, my_base=str(tmp_path / "bench_mine")) is True
-
-    def test_dead_runs_children_are_killable(self, tmp_path):
-        gone = str(tmp_path / "bench_gone")  # never created on disk
-        mine = str(tmp_path / "bench_mine")
-        assert self.bench._is_killable_orphan_marker(gone, my_base=mine) is True
-
-    def test_non_bench_marker_never_killable(self, tmp_path):
-        assert self.bench._is_killable_orphan_marker(
-            str(tmp_path / "user_run"), my_base="") is False
-        assert self.bench._is_killable_orphan_marker(None, my_base="") is False
-
-
 class TestRegistryCustomRoot:
     """ADVICE #4: registries at a non-default root are URI-addressable via
     $MAGGY_TPU_REGISTRY_ROOT or an explicit root/registry_root param."""

@@ -535,68 +535,78 @@ class TestTraceCompileSlices:
 
 
 class TestEnableCompileCache:
-    """Satellite: util.enable_compile_cache env gating + failure path."""
+    """util.enable_compile_cache: the cache directory is placed from
+    outside (JAX_COMPILATION_CACHE_DIR) or is ONE fixed path inside the
+    checkout; never a temp dir, and never silently unarmed."""
 
-    def _restore(self):
-        jax.config.update("jax_compilation_cache_dir", None)
+    @pytest.fixture
+    def config_updates(self, monkeypatch):
+        """Record jax.config.update calls instead of applying them (the
+        test process's own compile cache must stay as it is)."""
+        calls = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda key, value: calls.__setitem__(key, value))
+        monkeypatch.delenv("MAGGY_TPU_NO_COMPILE_CACHE", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        return calls
 
-    def test_disabled_by_env(self, monkeypatch, tmp_path):
+    def test_env_placed_dir_is_not_set_in_code(self, monkeypatch, tmp_path,
+                                               config_updates):
+        from maggy_tpu import util
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        assert util.enable_compile_cache() == str(tmp_path / "c")
+        # JAX took the directory from the environment at import; the
+        # program only lowers the two persistence thresholds.
+        assert config_updates == {
+            "jax_persistent_cache_min_compile_time_secs": 0.0,
+            "jax_persistent_cache_min_entry_size_bytes": 0}
+        assert not (tmp_path / "c").exists()
+
+    def test_unset_uses_the_fixed_path_inside_the_checkout(
+            self, monkeypatch, tmp_path, config_updates):
+        from maggy_tpu import util
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert util.COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        monkeypatch.setattr(util, "COMPILE_CACHE_DIR", str(tmp_path / "fixed"))
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        first = util.enable_compile_cache()
+        assert first == str(tmp_path / "fixed") and os.path.isdir(first)
+        assert config_updates["jax_compilation_cache_dir"] == first
+        assert config_updates[
+            "jax_persistent_cache_min_compile_time_secs"] == 0.0
+        assert util.enable_compile_cache() == first  # safe to re-call
+
+    def test_cpu_default_off(self, monkeypatch, config_updates):
+        from maggy_tpu import util
+
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        # XLA:CPU AOT entries embed host ISA features; CPU runs default
+        # the cache off unless the environment places a directory.
+        assert util.enable_compile_cache() is None
+        assert config_updates == {}
+
+    def test_disabled_by_env(self, monkeypatch, tmp_path, config_updates):
         from maggy_tpu import util
 
         monkeypatch.setenv("MAGGY_TPU_NO_COMPILE_CACHE", "1")
-        monkeypatch.setenv("MAGGY_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert util.enable_compile_cache() is None
+        assert config_updates == {}
 
-    def test_cpu_default_off(self, monkeypatch):
+    def test_unarmed_cache_says_so(self, monkeypatch, tmp_path,
+                                   config_updates):
         from maggy_tpu import util
 
-        monkeypatch.delenv("MAGGY_TPU_NO_COMPILE_CACHE", raising=False)
-        monkeypatch.delenv("MAGGY_TPU_COMPILE_CACHE_DIR", raising=False)
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        # XLA:CPU AOT entries embed host ISA features; the cache pays off
-        # on TPU — CPU runs default it off unless explicitly pointed at a
-        # dir.
-        assert util.enable_compile_cache() is None
-
-    def test_dir_override_and_idempotent_recall(self, monkeypatch, tmp_path):
-        from maggy_tpu import util
-
-        monkeypatch.delenv("MAGGY_TPU_NO_COMPILE_CACHE", raising=False)
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        monkeypatch.setenv("MAGGY_TPU_COMPILE_CACHE_DIR",
-                           str(tmp_path / "xla"))
-        try:
-            first = util.enable_compile_cache()
-            assert first == str(tmp_path / "xla")
-            assert os.path.isdir(first)
-            assert util.enable_compile_cache() == first  # safe to re-call
-            assert jax.config.jax_compilation_cache_dir == first
-        finally:
-            self._restore()
-
-    def test_explicit_dir_beats_cpu_default_off(self, monkeypatch, tmp_path):
-        from maggy_tpu import util
-
-        monkeypatch.delenv("MAGGY_TPU_NO_COMPILE_CACHE", raising=False)
-        monkeypatch.delenv("MAGGY_TPU_COMPILE_CACHE_DIR", raising=False)
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        try:
-            assert util.enable_compile_cache(str(tmp_path / "c")) == \
-                str(tmp_path / "c")
-        finally:
-            self._restore()
-
-    def test_never_fatal(self, monkeypatch, tmp_path):
-        from maggy_tpu import util
-
-        monkeypatch.delenv("MAGGY_TPU_NO_COMPILE_CACHE", raising=False)
-        monkeypatch.setenv("MAGGY_TPU_COMPILE_CACHE_DIR", str(tmp_path))
-
-        def boom(*a, **k):
-            raise RuntimeError("config exploded")
-
-        monkeypatch.setattr(jax.config, "update", boom)
-        assert util.enable_compile_cache() is None  # optimization, not a dep
+        blocker = tmp_path / "file"
+        blocker.write_text("")
+        monkeypatch.setattr(util, "COMPILE_CACHE_DIR", str(blocker / "sub"))
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        with pytest.warns(UserWarning, match="NOT armed"):
+            assert util.enable_compile_cache() is None
+        assert config_updates == {}
 
 
 class TestMonitorRendering:
