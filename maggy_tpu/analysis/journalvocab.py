@@ -14,6 +14,11 @@ speaks. This checker verifies three directions, all statically:
    ``*_PHASES`` constant, aliases of such fields) appears in the
    vocabulary — a consumer typo matches nothing, silently.
 
+Span names are checked the same two ways: every literal name a
+``span(...)`` / ``TraceAnnotation(...)`` / ``StepTraceAnnotation(...)``
+call opens is in ``SPAN_NAMES`` or ``ANNOTATION_NAMES``, and every entry
+of either is opened somewhere.
+
 ``# vocab-ok: <reason>`` on the emit/consume line suppresses.
 """
 
@@ -40,6 +45,8 @@ _CONST_FAMILY = (("PHASES", "phase"), ("REASONS", "reason"),
 
 #: Emitter call method names.
 _EMIT_EVENT = ("event", "_event")
+#: Calls whose first literal argument is a span or annotation name.
+_EMIT_SPAN = ("span", "_ckpt_span", "TraceAnnotation", "StepTraceAnnotation")
 
 
 class Vocab:
@@ -65,6 +72,9 @@ class Vocab:
             return self.sets.get("HEALTH_CHECKS", set())
         if name == "chaos_kind":
             return self.sets.get("CHAOS_KINDS", set())
+        if name == "span_name":
+            return self.sets.get("SPAN_NAMES", set()) \
+                | self.sets.get("ANNOTATION_NAMES", set())
         return set()
 
 
@@ -158,6 +168,10 @@ def _collect_emits(index: PackageIndex, vocab_mod
                                 and _is_str(kw.value):
                             out.append(("health_check", kw.value.value,
                                         mod, node.lineno))
+            elif name in _EMIT_SPAN:
+                if node.args and _is_str(node.args[0]):
+                    out.append(("span_name", node.args[0].value, mod,
+                                node.lineno))
             elif name == "mark":
                 # SpanTracker.mark(trial, "phase") — the facade's inner
                 # edge; literal phases here are emits too.
@@ -330,7 +344,9 @@ def check(index: PackageIndex) -> List["Finding"]:
     # Orphan vocabulary: core families must be emitted somewhere.
     for set_name, fam in (("SPAN_PHASES", "phase"),
                           ("EVENT_KINDS", "kind"),
-                          ("REQUEUE_REASONS", "reason")):
+                          ("REQUEUE_REASONS", "reason"),
+                          ("SPAN_NAMES", "span_name"),
+                          ("ANNOTATION_NAMES", "span_name")):
         for entry in sorted(vocab.sets.get(set_name, set())):
             if entry not in emitted_by_family.get(fam, set()):
                 emit_finding(vocab.mod, vocab.lines.get(entry, 1),

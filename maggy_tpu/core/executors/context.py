@@ -21,21 +21,23 @@ naming it in its signature — and gets:
 
 from __future__ import annotations
 
+import contextlib
 import os
-import time
 from typing import Any, Dict, Optional
 
 
-def _note_ckpt(**fields: Any) -> None:
-    """Route checkpoint I/O timing into the current trial's RunnerStats
-    via the warm trial scope (same channel note_compile rides). Never
-    fatal: checkpoint accounting must not break checkpointing itself."""
+def _ckpt_span(name: str):
+    """The current trial's `span` for a checkpoint phase (``ckpt_save`` /
+    ``ckpt_restore``): annotated in the profiler's trace and timed into
+    the trial's RunnerStats through the warm trial scope (the channel
+    note_compile rides). Never fatal: checkpoint accounting must not
+    break checkpointing itself."""
     try:
-        from maggy_tpu.train.warm import note_ckpt
+        from maggy_tpu.train.warm import span
 
-        note_ckpt(**fields)
+        return span(name)
     except Exception:  # noqa: BLE001 - accounting is best-effort
-        pass
+        return contextlib.nullcontext()
 
 
 def info_needs_fresh_state(info: Dict[str, Any]) -> bool:
@@ -158,22 +160,15 @@ class TrialContext:
         return self._checkpointer
 
     def save_checkpoint(self, step: int, state: Any) -> None:
-        t0 = time.perf_counter()
-        try:
+        with _ckpt_span("ckpt_save"):
             self.checkpointer().save(step, state)
-        finally:
-            _note_ckpt(save_ms=(time.perf_counter() - t0) * 1e3, saves=1)
 
     def restore_checkpoint(self, abstract_state: Any) -> Optional[Any]:
         """Resume this trial's own latest checkpoint (None if absent)."""
         if not os.path.isdir(os.path.join(self.trial_dir, "checkpoints")):
             return None
-        t0 = time.perf_counter()
-        try:
+        with _ckpt_span("ckpt_restore"):
             return self.checkpointer().restore(abstract_state)
-        finally:
-            _note_ckpt(restore_ms=(time.perf_counter() - t0) * 1e3,
-                       restores=1)
 
     def restore_parent(self, abstract_state: Any) -> Optional[Any]:
         """Warm-start from the promoted parent's checkpoint (None if this
@@ -183,12 +178,8 @@ class TrialContext:
             return None
         from maggy_tpu.train.checkpoint import restore_parent_state
 
-        t0 = time.perf_counter()
-        try:
+        with _ckpt_span("ckpt_restore"):
             return restore_parent_state(self.exp_dir, parent, abstract_state)
-        finally:
-            _note_ckpt(restore_ms=(time.perf_counter() - t0) * 1e3,
-                       restores=1)
 
     def close(self) -> None:
         if self._checkpointer is not None:

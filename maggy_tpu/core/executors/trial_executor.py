@@ -323,23 +323,25 @@ class TrialExecutor:
         are stripped from the assignment info so ``ctx.resume_step``
         reads None and the train fn's resume branch never opens a
         checkpoint that is not there."""
-        import time as _time
+        from maggy_tpu.telemetry.runnerstats import span
 
         fork = dict((client.last_info or {}).get("forked_from") or {})
-        t0 = _time.monotonic()
         staged = None
-        try:
-            if ctx is not None:
-                staged = ctx.stage_fork()
-            else:
-                from maggy_tpu.core.environment import EnvSing
-                from maggy_tpu.train.checkpoint import fork_checkpoint
+        # Timed whether or not the parent's checkpoint is there: the
+        # runner spent the time either way.
+        with span("fork_stage", stats=stats, trial_id=trial_id) as staging:
+            try:
+                if ctx is not None:
+                    staged = ctx.stage_fork()
+                else:
+                    from maggy_tpu.core.environment import EnvSing
+                    from maggy_tpu.train.checkpoint import fork_checkpoint
 
-                staged = fork_checkpoint(
-                    EnvSing.get_instance(), exp_dir, fork.get("trial"),
-                    trial_dir, step=fork.get("step"))
-        except Exception:  # noqa: BLE001 - a broken fork must not kill the trial
-            staged = None
+                    staged = fork_checkpoint(
+                        EnvSing.get_instance(), exp_dir, fork.get("trial"),
+                        trial_dir, step=fork.get("step"))
+            except Exception:  # noqa: BLE001 - a broken fork must not kill the trial
+                staged = None
         if staged is None:
             reporter.log(
                 "Trial {}: fork source {} step {} unavailable; running "
@@ -350,12 +352,11 @@ class TrialExecutor:
                 if ctx is not None:
                     ctx.info.pop(key, None)
             return
-        stats.note_compile(fork_load_ms=(_time.monotonic() - t0) * 1e3,
-                           forked=True)
+        stats.note_compile(forked=True)
         reporter.log("Trial {} forked from {} at checkpoint step {} "
                      "({}ms load).".format(
                          trial_id, fork.get("trial"), staged,
-                         round((_time.monotonic() - t0) * 1e3, 1)))
+                         round((staging.t_end - staging.t_start) * 1e3, 1)))
 
     def _run_vmap_block(self, leader_id: str, params: dict, client,
                         reporter, stats, env, exp_dir: str,
@@ -589,33 +590,40 @@ class TrialExecutor:
         an in-process thread pool tracing is best-effort: a trial whose
         start overlaps an already-traced trial runs untraced. Process/TPU
         pools have one trial per process and trace every trial."""
+        from maggy_tpu.telemetry.runnerstats import span
+
         if self.ship_prints:
             _install_print_tee()
             _print_ship.reporter = reporter
+        stats = getattr(reporter, "stats", None)
         try:
-            if not self.profile:
-                return self.train_fn(**call_params)
-            if not _PROFILE_LOCK.acquire(blocking=False):
-                # Another thread-pool trial holds the process-global
-                # profiler: this trial runs UNTRACED. Report it through
-                # the runner-stats channel so the journal carries a
-                # profile_skipped trial event — a missing TensorBoard
-                # trace must be explainable, not a mystery.
-                stats = getattr(reporter, "stats", None) if reporter else None
-                if stats is not None:
-                    stats.note_profile_skipped(
-                        getattr(reporter, "trial_id", None))
-                if reporter is not None:
-                    reporter.log("profiler busy (thread-pool contention); "
-                                 "trial runs untraced")
-                return self.train_fn(**call_params)
-            try:
-                import jax
-
-                with jax.profiler.trace(os.path.join(trial_dir, "tensorboard")):
+            # fn_enter to fn_exit on the runner's clock: the span that the
+            # trial's phases (init, trace, compile, checkpoints) lie in.
+            with span("trial", stats=stats,
+                      trial_id=str(getattr(reporter, "trial_id", None))):
+                if not self.profile:
                     return self.train_fn(**call_params)
-            finally:
-                _PROFILE_LOCK.release()
+                if not _PROFILE_LOCK.acquire(blocking=False):
+                    # Another thread-pool trial holds the process-global
+                    # profiler: this trial runs UNTRACED. Report it through
+                    # the runner-stats channel so the journal carries a
+                    # profile_skipped trial event — a missing TensorBoard
+                    # trace must be explainable, not a mystery.
+                    if stats is not None:
+                        stats.note_profile_skipped(
+                            getattr(reporter, "trial_id", None))
+                    if reporter is not None:
+                        reporter.log("profiler busy (thread-pool "
+                                     "contention); trial runs untraced")
+                    return self.train_fn(**call_params)
+                try:
+                    import jax
+
+                    with jax.profiler.trace(
+                            os.path.join(trial_dir, "tensorboard")):
+                        return self.train_fn(**call_params)
+                finally:
+                    _PROFILE_LOCK.release()
         finally:
             _print_ship.reporter = None
 

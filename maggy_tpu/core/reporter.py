@@ -16,6 +16,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from maggy_tpu import exceptions
+from maggy_tpu.telemetry import runnerstats
 
 
 class Reporter:
@@ -97,7 +98,9 @@ class Reporter:
         the heartbeat thread materializes the newest value in `get_data()`.
         A blocking `float(loss)` per reporting step would wait for the
         device each time and serialize the pipelined step stream."""
-        with self.lock:
+        # ``report`` in the profiler's trace (annotation only; nothing
+        # where no session is open or jax was never imported).
+        with runnerstats.span("report"), self.lock:
             if not self._scalar_like(metric):
                 raise exceptions.BroadcastMetricTypeError(metric)
             if step is not None and (not isinstance(step, (int, np.integer)) or isinstance(step, bool)):
@@ -208,6 +211,9 @@ class Reporter:
             metric, step, tid = self.metric, self.step, self.trial_id
             span = self.span
             cached = self._metric_cache
+        # The newest step the loop has broadcast, beside the one that ships
+        # (older, or none, while the newest loss is still on the device).
+        newest_step = step
         if metric is not None and not isinstance(metric, float):
             # Materialize OUTSIDE the lock: the device sync must not block
             # the training thread's broadcast.
@@ -265,7 +271,7 @@ class Reporter:
         # callers must ship THESE, not re-read reporter fields (which may
         # have rolled over to the next trial mid-call).
         data = {"metric": metric, "step": step, "logs": logs,
-                "trial_id": tid, "span": span}
+                "trial_id": tid, "span": span, "newest_step": newest_step}
         if lanes_out is not None:
             data["lanes"] = lanes_out
         return data

@@ -1898,6 +1898,12 @@ class Client:
                 stats = self.runner_stats
                 delta = None
                 if stats is not None:
+                    # Whether this beat carries a newer (metric, step)
+                    # than the last, and how far it lags the loop.
+                    lanes = data.get("lanes")
+                    stats.on_heartbeat(
+                        lanes[0]["step"] if lanes else data["step"],
+                        data.get("newest_step"))
                     delta = stats.snapshot_delta()
                     if delta:
                         payload["rstats"] = delta

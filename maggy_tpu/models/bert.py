@@ -12,6 +12,7 @@ import dataclasses
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from maggy_tpu.models.llama import EMBED, HEADS, MLP, VOCAB
@@ -73,12 +74,14 @@ class EncoderLayer(nn.Module):
         if cfg.dropout > 0:
             att = nn.Dropout(cfg.dropout, deterministic=not train)(att)
         x = x + att
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln_mlp")(x).astype(cfg.dtype)
-        h = _dense(cfg.intermediate_dim, (EMBED, MLP), cfg, "fc_in")(h)
-        h = nn.gelu(h)
-        h = _dense(cfg.hidden_dim, (MLP, EMBED), cfg, "fc_out")(h)
-        if cfg.dropout > 0:
-            h = nn.Dropout(cfg.dropout, deterministic=not train)(h)
+        with jax.named_scope("mlp"):
+            h = nn.LayerNorm(dtype=jnp.float32, name="ln_mlp")(x).astype(
+                cfg.dtype)
+            h = _dense(cfg.intermediate_dim, (EMBED, MLP), cfg, "fc_in")(h)
+            h = nn.gelu(h)
+            h = _dense(cfg.hidden_dim, (MLP, EMBED), cfg, "fc_out")(h)
+            if cfg.dropout > 0:
+                h = nn.Dropout(cfg.dropout, deterministic=not train)(h)
         return x + h
 
 

@@ -237,6 +237,7 @@ def _flash_fwd(qg, kg, vg, mask, causal, blk_q, blk_k, interpret):
                                  "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(*operands)
     return out, lse
 
@@ -451,6 +452,7 @@ def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, blk_q, blk_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(*operands)
 
     # --- dQ: grid (B, G, r, qi, kb); kb sequential, accumulating.
@@ -479,6 +481,7 @@ def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, blk_q, blk_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*operands)
     return dq, dk, dv
 
@@ -600,6 +603,7 @@ def _key_padding_mask(mask, B, Sk):
     return None, False
 
 
+@jax.named_scope("attention")  # names it in a trace whichever path runs it
 def multi_head_attention(q, k, v, causal: bool = True, mask=None,
                          force: Optional[str] = None):
     """Public attention entry: kernel dispatch with XLA fallback.

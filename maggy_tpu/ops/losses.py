@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("chunked_ce")
 def chunked_softmax_xent(h, kernel, targets, vocab_chunk: int = 16384):
     """Mean softmax cross-entropy of ``h @ kernel`` against ``targets``,
     without materializing the full [N, V] logits.

@@ -159,7 +159,8 @@ class Telemetry:
                 merged["updated_t"] = time.time()
             for key in ("hb_rtt_ms", "rss_mb", "dev_mem_mb", "cadence_ms",
                         "ttfm_ms", "warm_hits", "warm_misses",
-                        "xla_cache_hits", "xla_cache_misses"):
+                        "xla_cache_hits", "xla_cache_misses",
+                        "hb_beats", "hb_fresh", "metric_lag_steps"):
                 if stats.get(key) is not None:
                     self.metrics.gauge(
                         "runner.{}.p{}".format(key, pid)).set(stats[key])

@@ -32,6 +32,15 @@ heartbeat round-trip time, or memory attribution ever left the worker.
   harness routes counts through the trial scope to the right executor's
   buffer).
 
+- **spans** (``span``) — the one timer of the runner's phases: a
+  ``jax.profiler.TraceAnnotation`` on the profiler's clock whenever a
+  session is open and, for the per-trial phases in ``SPAN_FIELDS`` and
+  the ``trial`` span around ``train_fn``, the ``*_ms`` totals above plus
+  ``[name, t_start, t_end]`` in epoch seconds on the trial's record;
+- **heartbeat freshness** (``on_heartbeat``) — ``hb_beats``, ``hb_fresh``
+  and ``metric_lag_steps``: whether a beat carried a metric newer than
+  the last one shipped, and how far behind the training loop it was.
+
 Shipping is piggybacked on the existing heartbeat METRIC payload
 (``rstats`` field) — no new socket, no new verb. ``snapshot_delta()``
 returns only the fields that changed since the last successful ship
@@ -63,6 +72,70 @@ PROGRESS_KEYS = ("trial", "steps", "ttfm_ms", "cadence_ms", "trials_done")
 #: plain .get(k) would read a requeued (deleted) key as already-None and
 #: silently drop the re-send.
 _NEVER_SHIPPED = object()
+
+
+#: Per-trial phases `span` records: name -> (the record it lands on, the
+#: ``*_ms`` field its duration adds to, the count it bumps). The goodput
+#: fold reads the fields; the names are telemetry/vocab.py's SPAN_NAMES.
+SPAN_FIELDS = {
+    "init": ("compile", "init_ms", None),
+    "trace": ("compile", "trace_ms", None),
+    "compile": ("compile", "compile_ms", None),
+    "fork_stage": ("compile", "fork_load_ms", None),
+    "ckpt_save": ("ckpt", "save_ms", "saves"),
+    "ckpt_restore": ("ckpt", "restore_ms", "restores"),
+}
+#: The span around ``train_fn``: the cause of the phases above, which
+#: share its trial id. It rides first in the ``spans`` of every record
+#: the trial ships.
+TRIAL_SPAN = "trial"
+
+
+class span:
+    """Context manager: one named interval of the runner's host side.
+
+    Always opens a ``jax.profiler.TraceAnnotation(name, **attrs)``, which
+    writes the interval into the profiler's own trace, on the profiler's
+    clock, whenever a session is open (an operator's ``/profilez``, a
+    benchmark's traced run) and costs a flag test otherwise. In a process
+    that has not imported ``jax`` (drivers, orchestrators) it opens
+    nothing: the helper neither imports ``jax`` nor touches a backend.
+
+    With ``stats`` and a name in ``SPAN_FIELDS`` (or ``TRIAL_SPAN``) the
+    exit also hands ``[name, t_start, t_end]`` in epoch seconds to
+    ``RunnerStats.note_span``. Every other name (the per-step
+    ``place_batch`` / ``report``) is annotation only: no record, no lock,
+    no clock read."""
+
+    __slots__ = ("name", "t_start", "t_end", "_stats", "_annotation", "_c0")
+
+    def __init__(self, name: str, stats: Optional["RunnerStats"] = None,
+                 **attrs: Any):
+        self.name = name
+        self.t_start = self.t_end = None
+        self._stats = stats if stats is not None and (
+            name in SPAN_FIELDS or name == TRIAL_SPAN) else None
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        self._annotation = None if profiler is None \
+            else profiler.TraceAnnotation(name, **attrs)
+
+    def __enter__(self) -> "span":
+        if self._stats is not None:
+            self.t_start = time.time()
+            self._c0 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        if self._stats is not None:
+            # The duration from the monotonic clock, laid from t_start:
+            # a wall-clock step cannot turn a span inside out.
+            self.t_end = self.t_start + (time.perf_counter() - self._c0)
+            self._stats.note_span(self.name, self.t_start, self.t_end)
 
 
 def _rss_mb() -> Optional[float]:
@@ -144,6 +217,17 @@ class RunnerStats:
         self._ckpt: Dict[str, Any] = {}  # guarded-by: _lock
         self._ckpt_final = False  # guarded-by: _lock
         self._ckpt_events: List[Dict[str, Any]] = []  # guarded-by: _lock
+        # The CURRENT trial's ``trial`` span (fn_enter, fn_exit), put
+        # first in the ``spans`` of each record the trial ships.
+        self._trial_span: Optional[List[Any]] = None  # guarded-by: _lock
+        # Heartbeat freshness: cumulative beats sent while a trial ran,
+        # those that carried a newer (metric, step) than the last one
+        # shipped, the step last shipped for the current trial, and how
+        # many steps the last beat lagged the training loop.
+        self._hb_beats = 0  # guarded-by: _lock
+        self._hb_fresh = 0  # guarded-by: _lock
+        self._hb_shipped_step: Optional[int] = None  # guarded-by: _lock
+        self._metric_lag_steps: Optional[int] = None  # guarded-by: _lock
         # Cumulative warm-slot / compilation-cache counters for THIS
         # runner (train/warm.py routes them here through the trial scope).
         self._counters: Dict[str, int] = {}  # guarded-by: _lock
@@ -163,6 +247,8 @@ class RunnerStats:
             self._ttfm_accounted = None
             self._ckpt = {}
             self._ckpt_final = False
+            self._trial_span = None
+            self._hb_shipped_step = None
 
     def trial_end(self, trial_id: Optional[str] = None) -> None:
         with self._lock:
@@ -195,6 +281,7 @@ class RunnerStats:
         for k in ("init_ms", "trace_ms", "compile_ms"):
             if k in record:
                 record[k] = round(record[k], 1)
+        self._with_trial_span_locked(record)
         self._compile_events.append(record)
         self._compile_final = True
 
@@ -207,8 +294,40 @@ class RunnerStats:
         for k in ("save_ms", "restore_ms"):
             if k in record:
                 record[k] = round(record[k], 1)
+        self._with_trial_span_locked(record)
         self._ckpt_events.append(record)
         self._ckpt_final = True
+
+    # locked-by: _lock
+    def _with_trial_span_locked(self, record: Dict[str, Any]) -> None:
+        spans = list(record.get("spans") or ())
+        if self._trial_span is not None:
+            spans.insert(0, self._trial_span)
+        if spans:
+            record["spans"] = spans
+
+    def note_span(self, name: str, t_start: float, t_end: float) -> None:
+        """One finished `span` of the current trial, in epoch seconds: its
+        duration joins the ``*_ms`` field (and count) ``SPAN_FIELDS``
+        names, exactly as ``note_compile`` / ``note_ckpt`` accumulate
+        them, and ``[name, t_start, t_end]`` joins the record's
+        ``spans``."""
+        entry = [name, round(t_start, 6), round(t_end, 6)]
+        if name == TRIAL_SPAN:
+            with self._lock:
+                self._trial_span = entry
+            return
+        kind, field, count = SPAN_FIELDS[name]
+        fields = {field: (t_end - t_start) * 1e3}
+        if count is not None:
+            fields[count] = 1
+        if kind == "compile":
+            self.note_compile(**fields)
+        else:
+            self.note_ckpt(**fields)
+        with self._lock:
+            record = self._compile if kind == "compile" else self._ckpt
+            record.setdefault("spans", []).append(entry)
 
     def note_ckpt(self, **fields: Any) -> None:
         """Merge checkpoint I/O attribution for the current trial.
@@ -267,6 +386,28 @@ class RunnerStats:
             self._hb_rtt_ms = rtt_ms if self._hb_rtt_ms is None else \
                 (1 - _EWMA_ALPHA) * self._hb_rtt_ms + _EWMA_ALPHA * rtt_ms
 
+    def on_heartbeat(self, shipped_step: Optional[int],
+                     newest_step: Optional[int]) -> None:
+        """One heartbeat while a trial runs: ``shipped_step`` is the step
+        of the (metric, step) pair the beat carries (None: it carries no
+        metric), ``newest_step`` the newest step the loop has broadcast.
+        Two integer compares on the heartbeat thread."""
+        with self._lock:
+            if self._trial_id is None:
+                return
+            self._hb_beats += 1
+            if shipped_step is not None and (
+                    self._hb_shipped_step is None
+                    or shipped_step > self._hb_shipped_step):
+                self._hb_fresh += 1
+                self._hb_shipped_step = shipped_step
+            if newest_step is not None:
+                # Nothing shipped yet: every broadcast since the trial
+                # began is still waiting.
+                self._metric_lag_steps = self._steps \
+                    if self._hb_shipped_step is None \
+                    else newest_step - self._hb_shipped_step
+
     def note_profile_skipped(self, trial_id: Optional[str]) -> None:
         """The profiler lock was contended: this trial runs untraced.
         Shipped to the driver so the missing TensorBoard trace is
@@ -316,6 +457,9 @@ class RunnerStats:
                 else round(self._hb_rtt_ms, 2),
                 "rss_mb": self._rss_mb,
                 "dev_mem_mb": self._dev_mem_mb,
+                "hb_beats": self._hb_beats or None,
+                "hb_fresh": self._hb_fresh if self._hb_beats else None,
+                "metric_lag_steps": self._metric_lag_steps,
             }
             snap.update(self._counters)
         return {k: v for k, v in snap.items()

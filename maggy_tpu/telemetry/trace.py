@@ -348,14 +348,28 @@ def _trial_slices(trial_id: str, evs: List[Dict[str, Any]], us,
                         "ts": us(a), "dur": max(1, us(b) - us(a)),
                         "pid": _pid(partition), "tid": tid,
                         "args": {"trial": trial_id}})
-        # Runner-attributed ttfm breakdown: the compiled event carries
-        # DURATIONS (runner clock), so the sub-slices are laid out
-        # sequentially from the attempt's running edge — driver/runner
-        # clock skew shifts the anchor, never the widths.
         compiled = next((e for e in attempt
                          if e.get("phase") == "compiled"), None)
+        # The runner's recorded spans (telemetry.runnerstats.span), each
+        # drawn where it happened on the runner's clock: train_fn, and
+        # inside it init, trace, compile and the checkpoint phases. The
+        # ``trial`` span rides in both records; it is drawn once.
+        recorded = {tuple(sp) for e in attempt
+                    if e.get("phase") in ("compiled", "ckpt_saved")
+                    for sp in e.get("spans") or ()}
+        for name, s0, s1 in sorted(recorded, key=lambda sp: sp[1]):
+            out.append({"name": "train_fn" if name == "trial" else name,
+                        "cat": "span", "ph": "X", "ts": us(s0),
+                        "dur": max(1, us(s1) - us(s0)),
+                        "pid": _pid(partition), "tid": tid,
+                        "args": {"trial": trial_id, "warm": bool(
+                            (compiled or {}).get("warm"))}})
+        # A journal from before the spans carries DURATIONS only: the
+        # ttfm breakdown is then laid out sequentially from the
+        # attempt's running edge — driver/runner clock skew shifts the
+        # anchor, never the widths.
         anchor = marks.get("running")
-        if compiled is not None and anchor is not None:
+        if compiled is not None and anchor is not None and not recorded:
             cursor = us(anchor)
             warm_tag = "warm" if compiled.get("warm") else "cold"
             for name, key in _COMPILE_SLICES:

@@ -230,6 +230,18 @@ GOODPUT_BUCKETS = (
     "unaccounted",    # residual the accounting could not attribute
 )
 
+#: Names `telemetry.runnerstats.span` is opened with. SPAN_NAMES are the
+#: per-trial phases it also records as ``[name, t_start, t_end]`` in the
+#: ``spans`` of the trial's ``compiled`` / ``ckpt_saved`` record (``trial``
+#: is the span around ``train_fn`` that the others lie in; ``fork_stage``
+#: precedes it). ANNOTATION_NAMES are the per-step host annotations that
+#: exist only in a profiler trace.
+SPAN_NAMES = (
+    "trial", "init", "trace", "compile", "fork_stage", "ckpt_save",
+    "ckpt_restore",
+)
+ANNOTATION_NAMES = ("place_batch", "train_step", "report")
+
 #: Health-engine event fields (``ev: "health"``).
 HEALTH_STATUSES = frozenset({"raised", "cleared", "started", "error"})
 HEALTH_CHECKS = frozenset({"engine", "straggler", "hb_rtt", "hang"})
@@ -243,7 +255,7 @@ ALL_REASONS = REQUEUE_REASONS | LEASE_END_REASONS | PROFILE_REASONS
 
 __all__ = [
     "SPAN_PHASES", "EVENT_KINDS", "REQUEUE_REASONS", "PROFILE_REASONS",
-    "GOODPUT_BUCKETS",
+    "GOODPUT_BUCKETS", "SPAN_NAMES", "ANNOTATION_NAMES",
     "EXPERIMENT_PHASES", "RUNNER_PHASES", "WORKER_PHASES",
     "FLEET_PHASES", "FLEET_EXPERIMENT_PHASES", "LEASE_PHASES",
     "LEASE_END_REASONS", "AGENT_PHASES", "CHAOS_KINDS",

@@ -281,7 +281,7 @@ def build_step_fn(
 
     train_kwargs = train_kwargs or {}
 
-    def step(variables, opt_state, batch):
+    def train_step(variables, opt_state, batch):
         params = variables["params"]
         aux = {k: v for k, v in variables.items() if k != "params"}
 
@@ -297,19 +297,23 @@ def build_step_fn(
                 loss = loss + jnp.sum(leaf)
             return loss, updates
 
-        (loss, new_aux), grads = jax.value_and_grad(
-            compute_loss, has_aux=True)(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
+        with jax.named_scope("loss_and_grad"):
+            (loss, new_aux), grads = jax.value_and_grad(
+                compute_loss, has_aux=True)(params)
         import optax
 
-        params = optax.apply_updates(params, updates)
-        opt_state = apply_zero_sharding(
-            opt_state, mesh, strategy,
-            lambda x, sh: jax.lax.with_sharding_constraint(x, sh))
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            opt_state = apply_zero_sharding(
+                opt_state, mesh, strategy,
+                lambda x, sh: jax.lax.with_sharding_constraint(x, sh))
         return {"params": params, **new_aux} if has_aux_collections else \
             {"params": params, **aux}, opt_state, loss
 
-    return step
+    # The function's name is the program's: ``jit_train_step`` in the
+    # profiler's ``XLA Modules`` (the warm re-init is ``jit_reinit``).
+    return train_step
 
 
 def make_train_step(
@@ -489,33 +493,33 @@ class Trainer:
             self._step = build()
         self._init_ikey = None
         self._active_step = None
+        self._step_num = 0  # steps since init(), for the step annotation
         self.variables = None
         self.opt_state = None
         self.shardings = None
         _warm.register_trainer(self)
 
     def init(self, rng, example_inputs, init_kwargs=None):
-        import time as _time
-
         from maggy_tpu.train import warm as _warm
 
-        t0 = _time.perf_counter()
         self._active_step = None
-        if self._slot is not None:
-            allow = self._warm_enabled and not _warm.fresh_state_only()
-            (self.variables, self.opt_state, self.shardings, hit,
-             self._init_ikey) = _init_state_via_slot(
-                self._slot, self.model, self.tx, rng, example_inputs,
-                self.mesh, self.strategy, init_kwargs,
-                allow_buffers=allow)
-            _warm.record_warm_event(hit)
-            _warm.note_compile(warm=bool(hit))
-        else:
-            self.variables, self.opt_state, self.shardings = init_train_state(
-                self.model, self.tx, rng, example_inputs, self.mesh,
-                self.strategy, init_kwargs=init_kwargs)
-            _warm.note_compile(warm=False)
-        _warm.note_compile(init_ms=(_time.perf_counter() - t0) * 1e3)
+        self._step_num = 0
+        with _warm.span("init"):
+            if self._slot is not None:
+                allow = self._warm_enabled and not _warm.fresh_state_only()
+                (self.variables, self.opt_state, self.shardings, hit,
+                 self._init_ikey) = _init_state_via_slot(
+                    self._slot, self.model, self.tx, rng, example_inputs,
+                    self.mesh, self.strategy, init_kwargs,
+                    allow_buffers=allow)
+                _warm.record_warm_event(hit)
+                _warm.note_compile(warm=bool(hit))
+            else:
+                self.variables, self.opt_state, self.shardings = \
+                    init_train_state(
+                        self.model, self.tx, rng, example_inputs, self.mesh,
+                        self.strategy, init_kwargs=init_kwargs)
+                _warm.note_compile(warm=False)
         if self._step_shared and not _has_injected_hparams(self.opt_state):
             import warnings
 
@@ -556,7 +560,8 @@ class Trainer:
             sh = cached_batch_sharding(self.mesh, np.shape(x))
             return jax.device_put(jnp.asarray(x), sh)
 
-        return jax.tree_util.tree_map(put, batch)
+        with jax.profiler.TraceAnnotation("place_batch"):
+            return jax.tree_util.tree_map(put, batch)
 
     def _resolve_step(self, batch):
         """Warm AOT path: per-shape compiled executables cached on the
@@ -572,8 +577,6 @@ class Trainer:
         key = (self._init_ikey, _warm.shape_key(batch))
         fn = slot.compiled_step(key)
         if fn is None:
-            import time as _time
-
             # One compile per (slot, shape), even when N runner threads'
             # first trials race the same program — the losers wait on the
             # winner's executable instead of compiling their own.
@@ -581,22 +584,21 @@ class Trainer:
                 fn = slot.compiled_step(key)
                 if fn is None:
                     try:
-                        t0 = _time.perf_counter()
-                        lowered = self._step.lower(
-                            self.variables, self.opt_state, batch)
-                        t1 = _time.perf_counter()
-                        fn = lowered.compile()
-                        t2 = _time.perf_counter()
+                        with _warm.span("trace"):
+                            lowered = self._step.lower(
+                                self.variables, self.opt_state, batch)
+                        with _warm.span("compile"):
+                            fn = lowered.compile()
                     except Exception:  # noqa: BLE001 - AOT is an optimization
                         slot.aot_ok = False
                         return self._step
-                    _warm.note_compile(trace_ms=(t1 - t0) * 1e3,
-                                       compile_ms=(t2 - t1) * 1e3)
                     slot.store_compiled(key, fn)
         return fn
 
     def step(self, batch: Dict[str, Any]) -> float:
-        with self.mesh:
+        with jax.profiler.StepTraceAnnotation(
+                "train_step", step_num=self._step_num), self.mesh:
+            self._step_num += 1
             # Steady-state fast path: the batch shape is constant within
             # a trial, so reuse the last resolved executable without
             # recomputing its shape key (pure-Python per-step overhead on
@@ -608,6 +610,13 @@ class Trainer:
             if fn is None:
                 fn = self._resolve_step(batch)
                 self._active_step = fn
+                # The trial's first dispatch, stamped once (first write
+                # wins): until now the chip had nothing of it queued.
+                import time as _time
+
+                from maggy_tpu.train import warm as _warm
+
+                _warm.note_compile(first_dispatch=round(_time.time(), 6))
             try:
                 out = fn(self.variables, self.opt_state, batch)
             except TypeError:

@@ -157,38 +157,36 @@ class VmapTrainer:
         entry holds the previous block's retired stacked buffers, the
         broadcast-write DONATES them — fresh values into the retired
         block's memory, lane axis included."""
-        import time as _time
-
         import jax
         import jax.numpy as jnp
 
-        t0 = _time.perf_counter()
-        self._rng = rng
-        params, opt0, shardings, hit, ikey = self._scalar_init(
-            rng, example_inputs, init_kwargs)
-        self._init_ikey = ikey
-        self._ventry = self._slot.vmap_entry(("vmap", ikey), self.k)
-        lane_opts = [_warm.rebind_hyperparams(opt0, hp)
-                     for hp in self.lane_hparams]
-        retired = self._ventry.take_retired() if self._warm_enabled else None
-        if retired is not None and not _warm.fresh_state_only():
-            old_vars, old_opt, old_family = retired
-            try:
-                stacked = self._broadcast_reinit(params, lane_opts,
-                                                 old_vars, old_opt)
-            except Exception:  # noqa: BLE001 - donation is an optimization
-                stacked = None
-            if stacked is not None:
-                self.variables, self.opt_state = stacked
-        if self.variables is None:
-            self.variables = jax.tree_util.tree_map(
-                lambda x: jnp.stack([x] * self.k), params)
-            self.opt_state = stack_trees(lane_opts)
-        self._mask = [False] * self.k
-        self._vstep = None
+        with _warm.span("init"):
+            self._rng = rng
+            params, opt0, shardings, hit, ikey = self._scalar_init(
+                rng, example_inputs, init_kwargs)
+            self._init_ikey = ikey
+            self._ventry = self._slot.vmap_entry(("vmap", ikey), self.k)
+            lane_opts = [_warm.rebind_hyperparams(opt0, hp)
+                         for hp in self.lane_hparams]
+            retired = self._ventry.take_retired() \
+                if self._warm_enabled else None
+            if retired is not None and not _warm.fresh_state_only():
+                old_vars, old_opt, old_family = retired
+                try:
+                    stacked = self._broadcast_reinit(params, lane_opts,
+                                                     old_vars, old_opt)
+                except Exception:  # noqa: BLE001 - donation is an optimization
+                    stacked = None
+                if stacked is not None:
+                    self.variables, self.opt_state = stacked
+            if self.variables is None:
+                self.variables = jax.tree_util.tree_map(
+                    lambda x: jnp.stack([x] * self.k), params)
+                self.opt_state = stack_trees(lane_opts)
+            self._mask = [False] * self.k
+            self._vstep = None
         _warm.record_warm_event(bool(hit))
-        _warm.note_compile(warm=bool(hit), vmap_lanes=self.k,
-                           init_ms=(_time.perf_counter() - t0) * 1e3)
+        _warm.note_compile(warm=bool(hit), vmap_lanes=self.k)
         del shardings
         return self
 
@@ -218,8 +216,6 @@ class VmapTrainer:
         slot's vectorized entry: ``jax.vmap`` of the exact scalar step
         closure over the stacked (variables, opt_state) axis with the
         batch broadcast — every block of the family reuses it."""
-        import time as _time
-
         import jax
 
         bkey = _warm.shape_key(batch)
@@ -238,13 +234,11 @@ class VmapTrainer:
                             strategy=self.strategy)
         vstep = jax.jit(jax.vmap(raw, in_axes=(0, 0, None)),
                         donate_argnums=(0, 1))
-        t0 = _time.perf_counter()
         try:
-            lowered = vstep.lower(self.variables, self.opt_state, batch)
-            t1 = _time.perf_counter()
-            fn = lowered.compile()
-            _warm.note_compile(trace_ms=(t1 - t0) * 1e3,
-                               compile_ms=(_time.perf_counter() - t1) * 1e3)
+            with _warm.span("trace"):
+                lowered = vstep.lower(self.variables, self.opt_state, batch)
+            with _warm.span("compile"):
+                fn = lowered.compile()
         except Exception:  # noqa: BLE001 - AOT is an optimization
             fn = vstep
         stored = (bkey, fn)

@@ -156,15 +156,15 @@ def note_compile(**fields: Any) -> None:
         stats.note_compile(**fields)
 
 
-def note_ckpt(**fields: Any) -> None:
-    """Record checkpoint I/O telemetry for the current trial (merged into
-    its RunnerStats ``ckpt`` record; ``*_ms`` and ``saves``/``restores``
-    accumulate). No-op outside a trial scope — library users running
-    checkpointing outside an experiment pay nothing."""
+def span(name: str, **attrs: Any):
+    """`telemetry.runnerstats.span` for the current trial: the phase is
+    annotated in the profiler's trace and, inside a trial scope, timed
+    into its RunnerStats record (``*_ms`` and ``spans``)."""
+    from maggy_tpu.telemetry.runnerstats import span as _span
+
     scope = current_scope()
-    stats = scope.stats if scope is not None else None
-    if stats is not None:
-        stats.note_ckpt(**fields)
+    return _span(name, stats=scope.stats if scope is not None else None,
+                 **attrs)
 
 
 # ----------------------------------------------------------------- counters

@@ -26,7 +26,8 @@ class TestNativeCallbacks:
         cb = EpochEnd(rep, metric="acc")
         cb({"acc": 0.8}, step=3)
         assert rep.get_data() == {"metric": 0.8, "step": 3, "logs": [],
-                                  "trial_id": "t", "span": None}
+                                  "trial_id": "t", "span": None,
+                                  "newest_step": 3}
 
     def test_missing_metric_is_skipped(self):
         rep = Reporter()

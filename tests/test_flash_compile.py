@@ -51,3 +51,88 @@ def test_forward_and_both_backward_kernels_compile(
     # Forward, dK/dV and dQ: three Mosaic kernels in the executable.
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == 3
+
+
+def test_the_three_kernels_carry_their_names(v5e_device):
+    """A trace names a kernel after its HLO instruction, which takes the
+    `pallas_call`'s ``name=``: the benchmark's readers find the forward,
+    dK/dV and dQ kernels by these names and by nothing positional."""
+    q = jax.ShapeDtypeStruct((2, 128, 12, 64), jnp.bfloat16,
+                             sharding=v5e_device)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, None, False, 128, 128, False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    forward = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, None, False, 128, 128, False)).lower(q, q, q).compile()
+    backward = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, q, q).compile()
+
+    assert _kernel_names(forward.as_text()) == ["flash_fwd"]
+    assert _kernel_names(backward.as_text()) == [
+        "flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]
+
+
+def _kernel_names(text):
+    """The names the Mosaic kernels of a compiled program carry in their
+    HLO instructions' names (autodiff wraps them: ``%jvp_flash_fwd_.1``)."""
+    import re
+
+    return sorted(
+        re.search(r"flash_[a-z]+(?:_[a-z]+)*", line.split(" = ")[0]).group(0)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+
+
+def test_a_bert_width_step_carries_the_scope_names(v5e_device, monkeypatch):
+    """`train_step` at BERT-base width (two layers): the program is named
+    after the step function, its kernels after their `pallas_call`s, and the
+    ``op_name`` of its operations holds the scopes ``attention``, ``mlp``,
+    ``loss_and_grad`` and ``optimizer``."""
+    import re
+
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from maggy_tpu.models import BertConfig, BertEncoder
+    from maggy_tpu.ops import attention
+    from maggy_tpu.train import cross_entropy_loss
+    from maggy_tpu.train.trainer import make_train_step
+
+    # Off the TPU dispatch picks XLA's attention; a rehearsal of the chip's
+    # program steers it to the kernels (rehearsal only).
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    device, = v5e_device.device_set
+    mesh = Mesh([device], ("data",))
+    here = NamedSharding(mesh, P())
+    model = BertEncoder(BertConfig(num_layers=2, dropout=0.0))
+    tx = optax.adamw(1e-4)
+    B, S = 2, 128
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=here),
+            tree)
+
+    tokens = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=here)
+    mask = jax.ShapeDtypeStruct((B, S), jnp.bool_, sharding=here)
+    variables = jax.eval_shape(
+        lambda: jax.tree_util.tree_map(
+            lambda x: getattr(x, "value", x),
+            model.init(jax.random.key(0), jnp.zeros((B, S), jnp.int32)),
+            is_leaf=lambda x: hasattr(x, "value")))
+    opt_state = jax.eval_shape(tx.init, variables["params"])
+    batch = {"inputs": (tokens, mask),
+             "labels": jax.ShapeDtypeStruct((B,), jnp.int32, sharding=here)}
+    step = make_train_step(
+        model, tx, lambda logits, b: cross_entropy_loss(logits, b["labels"]),
+        mesh)
+    text = step.lower(abstract(variables), abstract(opt_state),
+                      batch).compile().as_text()
+    assert text.startswith("HloModule jit_train_step")
+    assert _kernel_names(text) == (  # two layers x forward, dK/dV, dQ
+        ["flash_bwd_dkdv"] * 2 + ["flash_bwd_dq"] * 2 + ["flash_fwd"] * 2)
+    scopes = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        scopes.update(op_name.split("/"))
+    assert {"attention", "mlp", "loss_and_grad", "optimizer"} <= scopes

@@ -453,10 +453,12 @@ class TestCkptChannel:
         stats.requeue_delta(delta)  # the ship failed; put them back
         assert stats.snapshot_delta()["ckpt_events"] == delta["ckpt_events"]
 
-    def test_warm_note_ckpt_noop_outside_trial_scope(self):
+    def test_warm_ckpt_span_noop_outside_trial_scope(self):
         from maggy_tpu.train import warm
 
-        warm.note_ckpt(save_ms=5.0, saves=1)  # must not raise
+        with warm.span("ckpt_save") as sp:  # must not raise
+            pass
+        assert sp.t_start is None  # no trial scope: nothing recorded
 
 
 # ------------------------------------------------------------ surfaces
