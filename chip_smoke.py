@@ -65,6 +65,8 @@ FLASH_SHAPES = (
     # name, B, Sq, H, Hkv, D, causal, ragged key mask
     ("bert_b32_s128_h12_d64_masked", 32, 128, 12, 12, 64, False, True),
     ("gqa_b1_s2048_h32_kv8_d128_causal", 1, 2048, 32, 8, 128, True, False),
+    # The class of the benchmark's `bert-base.steady-s512` cell.
+    ("bert_b64_s512_h12_d64_masked", 64, 512, 12, 12, 64, False, True),
 )
 
 
@@ -357,19 +359,26 @@ def run_sweep(model_cfg, pool: str, num_workers, base_dir: str,
 # ----------------------------------------------------- flash vs the reference
 
 
-def check_flash_attention() -> dict:
-    """`flash_attention` (compiled, never interpreted) against
-    `attention_reference` at full float32 matmul precision: forward and the
-    gradients for q, k and v, at the sweep's own shape and at one causal GQA
-    shape. Errors are relative to the reference tensor's largest magnitude."""
+def check_flash_attention(shapes=FLASH_SHAPES) -> dict:
+    """The flash kernels (compiled on a TPU, never interpreted there)
+    against `attention_reference` at full float32 matmul precision: forward
+    and the gradients for q, k and v, at the sweep's own shape, at one
+    causal GQA shape and at the benchmark cell's. Every shape runs twice:
+    at the tiles `tile_plan` chooses from it (what `multi_head_attention`
+    runs) and at an explicit 128 x 128 (what ring attention, Ulysses and
+    the tests pass). Errors are relative to the reference tensor's largest
+    magnitude. ``{shape: {"plan": ..., "planned" | "explicit_128":
+    {out, dq, dk, dv}}}``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from maggy_tpu.ops.attention import attention_reference, flash_attention
+    from maggy_tpu.ops.attention import (attention_reference, flash_attention,
+                                         multi_head_attention, tile_plan)
 
     report = {}
-    for name, B, S, H, Hkv, D, causal, masked in FLASH_SHAPES:
+    interpret = jax.default_backend() != "tpu"  # tests/test_chip_smoke.py
+    for name, B, S, H, Hkv, D, causal, masked in shapes:
         rng = np.random.default_rng(S)
         q = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.bfloat16)
         k, v = (jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.bfloat16)
@@ -379,32 +388,45 @@ def check_flash_attention() -> dict:
         if masked:
             keep = jnp.asarray(np.arange(S)[None, :]
                                < rng.integers(S // 2, S + 1, size=(B, 1)))
+        mask4 = None if keep is None else keep[:, None, None, :]
 
-        def flash(q, k, v):
-            out = flash_attention(q, k, v, keep, causal, 128, 128, False)
-            return jnp.sum(out.astype(jnp.float32) * w), out
+        def planned(q, k, v):  # the public entry, held to the kernels
+            return multi_head_attention(q, k, v, causal=causal, mask=mask4,
+                                        force="flash")
+
+        def explicit_128(q, k, v):
+            return flash_attention(q, k, v, keep, causal, 128, 128, interpret)
 
         def reference(q, k, v):
             with jax.default_matmul_precision("highest"):
-                out = attention_reference(
-                    q, k, v, causal=causal,
-                    mask=None if keep is None else keep[:, None, None, :])
-            return jnp.sum(out.astype(jnp.float32) * w), out
+                return attention_reference(q, k, v, causal=causal, mask=mask4)
 
-        results = []
-        for fn in (flash, reference):
+        def run(fn):
+            def loss(q, k, v):
+                out = fn(q, k, v)
+                return jnp.sum(out.astype(jnp.float32) * w), out
+
             (_, out), grads = jax.jit(jax.value_and_grad(
-                fn, (0, 1, 2), has_aux=True))(q, k, v)
-            results.append([np.asarray(x, np.float32) for x in (out, *grads)])
-        errs = {}
-        for label, got, want in zip(("out", "dq", "dk", "dv"), *results):
-            require(np.isfinite(got).all(), "flash {} at {} is not finite"
-                    .format(label, name))
-            errs[label] = float(np.abs(got - want).max() / np.abs(want).max())
-        report[name] = {k: round(e, 5) for k, e in errs.items()}
-        bad = {k: e for k, e in errs.items() if e > FLASH_TOL}
-        require(not bad, "flash_attention disagrees with the reference at {} "
-                "beyond {:.4f}: {}".format(name, FLASH_TOL, bad))
+                loss, (0, 1, 2), has_aux=True))(q, k, v)
+            return [np.asarray(x, np.float32) for x in (out, *grads)]
+
+        want = run(reference)
+        report[name] = {"plan": tile_plan(S, S, D, H, Hkv, 2, causal,
+                                          masked).describe()}
+        for tiles, fn in (("planned", planned),
+                          ("explicit_128", explicit_128)):
+            errs = {}
+            for label, got, ref in zip(("out", "dq", "dk", "dv"), run(fn),
+                                       want):
+                require(np.isfinite(got).all(), "flash {} at {} ({}) is not "
+                        "finite".format(label, name, tiles))
+                errs[label] = float(np.abs(got - ref).max()
+                                    / np.abs(ref).max())
+            report[name][tiles] = {k: round(e, 5) for k, e in errs.items()}
+            bad = {k: e for k, e in errs.items() if e > FLASH_TOL}
+            require(not bad, "flash attention ({}) disagrees with the "
+                    "reference at {} beyond {:.4f}: {}".format(
+                        tiles, name, FLASH_TOL, bad))
     return report
 
 

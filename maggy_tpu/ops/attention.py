@@ -16,10 +16,19 @@ TPU-first hot-op design the BERT/Llama baseline configs need:
       mask shape, streamed as one [1, blk_k] tile per k-block.
     * Sq != Sk, with bottom-right-aligned causal masking (offset = Sk-Sq),
       e.g. decode windows / ring-attention shards.
-    * head_dim >= 64 (64 for BERT-base; Mosaic lane-pads D < 128 tiles).
+    * head_dim >= 64. At D 64 (BERT-base) two heads of an MHA layer share
+      a tile's 128 lanes (`lane_pack`), so every tile moves in whole rows.
   Per-row statistics (log-sum-exp, and delta in the backward) are stored
-  COMPACTLY as [B, G, rep, 1, Sq] fp32 with q-rows on the lane dimension
-  (one [1, blk_q] tile per q-block) — not broadcast to 128 lanes in HBM.
+  COMPACTLY as [B, G, rep, pack, Sq] fp32 with q-rows on the lane dimension
+  (one [pack, blk_q] tile per q-block) — not broadcast to 128 lanes in HBM.
+- `tile_plan`: what one grid step of each kernel covers, chosen from the
+  shape: the [blk_q, blk_k] score tile (multiples of 128 that divide the
+  lengths; the largest that a stated VMEM budget holds, smaller under a
+  causal mask as block skipping asks) and how many heads share the step.
+  At BERT's S 512 the tile is the whole sequence: the k axis has one step
+  and the softmax is taken once, with no running state.
+  `multi_head_attention` runs the plan; `flash_attention` and the ring's
+  `flash_block_fwd` / `flash_block_bwd` take explicit tiles.
 - `attention_reference`: straightforward XLA softmax attention (CPU tests,
   odd shapes).
 - `multi_head_attention`: public entry — dispatches to the kernel when
@@ -28,14 +37,20 @@ TPU-first hot-op design the BERT/Llama baseline configs need:
 Kernel layout follows the pallas guide (/opt/skills/guides/pallas_guide.md):
 the k-block grid dimension is sequential ("arbitrary") and carries the
 online-softmax state in persistent VMEM scratch, so VMEM holds one K/V tile
-at a time (long-context capable); (8,128)-aligned tiles,
-`preferred_element_type=float32` on every MXU dot.
+a head at a time (long-context capable). A step's tile is what the pipeline
+moves; the arithmetic walks it in row chunks (`_PASS_ELEMS`). Every MXU dot
+takes its operands in the input dtype (bf16 in, bf16 on the MXU; p and ds
+are rounded to it as the left operand of their products) and accumulates in
+float32 (`preferred_element_type`); scores, exp, the row statistics, the
+masks and every accumulator are float32.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Optional
+import threading
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -74,132 +89,495 @@ def attention_reference(q, k, v, causal: bool = True, mask=None):
 # --------------------------------------------------------------- head views
 
 
-def _grouped_q(x, Hkv):
+_LANES = 128
+
+
+def lane_pack(H: int, Hkv: int, D: int) -> int:
+    """Heads that share the 128 lanes of a tile: 2 for MHA at D <= 64 with
+    an even head count (BERT-base: D 64), else 1. [B,S,H,D] is [B,S,H/2,2D]
+    for free, and a [S, 2D] tile of it moves in whole 128-lane rows where a
+    [S, 64] one moves half-rows (a copy through the same blocks takes 2.6
+    times as long on a v5e: PERF.md section 6, PR 24). The kernels tell a
+    pair's two heads apart by zeroing the other's lanes in one operand of
+    each product, which costs the MXU nothing: a 64-deep contraction and a
+    64-wide result each left half of it idle."""
+    return 2 if H == Hkv and H % 2 == 0 and 2 * D <= _LANES else 1
+
+
+def _grouped_q(x, Hkv, pack=1):
     """[B,S,H,D] -> [B, Hkv, rep, S, D]: query heads grouped by the kv head
-    they share, so kv index maps can drop the rep axis (GQA without repeat)."""
+    they share, so kv index maps can drop the rep axis (GQA without repeat).
+    With ``pack`` heads to a tile's lanes: [B, H/pack, 1, S, pack*D]."""
     B, S, H, D = x.shape
+    x = x.reshape(B, S, H // pack, pack * D)
     rep = H // Hkv
-    return x.transpose(0, 2, 1, 3).reshape(B, Hkv, rep, S, D)
+    return x.transpose(0, 2, 1, 3).reshape(B, Hkv // pack, rep, S, pack * D)
 
 
-def _grouped_kv(x):
-    """[B,S,Hkv,D] -> [B, Hkv, S, D]."""
-    return x.transpose(0, 2, 1, 3)
+def _grouped_kv(x, pack=1):
+    """[B,S,Hkv,D] -> [B, Hkv, S, D] ([B, Hkv/pack, S, pack*D])."""
+    B, S, Hkv, D = x.shape
+    return x.reshape(B, S, Hkv // pack, pack * D).transpose(0, 2, 1, 3)
 
 
-def _ungroup_q(x):
+def _ungroup_q(x, pack=1):
     """[B, Hkv, rep, S, D] -> [B,S,H,D]."""
     B, G, R, S, D = x.shape
-    return x.reshape(B, G * R, S, D).transpose(0, 2, 1, 3)
+    return x.reshape(B, G * R, S, D).transpose(0, 2, 1, 3).reshape(
+        B, S, G * R * pack, D // pack)
 
 
-def _ungroup_kv(x):
+def _grouped_stats(x, Hkv, pack=1):
+    """[B,H,Sq] row statistics -> [B, Hkv/pack, rep, pack, Sq] fp32, one
+    [pack, Sq] tile of rows to a [Sq, pack*D] slab of heads."""
+    B, H, Sq = x.shape
+    return x.reshape(B, Hkv // pack, H // Hkv, pack, Sq).astype(jnp.float32)
+
+
+def _ungroup_kv(x, pack=1):
     """[B, Hkv, S, D] -> [B,S,Hkv,D]."""
-    return x.transpose(0, 2, 1, 3)
+    B, G, S, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B, S, G * pack, D // pack)
 
 
-def _causal_tile_mask(s, qi, kb, blk_q, blk_k, offset):
-    """Bottom-right-aligned causal mask for one [blk_q, blk_k] tile:
-    query row p attends key col c iff c <= p + offset (offset = Sk - Sq)."""
-    q_pos = qi * blk_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = kb * blk_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+def _causal_tile_mask(s, q0, k0, offset, q_axis=0):
+    """Bottom-right-aligned causal mask for a score tile whose first query
+    is ``q0`` and first key ``k0``, [queries, keys] or, with ``q_axis=1``,
+    its transpose: query row p attends key col c iff c <= p + offset
+    (offset = Sk - Sq)."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     return jnp.where(q_pos + offset >= k_pos, s, NEG_INF)
 
 
-def _apply_pad_mask(s, mask_ref):
-    """mask_ref: [1, blk_k] int32 keep-mask tile, broadcast over q rows."""
-    return jnp.where(mask_ref[0][None, :] != 0, s, NEG_INF)
+# ------------------------------------------------------------------ tile plan
+
+
+class KernelTiles(NamedTuple):
+    """What one grid step of one kernel covers: a [blk_q, blk_k] score tile
+    for each of ``heads`` query heads (heads of one batch row for MHA, the
+    query heads of one K/V group for GQA, which share the step's K/V tile)."""
+    blk_q: int
+    blk_k: int
+    heads: int = 1
+
+
+class FlashPlan(NamedTuple):
+    """The tiles of the three kernels, by the kernels' names."""
+    fwd: KernelTiles
+    dkdv: KernelTiles
+    dq: KernelTiles
+
+    @classmethod
+    def explicit(cls, blk_q: int, blk_k: int) -> "FlashPlan":
+        """The caller's own tiles in all three kernels, one head a step."""
+        return cls(*(KernelTiles(blk_q, blk_k),) * 3)
+
+    def describe(self) -> str:
+        """``fwd q512 k512 h4; dkdv q512 k512 h2; dq q512 k512 h4``."""
+        return "; ".join("{} q{} k{} h{}".format(name, *tiles)
+                         for name, tiles in zip(self._fields, self))
+
+
+#: What a step's buffers may take of VMEM as `step_vmem_bytes` reckons them:
+#: half of the 16 MiB a v5e kernel gets by default, because the reckoning
+#: leaves out what Mosaic adds (relayouts, spills, the temporaries of more
+#: than one pass where it overlaps unrolled passes).
+VMEM_BUDGET = 8 * 2 ** 20
+#: The cost of stepping the grid once (about 0.35 us: pipeline bookkeeping
+#: and the tiles' DMA descriptors), in the plan's unit: one score element
+#: of one head through one kernel (a 512 x 512 tile is 0.9-1.4 us). Read
+#: from the chip: PERF.md section 6, PR 24.
+STEP_COST = 64 * 1024
+#: Heads are added to a step until stepping costs less than this share of it.
+STEP_SHARE = 1 / 16
+
+#: Score elements one pass of a kernel's inner loop covers (128 vector
+#: registers of float32). A step's [blk_q, blk_k] tile is what the pipeline
+#: moves; the arithmetic walks it in row chunks of this size, so that a
+#: chunk's scores, probabilities and their rounded copy stay near the
+#: registers where a whole 512 x 512 tile (1 MiB each) streams through VMEM
+#: once per elementwise operation.
+_PASS_ELEMS = 128 * 1024
+#: Loops of at most this many rounds are unrolled where the kernel is
+#: traced (static slices, and the scheduler may overlap a chunk's MXU work
+#: with the next one's); longer ones are `fori_loop`s.
+_UNROLL = 8
+
+
+def _lane_pad(n: int) -> int:
+    return -(-n // _LANES) * _LANES
+
+
+def _pass_rows(rows: int, cols: int) -> int:
+    """Rows of a [rows, cols] tile one pass of a kernel's inner loop takes:
+    the most multiples of 128 that divide ``rows`` and keep the pass within
+    `_PASS_ELEMS`; 128 at least (a row of statistics is stored 128 lanes at
+    a time)."""
+    fit = [r for r in range(_LANES, rows + 1, _LANES)
+           if rows % r == 0 and r * cols <= _PASS_ELEMS]
+    return max(fit, default=_LANES)
+
+
+def step_vmem_bytes(kernel: str, tiles: KernelTiles, D: int, itemsize: int,
+                    kv_shared: bool, pack: int = 1) -> int:
+    """VMEM one grid step of ``kernel`` holds, from the shapes alone: every
+    operand and result tile twice (the pipeline's double buffer), the
+    float32 accumulators once, and the float32 scores, probabilities and
+    their rounded copies of one pass over one head (passes and heads run
+    one after the other). The last dimension pads to 128 lanes (``pack``
+    heads share them); a row of statistics or mask pads to 8 sublanes."""
+    blk_q, blk_k, heads = tiles
+    slabs = -(-heads // pack)  # [rows, lanes] tiles on the query side
+    q_tile = slabs * blk_q * _lane_pad(pack * D)
+    kv_tile = (1 if kv_shared else slabs) * blk_k * _lane_pad(pack * D)
+    rows = 2 * 8 * 4 * (slabs * blk_q + blk_k)  # lse (delta) and the mask
+    if kernel == "fwd":      # q, o | k, v | acc, m, l | s, p and p rounded
+        tiles_io = 2 * itemsize * (2 * q_tile + 2 * kv_tile) + rows
+        scratch = 4 * (q_tile + 2 * heads * blk_q * _LANES)
+        temps = _pass_rows(blk_q, blk_k) * blk_k * (2 * 4 + itemsize)
+    elif kernel == "dkdv":   # q, dO | k, v, dk, dv | dk, dv | s, dp, ds
+        tiles_io = 2 * itemsize * (2 * q_tile + 4 * kv_tile) + 2 * rows
+        scratch = 4 * 2 * kv_tile
+        temps = _pass_rows(blk_k, blk_q) * blk_q * (3 * 4 + 2 * itemsize)
+    elif kernel == "dq":     # q, dO, dq | k, v | dq | s, dp, ds
+        tiles_io = 2 * itemsize * (3 * q_tile + 2 * kv_tile) + 2 * rows
+        scratch = 4 * q_tile
+        temps = _pass_rows(blk_q, blk_k) * blk_k * (3 * 4 + itemsize)
+    else:
+        raise ValueError("no kernel {!r}".format(kernel))
+    return tiles_io + scratch + temps
+
+
+def _blocks_run(Sq: int, Sk: int, blk_q: int, blk_k: int, causal: bool) -> int:
+    """Tiles of one head that do work: all of them, or under a causal mask
+    those the diagonal reaches (the kernels skip the rest)."""
+    nq, nk = Sq // blk_q, Sk // blk_k
+    if not causal:
+        return nq * nk
+    offset = Sk - Sq
+    return sum(min(nk, max(0, -(-((qi + 1) * blk_q + offset) // blk_k)))
+               for qi in range(nq))
+
+
+def _divisors(n: int):
+    return [d for d in range(1, n + 1) if n % d == 0]
+
+
+def _tiles_of(length: int):
+    return [t for t in range(_LANES, length + 1, _LANES) if length % t == 0]
+
+
+@functools.lru_cache(maxsize=None)
+def tile_plan(Sq: int, Sk: int, D: int, H: int, Hkv: int, itemsize: int,
+              causal: bool, has_mask: bool) -> FlashPlan:
+    """The tiles each kernel takes at this shape. Pure: the shape decides.
+
+    For each kernel, among the tiles that are multiples of 128, divide the
+    lengths and fit ``VMEM_BUDGET``, the pair that costs one head least:
+    ``steps x (STEP_COST + blk_q x blk_k)``, over the steps that run. Without
+    a causal mask that is the largest pair (at S 512, D 64 the whole
+    sequence: the k axis has one step and the online softmax rescales once).
+    Under one, block skipping is coarser at large tiles, and the same sum
+    settles for a smaller pair as the sequence grows. Ties go to the larger
+    tile along the axis the kernel accumulates over. Then a step takes on
+    query heads (of one batch row, or of one K/V group, which share the K/V
+    tile) until stepping is under ``STEP_SHARE`` of it or VMEM is full.
+    ``has_mask`` moves nothing yet: a key-padding row is 4 KB a step."""
+    del has_mask
+    kv_shared = H != Hkv
+    pack = lane_pack(H, Hkv, D)
+    head_axis = H // Hkv if kv_shared else H
+    plan = []
+    for kernel in FlashPlan._fields:
+        def cost(pair):
+            blk_q, blk_k = pair
+            along = blk_q if kernel == "dkdv" else blk_k
+            return (_blocks_run(Sq, Sk, blk_q, blk_k, causal)
+                    * (STEP_COST + blk_q * blk_k), -along)
+
+        def fits(blk_q, blk_k, heads):
+            return step_vmem_bytes(kernel, KernelTiles(blk_q, blk_k, heads),
+                                   D, itemsize, kv_shared, pack) <= VMEM_BUDGET
+
+        pairs = [(bq, bk) for bq in _tiles_of(Sq) for bk in _tiles_of(Sk)
+                 if fits(bq, bk, pack)] or [(_LANES, _LANES)]
+        blk_q, blk_k = min(pairs, key=cost)
+        heads = pack
+        for h in _divisors(head_axis):
+            if h % pack:
+                continue
+            if not fits(blk_q, blk_k, h):
+                break
+            heads = h
+            if STEP_COST / h <= STEP_SHARE * blk_q * blk_k:
+                break
+        plan.append(KernelTiles(blk_q, blk_k, heads))
+    return FlashPlan(*plan)
+
+
+_tracing = threading.local()
+
+
+@contextlib.contextmanager
+def plans_traced():
+    """Collects, in order and once each, the `FlashPlan.describe()` of every
+    plan `multi_head_attention` chooses on this thread while the body runs
+    (a program's trace): what `Trainer` notes as ``flash_plan``."""
+    chosen, outer = [], getattr(_tracing, "chosen", None)
+    _tracing.chosen = chosen
+    try:
+        yield chosen
+    finally:
+        _tracing.chosen = outer
+
+
+def _remember(plan: FlashPlan) -> None:
+    chosen = getattr(_tracing, "chosen", None)
+    if chosen is not None:
+        said = plan.describe()
+        if said not in chosen:
+            chosen.append(said)
 
 
 # -------------------------------------------------------------- pallas kernel
+#
+# Every kernel sees its query-side tiles (q, o, dO, dQ) as [slabs, rows, D]
+# and their row statistics (lse, delta) as [slabs, pack, rows]: a slab is one
+# [rows, D] tile of ``pack`` heads side by side in its lanes (`lane_pack`; one
+# head where pack is 1), and a step's heads are its slabs' heads. K/V-side
+# tiles (k, v, dK, dV) carry the slab axis when the heads are heads of one
+# batch row (MHA) and none when they are the query heads of one K/V group
+# (GQA), which share one tile. Slabs, row chunks and a slab's heads run one
+# after the other, so the [rows, blk_k] temporaries are one head's.
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
-def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset):
-    """One (b, g, r, q-block, k-block) program: K/V stream through the
-    grid's innermost (sequential) dimension, so VMEM holds only one
-    [blk_k, D] tile of K and V at a time — sequence length is bounded by
-    HBM, not VMEM. Online-softmax state (acc, running max, running sum)
-    lives in VMEM scratch that persists across the k-block iterations of
-    each program group.
+def _dot(a, b, dims):
+    """MXU dot on the operands as they are, accumulated in float32."""
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
-    Refs: q [BLK_Q, D]; k/v [BLK_K, D]; (mask [1, BLK_K] int32);
-    o [BLK_Q, D]; lse [1, BLK_Q] (q-rows on lanes — compact, no 128x pad);
-    scratch acc [BLK_Q, D], m/l [BLK_Q, 128] fp32.
+
+def _each(n, body):
+    """``body(i)`` for i in range(n), in order."""
+    if n <= _UNROLL:
+        for i in range(n):
+            body(i)
+    else:
+        jax.lax.fori_loop(0, n, lambda i, _: body(i), None)
+
+
+def _chunk(c, rows):
+    """The c-th run of ``rows`` rows (or lanes) of a tile."""
+    if isinstance(c, int):
+        return slice(c * rows, (c + 1) * rows)
+    from jax.experimental import pallas as pl
+
+    return pl.ds(pl.multiple_of(c * rows, rows), rows)
+
+
+def _lanes_of(j, pack, x, other=0):
+    """``x`` in the lanes of the j-th packed head and ``other`` in the rest
+    (``x`` itself where a tile holds one head). With zeros: the operand
+    that keeps a product to head j."""
+    if pack == 1:
+        return x
+    width = x.shape[-1] // pack
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.where((lane >= j * width) & (lane < (j + 1) * width), x,
+                     jnp.full_like(x, other))
+
+
+def _as_row(col):
+    """A [rows, 1] column of statistics as the [1, rows] row it is stored
+    as: spread over 128 lanes and transposed on the XLU, which a v5e does
+    in a fifth of the time the squeeze-and-expand relayout takes it."""
+    return jnp.broadcast_to(col, (col.shape[0], _LANES)).T[:1]
+
+
+def _each_chunk(slabs, chunks, body):
+    """``body(h, c)`` for every row chunk c of every slab h of a step."""
+    _each(slabs, lambda h: _each(chunks, lambda c: body(h, c)))
+
+
+def _scores(q, k_blk, q0, k0, causal, sm_scale, offset, mask_ref):
+    """[rows, blk_k] float32 logits of the queries from ``q0`` on against the
+    keys from ``k0`` on: Q K^T on the operands as they are, scaled after the
+    dot (forward and backward alike, so that the backward's p is the
+    forward's), then the masks."""
+    s = _dot(q, k_blk, _NT) * sm_scale
+    if causal:
+        s = _causal_tile_mask(s, q0, k0, offset)
+    if mask_ref is not None:
+        s = jnp.where(mask_ref[...] != 0, s, NEG_INF)
+    return s
+
+
+def _kv(ref, h, rows=slice(None)):
+    """Index of ``rows`` of head ``h`` in a K/V-side tile: its leading axis
+    carries the step's heads, or it has none and the heads share it."""
+    return (h, rows) if len(ref.shape) == 3 else (rows,)
+
+
+def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass):
+    """One (b, head block, q-block, k-block) program: K/V stream through the
+    grid's innermost (sequential) dimension, so VMEM holds one [blk_k, D]
+    tile of K and V a head — sequence length is bounded by HBM, not VMEM.
+    Online-softmax state (acc, running max, running sum) lives in VMEM
+    scratch that persists across the k-block steps of each program group;
+    where one k-block is the whole row (``one_pass``) there is no state to
+    keep and no scratch: the softmax is taken once.
+
+    Refs: q [slabs, BLK_Q, D]; k/v [slabs, BLK_K, D] or, for a GQA group,
+    [BLK_K, D]; (mask [1, BLK_K] int32); o [slabs, BLK_Q, D]; lse
+    [slabs, pack, BLK_Q] (q-rows on lanes — compact, no 128x pad); scratch
+    acc [slabs, BLK_Q, D], m/l [slabs, pack, BLK_Q, 128] fp32.
     """
     from jax.experimental import pallas as pl
 
-    if has_mask:
-        q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-        mask_ref = None
+    q_ref, k_ref, v_ref = refs[:3]
+    mask_ref = refs[3] if has_mask else None
+    o_ref, lse_ref = refs[3 + has_mask:5 + has_mask]
 
-    blk_q = q_ref.shape[0]
-    blk_k = k_ref.shape[0]
+    slabs, blk_q, _ = q_ref.shape
+    blk_k = k_ref.shape[-2]
+    pack = lse_ref.shape[1]
     qi = pl.program_id(3)
     kb = pl.program_id(4)
+    rows = _pass_rows(blk_q, blk_k)
+
+    def scores(h, c, j):
+        return _scores(_lanes_of(j, pack, q_ref[h, _chunk(c, rows)]),
+                       k_ref[_kv(k_ref, h)], qi * blk_q + c * rows,
+                       kb * blk_k, causal, sm_scale, offset, mask_ref)
+
+    def values(h, j, p):
+        v_blk = _lanes_of(j, pack, v_ref[_kv(v_ref, h)])
+        return _dot(p.astype(v_blk.dtype), v_blk, _NN)
+
+    each_chunk = functools.partial(_each_chunk, slabs, blk_q // rows)
+
+    if one_pass:
+        def whole_rows(h, c):
+            r = _chunk(c, rows)
+            out = None
+            for j in range(pack):
+                s = scores(h, c, j)
+                m = jnp.max(s, axis=1, keepdims=True)
+                p = jnp.exp(s - m)
+                l = jnp.sum(p, axis=1, keepdims=True)  # >= 1: the row's max
+                o = values(h, j, p) / l  # zero in the other head's lanes
+                out = o if out is None else out + o
+                lse_ref[h, j:j + 1, r] = _as_row(m + jnp.log(l))
+            o_ref[h, r] = out.astype(o_ref.dtype)
+
+        each_chunk(whole_rows)
+        return
+
+    acc_ref, m_ref, l_ref = refs[5 + has_mask:]
     num_kb = pl.num_programs(4)
 
     @pl.when(kb == 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-    def contribute():
-        q = q_ref[:].astype(jnp.float32) * sm_scale
-        k_blk = k_ref[:].astype(jnp.float32)
-        v_blk = v_ref[:].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_tile_mask(s, qi, kb, blk_q, blk_k, offset)
-        if mask_ref is not None:
-            s = _apply_pad_mask(s, mask_ref)
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1)
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+    def contribute(h, c):
+        r = _chunk(c, rows)
+        acc = acc_ref[h, r]
+        for j in range(pack):
+            s = scores(h, c, j)
+            m_prev = m_ref[h, j, r][:, :1]
+            l_prev = l_ref[h, j, r][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            # Head j's lanes of the accumulator are rescaled and added to;
+            # the other head's keep what they hold.
+            acc = acc * _lanes_of(j, pack, jnp.broadcast_to(alpha, acc.shape),
+                                  1.0) + values(h, j, p)
+            m_ref[h, j, r] = jnp.broadcast_to(m_new, (rows, _LANES))
+            l_ref[h, j, r] = jnp.broadcast_to(l_new, (rows, _LANES))
+        acc_ref[h, r] = acc
 
     if causal:
         # Blocks entirely above the diagonal contribute nothing — skip the
         # compute (the tile fetch still happens; cheap next to the MXU work).
         @pl.when(kb * blk_k < (qi + 1) * blk_q + offset)
         def _():
-            contribute()
+            each_chunk(contribute)
     else:
-        contribute()
+        each_chunk(contribute)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
-        l_safe = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[:] = (acc_ref[:] / l_safe[:, None]).astype(o_ref.dtype)
-        lse = m_ref[:, 0] + jnp.log(l_safe)
-        lse_ref[:] = lse[None, :]
+        def finish(h, c):
+            r = _chunk(c, rows)
+            acc, out = acc_ref[h, r], None
+            for j in range(pack):
+                l_safe = jnp.maximum(l_ref[h, j, r][:, :1], 1e-30)
+                o = _lanes_of(j, pack, acc / l_safe)
+                out = o if out is None else out + o
+                lse_ref[h, j:j + 1, r] = _as_row(m_ref[h, j, r][:, :1]
+                                                 + jnp.log(l_safe))
+            o_ref[h, r] = out.astype(o_ref.dtype)
+
+        each_chunk(finish)
 
 
-def _flash_fwd(qg, kg, vg, mask, causal, blk_q, blk_k, interpret):
-    """qg: [B,G,R,Sq,D]; kg/vg: [B,G,Sk,D]; mask: [B,1,Sk] int32 or None.
-    Returns (out [B,G,R,Sq,D], lse [B,G,R,1,Sq] fp32)."""
+def _head_blocks(Sq, Sk, G, R, tiles, pack):
+    """How a step's heads lie in [B, G, R, ...]: (slabs over G, slabs over
+    R, the K/V block's leading dims), a slab being a [rows, lanes] tile of
+    ``pack`` heads. MHA (R == 1) blocks the G axis and K/V with it; GQA
+    blocks the rep axis over one shared K/V tile. One head a step of
+    lane-packed heads is one slab, its two heads. Tiles or heads that do
+    not divide the shape are refused."""
+    if Sq % tiles.blk_q or Sk % tiles.blk_k:
+        raise ValueError("tiles q{} k{} do not divide Sq={}, Sk={}".format(
+            tiles.blk_q, tiles.blk_k, Sq, Sk))
+    slabs = -(-tiles.heads // pack)
+    if R == 1:
+        if G % slabs:
+            raise ValueError("{} heads a step do not divide H={}".format(
+                tiles.heads, G * pack))
+        return slabs, 1, (None, slabs)
+    if R % slabs:
+        raise ValueError("{} heads a step do not divide the {} query heads "
+                         "of a K/V group".format(tiles.heads, R))
+    return 1, slabs, (None, None)
+
+
+def _q_side(hg, hr, inner):
+    """Block shape of a query-side operand [B, G, R, *inner]: the step's
+    heads on whichever axis carries them, the other squeezed."""
+    return (None, hg, None) + inner if hr == 1 else (None, None, hr) + inner
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "tiles", "pack",
+                                             "interpret"))
+def _flash_fwd(qg, kg, vg, mask, causal, tiles, pack, interpret):
+    """qg: [B,G,R,Sq,D]; kg/vg: [B,G,Sk,D], ``pack`` heads to the D lanes;
+    mask: [B,1,Sk] int32 or None.
+    Returns (out [B,G,R,Sq,D], lse [B,G,R,pack,Sq] fp32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, G, R, Sq, D = qg.shape
     Sk = kg.shape[2]
+    blk_q, blk_k, _ = tiles
+    hg, hr, kv_lead = _head_blocks(Sq, Sk, G, R, tiles, pack)
+    slabs = hg * hr
     offset = Sk - Sq
-    sm_scale = 1.0 / (D ** 0.5)
-    grid = (B, G, R, Sq // blk_q, Sk // blk_k)
+    sm_scale = 1.0 / ((D // pack) ** 0.5)
+    grid = (B, G // hg, R // hr, Sq // blk_q, Sk // blk_k)
 
-    q_spec = pl.BlockSpec((None, None, None, blk_q, D),
+    q_spec = pl.BlockSpec(_q_side(hg, hr, (blk_q, D)),
                           lambda b, g, r, qi, kb: (b, g, r, qi, 0))
-    kv_spec = pl.BlockSpec((None, None, blk_k, D),
+    kv_spec = pl.BlockSpec(kv_lead + (blk_k, D),
                            lambda b, g, r, qi, kb: (b, g, kb, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qg, kg, vg]
@@ -208,26 +586,30 @@ def _flash_fwd(qg, kg, vg, mask, causal, blk_q, blk_k, interpret):
                                      lambda b, g, r, qi, kb: (b, 0, kb)))
         operands.append(mask)
 
+    # One k-block a row, and every query row sees a key: the softmax is
+    # whole in one step (under a causal mask with Sq > Sk the first rows see
+    # none, and the stepping path's skip gives them the zeros it always did).
+    one_pass = Sk == blk_k and not (causal and offset < 0)
     kernel = functools.partial(_flash_fwd_kernel, causal=causal,
                                sm_scale=sm_scale, has_mask=mask is not None,
-                               offset=offset)
+                               offset=offset, one_pass=one_pass)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
             q_spec,
-            pl.BlockSpec((None, None, None, 1, blk_q),
+            pl.BlockSpec(_q_side(hg, hr, (pack, blk_q)),
                          lambda b, g, r, qi, kb: (b, g, r, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, G, R, Sq, D), qg.dtype),
-            jax.ShapeDtypeStruct((B, G, R, 1, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, G, R, pack, Sq), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((blk_q, D), jnp.float32),
-            pltpu.VMEM((blk_q, 128), jnp.float32),
-            pltpu.VMEM((blk_q, 128), jnp.float32),
+        scratch_shapes=[] if one_pass else [
+            pltpu.VMEM((slabs, blk_q, D), jnp.float32),
+            pltpu.VMEM((slabs, pack, blk_q, _LANES), jnp.float32),
+            pltpu.VMEM((slabs, pack, blk_q, _LANES), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             # b/g/r/qi programs are independent (megacore-splittable); the
@@ -242,16 +624,27 @@ def _flash_fwd(qg, kg, vg, mask, causal, blk_q, blk_k, interpret):
     return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def flash_attention(q, k, v, mask=None, causal: bool = True, blk_q: int = 128,
                     blk_k: int = 128, interpret: bool = False):
     """Flash attention on q [B,Sq,H,D], k/v [B,Sk,Hkv,D] (Hkv divides H —
-    GQA handled without materializing repeated K/V). ``mask``: optional
-    [B, Sk] (or [B,1,Sk]) keep-mask over keys. A query row whose keys are
-    ALL masked outputs the uniform average of V (p = exp(NEG_INF-NEG_INF)
-    per key — the same value the reference's softmax-of-all-masked
-    produces); such rows are padding and must be excluded from the loss."""
-    out, _ = _flash_fwd_4d(q, k, v, mask, causal, blk_q, blk_k, interpret)
+    GQA handled without materializing repeated K/V) at the caller's own
+    [blk_q, blk_k] tiles in all three kernels, one head a step (ring
+    attention, Ulysses and the tests; `multi_head_attention` plans its
+    tiles from the shape). ``mask``: optional [B, Sk] (or [B,1,Sk])
+    keep-mask over keys. A query row whose keys are ALL masked outputs the
+    uniform average of V (p = exp(NEG_INF-NEG_INF) per key — the same value
+    the reference's softmax-of-all-masked produces); such rows are padding
+    and must be excluded from the loss."""
+    return flash_attention_planned(q, k, v, mask, causal,
+                                   FlashPlan.explicit(blk_q, blk_k), interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def flash_attention_planned(q, k, v, mask, causal: bool, plan: FlashPlan,
+                            interpret: bool = False):
+    """`flash_attention` with the tiles of each kernel given as a
+    `FlashPlan` (`tile_plan` makes one from the shape)."""
+    out, _ = _flash_fwd_4d(q, k, v, mask, causal, plan.fwd, interpret)
     return out
 
 
@@ -268,44 +661,32 @@ def _canon_mask(mask, B, Sk):
     return m.astype(jnp.int32)
 
 
-def _flash_fwd_4d(q, k, v, mask, causal, blk_q, blk_k, interpret):
+def _flash_fwd_4d(q, k, v, mask, causal, tiles, interpret):
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
+    pack = lane_pack(H, Hkv, D)
     mask3 = _canon_mask(mask, B, k.shape[1])
-    out_g, lse = _flash_fwd(_grouped_q(q, Hkv), _grouped_kv(k), _grouped_kv(v),
-                            mask3, causal, blk_q, blk_k, interpret)
-    return _ungroup_q(out_g), lse
+    out_g, lse = _flash_fwd(_grouped_q(q, Hkv, pack), _grouped_kv(k, pack),
+                            _grouped_kv(v, pack), mask3, causal, tiles, pack,
+                            interpret)
+    return _ungroup_q(out_g, pack), lse
 
 
-def _flash_fwd_rule(q, k, v, mask, causal, blk_q, blk_k, interpret):
-    out, lse = _flash_fwd_4d(q, k, v, mask, causal, blk_q, blk_k, interpret)
+def _flash_fwd_rule(q, k, v, mask, causal, plan, interpret):
+    out, lse = _flash_fwd_4d(q, k, v, mask, causal, plan.fwd, interpret)
     return out, (q, k, v, mask, out, lse)
 
 
-def _recompute_p_ds(q, k_blk, v_blk, do, lse, delta, qi, kb, blk_q, blk_k,
-                    causal, sm_scale, offset, mask_ref):
-    """Shared bwd block math: probabilities from the saved LSE, then the
-    softmax-transpose ds = p * (dO·Vᵀ - delta) * scale. All [blk_q, blk_k].
-    ``lse``/``delta`` arrive as [blk_q, 1] (lane->sublane relayout done by
-    the caller from the compact [1, blk_q] tiles)."""
-    s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-    if causal:
-        s = _causal_tile_mask(s, qi, kb, blk_q, blk_k, offset)
-    if mask_ref is not None:
-        s = _apply_pad_mask(s, mask_ref)
-    p = jnp.exp(s - lse)
-    dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta) * sm_scale
-    return p, ds
-
-
 def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset):
-    """grid (B, G, kb, r, qi): one K/V tile per program group; the two
-    sequential inner dims stream every (rep, q-block) pair of the group
-    through it, accumulating dK/dV in VMEM scratch — GQA gradients sum over
-    the group's query heads without any repeated K/V in HBM."""
+    """grid (B, head block, kb, r, qi): one K/V tile a head per program
+    group; the two sequential inner dims stream every (rep, q-block) pair of
+    the group through it, accumulating dK/dV in VMEM scratch — GQA gradients
+    sum over the group's query heads without any repeated K/V in HBM.
+
+    The scores are taken transposed, [keys, queries]: the row statistics
+    are used as the [1, blk_q] rows they are stored as, P^T dO and dS^T Q
+    are plain products with no transposed operand, and a chunk of key rows
+    owns its rows of dK and dV."""
     from jax.experimental import pallas as pl
 
     if has_mask:
@@ -316,48 +697,60 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset):
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
         mask_ref = None
 
-    blk_q = q_ref.shape[0]
-    blk_k = k_ref.shape[0]
+    slabs, blk_q, _ = q_ref.shape
+    blk_k = k_ref.shape[-2]
+    pack = lse_ref.shape[1]
     kb = pl.program_id(2)
     r = pl.program_id(3)
     qi = pl.program_id(4)
     num_r = pl.num_programs(3)
     num_qb = pl.num_programs(4)
+    rows = _pass_rows(blk_k, blk_q)
 
     @pl.when((r == 0) & (qi == 0))
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def contribute():
-        q = q_ref[:].astype(jnp.float32)
-        do = do_ref[:].astype(jnp.float32)
-        p, ds = _recompute_p_ds(
-            q, k_ref[:].astype(jnp.float32), v_ref[:].astype(jnp.float32),
-            do, lse_ref[0][:, None], delta_ref[0][:, None],
-            qi, kb, blk_q, blk_k, causal, sm_scale, offset, mask_ref)
-        dv_acc[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dk_acc[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+    def contribute(h, c):
+        keys = _chunk(c, rows)
+        k_blk, v_blk = k_ref[_kv(k_ref, h, keys)], v_ref[_kv(v_ref, h, keys)]
+        dk = dv = 0.0
+        for j in range(pack):
+            q = _lanes_of(j, pack, q_ref[h])
+            do = _lanes_of(j, pack, do_ref[h])
+            s = _dot(k_blk, q, _NT) * sm_scale
+            if causal:
+                s = _causal_tile_mask(s, qi * blk_q, kb * blk_k + c * rows,
+                                      offset, q_axis=1)
+            if mask_ref is not None:  # the key mask as a [rows, 1] column
+                s = jnp.where(mask_ref[:, keys][0][:, None] != 0, s, NEG_INF)
+            p = jnp.exp(s - lse_ref[h, j:j + 1])
+            ds = p * (_dot(v_blk, do, _NT) - delta_ref[h, j:j + 1])
+            dv += _dot(p.astype(do.dtype), do, _NN)  # head j's lanes alone
+            dk += _dot(ds.astype(q.dtype), q, _NN)
+        dv_acc[_kv(dv_acc, h, keys)] += dv
+        dk_acc[_kv(dk_acc, h, keys)] += dk
 
     if causal:
         # Q blocks strictly above this K tile's diagonal see none of it.
         @pl.when(kb * blk_k < (qi + 1) * blk_q + offset)
         def _():
-            contribute()
+            _each_chunk(slabs, blk_k // rows, contribute)
     else:
-        contribute()
+        _each_chunk(slabs, blk_k // rows, contribute)
 
     @pl.when((r == num_r - 1) & (qi == num_qb - 1))
     def _finalize():
-        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+        # ds lacked the scale; dK takes it once, on [blk_k, D].
+        dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset):
-    """grid (B, G, r, qi, kb): one Q tile per program group; stream K/V
-    tiles through the sequential kb dimension, accumulating dQ in VMEM."""
+    """grid (B, head block, r, qi, kb): one Q tile a head per program group;
+    stream K/V tiles through the sequential kb dimension, accumulating dQ
+    in VMEM."""
     from jax.experimental import pallas as pl
 
     if has_mask:
@@ -368,42 +761,53 @@ def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset):
          dq_ref, dq_acc) = refs
         mask_ref = None
 
-    blk_q = q_ref.shape[0]
-    blk_k = k_ref.shape[0]
+    slabs, blk_q, _ = q_ref.shape
+    blk_k = k_ref.shape[-2]
+    pack = lse_ref.shape[1]
     qi = pl.program_id(3)
     kb = pl.program_id(4)
     num_kb = pl.num_programs(4)
+    rows = _pass_rows(blk_q, blk_k)
 
     @pl.when(kb == 0)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def contribute():
-        _, ds = _recompute_p_ds(
-            q_ref[:].astype(jnp.float32), k_ref[:].astype(jnp.float32),
-            v_ref[:].astype(jnp.float32), do_ref[:].astype(jnp.float32),
-            lse_ref[0][:, None], delta_ref[0][:, None],
-            qi, kb, blk_q, blk_k, causal, sm_scale, offset, mask_ref)
-        dq_acc[:] += jax.lax.dot_general(ds, k_ref[:].astype(jnp.float32),
-                                         (((1,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+    def contribute(h, c):
+        r = _chunk(c, rows)
+        k_blk, v_blk = k_ref[_kv(k_ref, h)], v_ref[_kv(v_ref, h)]
+        dq = 0.0
+        for j in range(pack):
+            q = _lanes_of(j, pack, q_ref[h, r])
+            do = _lanes_of(j, pack, do_ref[h, r])
+            s = _scores(q, k_blk, qi * blk_q + c * rows, kb * blk_k, causal,
+                        sm_scale, offset, mask_ref)
+            # lane->sublane relayout of the compact [1, rows] statistics
+            p = jnp.exp(s - lse_ref[h, j:j + 1, r][0][:, None])
+            ds = p * (_dot(do, v_blk, _NT)
+                      - delta_ref[h, j:j + 1, r][0][:, None])
+            dq += _dot(ds.astype(q.dtype), _lanes_of(j, pack, k_blk), _NN)
+        dq_acc[h, r] += dq
 
     if causal:
         @pl.when(kb * blk_k < (qi + 1) * blk_q + offset)
         def _():
-            contribute()
+            _each_chunk(slabs, blk_q // rows, contribute)
     else:
-        contribute()
+        _each_chunk(slabs, blk_q // rows, contribute)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
-        dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, blk_q, blk_k,
+@functools.partial(jax.jit, static_argnames=("causal", "plan", "pack",
+                                             "interpret"))
+def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, plan, pack,
                interpret):
-    """Pallas flash backward. qg/dog: [B,G,R,Sq,D]; kg/vg: [B,G,Sk,D];
-    lse/delta: [B,G,R,1,Sq] fp32 (compact); mask: [B,1,Sk] int32 or None.
+    """Pallas flash backward. qg/dog: [B,G,R,Sq,D]; kg/vg: [B,G,Sk,D],
+    ``pack`` heads to the D lanes; lse/delta: [B,G,R,pack,Sq] fp32
+    (compact); mask: [B,1,Sk] int32 or None.
     Returns (dq [B,G,R,Sq,D], dk/dv [B,G,Sk,D])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -411,15 +815,19 @@ def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, blk_q, blk_k,
     B, G, R, Sq, D = qg.shape
     Sk = kg.shape[2]
     offset = Sk - Sq
-    sm_scale = 1.0 / (D ** 0.5)
+    sm_scale = 1.0 / ((D // pack) ** 0.5)
     has_mask = mask is not None
+    kernel_args = dict(causal=causal, sm_scale=sm_scale, has_mask=has_mask,
+                       offset=offset)
 
     # --- dK/dV: grid (B, G, kb, r, qi); r+qi sequential, accumulating.
-    q_by_inner = pl.BlockSpec((None, None, None, blk_q, D),
+    blk_q, blk_k, _ = plan.dkdv
+    hg, hr, kv_lead = _head_blocks(Sq, Sk, G, R, plan.dkdv, pack)
+    q_by_inner = pl.BlockSpec(_q_side(hg, hr, (blk_q, D)),
                               lambda b, g, kb, r, qi: (b, g, r, qi, 0))
-    kv_by_outer = pl.BlockSpec((None, None, blk_k, D),
+    kv_by_outer = pl.BlockSpec(kv_lead + (blk_k, D),
                                lambda b, g, kb, r, qi: (b, g, kb, 0))
-    stat_by_inner = pl.BlockSpec((None, None, None, 1, blk_q),
+    stat_by_inner = pl.BlockSpec(_q_side(hg, hr, (pack, blk_q)),
                                  lambda b, g, kb, r, qi: (b, g, r, 0, qi))
     in_specs = [q_by_inner, kv_by_outer, kv_by_outer, q_by_inner,
                 stat_by_inner, stat_by_inner]
@@ -428,25 +836,20 @@ def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, blk_q, blk_k,
         in_specs.append(pl.BlockSpec((None, 1, blk_k),
                                      lambda b, g, kb, r, qi: (b, 0, kb)))
         operands.append(mask)
+    kv_acc = tuple(n for n in kv_lead if n is not None) + (blk_k, D)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkdv_kernel, causal=causal,
-                          sm_scale=sm_scale, has_mask=has_mask, offset=offset),
-        grid=(B, G, Sk // blk_k, R, Sq // blk_q),
+        functools.partial(_flash_bwd_dkdv_kernel, **kernel_args),
+        grid=(B, G // hg, Sk // blk_k, R // hr, Sq // blk_q),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, None, blk_k, D),
-                         lambda b, g, kb, r, qi: (b, g, kb, 0)),
-            pl.BlockSpec((None, None, blk_k, D),
-                         lambda b, g, kb, r, qi: (b, g, kb, 0)),
-        ],
+        out_specs=[kv_by_outer, kv_by_outer],
         out_shape=[
             jax.ShapeDtypeStruct((B, G, Sk, D), kg.dtype),
             jax.ShapeDtypeStruct((B, G, Sk, D), vg.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk_k, D), jnp.float32),
-            pltpu.VMEM((blk_k, D), jnp.float32),
+            pltpu.VMEM(kv_acc, jnp.float32),
+            pltpu.VMEM(kv_acc, jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -456,11 +859,13 @@ def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, blk_q, blk_k,
     )(*operands)
 
     # --- dQ: grid (B, G, r, qi, kb); kb sequential, accumulating.
-    q_spec = pl.BlockSpec((None, None, None, blk_q, D),
+    blk_q, blk_k, _ = plan.dq
+    hg, hr, kv_lead = _head_blocks(Sq, Sk, G, R, plan.dq, pack)
+    q_spec = pl.BlockSpec(_q_side(hg, hr, (blk_q, D)),
                           lambda b, g, r, qi, kb: (b, g, r, qi, 0))
-    kv_spec = pl.BlockSpec((None, None, blk_k, D),
+    kv_spec = pl.BlockSpec(kv_lead + (blk_k, D),
                            lambda b, g, r, qi, kb: (b, g, kb, 0))
-    stat_spec = pl.BlockSpec((None, None, None, 1, blk_q),
+    stat_spec = pl.BlockSpec(_q_side(hg, hr, (pack, blk_q)),
                              lambda b, g, r, qi, kb: (b, g, r, 0, qi))
     in_specs = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec]
     operands = [qg, kg, vg, dog, lse, delta]
@@ -470,13 +875,12 @@ def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, blk_q, blk_k,
         operands.append(mask)
 
     (dq,) = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, causal=causal,
-                          sm_scale=sm_scale, has_mask=has_mask, offset=offset),
-        grid=(B, G, R, Sq // blk_q, Sk // blk_k),
+        functools.partial(_flash_bwd_dq_kernel, **kernel_args),
+        grid=(B, G // hg, R // hr, Sq // blk_q, Sk // blk_k),
         in_specs=in_specs,
         out_specs=[q_spec],
         out_shape=[jax.ShapeDtypeStruct((B, G, R, Sq, D), qg.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hg * hr, blk_q, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel", "arbitrary")),
@@ -486,29 +890,29 @@ def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, blk_q, blk_k,
     return dq, dk, dv
 
 
-def _flash_bwd_rule(causal, blk_q, blk_k, interpret, res, g):
+def _flash_bwd_rule(causal, plan, interpret, res, g):
     """Flash backward as two Pallas kernels (dK/dV then dQ), recomputing
     probabilities from the saved log-sum-exp — the S x S matrix never
-    materializes and VMEM holds one tile pair at a time."""
+    materializes and VMEM holds one tile pair a head at a time."""
     q, k, v, mask, out, lse = res
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
+    pack = lane_pack(H, Hkv, D)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)  # [B,Sq,H]
-    delta_g = delta.transpose(0, 2, 1).reshape(
-        B, Hkv, H // Hkv, 1, Sq)
     mask3 = _canon_mask(mask, B, k.shape[1])
     dqg, dkg, dvg = _flash_bwd(
-        _grouped_q(q, Hkv), _grouped_kv(k), _grouped_kv(v),
-        _grouped_q(g, Hkv), lse, delta_g, mask3,
-        causal, blk_q, blk_k, interpret)
-    return (_ungroup_q(dqg).astype(q.dtype),
-            _ungroup_kv(dkg).astype(k.dtype),
-            _ungroup_kv(dvg).astype(v.dtype),
+        _grouped_q(q, Hkv, pack), _grouped_kv(k, pack), _grouped_kv(v, pack),
+        _grouped_q(g, Hkv, pack), lse,
+        _grouped_stats(delta.transpose(0, 2, 1), Hkv, pack), mask3,
+        causal, plan, pack, interpret)
+    return (_ungroup_q(dqg, pack).astype(q.dtype),
+            _ungroup_kv(dkg, pack).astype(k.dtype),
+            _ungroup_kv(dvg, pack).astype(v.dtype),
             None)
 
 
-flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+flash_attention_planned.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 # ------------------------------------------------- ring-attention building blocks
@@ -521,7 +925,8 @@ def flash_block_fwd(q, k, v, causal: bool = True, blk_q: int = 128,
     attention merges across shards. q: [B,Sq,H,D], k/v: [B,Sk,Hkv,D];
     returns (out [B,Sq,H,D], lse [B,H,Sq] fp32). Not differentiable on its
     own: the ring owns the VJP (see parallel/ring_attention.py)."""
-    out, lse = _flash_fwd_4d(q, k, v, None, causal, blk_q, blk_k, interpret)
+    out, lse = _flash_fwd_4d(q, k, v, None, causal,
+                             KernelTiles(blk_q, blk_k), interpret)
     B, Sq, H, _ = q.shape
     return out, lse.reshape(B, H, Sq)
 
@@ -534,17 +939,16 @@ def flash_block_bwd(q, k, v, do, lse, delta, causal: bool = True,
     gradient contribution is independent and additive — p recomputed from
     the global lse is the true global probability for this block.
     lse/delta: [B,H,Sq] fp32. Returns (dq, dk, dv) fp32."""
-    B, Sq, H, D = q.shape
     Hkv = k.shape[2]
-    R = H // Hkv
-    stats = lambda x: x.reshape(B, Hkv, R, 1, Sq).astype(jnp.float32)  # noqa: E731
+    pack = lane_pack(q.shape[2], Hkv, q.shape[3])
     dqg, dkg, dvg = _flash_bwd(
-        _grouped_q(q, Hkv), _grouped_kv(k), _grouped_kv(v),
-        _grouped_q(do, Hkv), stats(lse), stats(delta), None,
-        causal, blk_q, blk_k, interpret)
-    return (_ungroup_q(dqg).astype(jnp.float32),
-            _ungroup_kv(dkg).astype(jnp.float32),
-            _ungroup_kv(dvg).astype(jnp.float32))
+        _grouped_q(q, Hkv, pack), _grouped_kv(k, pack), _grouped_kv(v, pack),
+        _grouped_q(do, Hkv, pack), _grouped_stats(lse, Hkv, pack),
+        _grouped_stats(delta, Hkv, pack), None,
+        causal, FlashPlan.explicit(blk_q, blk_k), pack, interpret)
+    return (_ungroup_q(dqg, pack).astype(jnp.float32),
+            _ungroup_kv(dkg, pack).astype(jnp.float32),
+            _ungroup_kv(dvg, pack).astype(jnp.float32))
 
 
 # ----------------------------------------------------------------- dispatch
@@ -636,5 +1040,8 @@ def multi_head_attention(q, k, v, causal: bool = True, mask=None,
             and not _flash_disabled()
     if not use_flash:
         return attention_reference(q, k, v, causal=causal, mask=mask)
-    interpret = not _tpu_backend()
-    return flash_attention(q, k, v, pad_mask, causal, 128, 128, interpret)
+    plan = tile_plan(Sq, Sk, D, H, Hkv, q.dtype.itemsize, causal,
+                     pad_mask is not None)
+    _remember(plan)
+    return flash_attention_planned(q, k, v, pad_mask, causal, plan,
+                                   not _tpu_backend())

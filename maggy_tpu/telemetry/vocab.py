@@ -242,6 +242,21 @@ SPAN_NAMES = (
 )
 ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 
+#: What a trial's ``compiled`` record carries beside the ``*_ms`` sums of
+#: its spans and the ``spans`` themselves (`RunnerStats.note_compile`; first
+#: write wins): ``warm`` (the trial found a resident program), ``forked``
+#: (it resumed a staged parent checkpoint), ``vmap_lanes`` (lanes of a
+#: vectorized block), ``first_dispatch`` (epoch seconds of the trial's
+#: first step dispatch) and ``flash_plan``: the tiles of the Pallas flash
+#: kernels in the step program this trial traced, as
+#: `ops.attention.FlashPlan.describe` writes them (``fwd q512 k512 h4;
+#: dkdv q512 k512 h4; dq q512 k512 h4``: per kernel the query and key tile
+#: and the heads a grid step covers; several shapes in one program are
+#: joined by `` | ``). Absent where the trial traced nothing (a warm
+#: trial) or the program holds no flash kernel.
+COMPILED_FIELDS = ("warm", "forked", "vmap_lanes", "first_dispatch",
+                   "flash_plan")
+
 #: Health-engine event fields (``ev: "health"``).
 HEALTH_STATUSES = frozenset({"raised", "cleared", "started", "error"})
 HEALTH_CHECKS = frozenset({"engine", "straggler", "hb_rtt", "hang"})
@@ -255,7 +270,7 @@ ALL_REASONS = REQUEUE_REASONS | LEASE_END_REASONS | PROFILE_REASONS
 
 __all__ = [
     "SPAN_PHASES", "EVENT_KINDS", "REQUEUE_REASONS", "PROFILE_REASONS",
-    "GOODPUT_BUCKETS", "SPAN_NAMES", "ANNOTATION_NAMES",
+    "GOODPUT_BUCKETS", "SPAN_NAMES", "ANNOTATION_NAMES", "COMPILED_FIELDS",
     "EXPERIMENT_PHASES", "RUNNER_PHASES", "WORKER_PHASES",
     "FLEET_PHASES", "FLEET_EXPERIMENT_PHASES", "LEASE_PHASES",
     "LEASE_END_REASONS", "AGENT_PHASES", "CHAOS_KINDS",

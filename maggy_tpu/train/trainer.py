@@ -583,10 +583,14 @@ class Trainer:
             with slot.aot_lock:
                 fn = slot.compiled_step(key)
                 if fn is None:
+                    from maggy_tpu.ops.attention import plans_traced
+
                     try:
-                        with _warm.span("trace"):
+                        with _warm.span("trace"), plans_traced() as plans:
                             lowered = self._step.lower(
                                 self.variables, self.opt_state, batch)
+                        if plans:  # which tiles the flash kernels were built at
+                            _warm.note_compile(flash_plan=" | ".join(plans))
                         with _warm.span("compile"):
                             fn = lowered.compile()
                     except Exception:  # noqa: BLE001 - AOT is an optimization

@@ -32,6 +32,24 @@ def test_sweep_runs_and_checks_itself_on_cpu_at_tiny(tmp_path):
                    for _, dirs, _ in os.walk(tmp_path / "sweep"))
 
 
+def test_flash_check_runs_every_shape_at_planned_and_explicit_tiles():
+    """Off the chip the same check runs interpreted, at a small shape of
+    each kind; the chip's list holds the benchmark cell's class."""
+    report = chip_smoke.check_flash_attention(shapes=(
+        ("bert_b1_s256_h4_d64_masked", 1, 256, 4, 4, 64, False, True),
+        ("gqa_b1_s256_h4_kv2_d128_causal", 1, 256, 4, 2, 128, True, False)))
+    assert report["bert_b1_s256_h4_d64_masked"]["plan"] == \
+        "fwd q256 k256 h4; dkdv q256 k256 h4; dq q256 k256 h4"
+    for entry in report.values():
+        for tiles in ("planned", "explicit_128"):
+            assert set(entry[tiles]) == {"out", "dq", "dk", "dv"}
+            assert max(entry[tiles].values()) <= chip_smoke.FLASH_TOL
+    assert chip_smoke.FLASH_TOL == 4 * 2.0 ** -8
+    assert ("bert_b64_s512_h12_d64_masked", 64, 512, 12, 12, 64, False,
+            True) in chip_smoke.FLASH_SHAPES
+    assert len(chip_smoke.FLASH_SHAPES) == 3
+
+
 def test_trial_refuses_another_platform_than_asked():
     import pytest
 

@@ -10,7 +10,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from maggy_tpu.ops.attention import flash_attention
+from maggy_tpu.ops.attention import (FlashPlan, flash_attention,
+                                     flash_attention_planned, tile_plan)
 
 
 @pytest.fixture(scope="module")
@@ -26,31 +27,44 @@ def v5e_device():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize(
-    "name,B,Sq,Sk,H,Hkv,D,causal,masked,dtype", [
-        ("bert_d64_masked", 2, 128, 128, 12, 12, 64, False, True, jnp.bfloat16),
-        ("llama_gqa_d128_causal", 1, 2048, 2048, 32, 8, 128, True, False,
-         jnp.bfloat16),
-        ("sq_ne_sk_causal", 2, 128, 512, 4, 4, 128, True, False, jnp.bfloat16),
-        ("float32", 2, 256, 256, 4, 2, 128, True, True, jnp.float32),
-        ("d72", 2, 128, 128, 4, 4, 72, False, False, jnp.bfloat16),
-    ])
-def test_forward_and_both_backward_kernels_compile(
-        v5e_device, name, B, Sq, Sk, H, Hkv, D, causal, masked, dtype):
+#: The cell's own shape (`bert-base.steady-s512`: B 64, S 512, 12 heads of 64).
+CELL_SHAPE = ("bert_b64_s512_h12_d64_masked", 64, 512, 512, 12, 12, 64, False,
+              True, jnp.bfloat16)
+#: name, B, Sq, Sk, H, Hkv, D, causal, ragged key mask, dtype: one of every
+#: shape class dispatch can reach, and the cell's.
+SHAPE_CLASSES = [
+    ("bert_d64_masked", 2, 128, 128, 12, 12, 64, False, True, jnp.bfloat16),
+    ("llama_gqa_d128_causal", 1, 2048, 2048, 32, 8, 128, True, False,
+     jnp.bfloat16),
+    ("sq_ne_sk_causal", 2, 128, 512, 4, 4, 128, True, False, jnp.bfloat16),
+    ("float32", 2, 256, 256, 4, 2, 128, True, True, jnp.float32),
+    ("d72", 2, 128, 128, 4, 4, 72, False, False, jnp.bfloat16),
+    CELL_SHAPE,
+]
+
+
+@pytest.mark.parametrize("tiles", ["explicit_128", "planned"])
+@pytest.mark.parametrize("shape", SHAPE_CLASSES, ids=lambda s: s[0])
+def test_forward_and_both_backward_kernels_compile(v5e_device, shape, tiles):
+    name, B, Sq, Sk, H, Hkv, D, causal, masked, dtype = shape
     q = jax.ShapeDtypeStruct((B, Sq, H, D), dtype, sharding=v5e_device)
     kv = jax.ShapeDtypeStruct((B, Sk, Hkv, D), dtype, sharding=v5e_device)
     keep = jax.ShapeDtypeStruct((B, Sk), jnp.bool_, sharding=v5e_device)
+    plan = FlashPlan.explicit(128, 128) if tiles == "explicit_128" else \
+        tile_plan(Sq, Sk, D, H, Hkv, jnp.dtype(dtype).itemsize, causal, masked)
 
     def loss(q, k, v, keep):
-        out = flash_attention(q, k, v, keep if masked else None, causal,
-                              128, 128, False)  # compiled, never interpreted
+        out = flash_attention_planned(
+            q, k, v, keep if masked else None, causal, plan,
+            False)  # compiled, never interpreted
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
         q, kv, kv, keep).compile()
-    # Forward, dK/dV and dQ: three Mosaic kernels in the executable.
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 3
+    # Forward, dK/dV and dQ: three Mosaic kernels in the executable, under
+    # the names the benchmark's readers find them by.
+    assert _kernel_names(compiled.as_text()) == [
+        "flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]
 
 
 def test_the_three_kernels_carry_their_names(v5e_device):

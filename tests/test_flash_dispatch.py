@@ -24,7 +24,7 @@ class TestDispatch:
         monkeypatch.setattr(att, "_tpu_backend", lambda: True)
         called = {"flash": False}
         monkeypatch.setattr(
-            att, "flash_attention",
+            att, "flash_attention_planned",
             lambda *a, **k: called.__setitem__("flash", True))
         q, k, v = _qkv()
         out = att.multi_head_attention(q, k, v, causal=True)
@@ -36,13 +36,17 @@ class TestDispatch:
         monkeypatch.setattr(att, "_tpu_backend", lambda: True)
         seen = {}
 
-        def stub(q, k, v, mask, causal, blk_q, blk_k, interpret):
+        def stub(q, k, v, mask, causal, plan, interpret):
             seen["interpret"] = interpret
+            seen["plan"] = plan
             return att.attention_reference(q, k, v, causal=causal)
 
-        monkeypatch.setattr(att, "flash_attention", stub)
+        monkeypatch.setattr(att, "flash_attention_planned", stub)
         att.multi_head_attention(*_qkv(), causal=True)
-        assert seen == {"interpret": False}
+        # Compiled, at the tiles the shape's plan gives.
+        assert seen == {"interpret": False,
+                        "plan": att.tile_plan(128, 128, 128, 2, 2, 4, True,
+                                              False)}
 
     def test_kernel_build_failure_is_an_error(self, monkeypatch):
         monkeypatch.setattr(att, "_tpu_backend", lambda: True)
@@ -50,14 +54,14 @@ class TestDispatch:
         def boom(*a, **k):
             raise RuntimeError("Mosaic lowering failed")
 
-        monkeypatch.setattr(att, "flash_attention", boom)
+        monkeypatch.setattr(att, "flash_attention_planned", boom)
         with pytest.raises(RuntimeError, match="Mosaic lowering failed"):
             att.multi_head_attention(*_qkv(), causal=True)
 
     def test_shapes_that_do_not_tile_take_the_reference(self, monkeypatch):
         monkeypatch.setattr(att, "_tpu_backend", lambda: True)
         monkeypatch.setattr(
-            att, "flash_attention",
+            att, "flash_attention_planned",
             lambda *a, **k: pytest.fail("kernel called for S=96"))
         rng = np.random.default_rng(0)
         q, k, v = (jnp.asarray(rng.normal(size=(1, 96, 2, 128)), jnp.float32)

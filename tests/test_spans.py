@@ -197,6 +197,66 @@ def test_trainer_phases_and_first_dispatch_land_on_the_compiled_record():
         <= by_name["trial"][2]
 
 
+def test_the_flash_plan_of_the_traced_step_lands_on_the_compiled_record(
+        monkeypatch):
+    """A trial that traces a step holding flash attention notes the plan
+    the shape chose as ``flash_plan`` (here the chip's dispatch with the
+    kernel stubbed: the CPU cannot build it); a warm trial, which traces
+    nothing, notes none."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from maggy_tpu.models import BertConfig, BertEncoder
+    from maggy_tpu.ops import attention
+    from maggy_tpu.parallel import make_mesh
+    from maggy_tpu.telemetry.vocab import COMPILED_FIELDS
+    from maggy_tpu.train import (Trainer, clear_warm, cross_entropy_loss,
+                                 swept_transform, warm)
+
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    monkeypatch.setattr(
+        attention, "flash_attention_planned",
+        lambda q, k, v, mask, causal, plan, interpret:
+        attention.attention_reference(
+            q, k, v, causal=causal,
+            mask=None if mask is None else mask[:, None, None, :]))
+    cfg = BertConfig(vocab_size=64, hidden_dim=128, intermediate_dim=128,
+                     num_layers=2, num_heads=2, max_seq_len=128, dropout=0.0)
+    rng = np.random.default_rng(0)
+    batch = {"inputs": (rng.integers(0, 64, size=(2, 128)).astype(np.int32),
+                        np.ones((2, 128), bool)),
+             "labels": np.zeros((2,), np.int32)}
+
+    def loss_fn(logits, b):  # one object: part of the program's identity
+        return cross_entropy_loss(logits, b["labels"])
+
+    def trial(trial_id):
+        stats = RunnerStats()
+        stats.trial_start(trial_id)
+        with warm.trial_scope(trial_id=trial_id, stats=stats), \
+                span("trial", stats=stats, trial_id=trial_id):
+            trainer = Trainer(
+                BertEncoder(cfg),
+                swept_transform(optax.adam, learning_rate=1e-3), loss_fn,
+                make_mesh({"data": 1}, devices=jax.devices()[:1]))
+            trainer.init(jax.random.key(0), (jnp.zeros((1, 128), jnp.int32),))
+            float(trainer.step(trainer.place_batch(batch)))
+        stats.trial_end(trial_id)
+        return _records(stats)[0]
+
+    clear_warm()
+    cold, warm_trial = trial("t1"), trial("t2")
+    clear_warm()
+    plan = attention.tile_plan(128, 128, 64, 2, 2, 2, False, True).describe()
+    assert cold["flash_plan"] == plan == \
+        "fwd q128 k128 h2; dkdv q128 k128 h2; dq q128 k128 h2"
+    assert cold["trace_ms"] > 0 and cold["warm"] is False
+    assert warm_trial["warm"] is True and "trace_ms" not in warm_trial
+    assert "flash_plan" not in warm_trial
+    assert "flash_plan" in COMPILED_FIELDS
+
+
 def test_a_profiler_session_holds_the_loops_annotations(tmp_path):
     """A 0.2 s session around three steps of `Trainer.fit`'s loop holds
     ``place_batch``, ``train_step`` (``step_num`` 0, 1, 2) and ``report``,
