@@ -4,6 +4,7 @@
 - `resnet`: ResNet for CIFAR-10 (ASHA sweep config)
 - `bert`: BERT-base-style encoder (GLUE fine-tune HPO config)
 - `llama`: Llama-style decoder + LoRA (the LoRA-sweep config; flagship)
+- `sdar`: SDAR-MoE block-diffusion decoder holding a share of its experts
 - `surgery`: ablatable-module helpers for LOCO model surgery
 """
 
@@ -11,8 +12,10 @@ from maggy_tpu.models.mnist_cnn import MnistCNN, MnistMLP
 from maggy_tpu.models.resnet import ResNet
 from maggy_tpu.models.bert import BertEncoder, BertConfig
 from maggy_tpu.models.llama import Llama, LlamaConfig
-from maggy_tpu.models.moe import MoEMLP
+from maggy_tpu.models.moe import ExpertShareMLP, MoEMLP
+from maggy_tpu.models.sdar import SdarMoe, SdarMoeConfig
 from maggy_tpu.models.vit import ViT, ViTConfig
 
 __all__ = ["MnistCNN", "MnistMLP", "ResNet", "BertEncoder", "BertConfig",
-           "Llama", "LlamaConfig", "MoEMLP", "ViT", "ViTConfig"]
+           "Llama", "LlamaConfig", "MoEMLP", "ExpertShareMLP", "SdarMoe",
+           "SdarMoeConfig", "ViT", "ViTConfig"]

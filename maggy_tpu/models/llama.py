@@ -88,11 +88,17 @@ def _rms_norm(x, weight, eps):
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     param_dtype: Any = jnp.float32
+    #: Logical axis of the scale: the model width, or None for a norm over
+    #: some other last axis (a head's 128).
+    axis: Optional[str] = EMBED
+    #: What the learned scale starts at.
+    scale_init: float = 1.0
 
     @nn.compact
     def __call__(self, x):
         w = self.param("scale", nn.with_logical_partitioning(
-            nn.initializers.ones_init(), (EMBED,)), (x.shape[-1],), self.param_dtype)
+            nn.initializers.constant(self.scale_init), (self.axis,)),
+            (x.shape[-1],), self.param_dtype)
         return _rms_norm(x, w.astype(x.dtype), self.eps)
 
 

@@ -1,4 +1,17 @@
-"""Mixture-of-experts MLP with expert parallelism over an "expert" mesh axis.
+"""Mixture-of-experts MLPs: a capacity-bound one for GSPMD expert
+parallelism, and a dropless one that holds a share of the experts.
+
+`ExpertShareMLP` (second half of the file) is the dropless layer: it routes
+over all ``num_experts``, is TOLD which experts it holds, sorts the
+token-expert pairs by expert and multiplies each held expert's rows by that
+expert's weights in Pallas grouped matrix products (`moe_gmm_fwd`,
+`moe_gmm_dlhs`, `moe_gmm_drhs`). No capacity, no dropped token. On one chip
+it runs without its exchange; nothing stands in for absent chips.
+
+`MoEMLP` (first half) is the older layer, kept because the ``dp_ep``
+strategy's tests (tests/test_parallel_strategies.py) shard it over an
+"expert" mesh axis through GSPMD, which the dropless layer's kernels
+cannot be (Mosaic kernels are not partitioned automatically):
 
 Green-field TPU-first design (the reference has no model code, SURVEY.md
 §5.7; expert parallelism is listed absent in §2.8). GShard-style top-k
@@ -20,7 +33,8 @@ parallel/sharding.logical_axis_rules("..._ep") maps them onto the mesh.
 
 from __future__ import annotations
 
-from typing import Any, Tuple
+import functools
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -125,3 +139,462 @@ class MoEMLP(nn.Module):
         expert_out = jnp.einsum("ebcf,efd->ebcd", act, w_down.astype(self.dtype))
         # Combine back to token order, weighted by the (renormalized) gates.
         return jnp.einsum("bsec,ebcd->bsd", combine, expert_out)
+
+
+# ---------------------------------------------------------------- dropless
+#
+# The held experts' rows live in a buffer in which every expert's rows start
+# at a multiple of ``TILE_ROWS``, so a tile of rows belongs to one expert and
+# a grouped product is a tiled matmul whose weight block is looked up per
+# row tile. The buffer exists as INDICES only ([rows] int32); the rows
+# themselves are gathered, multiplied and scattered back a chunk at a time.
+
+#: Rows of one tile of the grouped products.
+TILE_ROWS = 512
+#: Tiles one round of the layer's loop gathers, multiplies and scatters
+#: back. The loop runs as many rounds as the routed rows need, so this is
+#: the grain at which the work follows the routing; on the v5e a layer at
+#: 16,384 positions took the same time at 4 tiles a round as at 34 (PERF.md
+#: section 6, PR 26).
+CHUNK_TILES = 4
+#: The widest block of a weight's output (or, in `moe_gmm_drhs`, of either
+#: of its dimensions) one grid step holds.
+BLOCK_CAP = 512
+#: VMEM a grouped product may take: the largest step ([512, 2048] rows
+#: against a [2048, 512] weight block, double-buffered, and the float32
+#: result) is 9 MiB; a v5e's default of 16 MiB is raised for headroom.
+VMEM_LIMIT = 32 * 2 ** 20
+
+
+#: The `jax.named_scope`s of the layer, in the order a token meets them.
+#: The layer names them with its plan (`telemetry.plans.remember_plan`), so
+#: whoever traces the step can note which of its instructions ran under
+#: each (``moe_ops`` of the ``compiled`` record).
+SCOPES = ("moe_routing", "moe_dispatch", "moe_experts", "moe_combine")
+
+
+def _block(dim: int, cap: int = BLOCK_CAP) -> int:
+    """The largest multiple of 128 that divides ``dim`` and is at most
+    ``cap``; ``dim`` itself where it is small or has none."""
+    fit = [b for b in range(128, min(dim, cap) + 1, 128) if dim % b == 0]
+    return max(fit, default=dim)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def route_top_k(x, router, top_k: int, renormalize: bool):
+    """(expert ids [N, k] int32, gates [N, k] float32) of tokens x [N, D]:
+    softmax over ALL experts in float32 (the logits at full matmul
+    precision: a near-tie decides which expert a token takes), the ``top_k``
+    largest, renormalised over the chosen where ``renormalize``."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, ids = jax.lax.top_k(probs, top_k)
+    if renormalize:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), gates
+
+
+def buffer_rows(tokens: int, top_k: int, held: int,
+                tile_rows: int = TILE_ROWS) -> int:
+    """Rows of the index buffer: a token's ``top_k`` experts are distinct,
+    so at most ``min(top_k, held)`` of its pairs can name a held expert and
+    at most ``tokens x min(top_k, held)`` rows are ever routed here,
+    whatever the router does; each held expert's rows are padded to whole
+    tiles, which adds under one tile an expert. No pair can overflow it."""
+    worst = tokens * min(top_k, held)
+    return -(-worst // tile_rows) * tile_rows + held * tile_rows
+
+
+def grouped_layout(ids, first_expert: int, held: int, tile_rows: int):
+    """Where each token-expert pair goes. ``ids`` [N, k] are the chosen
+    experts; the held ones are ``first_expert .. first_expert + held - 1``.
+
+    Returns ``row_pair`` [rows] (the flat pair index n * k + j that sits in
+    each buffer row, or N * k for a padding row), ``tile_group`` [rows /
+    tile_rows] (the held expert, 0-based, whose rows the tile holds; tiles
+    past the last used one repeat the last used tile's expert, so that a
+    kernel stepping onto them changes no block) and ``tiles_used`` (scalar).
+    Pairs that chose an absent expert are in no row: they contribute
+    nothing, here as in the reference."""
+    N, k = ids.shape
+    P = N * k
+    rows = buffer_rows(N, k, held, tile_rows)
+    local = ids.reshape(P) - first_expert
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                     dtype=jnp.int32)
+    tiles = -(-counts // tile_rows)
+    starts = jnp.cumsum(counts) - counts           # in sorted order
+    tile_ends = jnp.cumsum(tiles)
+    padded_starts = (tile_ends - tiles) * tile_rows
+    group = key[order]
+    g = jnp.minimum(group, held - 1)
+    dest = jnp.where(group < held,
+                     padded_starts[g] + jnp.arange(P) - starts[g], rows)
+    row_pair = jnp.full((rows,), P, jnp.int32).at[dest].set(
+        order, mode="drop", unique_indices=True)
+    tiles_used = tile_ends[-1]
+    tile = jnp.minimum(jnp.arange(rows // tile_rows),
+                       jnp.maximum(tiles_used - 1, 0))
+    tile_group = jnp.minimum(  # experts whose tiles end at or before it
+        jnp.sum(tile[:, None] >= tile_ends[None, :], axis=1), held - 1)
+    return row_pair, tile_group.astype(jnp.int32), tiles_used
+
+
+def _gmm(lhs, rhs, tile_group, tiles_left, *, transpose_rhs: bool, name: str,
+         tile_rows: int, interpret: bool):
+    """Grouped product of a chunk: row tile m of ``lhs`` [C, K] times the
+    weights of expert ``tile_group[m]``, ``rhs`` [G, K, N] (or, with
+    ``transpose_rhs``, [G, N, K]: the product with W^T that the gradient
+    with respect to the rows needs). Tiles from ``tiles_left`` on hold no
+    routed row: no product is taken for them and their output is zeros.
+    Grid (N blocks, row tiles): consecutive tiles of one expert reuse the
+    weight block that is in VMEM."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    C, K = lhs.shape
+    N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tn = _block(N)
+    dims = (((1,), (1,)), ((), ())) if transpose_rhs \
+        else (((1,), (0,)), ((), ()))
+
+    def kernel(group_ref, left_ref, lhs_ref, rhs_ref, out_ref):
+        del group_ref
+        used = pl.program_id(1) < left_ref[0]
+
+        @pl.when(used)
+        def _():
+            out_ref[...] = jax.lax.dot_general(
+                lhs_ref[...], rhs_ref[...], dims,
+                preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+        @pl.when(jnp.logical_not(used))
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+    rhs_block = (None, tn, K) if transpose_rhs else (None, K, tn)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N // tn, C // tile_rows),
+            in_specs=[
+                pl.BlockSpec((tile_rows, K), lambda n, m, g, left: (m, 0)),
+                pl.BlockSpec(rhs_block,
+                             (lambda n, m, g, left: (g[m], n, 0))
+                             if transpose_rhs else
+                             (lambda n, m, g, left: (g[m], 0, n))),
+            ],
+            out_specs=pl.BlockSpec((tile_rows, tn),
+                                   lambda n, m, g, left: (m, n)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((C, N), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=name,
+    )(tile_group, tiles_left, lhs, rhs)
+
+
+def _gmm_drhs(lhs, dout, tile_group, tiles_left, acc, *, tile_rows: int,
+              interpret: bool):
+    """``acc[g] += lhs_g^T dout_g`` for every expert g with row tiles in the
+    chunk: the gradient of a grouped product with respect to its weights.
+    ``lhs`` [C, K], ``dout`` [C, N], ``acc`` [G, K, N] float32, updated in
+    place (experts without a row in the chunk are not touched). Grid (K
+    blocks, N blocks, row tiles): the tiles of one expert follow each other,
+    so its block is loaded at the first, summed in VMEM and stored at the
+    last."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    C, K = lhs.shape
+    N = dout.shape[1]
+    T = C // tile_rows
+    tk, tn = _block(K), _block(N)
+
+    def kernel(group_ref, left_ref, lhs_ref, dout_ref, acc_in_ref, out_ref,
+               acc_ref):
+        m = pl.program_id(2)
+        last_used = jnp.minimum(left_ref[0], T) - 1
+        g = group_ref[m]
+        first = (m == 0) | (group_ref[jnp.maximum(m - 1, 0)] != g)
+        final = (m == last_used) | (group_ref[jnp.minimum(m + 1, T - 1)] != g)
+        used = m <= last_used
+
+        @pl.when(used & first)
+        def _():
+            acc_ref[...] = acc_in_ref[...]
+
+        @pl.when(used)
+        def _():
+            acc_ref[...] += jax.lax.dot_general(
+                lhs_ref[...], dout_ref[...], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        @pl.when(used & final)
+        def _():
+            out_ref[...] = acc_ref[...]
+
+    acc_spec = pl.BlockSpec((None, tk, tn),
+                            lambda k, n, m, g, left: (g[m], k, n))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(K // tk, N // tn, T),
+            in_specs=[
+                pl.BlockSpec((tile_rows, tk), lambda k, n, m, g, left: (m, k)),
+                pl.BlockSpec((tile_rows, tn), lambda k, n, m, g, left: (m, n)),
+                acc_spec,
+            ],
+            out_specs=acc_spec,
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_gmm_drhs",
+    )(tile_group, tiles_left, lhs, dout, acc)
+
+
+class _Chunk:
+    """One chunk of the index buffer, as the forward and the backward pass
+    both need it: the rows' tokens and gates, and the chunk's tiles."""
+
+    def __init__(self, c, pair_gate, row_pair, tile_group, tiles_used, top_k,
+                 chunk_rows, tile_rows):
+        P = pair_gate.shape[0]
+        tiles = chunk_rows // tile_rows
+        self.pair = jax.lax.dynamic_slice_in_dim(
+            row_pair, c * chunk_rows, chunk_rows)
+        self.valid = self.pair < P
+        self.token = jnp.where(self.valid, self.pair // top_k, 0)
+        self.gate = jnp.where(
+            self.valid, pair_gate[jnp.minimum(self.pair, P - 1)], 0.0)
+        self.tile_group = jax.lax.dynamic_slice_in_dim(
+            tile_group, c * tiles, tiles)
+        self.tiles_left = jnp.reshape(tiles_used - c * tiles, (1,))
+
+
+def _silu_mul(gate, up):
+    gate, up = gate.astype(jnp.float32), up.astype(jnp.float32)
+    return gate * jax.nn.sigmoid(gate) * up
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
+def expert_ffn(x, w_gate, w_up, w_down, pair_gate, row_pair, tile_group,
+               tiles_used, top_k: int, chunk_rows: int, tile_rows: int,
+               interpret: bool):
+    """``out[n] = sum over n's pairs in the buffer of gate * W_down_e
+    (silu(x_n W_gate_e) * (x_n W_up_e))``: x [N, D], the held experts'
+    weights [G, D, F], [G, D, F], [G, F, D], ``pair_gate`` [N * k] float32,
+    and `grouped_layout`'s three. The buffer's used rows are walked in
+    chunks of ``chunk_rows`` (a `fori_loop` whose trip count is the used
+    rows over the chunk, so the work follows the rows routed here and the
+    live memory is one chunk's): gather the chunk's rows of x, three
+    grouped products, and a gate-weighted scatter-add back to the tokens.
+    Its own VJP (below) keeps x, the weights and the indices, and
+    recomputes a chunk's activations beside their gradients."""
+    out, _ = _expert_ffn_fwd(x, w_gate, w_up, w_down, pair_gate, row_pair,
+                             tile_group, tiles_used, top_k, chunk_rows,
+                             tile_rows, interpret)
+    return out
+
+
+def _chunks(tiles_used, chunk_rows, tile_rows):
+    return -(-(tiles_used * tile_rows) // chunk_rows)
+
+
+def _expert_ffn_fwd(x, w_gate, w_up, w_down, pair_gate, row_pair, tile_group,
+                    tiles_used, top_k, chunk_rows, tile_rows, interpret):
+    dtype = x.dtype
+    gmm = functools.partial(_gmm, transpose_rhs=False, name="moe_gmm_fwd",
+                            tile_rows=tile_rows, interpret=interpret)
+    wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+
+    def chunk(c, out):
+        ck = _Chunk(c, pair_gate, row_pair, tile_group, tiles_used, top_k,
+                    chunk_rows, tile_rows)
+        with jax.named_scope("moe_dispatch"):
+            xg = x[ck.token]
+        with jax.named_scope("moe_experts"):
+            act = _silu_mul(gmm(xg, wg, ck.tile_group, ck.tiles_left),
+                            gmm(xg, wu, ck.tile_group, ck.tiles_left))
+            y = gmm(act.astype(dtype), wd, ck.tile_group, ck.tiles_left)
+        with jax.named_scope("moe_combine"):
+            return out.at[ck.token].add(
+                y.astype(jnp.float32) * ck.gate[:, None])
+
+    out = jax.lax.fori_loop(
+        0, _chunks(tiles_used, chunk_rows, tile_rows), chunk,
+        jnp.zeros(x.shape, jnp.float32))
+    return out.astype(dtype), (x, w_gate, w_up, w_down, pair_gate, row_pair,
+                               tile_group, tiles_used)
+
+
+def _expert_ffn_bwd(top_k, chunk_rows, tile_rows, interpret, res, dout):
+    x, w_gate, w_up, w_down, pair_gate, row_pair, tile_group, tiles_used = res
+    dtype = x.dtype
+    P = pair_gate.shape[0]
+    fwd = functools.partial(_gmm, transpose_rhs=False, name="moe_gmm_fwd",
+                            tile_rows=tile_rows, interpret=interpret)
+    dlhs = functools.partial(_gmm, transpose_rhs=True, name="moe_gmm_dlhs",
+                             tile_rows=tile_rows, interpret=interpret)
+    drhs = functools.partial(_gmm_drhs, tile_rows=tile_rows,
+                             interpret=interpret)
+    wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+    dout = dout.astype(dtype)
+
+    def chunk(c, carry):
+        dx, dwg, dwu, dwd, dpair = carry
+        ck = _Chunk(c, pair_gate, row_pair, tile_group, tiles_used, top_k,
+                    chunk_rows, tile_rows)
+        tiles = (ck.tile_group, ck.tiles_left)
+        with jax.named_scope("moe_dispatch"):
+            xg, dog = x[ck.token], dout[ck.token]
+        with jax.named_scope("moe_experts"):
+            gate = fwd(xg, wg, *tiles).astype(jnp.float32)
+            up = fwd(xg, wu, *tiles).astype(jnp.float32)
+            sig = jax.nn.sigmoid(gate)
+            act = gate * sig * up
+            # y = act W_down, so d/d(gate of the pair) <y, dout> is
+            # <act, dout W_down^T>, and d/d(act) is that times the gate.
+            u = dlhs(dog, wd, *tiles).astype(jnp.float32)
+            dgate_of_pair = jnp.sum(act * u, axis=-1)
+            dact = u * ck.gate[:, None]
+            dgate = (dact * up * sig * (1.0 + gate * (1.0 - sig))).astype(dtype)
+            dup = (dact * gate * sig).astype(dtype)
+            dxg = dlhs(dgate, wg, *tiles).astype(jnp.float32) \
+                + dlhs(dup, wu, *tiles).astype(jnp.float32)
+            dwg = drhs(xg, dgate, *tiles, dwg)
+            dwu = drhs(xg, dup, *tiles, dwu)
+            dwd = drhs(act.astype(dtype),
+                       (dog.astype(jnp.float32) * ck.gate[:, None]).astype(
+                           dtype), *tiles, dwd)
+        with jax.named_scope("moe_combine"):
+            dx = dx.at[ck.token].add(dxg * ck.valid[:, None])
+            dpair = dpair.at[ck.pair].set(dgate_of_pair, mode="drop",
+                                          unique_indices=True)
+        return dx, dwg, dwu, dwd, dpair
+
+    zeros = lambda w: jnp.zeros(w.shape, jnp.float32)  # noqa: E731
+    dx, dwg, dwu, dwd, dpair = jax.lax.fori_loop(
+        0, _chunks(tiles_used, chunk_rows, tile_rows), chunk,
+        (jnp.zeros(x.shape, jnp.float32), zeros(w_gate), zeros(w_up),
+         zeros(w_down), jnp.zeros((P,), jnp.float32)))
+    return (dx.astype(x.dtype), dwg.astype(w_gate.dtype),
+            dwu.astype(w_up.dtype), dwd.astype(w_down.dtype),
+            dpair.astype(pair_gate.dtype), None, None, None)
+
+
+expert_ffn.defvjp(_expert_ffn_fwd, _expert_ffn_bwd)
+
+
+class ExpertShareMLP(nn.Module):
+    """Top-k routed SwiGLU experts, dropless, holding a share of them.
+
+    x [B, S, D] -> [B, S, D]. The router scores all ``num_experts``; this
+    layer holds ``experts_held`` of them from ``first_expert`` on (all, by
+    default) and returns the held experts' part of the sum: the shares of
+    the holders of all experts add up to the whole layer. Pairs that chose
+    an expert held elsewhere contribute nothing here. ``renormalize``
+    divides a token's ``top_k`` gates by their sum. There is no capacity
+    and no auxiliary loss.
+
+    **A share held alone does not train the router.** Where the layer holds
+    a part of the experts, its gates pass no gradient. Of a token's
+    ``top_k`` gates only those of experts held here could get one (the
+    other experts' outputs are with their holders); each of those says
+    "more of this expert" as soon as the experts have learnt anything, and
+    an optimizer that steps the router along that partial sum pulls the
+    routing towards the held experts: the rows routed here grew 2.7 x in a
+    hundred steps (PERF.md section 6, PR 26), where a layer that holds all
+    its experts keeps its routing. The gates' gradient belongs where all of
+    a token's expert outputs meet, after the exchange between the holders,
+    which is not written (ROADMAP B-II). A layer that holds all its experts
+    trains its router as any other weight.
+
+    Shapes are static whatever the imbalance: the index buffer has
+    `buffer_rows` rows, which no routing can overflow (see there), and the
+    rows themselves are handled `CHUNK_TILES` tiles at a time, for as many
+    rounds as the routed rows need: the time follows the rows routed here,
+    not the buffer."""
+
+    hidden_dim: int
+    intermediate_dim: int
+    num_experts: int
+    top_k: int
+    experts_held: Optional[int] = None
+    first_expert: int = 0
+    renormalize: bool = True
+    tile_rows: int = TILE_ROWS
+    #: Scale of ``down_proj``'s initial values (a model that scales its
+    #: residual branches' output projections by depth passes it).
+    down_init_scale: float = 1.0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    embed_axis: str = "embed"
+    mlp_axis: str = "mlp"
+
+    @nn.compact
+    def __call__(self, x):
+        from maggy_tpu.telemetry.plans import remember_plan
+
+        B, S, D = x.shape
+        E, F, k = self.num_experts, self.intermediate_dim, self.top_k
+        G = E if self.experts_held is None else self.experts_held
+        if not 0 <= self.first_expert <= E - G:
+            raise ValueError("experts {}..{} are not among {}".format(
+                self.first_expert, self.first_expert + G - 1, E))
+        N = B * S
+        tm = self.tile_rows
+        rows = buffer_rows(N, k, G, tm)
+        # Whole chunks cover the buffer, so no slice of it runs off its end.
+        chunk_rows = tm * max(t for t in range(1, CHUNK_TILES + 1)
+                              if (rows // tm) % t == 0)
+        remember_plan("moe", "experts {}+{}/{} top{} rows {} chunk {} tile {} "
+                      "pallas_gmm".format(self.first_expert, G, E, k, rows,
+                                          chunk_rows, tm), SCOPES)
+
+        router = self.param("router", nn.with_logical_partitioning(
+            nn.initializers.lecun_normal(), (self.embed_axis, None)),
+            (D, E), self.param_dtype)
+
+        def expert_param(name, shape, axes, scale=1.0):
+            return self.param(name, nn.with_logical_partitioning(
+                nn.initializers.variance_scaling(
+                    scale ** 2, "fan_in", "truncated_normal",
+                    batch_axis=(0,)),
+                (EXPERT,) + axes), shape, self.param_dtype)
+
+        w_gate = expert_param("gate_proj", (G, D, F),
+                              (self.embed_axis, self.mlp_axis))
+        w_up = expert_param("up_proj", (G, D, F),
+                            (self.embed_axis, self.mlp_axis))
+        w_down = expert_param("down_proj", (G, F, D),
+                              (self.mlp_axis, self.embed_axis),
+                              self.down_init_scale)
+
+        xd = x.reshape(N, D).astype(self.dtype)
+        with jax.named_scope("moe_routing"):
+            ids, gates = route_top_k(xd, router, k, self.renormalize)
+            if G < E:  # a share held alone: see the class docstring
+                gates = jax.lax.stop_gradient(gates)
+            # Which experts each token took, for whoever asks for the
+            # "intermediates" collection (nothing in a training step).
+            self.sow("intermediates", "expert_ids", ids)
+            layout = grouped_layout(ids, self.first_expert, G, tm)
+        out = expert_ffn(xd, w_gate, w_up, w_down, gates.reshape(N * k),
+                         *layout, k, chunk_rows, tm, not _on_tpu())
+        return out.reshape(B, S, D)

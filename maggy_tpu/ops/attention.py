@@ -14,6 +14,11 @@ TPU-first hot-op design the BERT/Llama baseline configs need:
       (sequential) grid dimension.
     * key-padding masks ([B, Sk] keep-mask) — the BERT fine-tune config's
       mask shape, streamed as one [1, blk_k] tile per k-block.
+    * mask *descriptions* with per-query structure (`BlockDiffusionMask`:
+      the block-diffusion training mask over a noised and a clean copy of
+      the sequence) — computed per tile from indices as the causal mask is;
+      the tiles a description empties are skipped (`_tile_runs`) and the
+      plan counts only those that run.
     * Sq != Sk, with bottom-right-aligned causal masking (offset = Sk-Sq),
       e.g. decode windows / ring-attention shards.
     * head_dim >= 64. At D 64 (BERT-base) two heads of an MHA layer share
@@ -32,7 +37,9 @@ TPU-first hot-op design the BERT/Llama baseline configs need:
 - `attention_reference`: straightforward XLA softmax attention (CPU tests,
   odd shapes).
 - `multi_head_attention`: public entry — dispatches to the kernel when
-  shapes tile cleanly on a TPU backend, XLA reference otherwise.
+  shapes tile cleanly on a TPU backend (no mask, key padding, causal, or a
+  mask description), XLA reference otherwise (mask tensors with per-query
+  structure, lengths that do not tile by 128, head_dim under 64).
 
 Kernel layout follows the pallas guide (/opt/skills/guides/pallas_guide.md):
 the k-block grid dimension is sequential ("arbitrary") and carries the
@@ -47,13 +54,14 @@ masks and every accumulator are float32.
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+# `plans_traced` is re-exported: PR 24's tests and callers take it from here.
+from maggy_tpu.telemetry.plans import plans_traced, remember_plan  # noqa: F401
 
 NEG_INF = -1e30
 
@@ -148,6 +156,91 @@ def _causal_tile_mask(s, q0, k0, offset, q_axis=0):
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     return jnp.where(q_pos + offset >= k_pos, s, NEG_INF)
+
+
+class BlockDiffusionMask(NamedTuple):
+    """A *description* of the block-diffusion training mask (BD3-LM's
+    vectorised step, arXiv:2503.09573), which the kernels compute per tile
+    from indices: no [Sq, Sk] tensor exists anywhere.
+
+    The sequence is two copies of ``length`` data positions, the noised copy
+    first and the clean copy after it (Sq = Sk = 2 x ``length``), both cut
+    into blocks of ``block`` positions. With b(i) the block of data position
+    i, query i sees key j iff
+
+    - i noised, j noised: b(j) == b(i) (its own block);
+    - i noised, j clean:  b(j) <  b(i) (the clean past);
+    - i clean,  j clean:  b(j) <= b(i) (block-causal);
+    - i clean,  j noised: never.
+
+    Every query row sees at least its own position's block, so no row is
+    empty. Hashable: it is a static argument of the kernels' jits."""
+    length: int
+    block: int
+
+    def dense(self) -> jnp.ndarray:
+        """The [2 length, 2 length] keep-mask, for `attention_reference`
+        (off the TPU, and shapes the kernels cannot tile)."""
+        pos = jnp.arange(2 * self.length)
+        return _block_diffusion_visible(pos[:, None], pos[None, :], self)
+
+
+def _block_diffusion_visible(q_pos, k_pos, mask: BlockDiffusionMask):
+    """``q_pos`` [rows, 1] (or [1, cols]) against ``k_pos`` the other way
+    round, both int32 positions in the doubled sequence: the boolean tile.
+    The per-position quantities stay column and row vectors; only the two
+    comparisons and their union work on the whole tile."""
+    q_clean, k_clean = q_pos >= mask.length, k_pos >= mask.length
+    q_blk = (q_pos - jnp.where(q_clean, mask.length, 0)) // mask.block
+    k_blk = (k_pos - jnp.where(k_clean, mask.length, 0)) // mask.block
+    # Clean keys: blocks up to the query's own (clean) or before it (noised).
+    # Noised keys: the query's own block, and only for a noised query. Each
+    # side's vector carries a value the other rule can never meet, so the
+    # tile needs no select between booleans (Mosaic has none).
+    upto = jnp.where(q_clean, q_blk, q_blk - 1)
+    own = jnp.where(q_clean, -1, q_blk)
+    k_as_clean = jnp.where(k_clean, k_blk, jnp.iinfo(jnp.int32).max)
+    k_as_noised = jnp.where(k_clean, -2, k_blk)
+    return (k_as_clean <= upto) | (k_as_noised == own)
+
+
+def _structure_tile_mask(s, q0, k0, structure, q_axis=0):
+    """The block-diffusion mask for a score tile whose first query is ``q0``
+    and first key ``k0``, [queries, keys] or, with ``q_axis=1``, transposed."""
+    rows, cols = s.shape
+    q_shape = (rows, 1) if q_axis == 0 else (1, cols)
+    k_shape = (1, cols) if q_axis == 0 else (rows, 1)
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, q_shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, k_shape, 1 - q_axis)
+    return jnp.where(_block_diffusion_visible(q_pos, k_pos, structure), s,
+                     NEG_INF)
+
+
+def _tile_runs(q0, blk_q, k0, blk_k, offset, causal, structure,
+               lo=min, hi=max):
+    """Whether the [blk_q, blk_k] tile at (q0, k0) holds a visible pair, from
+    indices alone: None where every tile does (no causal mask and no mask
+    description), else a boolean the kernels skip on and `_blocks_run`
+    counts. Works on Python ints, numpy arrays (``lo=np.minimum``) and the
+    kernels' traced program ids (``lo=jnp.minimum``) alike."""
+    runs = None
+    if causal:
+        # Tiles strictly above the diagonal see nothing.
+        runs = k0 < q0 + blk_q + offset
+    if structure is not None:
+        L, b = structure.length, structure.block
+        # Data positions [first, last] of the tile's noised and clean parts,
+        # as block indices; a part is empty where first > last.
+        qn0, qn1 = q0 // b, (lo(q0 + blk_q, L) - 1) // b
+        qc0, qc1 = (hi(q0, L) - L) // b, (q0 + blk_q - 1 - L) // b
+        kn0, kn1 = k0 // b, (lo(k0 + blk_k, L) - 1) // b
+        kc0, kc1 = (hi(k0, L) - L) // b, (k0 + blk_k - 1 - L) // b
+        q_n, q_c = q0 < L, q0 + blk_q > L
+        k_n, k_c = k0 < L, k0 + blk_k > L
+        seen = (q_n & k_n & (qn0 <= kn1) & (kn0 <= qn1)) \
+            | (q_n & k_c & (kc0 < qn1)) | (q_c & k_c & (kc0 <= qc1))
+        runs = seen if runs is None else runs & seen
+    return runs
 
 
 # ------------------------------------------------------------------ tile plan
@@ -249,15 +342,18 @@ def step_vmem_bytes(kernel: str, tiles: KernelTiles, D: int, itemsize: int,
     return tiles_io + scratch + temps
 
 
-def _blocks_run(Sq: int, Sk: int, blk_q: int, blk_k: int, causal: bool) -> int:
+def _blocks_run(Sq: int, Sk: int, blk_q: int, blk_k: int, causal: bool,
+                structure: Optional[BlockDiffusionMask] = None) -> int:
     """Tiles of one head that do work: all of them, or under a causal mask
-    those the diagonal reaches (the kernels skip the rest)."""
+    those the diagonal reaches, or under a mask description those that hold
+    a visible pair (the kernels skip the rest on the same `_tile_runs`)."""
+    import numpy as np
+
     nq, nk = Sq // blk_q, Sk // blk_k
-    if not causal:
-        return nq * nk
-    offset = Sk - Sq
-    return sum(min(nk, max(0, -(-((qi + 1) * blk_q + offset) // blk_k)))
-               for qi in range(nq))
+    runs = _tile_runs(np.arange(nq)[:, None] * blk_q, blk_q,
+                      np.arange(nk)[None, :] * blk_k, blk_k, Sk - Sq, causal,
+                      structure, lo=np.minimum, hi=np.maximum)
+    return nq * nk if runs is None else int(np.sum(runs))
 
 
 def _divisors(n: int):
@@ -270,7 +366,8 @@ def _tiles_of(length: int):
 
 @functools.lru_cache(maxsize=None)
 def tile_plan(Sq: int, Sk: int, D: int, H: int, Hkv: int, itemsize: int,
-              causal: bool, has_mask: bool) -> FlashPlan:
+              causal: bool, has_mask: bool,
+              structure: Optional[BlockDiffusionMask] = None) -> FlashPlan:
     """The tiles each kernel takes at this shape. Pure: the shape decides.
 
     For each kernel, among the tiles that are multiples of 128, divide the
@@ -283,7 +380,9 @@ def tile_plan(Sq: int, Sk: int, D: int, H: int, Hkv: int, itemsize: int,
     tile along the axis the kernel accumulates over. Then a step takes on
     query heads (of one batch row, or of one K/V group, which share the K/V
     tile) until stepping is under ``STEP_SHARE`` of it or VMEM is full.
-    ``has_mask`` moves nothing yet: a key-padding row is 4 KB a step."""
+    ``has_mask`` moves nothing yet: a key-padding row is 4 KB a step. A mask
+    description (``structure``) weighs in as the causal mask does, through
+    the tiles it leaves to run."""
     del has_mask
     kv_shared = H != Hkv
     pack = lane_pack(H, Hkv, D)
@@ -293,7 +392,7 @@ def tile_plan(Sq: int, Sk: int, D: int, H: int, Hkv: int, itemsize: int,
         def cost(pair):
             blk_q, blk_k = pair
             along = blk_q if kernel == "dkdv" else blk_k
-            return (_blocks_run(Sq, Sk, blk_q, blk_k, causal)
+            return (_blocks_run(Sq, Sk, blk_q, blk_k, causal, structure)
                     * (STEP_COST + blk_q * blk_k), -along)
 
         def fits(blk_q, blk_k, heads):
@@ -316,28 +415,20 @@ def tile_plan(Sq: int, Sk: int, D: int, H: int, Hkv: int, itemsize: int,
     return FlashPlan(*plan)
 
 
-_tracing = threading.local()
-
-
-@contextlib.contextmanager
-def plans_traced():
-    """Collects, in order and once each, the `FlashPlan.describe()` of every
-    plan `multi_head_attention` chooses on this thread while the body runs
-    (a program's trace): what `Trainer` notes as ``flash_plan``."""
-    chosen, outer = [], getattr(_tracing, "chosen", None)
-    _tracing.chosen = chosen
-    try:
-        yield chosen
-    finally:
-        _tracing.chosen = outer
-
-
-def _remember(plan: FlashPlan) -> None:
-    chosen = getattr(_tracing, "chosen", None)
-    if chosen is not None:
-        said = plan.describe()
-        if said not in chosen:
-            chosen.append(said)
+def _remember(plan: FlashPlan, Sq: int, Sk: int, causal: bool,
+              structure: Optional[BlockDiffusionMask]) -> None:
+    said = plan.describe()
+    if structure is not None:
+        # The mask kind, its block length, and per kernel the tiles of a
+        # head that run of those there are.
+        said += "; block_diffusion b{} L{} tiles {}".format(
+            structure.block, structure.length, " ".join(
+                "{} {}/{}".format(
+                    name, _blocks_run(Sq, Sk, t.blk_q, t.blk_k, causal,
+                                      structure),
+                    (Sq // t.blk_q) * (Sk // t.blk_k))
+                for name, t in zip(plan._fields, plan)))
+    remember_plan("flash", said)
 
 
 # -------------------------------------------------------------- pallas kernel
@@ -402,7 +493,8 @@ def _each_chunk(slabs, chunks, body):
     _each(slabs, lambda h: _each(chunks, lambda c: body(h, c)))
 
 
-def _scores(q, k_blk, q0, k0, causal, sm_scale, offset, mask_ref):
+def _scores(q, k_blk, q0, k0, causal, sm_scale, offset, mask_ref,
+            structure=None):
     """[rows, blk_k] float32 logits of the queries from ``q0`` on against the
     keys from ``k0`` on: Q K^T on the operands as they are, scaled after the
     dot (forward and backward alike, so that the backward's p is the
@@ -410,9 +502,26 @@ def _scores(q, k_blk, q0, k0, causal, sm_scale, offset, mask_ref):
     s = _dot(q, k_blk, _NT) * sm_scale
     if causal:
         s = _causal_tile_mask(s, q0, k0, offset)
+    if structure is not None:
+        s = _structure_tile_mask(s, q0, k0, structure)
     if mask_ref is not None:
         s = jnp.where(mask_ref[...] != 0, s, NEG_INF)
     return s
+
+
+def _where_tile_runs(qi, blk_q, kb, blk_k, offset, causal, structure, body):
+    """``body()`` on the tiles that hold a visible pair (`_tile_runs`): tiles
+    a causal mask or a mask description empties contribute nothing, so their
+    compute is skipped (the tile fetch still happens; cheap next to the MXU
+    work). With neither, the body runs unconditionally, as it always did."""
+    from jax.experimental import pallas as pl
+
+    runs = _tile_runs(qi * blk_q, blk_q, kb * blk_k, blk_k, offset, causal,
+                      structure, lo=jnp.minimum, hi=jnp.maximum)
+    if runs is None:
+        body()
+    else:
+        pl.when(runs)(body)
 
 
 def _kv(ref, h, rows=slice(None)):
@@ -421,7 +530,8 @@ def _kv(ref, h, rows=slice(None)):
     return (h, rows) if len(ref.shape) == 3 else (rows,)
 
 
-def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass):
+def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
+                      structure=None):
     """One (b, head block, q-block, k-block) program: K/V stream through the
     grid's innermost (sequential) dimension, so VMEM holds one [blk_k, D]
     tile of K and V a head — sequence length is bounded by HBM, not VMEM.
@@ -451,7 +561,8 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass):
     def scores(h, c, j):
         return _scores(_lanes_of(j, pack, q_ref[h, _chunk(c, rows)]),
                        k_ref[_kv(k_ref, h)], qi * blk_q + c * rows,
-                       kb * blk_k, causal, sm_scale, offset, mask_ref)
+                       kb * blk_k, causal, sm_scale, offset, mask_ref,
+                       structure)
 
     def values(h, j, p):
         v_blk = _lanes_of(j, pack, v_ref[_kv(v_ref, h)])
@@ -504,14 +615,8 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass):
             l_ref[h, j, r] = jnp.broadcast_to(l_new, (rows, _LANES))
         acc_ref[h, r] = acc
 
-    if causal:
-        # Blocks entirely above the diagonal contribute nothing — skip the
-        # compute (the tile fetch still happens; cheap next to the MXU work).
-        @pl.when(kb * blk_k < (qi + 1) * blk_q + offset)
-        def _():
-            each_chunk(contribute)
-    else:
-        each_chunk(contribute)
+    _where_tile_runs(qi, blk_q, kb, blk_k, offset, causal, structure,
+                     lambda: each_chunk(contribute))
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -558,8 +663,9 @@ def _q_side(hg, hr, inner):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "tiles", "pack",
-                                             "interpret"))
-def _flash_fwd(qg, kg, vg, mask, causal, tiles, pack, interpret):
+                                             "interpret", "structure"))
+def _flash_fwd(qg, kg, vg, mask, causal, tiles, pack, interpret,
+               structure=None):
     """qg: [B,G,R,Sq,D]; kg/vg: [B,G,Sk,D], ``pack`` heads to the D lanes;
     mask: [B,1,Sk] int32 or None.
     Returns (out [B,G,R,Sq,D], lse [B,G,R,pack,Sq] fp32)."""
@@ -592,7 +698,8 @@ def _flash_fwd(qg, kg, vg, mask, causal, tiles, pack, interpret):
     one_pass = Sk == blk_k and not (causal and offset < 0)
     kernel = functools.partial(_flash_fwd_kernel, causal=causal,
                                sm_scale=sm_scale, has_mask=mask is not None,
-                               offset=offset, one_pass=one_pass)
+                               offset=offset, one_pass=one_pass,
+                               structure=structure)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -639,12 +746,15 @@ def flash_attention(q, k, v, mask=None, causal: bool = True, blk_q: int = 128,
                                    FlashPlan.explicit(blk_q, blk_k), interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def flash_attention_planned(q, k, v, mask, causal: bool, plan: FlashPlan,
-                            interpret: bool = False):
+                            interpret: bool = False,
+                            structure: Optional[BlockDiffusionMask] = None):
     """`flash_attention` with the tiles of each kernel given as a
-    `FlashPlan` (`tile_plan` makes one from the shape)."""
-    out, _ = _flash_fwd_4d(q, k, v, mask, causal, plan.fwd, interpret)
+    `FlashPlan` (`tile_plan` makes one from the shape), and optionally a
+    mask description (``structure``) the kernels compute from indices."""
+    out, _ = _flash_fwd_4d(q, k, v, mask, causal, plan.fwd, interpret,
+                           structure)
     return out
 
 
@@ -661,23 +771,25 @@ def _canon_mask(mask, B, Sk):
     return m.astype(jnp.int32)
 
 
-def _flash_fwd_4d(q, k, v, mask, causal, tiles, interpret):
+def _flash_fwd_4d(q, k, v, mask, causal, tiles, interpret, structure=None):
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     pack = lane_pack(H, Hkv, D)
     mask3 = _canon_mask(mask, B, k.shape[1])
     out_g, lse = _flash_fwd(_grouped_q(q, Hkv, pack), _grouped_kv(k, pack),
                             _grouped_kv(v, pack), mask3, causal, tiles, pack,
-                            interpret)
+                            interpret, structure)
     return _ungroup_q(out_g, pack), lse
 
 
-def _flash_fwd_rule(q, k, v, mask, causal, plan, interpret):
-    out, lse = _flash_fwd_4d(q, k, v, mask, causal, plan.fwd, interpret)
+def _flash_fwd_rule(q, k, v, mask, causal, plan, interpret, structure=None):
+    out, lse = _flash_fwd_4d(q, k, v, mask, causal, plan.fwd, interpret,
+                             structure)
     return out, (q, k, v, mask, out, lse)
 
 
-def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset):
+def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset,
+                           structure=None):
     """grid (B, head block, kb, r, qi): one K/V tile a head per program
     group; the two sequential inner dims stream every (rep, q-block) pair of
     the group through it, accumulating dK/dV in VMEM scratch — GQA gradients
@@ -723,6 +835,9 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset):
             if causal:
                 s = _causal_tile_mask(s, qi * blk_q, kb * blk_k + c * rows,
                                       offset, q_axis=1)
+            if structure is not None:
+                s = _structure_tile_mask(s, qi * blk_q, kb * blk_k + c * rows,
+                                         structure, q_axis=1)
             if mask_ref is not None:  # the key mask as a [rows, 1] column
                 s = jnp.where(mask_ref[:, keys][0][:, None] != 0, s, NEG_INF)
             p = jnp.exp(s - lse_ref[h, j:j + 1])
@@ -732,13 +847,8 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset):
         dv_acc[_kv(dv_acc, h, keys)] += dv
         dk_acc[_kv(dk_acc, h, keys)] += dk
 
-    if causal:
-        # Q blocks strictly above this K tile's diagonal see none of it.
-        @pl.when(kb * blk_k < (qi + 1) * blk_q + offset)
-        def _():
-            _each_chunk(slabs, blk_k // rows, contribute)
-    else:
-        _each_chunk(slabs, blk_k // rows, contribute)
+    _where_tile_runs(qi, blk_q, kb, blk_k, offset, causal, structure,
+                     lambda: _each_chunk(slabs, blk_k // rows, contribute))
 
     @pl.when((r == num_r - 1) & (qi == num_qb - 1))
     def _finalize():
@@ -747,7 +857,8 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset):
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset):
+def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset,
+                         structure=None):
     """grid (B, head block, r, qi, kb): one Q tile a head per program group;
     stream K/V tiles through the sequential kb dimension, accumulating dQ
     in VMEM."""
@@ -781,7 +892,7 @@ def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset):
             q = _lanes_of(j, pack, q_ref[h, r])
             do = _lanes_of(j, pack, do_ref[h, r])
             s = _scores(q, k_blk, qi * blk_q + c * rows, kb * blk_k, causal,
-                        sm_scale, offset, mask_ref)
+                        sm_scale, offset, mask_ref, structure)
             # lane->sublane relayout of the compact [1, rows] statistics
             p = jnp.exp(s - lse_ref[h, j:j + 1, r][0][:, None])
             ds = p * (_dot(do, v_blk, _NT)
@@ -789,12 +900,8 @@ def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset):
             dq += _dot(ds.astype(q.dtype), _lanes_of(j, pack, k_blk), _NN)
         dq_acc[h, r] += dq
 
-    if causal:
-        @pl.when(kb * blk_k < (qi + 1) * blk_q + offset)
-        def _():
-            _each_chunk(slabs, blk_q // rows, contribute)
-    else:
-        _each_chunk(slabs, blk_q // rows, contribute)
+    _where_tile_runs(qi, blk_q, kb, blk_k, offset, causal, structure,
+                     lambda: _each_chunk(slabs, blk_q // rows, contribute))
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -802,9 +909,9 @@ def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "plan", "pack",
-                                             "interpret"))
+                                             "interpret", "structure"))
 def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, plan, pack,
-               interpret):
+               interpret, structure=None):
     """Pallas flash backward. qg/dog: [B,G,R,Sq,D]; kg/vg: [B,G,Sk,D],
     ``pack`` heads to the D lanes; lse/delta: [B,G,R,pack,Sq] fp32
     (compact); mask: [B,1,Sk] int32 or None.
@@ -818,7 +925,7 @@ def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, plan, pack,
     sm_scale = 1.0 / ((D // pack) ** 0.5)
     has_mask = mask is not None
     kernel_args = dict(causal=causal, sm_scale=sm_scale, has_mask=has_mask,
-                       offset=offset)
+                       offset=offset, structure=structure)
 
     # --- dK/dV: grid (B, G, kb, r, qi); r+qi sequential, accumulating.
     blk_q, blk_k, _ = plan.dkdv
@@ -890,7 +997,7 @@ def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, plan, pack,
     return dq, dk, dv
 
 
-def _flash_bwd_rule(causal, plan, interpret, res, g):
+def _flash_bwd_rule(causal, plan, interpret, structure, res, g):
     """Flash backward as two Pallas kernels (dK/dV then dQ), recomputing
     probabilities from the saved log-sum-exp — the S x S matrix never
     materializes and VMEM holds one tile pair a head at a time."""
@@ -905,7 +1012,7 @@ def _flash_bwd_rule(causal, plan, interpret, res, g):
         _grouped_q(q, Hkv, pack), _grouped_kv(k, pack), _grouped_kv(v, pack),
         _grouped_q(g, Hkv, pack), lse,
         _grouped_stats(delta.transpose(0, 2, 1), Hkv, pack), mask3,
-        causal, plan, pack, interpret)
+        causal, plan, pack, interpret, structure)
     return (_ungroup_q(dqg, pack).astype(q.dtype),
             _ungroup_kv(dkg, pack).astype(k.dtype),
             _ungroup_kv(dvg, pack).astype(v.dtype),
@@ -1012,17 +1119,32 @@ def multi_head_attention(q, k, v, causal: bool = True, mask=None,
                          force: Optional[str] = None):
     """Public attention entry: kernel dispatch with XLA fallback.
 
-    q: [B,Sq,H,D], k/v: [B,Sk,Hkv,D]. ``force`` in {"flash", "reference"}
-    overrides dispatch (tests). Flash handles GQA natively (no kv repeat),
-    key-padding masks, Sq != Sk, and head_dim >= 64; masks with per-query
-    structure or non-tiling shapes fall back to the XLA reference.
+    q: [B,Sq,H,D], k/v: [B,Sk,Hkv,D]. ``mask`` is a keep-mask tensor that
+    broadcasts against [B,H,Sq,Sk], or a mask *description*
+    (`BlockDiffusionMask`) that the kernels compute from indices. ``force``
+    in {"flash", "reference"} overrides dispatch (tests). Flash handles GQA
+    natively (no kv repeat), no mask, key-padding masks ([B,1,1,Sk] or
+    [Sk]), causal masks, mask descriptions, Sq != Sk, and head_dim >= 64 at
+    lengths that tile by 128. A mask *tensor* with per-query structure, and
+    any shape that does not tile, go to the XLA reference (a description is
+    made dense for it); on a TPU a shape and mask the kernels can take never
+    does, short of the ``MAGGY_TPU_NO_FLASH`` switch.
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     Hkv = k.shape[2]
     if H % Hkv != 0:
         raise ValueError("H={} not divisible by Hkv={}".format(H, Hkv))
-    pad_mask, mask_ok = _key_padding_mask(mask, B, Sk)
+    structure = mask if isinstance(mask, BlockDiffusionMask) else None
+    if structure is not None:
+        if Sq != Sk or Sq != 2 * structure.length \
+                or structure.length % structure.block:
+            raise ValueError(
+                "{} describes Sq = Sk = {} in whole blocks; got Sq={}, "
+                "Sk={}".format(structure, 2 * structure.length, Sq, Sk))
+        pad_mask, mask_ok = None, True
+    else:
+        pad_mask, mask_ok = _key_padding_mask(mask, B, Sk)
     tiles_ok = (
         mask_ok and D >= 64 and D % 8 == 0
         and Sq % 128 == 0 and Sk % 128 == 0
@@ -1030,18 +1152,25 @@ def multi_head_attention(q, k, v, causal: bool = True, mask=None,
     if force == "flash":
         if not tiles_ok:
             raise ValueError(
-                "force='flash' requires a key-padding (or no) mask, "
-                "D>=64 with D%8==0, and 128-tiling Sq/Sk; got D={}, Sq={}, "
-                "Sk={}, mask shape={}".format(
-                    D, Sq, Sk, None if mask is None else jnp.shape(mask)))
+                "force='flash' requires no mask, a key-padding mask "
+                "([B,1,1,Sk] or [Sk]) or a mask description, D>=64 with "
+                "D%8==0, and 128-tiling Sq/Sk; got D={}, Sq={}, Sk={}, "
+                "mask={}".format(
+                    D, Sq, Sk, mask if structure is not None or mask is None
+                    else jnp.shape(mask)))
         use_flash = True
     else:
         use_flash = force is None and _tpu_backend() and tiles_ok \
             and not _flash_disabled()
     if not use_flash:
+        if structure is not None:
+            mask = structure.dense()
         return attention_reference(q, k, v, causal=causal, mask=mask)
     plan = tile_plan(Sq, Sk, D, H, Hkv, q.dtype.itemsize, causal,
-                     pad_mask is not None)
-    _remember(plan)
+                     pad_mask is not None, structure)
+    _remember(plan, Sq, Sk, causal, structure)
+    # A description rides as one more static argument; without one the call
+    # is the one it always was.
+    extra = () if structure is None else (structure,)
     return flash_attention_planned(q, k, v, pad_mask, causal, plan,
-                                   not _tpu_backend())
+                                   not _tpu_backend(), *extra)

@@ -100,3 +100,20 @@ def chunked_next_token_loss(hidden, kernel, tokens, vocab_chunk: int = 16384):
     h = hidden[:, :-1, :].reshape(-1, H)
     targets = tokens[:, 1:].reshape(-1)
     return chunked_softmax_xent(h, kernel, targets, vocab_chunk)
+
+
+@jax.named_scope("weighted_ce")
+def weighted_token_xent(logits, targets, weights):
+    """``sum_i weights[i] * CE(logits[i], targets[i])``: the masked-token
+    loss of a diffusion language model, whose weights carry everything the
+    objective says beside the cross-entropy (which positions are masked, the
+    1/t of the noise level, the normaliser), so that a position with weight
+    0 counts nothing whatever its logits are.
+
+    logits: [..., V] (any float dtype; the softmax is float32); targets:
+    [...] int ids in [0, V); weights: [...] float."""
+    logits = logits.astype(jnp.float32)
+    picked = jnp.take_along_axis(
+        logits, targets.astype(jnp.int32)[..., None], axis=-1)[..., 0]
+    nll = jax.nn.logsumexp(logits, axis=-1) - picked
+    return jnp.sum(weights.astype(jnp.float32) * nll)

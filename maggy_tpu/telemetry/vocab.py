@@ -252,10 +252,22 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: `ops.attention.FlashPlan.describe` writes them (``fwd q512 k512 h4;
 #: dkdv q512 k512 h4; dq q512 k512 h4``: per kernel the query and key tile
 #: and the heads a grid step covers; several shapes in one program are
-#: joined by `` | ``). Absent where the trial traced nothing (a warm
-#: trial) or the program holds no flash kernel.
+#: joined by `` | ``; under a mask description the plan also says the mask
+#: kind, its block length and per kernel the tiles of a head that run of
+#: those there are: ``; block_diffusion b4 L4096 tiles fwd 80/256 dkdv
+#: 80/256 dq 80/256``). Absent where the trial traced nothing (a warm
+#: trial) or the program holds no flash kernel. ``moe_plan``: what each
+#: dropless expert layer (`models.moe.ExpertShareMLP`) holds and how it
+#: multiplies (``experts 0+16/128 top8 rows 139264 chunk 2048 tile 512
+#: pallas_gmm``: first expert + experts held / routed over, pairs a token,
+#: the index buffer's rows, the rows a chunk handles, the row tile, what
+#: multiplies the groups). ``moe_ops``: ``{scope: [HLO instruction names]}``
+#: of the step executable for the layer's `jax.named_scope`s
+#: (``moe_routing``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``),
+#: by which a trace's operations are told apart. Plans and scopes reach
+#: the record through `telemetry.plans` (``<kind>_plan``, ``<kind>_ops``).
 COMPILED_FIELDS = ("warm", "forked", "vmap_lanes", "first_dispatch",
-                   "flash_plan")
+                   "flash_plan", "moe_plan", "moe_ops")
 
 #: Health-engine event fields (``ev: "health"``).
 HEALTH_STATUSES = frozenset({"raised", "cleared", "started", "error"})

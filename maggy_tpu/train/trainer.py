@@ -583,19 +583,21 @@ class Trainer:
             with slot.aot_lock:
                 fn = slot.compiled_step(key)
                 if fn is None:
-                    from maggy_tpu.ops.attention import plans_traced
+                    from maggy_tpu.telemetry import plans as _plans
 
                     try:
-                        with _warm.span("trace"), plans_traced() as plans:
+                        with _warm.span("trace"), _plans.traced() as said:
                             lowered = self._step.lower(
                                 self.variables, self.opt_state, batch)
-                        if plans:  # which tiles the flash kernels were built at
-                            _warm.note_compile(flash_plan=" | ".join(plans))
                         with _warm.span("compile"):
                             fn = lowered.compile()
                     except Exception:  # noqa: BLE001 - AOT is an optimization
                         slot.aot_ok = False
                         return self._step
+                    # What the traced parts said of themselves (the flash
+                    # kernels' tiles, an expert layer's share), and which
+                    # instructions ran under the scopes they named.
+                    _warm.note_compile(**_plans.notes(said, fn))
                     slot.store_compiled(key, fn)
         return fn
 

@@ -1,0 +1,211 @@
+"""The flash kernels under a mask *description* (`BlockDiffusionMask`):
+values in interpret mode against a dense-mask `attention_reference`, the
+tiles the description empties against brute force, the build for the v5e,
+and the key-padding and causal plans and predicates left as they were."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from maggy_tpu.ops import attention as att
+from maggy_tpu.ops.attention import (BlockDiffusionMask, FlashPlan,
+                                     attention_reference,
+                                     multi_head_attention, tile_plan)
+
+TENSORS = ("out", "dq", "dk", "dv")
+CASES = {
+    # name: data length L, block, H, Hkv, D, explicit tiles or None (planned)
+    "block4_gqa_d128": (128, 4, 4, 2, 128, (128, 128)),
+    "block32_gqa_d128": (128, 32, 4, 2, 128, (128, 128)),
+    # L 192: the halves meet inside the second 128-tile of 384 positions.
+    "block4_straddles_a_tile": (192, 4, 2, 2, 64, (128, 128)),
+    "block32_straddles_planned": (192, 32, 4, 1, 128, None),
+    "block4_planned_256_tiles": (256, 4, 4, 1, 128, None),
+}
+
+
+def test_the_dense_mask_is_the_three_rules():
+    """A noised query sees its own noised block and the clean blocks before
+    it; a clean query the clean blocks up to its own; never clean -> noised."""
+    L, b = 8, 4
+    m = np.asarray(BlockDiffusionMask(L, b).dense())
+    blk = lambda i: (i % L) // b  # noqa: E731
+    for i in range(2 * L):
+        for j in range(2 * L):
+            qn, kn = i < L, j < L
+            want = (qn and kn and blk(j) == blk(i)) \
+                or (qn and not kn and blk(j) < blk(i)) \
+                or (not qn and not kn and blk(j) <= blk(i))
+            assert m[i, j] == want, (i, j)
+    assert m.any(axis=1).all()  # no query row is empty
+
+
+@functools.lru_cache(maxsize=None)
+def _results(name):
+    L, block, H, Hkv, D, tiles = CASES[name]
+    S, mask = 2 * L, BlockDiffusionMask(L, block)
+    rng = np.random.default_rng(L + block)
+    q = jnp.asarray(rng.normal(size=(1, S, H, D)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(1, S, Hkv, D)), jnp.float32)
+            for _ in range(2))
+    w = jnp.asarray(rng.normal(size=(1, S, H, D)), jnp.float32)
+
+    def flash(q, k, v):
+        if tiles is None:
+            return multi_head_attention(q, k, v, causal=False, mask=mask,
+                                        force="flash")
+        return att.flash_attention_planned(
+            q, k, v, None, False, FlashPlan.explicit(*tiles), True, mask)
+
+    def reference(q, k, v):
+        return attention_reference(q, k, v, causal=False, mask=mask.dense())
+
+    def all_of(fn):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return tuple(np.asarray(t) for t in (out,) + vjp(w))
+
+    return {"flash": all_of(flash), "reference": all_of(reference)}
+
+
+@pytest.mark.parametrize("tensor", TENSORS)
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_flash_under_the_description_matches_the_dense_mask(name, tensor):
+    i = TENSORS.index(tensor)
+    got, want = (_results(name)[k][i] for k in ("flash", "reference"))
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
+def _brute_force_tiles(L, block, blk_q, blk_k):
+    m = np.asarray(BlockDiffusionMask(L, block).dense())
+    return sum(bool(m[i:i + blk_q, j:j + blk_k].any())
+               for i in range(0, 2 * L, blk_q) for j in range(0, 2 * L, blk_k))
+
+
+@pytest.mark.parametrize("L,block,blk_q,blk_k", [
+    (256, 4, 128, 128), (192, 32, 128, 128), (192, 4, 128, 128),
+    (512, 4, 256, 128), (384, 64, 128, 256), (1024, 32, 512, 256),
+    (2048, 4, 512, 512)])
+def test_tiles_run_match_brute_force(L, block, blk_q, blk_k):
+    mask = BlockDiffusionMask(L, block)
+    assert att._blocks_run(2 * L, 2 * L, blk_q, blk_k, False, mask) \
+        == _brute_force_tiles(L, block, blk_q, blk_k)
+
+
+def test_the_cell_skips_176_of_256_tiles():
+    """L 4096 in 512-tiles: 8 own-block tiles on the noised diagonal, 36 of
+    noised queries on the clean past, 36 block-causal clean ones."""
+    mask = BlockDiffusionMask(4096, 4)
+    assert att._blocks_run(8192, 8192, 512, 512, False, mask) == 80
+    plan = tile_plan(8192, 8192, 128, 32, 4, 2, False, False, mask)
+    assert plan.describe() == \
+        "fwd q512 k512 h4; dkdv q512 k512 h4; dq q512 k512 h4"
+
+
+@pytest.mark.parametrize("Sq,Sk,blk_q,blk_k", [
+    (512, 512, 128, 128), (128, 384, 128, 128), (384, 128, 128, 128),
+    (2048, 2048, 512, 256), (1024, 2048, 256, 512)])
+def test_causal_tiles_run_are_what_they_were(Sq, Sk, blk_q, blk_k):
+    """The causal count as PR 24 wrote it, in closed form per row of tiles."""
+    nq, nk, offset = Sq // blk_q, Sk // blk_k, Sk - Sq
+    want = sum(min(nk, max(0, -(-((qi + 1) * blk_q + offset) // blk_k)))
+               for qi in range(nq))
+    assert att._blocks_run(Sq, Sk, blk_q, blk_k, True) == want
+    assert att._blocks_run(Sq, Sk, blk_q, blk_k, False) == nq * nk
+
+
+@pytest.mark.parametrize("shape,said", [
+    # BERT-base's cell, Llama's causal GQA, the kept ASHA mix's S 128.
+    ((512, 512, 64, 12, 12, 2, False, True),
+     "fwd q512 k512 h4; dkdv q512 k512 h4; dq q512 k512 h4"),
+    ((2048, 2048, 128, 32, 8, 2, True, False),
+     "fwd q512 k512 h4; dkdv q512 k512 h4; dq q512 k512 h4"),
+    ((128, 128, 64, 12, 12, 2, False, True),
+     "fwd q128 k128 h12; dkdv q128 k128 h12; dq q128 k128 h12"),
+])
+def test_plans_without_a_description_are_unchanged(shape, said):
+    assert tile_plan(*shape).describe() == said
+    assert tile_plan(*shape, None) == tile_plan(*shape)
+
+
+def test_a_description_is_a_static_argument_not_a_branch():
+    """Without a description the kernels are traced exactly as before: the
+    predicate gives None (no `pl.when`) and no mask code is emitted."""
+    assert att._tile_runs(0, 128, 0, 128, 0, False, None) is None
+    assert att._tile_runs(0, 128, 128, 128, 0, True, None) is False
+    assert att._tile_runs(128, 128, 0, 128, 0, True, None) is True
+
+
+def test_the_plan_says_the_mask_and_the_tiles_run():
+    q = jnp.zeros((1, 256, 2, 128), jnp.float32)
+    with att.plans_traced() as plans:
+        multi_head_attention(q, q, q, causal=False,
+                             mask=BlockDiffusionMask(128, 4), force="flash")
+    assert plans == ["fwd q256 k256 h2; dkdv q256 k256 h2; dq q256 k256 h2; "
+                     "block_diffusion b4 L128 tiles fwd 1/1 dkdv 1/1 dq 1/1"]
+
+
+def test_a_description_never_falls_back_silently_on_a_tpu(monkeypatch):
+    monkeypatch.setattr(att, "_tpu_backend", lambda: True)
+    seen = {}
+
+    def stub(q, k, v, mask, causal, plan, interpret, structure):
+        seen.update(mask=mask, structure=structure, interpret=interpret)
+        return q
+
+    monkeypatch.setattr(att, "flash_attention_planned", stub)
+    monkeypatch.setattr(att, "attention_reference",
+                        lambda *a, **k: pytest.fail("reference taken"))
+    q = jnp.zeros((1, 256, 4, 128), jnp.bfloat16)
+    kv = jnp.zeros((1, 256, 2, 128), jnp.bfloat16)
+    mask = BlockDiffusionMask(128, 4)
+    multi_head_attention(q, kv, kv, causal=False, mask=mask)
+    assert seen == {"mask": None, "structure": mask, "interpret": False}
+
+
+@pytest.mark.parametrize("mask,match", [
+    (BlockDiffusionMask(100, 4), "describes Sq = Sk = 200"),
+    (BlockDiffusionMask(128, 3), "whole blocks")])
+def test_a_description_that_does_not_fit_the_shape_is_refused(mask, match):
+    q = jnp.zeros((1, 256, 2, 128), jnp.float32)
+    with pytest.raises(ValueError, match=match):
+        multi_head_attention(q, q, q, causal=False, mask=mask)
+
+
+def test_force_flash_says_what_the_kernels_take():
+    q = jnp.zeros((1, 128, 2, 128), jnp.float32)
+    with pytest.raises(ValueError, match="mask description"):
+        multi_head_attention(q, q, q, mask=jnp.ones((128, 128), bool),
+                             force="flash")
+
+
+def test_the_three_kernels_compile_for_the_v5e_under_a_description():
+    """The cell's shape but for the batch (one sequence), through the real
+    Mosaic compiler: tests/test_flash_compile.py has the other classes."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:  # noqa: BLE001 - no libtpu, or one without AOT
+        pytest.skip("libtpu cannot describe a v5e:2x2 topology: {!r}".format(e))
+    dev = SingleDeviceSharding(topo.devices[0])
+    L, H, Hkv, D = 4096, 32, 4, 128
+    mask = BlockDiffusionMask(L, 4)
+    plan = tile_plan(2 * L, 2 * L, D, H, Hkv, 2, False, False, mask)
+    q = jax.ShapeDtypeStruct((1, 2 * L, H, D), jnp.bfloat16, sharding=dev)
+    kv = jax.ShapeDtypeStruct((1, 2 * L, Hkv, D), jnp.bfloat16, sharding=dev)
+
+    def loss(q, k, v):
+        out = att.flash_attention_planned(q, k, v, None, False, plan, False,
+                                          mask)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile() \
+        .as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"):
+        assert "%{}".format(name) in text

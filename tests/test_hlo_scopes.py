@@ -1,0 +1,73 @@
+"""`telemetry.hlo_scopes.ops_by_scope`: instruction names by named scope,
+from an executable's text; `telemetry.plans`: what a trace's parts say of
+themselves, and the ``compiled`` record's fields made of it."""
+
+from maggy_tpu.telemetry import plans
+from maggy_tpu.telemetry.hlo_scopes import ops_by_scope
+
+TEXT = '''
+%fused_computation.7 (p: f32[8]) -> f32[8] {
+  %inner.1 = f32[8] add(%p, %p), metadata={op_name="jit(f)/moe_experts/add"}
+}
+
+%region_1.2 (a: f32[], b: f32[]) -> f32[] {
+  ROOT %add.9 = f32[] add(%a, %b), metadata={op_name="jit(f)/other/add"}
+}
+
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8] parameter(0), metadata={op_name="jit(f)/moe_routing/x"}
+  %fusion.3 = f32[8] fusion(%x), kind=kLoop, calls=%fused_computation.7, metadata={op_name="jit(f)/layer_0/moe/moe_experts/mul"}
+  %moe_gmm_fwd.5 = f32[8] custom-call(%fusion.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/layer_0/moe/while/body/moe_experts/moe_gmm_fwd"}
+  %gte.1 = f32[8] get-tuple-element(%t), index=0, metadata={op_name="jit(f)/moe_dispatch/gte"}
+  %fusion.4 = f32[8] fusion(%x), kind=kLoop, calls=%fused_computation.8, metadata={op_name="jit(f)/moe_dispatch/gather"}
+  ROOT %copy.2 = f32[8] copy(%fusion.4), metadata={op_name="jit(f)/optimizer/copy"}
+}
+'''
+
+
+def test_top_level_instructions_by_innermost_scope():
+    found = ops_by_scope(TEXT, ("moe_routing", "moe_dispatch", "moe_experts",
+                                "moe_combine"))
+    # No fused-computation bodies, no parameters or tuple plumbing, and a
+    # scope without an instruction is left out.
+    assert found == {"moe_dispatch": ["fusion.4"],
+                     "moe_experts": ["fusion.3", "moe_gmm_fwd.5"]}
+
+
+def test_no_scope_no_names():
+    assert ops_by_scope(TEXT, ()) == {}
+    assert ops_by_scope("", ("moe_experts",)) == {}
+
+
+class Compiled:
+    def __init__(self, text):
+        self.text = text
+
+    def as_text(self):
+        if self.text is None:
+            raise RuntimeError("no text")
+        return self.text
+
+
+def test_notes_are_made_of_whatever_kinds_spoke():
+    with plans.traced() as said:
+        plans.remember_plan("flash", "fwd q128")
+        plans.remember_plan("flash", "fwd q128")  # once
+        plans.remember_plan("flash", "fwd q256")
+        plans.remember_plan("moe", "experts 0+4/8",
+                            ("moe_dispatch", "moe_experts"))
+    plans.remember_plan("moe", "none open")
+    assert plans.notes(said, Compiled(TEXT)) == {
+        "flash_plan": "fwd q128 | fwd q256", "moe_plan": "experts 0+4/8",
+        "moe_ops": {"moe_dispatch": ["fusion.4"],
+                    "moe_experts": ["fusion.3", "moe_gmm_fwd.5"]}}
+    # An executable without a text costs the ops, not the plans; a trace in
+    # which nothing spoke notes nothing and reads no text.
+    assert plans.notes(said, Compiled(None)) == {
+        "flash_plan": "fwd q128 | fwd q256", "moe_plan": "experts 0+4/8"}
+    # ... and so does a text that cannot be read through.
+    assert plans.notes(said, Compiled(0)) == {
+        "flash_plan": "fwd q128 | fwd q256", "moe_plan": "experts 0+4/8"}
+    with plans.traced() as silent:
+        pass
+    assert plans.notes(silent, Compiled(None)) == {}
