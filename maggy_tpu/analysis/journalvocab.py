@@ -17,7 +17,11 @@ speaks. This checker verifies three directions, all statically:
 Span names are checked the same two ways: every literal name a
 ``span(...)`` / ``TraceAnnotation(...)`` / ``StepTraceAnnotation(...)``
 call opens is in ``SPAN_NAMES`` or ``ANNOTATION_NAMES``, and every entry
-of either is opened somewhere.
+of either is opened somewhere. So are the fields a traced part of the
+program gives the ``compiled`` record: a ``remember_plan("kind", ...)``
+call stands for ``kind_plan`` (and ``kind_ops`` where it names scopes),
+which must be in ``COMPILED_FIELDS``, and every ``*_plan`` / ``*_ops``
+entry there is said by some call.
 
 ``# vocab-ok: <reason>`` on the emit/consume line suppresses.
 """
@@ -75,6 +79,8 @@ class Vocab:
         if name == "span_name":
             return self.sets.get("SPAN_NAMES", set()) \
                 | self.sets.get("ANNOTATION_NAMES", set())
+        if name == "compiled_field":
+            return self.sets.get("COMPILED_FIELDS", set())
         return set()
 
 
@@ -172,6 +178,17 @@ def _collect_emits(index: PackageIndex, vocab_mod
                 if node.args and _is_str(node.args[0]):
                     out.append(("span_name", node.args[0].value, mod,
                                 node.lineno))
+            elif name == "remember_plan":
+                # telemetry.plans: the record gains <kind>_plan, and
+                # <kind>_ops where the call names the kind's scopes.
+                if node.args and _is_str(node.args[0]):
+                    kind = node.args[0].value
+                    out.append(("compiled_field", kind + "_plan", mod,
+                                node.lineno))
+                    if len(node.args) >= 3 or any(
+                            kw.arg == "scopes" for kw in node.keywords):
+                        out.append(("compiled_field", kind + "_ops", mod,
+                                    node.lineno))
             elif name == "mark":
                 # SpanTracker.mark(trial, "phase") — the facade's inner
                 # edge; literal phases here are emits too.
@@ -352,6 +369,14 @@ def check(index: PackageIndex) -> List["Finding"]:
                 emit_finding(vocab.mod, vocab.lines.get(entry, 1),
                              "vocabulary entry {!r} ({}) is never emitted "
                              "by any call site".format(entry, set_name))
+
+    # Of the compiled record's fields, those a traced part gives.
+    for entry in sorted(vocab.sets.get("COMPILED_FIELDS", set())):
+        if entry.endswith(("_plan", "_ops")) and entry not in \
+                emitted_by_family.get("compiled_field", set()):
+            emit_finding(vocab.mod, vocab.lines.get(entry, 1),
+                         "vocabulary entry {!r} (COMPILED_FIELDS) is said "
+                         "by no remember_plan call".format(entry))
 
     for fam, lit, mod, line in _collect_consumes(index, vocab.mod):
         if lit not in vocab.family(fam):

@@ -39,6 +39,7 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 EXPERT = "expert"
 
@@ -171,6 +172,12 @@ VMEM_LIMIT = 32 * 2 ** 20
 #: whoever traces the step can note which of its instructions ran under
 #: each (``moe_ops`` of the ``compiled`` record).
 SCOPES = ("moe_routing", "moe_dispatch", "moe_experts", "moe_combine")
+
+#: The name `ExpertShareMLP` gives what its routing hands to `expert_ffn`:
+#: the pairs' gates and `grouped_layout`'s three, under 3 MB a layer at
+#: 16,384 positions. A caller that rematerialises the layer and keeps it
+#: (`save_only_these_names`) scores, selects and sorts once a step.
+REMAT_KEEP = ("moe_route",)
 
 
 def _block(dim: int, cap: int = BLOCK_CAP) -> int:
@@ -594,7 +601,9 @@ class ExpertShareMLP(nn.Module):
             # Which experts each token took, for whoever asks for the
             # "intermediates" collection (nothing in a training step).
             self.sow("intermediates", "expert_ids", ids)
-            layout = grouped_layout(ids, self.first_expert, G, tm)
-        out = expert_ffn(xd, w_gate, w_up, w_down, gates.reshape(N * k),
-                         *layout, k, chunk_rows, tm, not _on_tpu())
+            route = [checkpoint_name(r, REMAT_KEEP[0]) for r in (
+                gates.reshape(N * k),
+                *grouped_layout(ids, self.first_expert, G, tm))]
+        out = expert_ffn(xd, w_gate, w_up, w_down, *route, k, chunk_rows, tm,
+                         not _on_tpu())
         return out.reshape(B, S, D)

@@ -49,12 +49,14 @@ import dataclasses
 from typing import Any, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
+from maggy_tpu.models import moe
 from maggy_tpu.models.llama import (EMBED, HEADS, KV, VOCAB, LoRADense,
                                     RMSNorm, rope)
-from maggy_tpu.models.moe import ExpertShareMLP
-from maggy_tpu.ops.attention import BlockDiffusionMask, multi_head_attention
+from maggy_tpu.ops import attention
+from maggy_tpu.telemetry.plans import remember_plan
 
 #: What the q and k norms' learned scales start at: the scores' spread is
 #: then 3 where unit scales give 1.
@@ -62,6 +64,12 @@ QK_SCALE_INIT = 3 ** 0.5
 #: The scale the mask id's embedding row starts at, beside the unit-normal
 #: rows of the data tokens (a reserved token that no pretraining trained).
 MASK_EMBED_SCALE = 0.3
+#: What a rematerialised layer keeps beside its input: what is dear to make
+#: again and cheap to hold, by the names the two layers below give it (the
+#: flash kernel's output and log-sum-exp, twice the layer's input in bytes;
+#: the routing's indices and gates, under 3 MB). All else is made again in
+#: the backward pass.
+REMAT_KEEP = attention.REMAT_KEEP + moe.REMAT_KEEP
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,7 +138,8 @@ class SdarAttention(nn.Module):
                         "k_proj")(x), cfg.num_kv_heads, "k_norm")
         v = dense(cfg.num_kv_heads * cfg.head_dim, (EMBED, KV), "v_proj")(
             x).reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
-        out = multi_head_attention(q, k, v, causal=False, mask=mask)
+        out = attention.multi_head_attention(q, k, v, causal=False,
+                                             mask=mask)
         return dense(cfg.hidden_dim, (HEADS, EMBED), "o_proj")(
             out.reshape(B, S, cfg.num_heads * cfg.head_dim))
 
@@ -141,11 +150,12 @@ class SdarLayer(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        mask = BlockDiffusionMask(x.shape[1] // 2, cfg.block_length)
+        mask = attention.BlockDiffusionMask(x.shape[1] // 2,
+                                            cfg.block_length)
         h = x + SdarAttention(cfg, name="attn")(
             RMSNorm(cfg.norm_eps, cfg.param_dtype, name="attn_norm")(x),
             positions, mask)
-        moe = ExpertShareMLP(
+        experts = moe.ExpertShareMLP(
             hidden_dim=cfg.hidden_dim,
             intermediate_dim=cfg.moe_intermediate_dim,
             num_experts=cfg.num_experts, top_k=cfg.top_k,
@@ -153,7 +163,7 @@ class SdarLayer(nn.Module):
             renormalize=cfg.norm_topk_prob,
             down_init_scale=cfg.residual_scale, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="moe")
-        return h + moe(
+        return h + experts(
             RMSNorm(cfg.norm_eps, cfg.param_dtype, name="mlp_norm")(h))
 
 
@@ -187,7 +197,13 @@ class SdarMoe(nn.Module):
             embedding_init, (VOCAB, EMBED)),
             (cfg.vocab_size, cfg.hidden_dim), cfg.param_dtype)
         x = emb.astype(cfg.dtype)[tokens]
-        layer_cls = nn.remat(SdarLayer) if cfg.remat else SdarLayer
+        layer_cls = SdarLayer
+        if cfg.remat:
+            remember_plan("remat", "layer keeps " + " ".join(REMAT_KEEP))
+            layer_cls = nn.remat(
+                SdarLayer,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *REMAT_KEEP))
         for i in range(cfg.num_layers):
             x = layer_cls(cfg, name="layer_{}".format(i))(x, positions)
         x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(

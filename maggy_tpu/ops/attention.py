@@ -59,11 +59,19 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 # `plans_traced` is re-exported: PR 24's tests and callers take it from here.
 from maggy_tpu.telemetry.plans import plans_traced, remember_plan  # noqa: F401
 
 NEG_INF = -1e30
+
+#: The names `flash_attention_planned`'s forward rule gives its output and
+#: its log-sum-exp (`jax.ad_checkpoint.checkpoint_name`). A caller that
+#: rematerialises a layer and keeps these (`save_only_these_names`) does not
+#: run the forward kernel again in its backward pass; outside a
+#: `jax.checkpoint` a name is an identity and lowers to nothing.
+REMAT_KEEP = ("flash_out", "flash_lse")
 
 
 # ----------------------------------------------------------------- reference
@@ -785,6 +793,10 @@ def _flash_fwd_4d(q, k, v, mask, causal, tiles, interpret, structure=None):
 def _flash_fwd_rule(q, k, v, mask, causal, plan, interpret, structure=None):
     out, lse = _flash_fwd_4d(q, k, v, mask, causal, plan.fwd, interpret,
                              structure)
+    # Named here, so that the primal and the residuals are the named values:
+    # naming the output in the caller would keep `out` and still run the
+    # kernel again for `lse`.
+    out, lse = map(checkpoint_name, (out, lse), REMAT_KEEP)
     return out, (q, k, v, mask, out, lse)
 
 

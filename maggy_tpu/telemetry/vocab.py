@@ -264,10 +264,17 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: multiplies the groups). ``moe_ops``: ``{scope: [HLO instruction names]}``
 #: of the step executable for the layer's `jax.named_scope`s
 #: (``moe_routing``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``),
-#: by which a trace's operations are told apart. Plans and scopes reach
-#: the record through `telemetry.plans` (``<kind>_plan``, ``<kind>_ops``).
+#: by which a trace's operations are told apart. ``remat_plan``: what a
+#: model whose layers are rematerialised keeps of each layer beside its
+#: input, by the names the parts give those values (``layer keeps
+#: flash_out flash_lse moe_route``, `models.sdar`: the forward kernel and
+#: the routing then run once a step and not again in the backward pass);
+#: absent where the traced model keeps nothing by name. Plans and scopes
+#: reach the record through `telemetry.plans` (``<kind>_plan``,
+#: ``<kind>_ops``), and the vocabulary checker holds these entries to the
+#: `remember_plan` calls.
 COMPILED_FIELDS = ("warm", "forked", "vmap_lanes", "first_dispatch",
-                   "flash_plan", "moe_plan", "moe_ops")
+                   "flash_plan", "moe_plan", "moe_ops", "remat_plan")
 
 #: Health-engine event fields (``ev: "health"``).
 HEALTH_STATUSES = frozenset({"raised", "cleared", "started", "error"})

@@ -434,6 +434,46 @@ class TestJournalVocabChecker:
         assert _findings(analyze_paths(paths, checkers=("journalvocab",)),
                          "journalvocab") == []
 
+    PLAN_VOCAB = VOCAB_FIXTURE + '''
+COMPILED_FIELDS = ("warm", "flash_plan", "moe_plan", "moe_ops", "remat_plan")
+'''
+    PLAN_CALLS = EMIT_CLEAN + '''
+def trace_parts(scopes):
+    remember_plan("flash", "fwd q128")
+    remember_plan("moe", "experts 0+4/8", scopes)
+    remember_plan("{}", "layer keeps flash_out")
+'''
+
+    @pytest.mark.parametrize("said,finding", [
+        ("remat", None),
+        ("rematt", "emitted compiled_field 'rematt_plan' is not in"),
+        ("flash", "entry 'remat_plan' (COMPILED_FIELDS) is said by no"),
+    ])
+    def test_compiled_fields_are_held_to_the_remember_plan_calls(
+            self, tmp_path, said, finding):
+        # A kind nobody listed, and a listed plan that no traced part says
+        # (``warm`` is the trainer's own field, not a plan: never asked for).
+        paths = [_write(tmp_path, "vocab.py", self.PLAN_VOCAB),
+                 _write(tmp_path, "code.py", self.PLAN_CALLS.format(said))]
+        out = _findings(analyze_paths(paths, checkers=("journalvocab",)),
+                        "journalvocab")
+        if finding is None:
+            assert out == []
+        else:
+            assert any(finding in f.message for f in out)
+            assert len(out) == (2 if said == "rematt" else 1)
+
+    def test_the_package_says_every_plan_it_lists(self):
+        # The model's `remember_plan("remat", ...)` is what `remat_plan`
+        # in COMPILED_FIELDS stands on: without the entry the package run
+        # has a finding, with it none.
+        from maggy_tpu.telemetry import vocab
+
+        assert {"flash_plan", "moe_plan", "moe_ops", "remat_plan"} \
+            <= set(vocab.COMPILED_FIELDS)
+        report = run_analysis(checkers=("journalvocab",))
+        assert report["summary"] == {"journalvocab": 0}
+
     def test_package_vocab_module_exists(self):
         # The real vocabulary module the checker verifies against.
         from maggy_tpu.telemetry import vocab

@@ -181,6 +181,82 @@ def test_force_flash_says_what_the_kernels_take():
                              force="flash")
 
 
+REMAT_MASKS = {
+    "description": lambda S: (None, BlockDiffusionMask(S // 2, 4)),
+    "key_padding": lambda S: (jnp.arange(S)[None, :] < S - 40, None),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _under_checkpoint(kind):
+    """dq, dk, dv and the gradient's `pallas_call`s of one attention between
+    two products, with no `jax.checkpoint`, under one that keeps nothing and
+    under one that keeps the names the forward rule gives."""
+    from jaxpr_counts import primitives
+
+    S, H, Hkv, D = 256, 4, 2, 64
+    pad, structure = REMAT_MASKS[kind](S)
+    rng = np.random.default_rng(S)
+    q, w = (jnp.asarray(rng.normal(size=(1, S, H, D)), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=(1, S, Hkv, D)), jnp.float32)
+            for _ in range(2))
+
+    def loss(q, k, v):  # a layer's shape: work before and after the kernel
+        out = att.flash_attention_planned(
+            q * 0.5, k, jnp.tanh(v), pad, False,
+            FlashPlan.explicit(128, 128), True, structure)
+        return jnp.sum(jnp.tanh(out) * w)
+
+    policy = jax.checkpoint_policies.save_only_these_names(*att.REMAT_KEEP)
+    found = {}
+    for name, fn in (("plain", loss), ("remat", jax.checkpoint(loss)),
+                     ("policy", jax.checkpoint(loss, policy=policy))):
+        grad = jax.grad(fn, (0, 1, 2))
+        counts = primitives(grad, q, k, v)
+        found[name] = (
+            tuple(np.asarray(g) for g in grad(q, k, v)),
+            {n: counts["pallas_call:" + n]
+             for n in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")})
+    return found
+
+
+@pytest.mark.parametrize("kind", sorted(REMAT_MASKS))
+def test_a_checkpoint_that_keeps_the_names_runs_the_forward_kernel_once(kind):
+    """Under `jax.checkpoint` with no policy the backward pass runs the
+    forward kernel again for ``out`` and ``lse`` (what a rematerialised
+    layer paid until PR 27); keeping `REMAT_KEEP` it does not."""
+    found = _under_checkpoint(kind)
+    once = {"flash_fwd": 1, "flash_bwd_dkdv": 1, "flash_bwd_dq": 1}
+    assert found["plain"][1] == once
+    assert found["remat"][1] == dict(once, flash_fwd=2)
+    assert found["policy"][1] == once
+
+
+@pytest.mark.parametrize("tensor", TENSORS[1:])
+@pytest.mark.parametrize("kind", sorted(REMAT_MASKS))
+def test_the_kept_values_are_bitwise_what_a_second_run_gives(kind, tensor):
+    i = TENSORS.index(tensor) - 1
+    found = _under_checkpoint(kind)
+    assert np.any(found["plain"][0][i] != 0)
+    for name in ("remat", "policy"):
+        np.testing.assert_array_equal(found[name][0][i], found["plain"][0][i])
+
+
+def test_a_name_outside_a_checkpoint_lowers_to_nothing():
+    """With no `jax.checkpoint` around it the forward rule's names are
+    identities: the lowered program does not mention them."""
+    q = jnp.zeros((1, 128, 2, 64), jnp.float32)
+
+    def loss(q):
+        return jnp.sum(att.flash_attention_planned(
+            q, q, q, None, False, FlashPlan.explicit(128, 128), True))
+
+    text = jax.jit(jax.grad(loss)).lower(q).as_text()
+    assert not any(name in text for name in att.REMAT_KEEP)
+    assert "checkpoint" not in text and "optimization_barrier" not in text
+
+
 def test_the_three_kernels_compile_for_the_v5e_under_a_description():
     """The cell's shape but for the batch (one sequence), through the real
     Mosaic compiler: tests/test_flash_compile.py has the other classes."""

@@ -3,6 +3,8 @@ against a dense float32 evaluation of the same equations (every expert on
 every token, weighted by its gate). The grouped products run in Pallas
 interpret mode here; the last test builds them for the v5e."""
 
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -144,6 +146,62 @@ def test_the_layer_says_what_it_holds_and_which_scopes_are_its_own():
     assert said.plans == {
         "moe": ["experts 2+2/8 top2 rows 176 chunk 16 tile 8 pallas_gmm"]}
     assert said.scopes == {"moe": moe.SCOPES}
+
+
+@functools.lru_cache(maxsize=None)
+def _under_checkpoint(held):
+    """Every gradient leaf, and what the gradient's jaxpr holds, of a layer
+    between two products: with no `jax.checkpoint`, under one that keeps
+    nothing and under one that keeps the routing's name."""
+    from jaxpr_counts import primitives
+
+    layer, params, x = make(0 if held is None else 2, held)
+    w = jnp.asarray(np.random.default_rng(5).normal(size=x.shape),
+                    jnp.float32)
+
+    def loss(p, x):
+        return jnp.sum(jnp.tanh(layer.apply({"params": p}, jnp.tanh(x))) * w)
+
+    policy = jax.checkpoint_policies.save_only_these_names(*moe.REMAT_KEEP)
+    found = {}
+    for name, fn in (("plain", loss), ("remat", jax.checkpoint(loss)),
+                     ("policy", jax.checkpoint(loss, policy=policy))):
+        grad = jax.grad(fn, (0, 1))
+        found[name] = (jax.tree_util.tree_leaves_with_path(grad(params, x)),
+                       primitives(grad, params, x))
+    return found
+
+
+@pytest.mark.parametrize("held", [2, None])
+def test_a_checkpoint_that_keeps_the_route_sorts_once(held):
+    """The layout's sort (and for a share the scores and the selection with
+    it) runs again in the backward pass of a `jax.checkpoint` with no
+    policy; keeping `REMAT_KEEP` it does not. A layer that holds all its
+    experts trains its router, so it makes the softmax again either way."""
+    found = _under_checkpoint(held)
+    sorts, top_ks = ({name: counts[prim] for name, (_g, counts)
+                      in found.items()} for prim in ("sort", "top_k"))
+    assert sorts == {"plain": 1, "remat": 2, "policy": 1}
+    assert top_ks == {"plain": 1, "remat": 2,
+                      "policy": 1 if held is not None else 2}
+    for name in ("moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"):
+        kernel = "pallas_call:" + name
+        assert found["policy"][1][kernel] == found["remat"][1][kernel] > 0
+
+
+@pytest.mark.parametrize("held", [2, None])
+def test_the_kept_route_is_bitwise_what_a_second_routing_gives(held):
+    found = _under_checkpoint(held)
+    plain = found["plain"][0]
+    assert len(plain) == 5  # four weights and x
+    for name in ("remat", "policy"):
+        for (path, want), (_p, got) in zip(plain, found[name][0]):
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(want),
+                err_msg=name + jax.tree_util.keystr(path))
+    router = dict((jax.tree_util.keystr(p), g) for p, g in plain)[
+        "[0]['router']"]
+    assert bool(jnp.any(router != 0)) == (held is None)
 
 
 def test_experts_outside_the_routed_ones_are_refused():

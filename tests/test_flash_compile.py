@@ -6,6 +6,8 @@ topology description, through the real Mosaic/XLA:TPU compiler. Interpret
 mode (tests/test_models.py) proves the algorithm; this proves the build.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -87,13 +89,49 @@ def test_the_three_kernels_carry_their_names(v5e_device):
         "flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]
 
 
+@pytest.mark.parametrize("keeps,forwards", [(False, 2), (True, 1)],
+                         ids=["keeps_nothing", "keeps_the_names"])
+def test_a_checkpointed_layer_compiles_with_one_forward_kernel(
+        v5e_device, keeps, forwards):
+    """Through XLA:TPU as through the jaxpr: a `jax.checkpoint` that keeps
+    the forward rule's names (`REMAT_KEEP`) holds the forward kernel once,
+    one that keeps nothing twice; the SDAR cell's heads (GQA, D 128) under
+    its mask description."""
+    from maggy_tpu.ops import attention
+    from maggy_tpu.ops.attention import BlockDiffusionMask
+
+    names = attention.REMAT_KEEP if keeps else ()
+    q = jax.ShapeDtypeStruct((1, 1024, 8, 128), jnp.bfloat16,
+                             sharding=v5e_device)
+    kv = jax.ShapeDtypeStruct((1, 1024, 2, 128), jnp.bfloat16,
+                              sharding=v5e_device)
+    mask = BlockDiffusionMask(512, 4)
+    plan = tile_plan(1024, 1024, 128, 8, 2, 2, False, False, mask)
+
+    @functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(*names))
+    def layer(q, k, v):
+        return flash_attention_planned(q * 2, k, v, None, False, plan, False,
+                                       mask)
+
+    def loss(q, k, v):  # what follows the layer needs its output
+        return jnp.sum(jnp.tanh(layer(q, k, v).astype(jnp.float32)))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
+        ).as_text()
+    assert _kernel_names(text) == (
+        ["flash_bwd_dkdv", "flash_bwd_dq"] + ["flash_fwd"] * forwards)
+
+
 def _kernel_names(text):
     """The names the Mosaic kernels of a compiled program carry in their
     HLO instructions' names (autodiff wraps them: ``%jvp_flash_fwd_.1``)."""
     import re
 
     return sorted(
-        re.search(r"flash_[a-z]+(?:_[a-z]+)*", line.split(" = ")[0]).group(0)
+        re.search(r"(?:flash|moe_gmm)_[a-z]+(?:_[a-z]+)*",
+                  line.split(" = ")[0]).group(0)
         for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line)
 
@@ -150,3 +188,50 @@ def test_a_bert_width_step_carries_the_scope_names(v5e_device, monkeypatch):
     for op_name in re.findall(r'op_name="([^"]*)"', text):
         scopes.update(op_name.split("/"))
     assert {"attention", "mlp", "loss_and_grad", "optimizer"} <= scopes
+
+
+def test_a_rematerialised_sdar_step_holds_each_kernel_once_a_layer(
+        v5e_device, monkeypatch):
+    """The model's own gradient through XLA:TPU, two layers at toy widths
+    (heads of 128, GQA, a share of the experts): with ``remat=True`` the
+    layers keep `models.sdar.REMAT_KEEP`, so the executable holds ONE
+    `flash_fwd` a layer beside its two backward kernels (two before PR 27);
+    the grouped products are what the expert layer's own VJP asks for
+    either way (three forward, two of them again in the backward pass)."""
+    import collections
+
+    import flax.linen as nn
+
+    from maggy_tpu.models import SdarMoe, SdarMoeConfig, moe
+    from maggy_tpu.ops import attention
+    from maggy_tpu.ops.losses import weighted_token_xent
+
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    cfg = SdarMoeConfig(
+        vocab_size=512, hidden_dim=256, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=128, moe_intermediate_dim=128,
+        num_experts=8, top_k=2, experts_held=4, mask_token_id=511)
+    assert cfg.remat
+    module, L = SdarMoe(cfg), 256
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=v5e_device), tree)
+
+    tokens = jax.ShapeDtypeStruct((1, 2 * L), jnp.int32, sharding=v5e_device)
+    targets = jax.ShapeDtypeStruct((1, L), jnp.int32, sharding=v5e_device)
+    weights = jax.ShapeDtypeStruct((1, L), jnp.float32, sharding=v5e_device)
+    params = abstract(nn.meta.unbox(jax.eval_shape(
+        module.init, jax.random.key(0), tokens))["params"])
+
+    def loss(p, tokens, targets, weights):
+        return weighted_token_xent(module.apply({"params": p}, tokens),
+                                   targets, weights)
+
+    text = jax.jit(jax.grad(loss)).lower(
+        params, tokens, targets, weights).compile().as_text()
+    assert collections.Counter(_kernel_names(text)) == {
+        "flash_fwd": 2, "flash_bwd_dkdv": 2, "flash_bwd_dq": 2,
+        "moe_gmm_fwd": 10, "moe_gmm_dlhs": 6, "moe_gmm_drhs": 6}
