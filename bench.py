@@ -77,7 +77,7 @@ def train_mnist(lr, batch=256, budget=1, reporter=None):
     model = MnistCNN(kernel_size=3, pool_size=2, features=16, num_classes=2)
     # lr rides in opt_state (swept_transform), so every trial of the sweep
     # is the SAME program: repeat-shape trials reuse the warm slot's
-    # compiled step and donated state buffers.
+    # compiled step and init programs.
     trainer = Trainer(
         model, swept_transform(optax.adam, learning_rate=lr),
         _bench_loss, mesh, strategy="dp",
@@ -111,8 +111,8 @@ VMAP_BATCH = int(os.environ.get("BENCH_VMAP_BATCH", "256"))
 
 def train_mnist_vmap(lr, lanes=None, reporter=None):
     """Micro-trial for the --vmap gate: a tiny MnistMLP (matmul +
-    elementwise only — the model family the bitwise lane-parity property
-    is pinned on) trained full-batch for VMAP_STEPS. Lanes-capable: under
+    elementwise only — the model family the lane-parity property is
+    stated on) trained full-batch for VMAP_STEPS. Lanes-capable: under
     ``config.vmap_lanes`` > 1 the executor hands a `LaneSet` and the K
     configs train as ONE vmapped program; with ``lanes=None`` (scalar
     dispatch, and the warm-up trial every runner's first dispatch always
@@ -1248,12 +1248,13 @@ def fork_main():
     return 0 if ok else 1
 
 
-def _vmap_lane_parity(steps=25):
-    """Engine-level bitwise sub-gate for --vmap (idiom shared with
+def _vmap_lane_parity():
+    """Engine-level parity sub-gate for --vmap (idiom shared with
     tests/test_vmap.py): K scalar Trainer runs vs one VmapTrainer block
-    over the SAME configs must agree bit-for-bit per lane, per step —
-    MnistMLP is matmul+elementwise only, so XLA's scalar and vmapped
-    programs schedule the same float ops in the same order. Returns a
+    over the SAME configs must agree per lane, per step, to the parity
+    the platform gives (`train/vmap.py`, module docstring: the K-lane
+    program batches its matmuls, so `LANE_VS_SCALAR_ULP` float32 ulp over
+    the first `LANE_VS_SCALAR_STEPS` steps, not bitwise). Returns a
     violations list (empty = parity holds)."""
     import jax
     import jax.numpy as jnp
@@ -1263,6 +1264,8 @@ def _vmap_lane_parity(steps=25):
     from maggy_tpu.parallel import make_mesh
     from maggy_tpu.train import (Trainer, VmapTrainer, clear_warm,
                                  swept_transform)
+    from maggy_tpu.train.vmap import (LANE_VS_SCALAR_STEPS as steps,
+                                      LANE_VS_SCALAR_ULP, ulp_distance)
 
     mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
     model = MnistMLP(features=8, num_classes=2)
@@ -1290,11 +1293,12 @@ def _vmap_lane_parity(steps=25):
     clear_warm()
     violations = []
     for i, lr in enumerate(lrs):
-        if not np.array_equal(scalar[lr], vlosses[:, i]):
-            d = int(np.argmax(scalar[lr] != vlosses[:, i]))
+        ulp = ulp_distance(scalar[lr], vlosses[:, i])
+        if ulp.max() > LANE_VS_SCALAR_ULP:
+            d = int(np.argmax(ulp))
             violations.append(
-                "lane {} (lr={}) diverges from its scalar run at step {}: "
-                "{!r} vs {!r}".format(i, lr, d, scalar[lr][d],
+                "lane {} (lr={}) is {} ulp from its scalar run at step {}: "
+                "{!r} vs {!r}".format(i, lr, int(ulp[d]), d, scalar[lr][d],
                                       vlosses[d, i]))
     return violations
 
@@ -1313,7 +1317,7 @@ def vmap_main():
 
     Gates: (a) trials/hour ratio wall_scalar / wall_vmap >= 5 (the
     micro-trial regime is dispatch-overhead-dominated, so K lanes per
-    program approaches Kx even on CPU); (b) engine-level bitwise
+    program approaches Kx even on CPU); (b) engine-level
     per-lane parity vs scalar runs (`_vmap_lane_parity`); (c) scalar vs
     lanes1 finalized-schedule parity via `journal_schedule_parity` with
     per-arm platform stamps; (d) the vmap arm actually assembled blocks
@@ -1372,7 +1376,7 @@ def vmap_main():
                 arms["scalar"]["wall_s"], arms["vmap"]["wall_s"],
                 speedup, need))
 
-    # (b) bitwise per-lane loss parity at the engine level.
+    # (b) per-lane loss parity at the engine level.
     parity_violations = _vmap_lane_parity()
     violations.extend(parity_violations)
 

@@ -3,7 +3,7 @@
     python chip_smoke.py
 
 drives `experiment.lagom` -> `OptimizationDriver` -> runner pool ->
-`trial_executor` -> `train.Trainer` (warm slot, AOT step, donated state) ->
+`trial_executor` -> `train.Trainer` (warm slot, AOT step that donates its state) ->
 `models.BertEncoder` -> `ops.attention.multi_head_attention` -> the Pallas
 flash kernels, at `BertConfig.base()` (published width and depth, B=32,
 S=128, bf16, a ragged key-padding mask, synthetic tokens from a seed), as an

@@ -193,14 +193,13 @@ class OptimizationConfig(LagomConfig):
     # back automatically. See docs/telemetry.md "Hand-off path".
     prefetch: bool = True
     # Compile-once hot path (train/warm.py): runners keep the compiled
-    # train step, computed shardings, and donated state buffers resident
-    # across trials whose program identity matches (model config, mesh
-    # topology, strategy, input shapes, swept-optimizer family), so a
+    # train step, the computed shardings and the two init programs
+    # resident across trials whose program identity matches (model config,
+    # mesh topology, strategy, input shapes, swept-optimizer family), so a
     # repeat-shape trial's time-to-first-metric drops from a fresh XLA
-    # trace+compile to near dispatch cost. State VALUES
-    # are always recomputed per trial — only memory and executables are
-    # reused — and resumed/promoted trials never consume retired buffers.
-    # False restores the build-per-trial behavior bit-for-bit.
+    # trace+compile to near dispatch cost. Only programs are kept: every
+    # trial's state is initialized anew from its rng and freed when the
+    # trial ends. False restores the build-per-trial behavior bit-for-bit.
     warm_start: bool = True
     # Checkpoint-forking search (docs/user.md "Forking search"): an ASHA
     # promotion / PBT exploit-or-continue segment / BO near-duplicate is

@@ -40,22 +40,6 @@ def _ckpt_span(name: str):
         return contextlib.nullcontext()
 
 
-def info_needs_fresh_state(info: Dict[str, Any]) -> bool:
-    """Does a trial's assignment ``info`` dict mark it as CONTINUING
-    saved state (preemption resume / promoted parent / checkpoint
-    fork)? The single home of this rule: ``TrialContext.
-    needs_fresh_state`` and the executor's warm trial scope both
-    consult it — widening it in one place but not the other would
-    silently re-enable retired-buffer donation for exactly the trials
-    that must restore a checkpoint instead. The fork case keeps the
-    COMPILED step (the warm slot's executables are program identity,
-    not values) while dropping the retired buffers the staged
-    checkpoint replaces."""
-    return (info.get("resume_step") is not None
-            or info.get("parent") is not None
-            or info.get("forked_from") is not None)
-
-
 class TrialContext:
     def __init__(
         self,
@@ -138,18 +122,6 @@ class TrialContext:
         # The member's own partition rides along so a REMOTE gang can
         # resolve this process's jax.distributed process id.
         return GangContext({**info, "partition": self.info.get("partition")})
-
-    @property
-    def needs_fresh_state(self) -> bool:
-        """True when this trial CONTINUES saved state — a preemption
-        resume (``resume_step``) or an ASHA/Hyperband promotion
-        (``parent_trial_id``). The warm harness (train/warm.py) consults
-        the same condition: such a trial must restore its checkpoint into
-        freshly initialized buffers, never consume the previous trial's
-        retired ones — the executor's trial scope arms ``fresh_state`` so
-        the warm slot's donation path is skipped while the compiled
-        executables are still reused."""
-        return info_needs_fresh_state(self.info)
 
     # ------------------------------------------------------- checkpointing
     def checkpointer(self):

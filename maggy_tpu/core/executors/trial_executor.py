@@ -236,22 +236,16 @@ class TrialExecutor:
                         self._stage_fork(ctx, trial_id, trial_dir,
                                          exp_dir, params, client,
                                          reporter, stats)
-                    # Warm-slot lifecycle around the trial fn: inside the
-                    # scope, Trainers default to the warm path
-                    # (config.warm_start), compile telemetry lands in this
-                    # runner's stats, and on exit the trial's state
-                    # buffers retire into the warm slot for the next
-                    # trial's donating re-init. A trial that RESUMES
-                    # state (preemption resume / promoted parent) must
-                    # restore its checkpoint, never touch retired
-                    # buffers — fresh_state forbids their reuse.
-                    from maggy_tpu.core.executors.context import \
-                        info_needs_fresh_state
-
-                    fresh = info_needs_fresh_state(client.last_info or {})
+                    # Inside the scope, Trainers default to the warm
+                    # path (config.warm_start) and compile telemetry
+                    # lands in this runner's stats. The warm slot holds
+                    # programs only, so a trial that RESUMES state
+                    # (preemption resume / promoted parent / fork) needs
+                    # no other treatment: it inits fresh like any trial
+                    # and restores its checkpoint over that.
                     with warm.trial_scope(trial_id=trial_id,
                                           enabled=self.warm_start,
-                                          stats=stats, fresh_state=fresh):
+                                          stats=stats):
                         retval = self._run_trial(call_params, trial_dir,
                                                  reporter)
                     metric = util.handle_return_val(
@@ -417,8 +411,7 @@ class TrialExecutor:
             call_params["reporter"] = reporter
         try:
             with warm.trial_scope(trial_id=leader_id,
-                                  enabled=self.warm_start, stats=stats,
-                                  fresh_state=False):
+                                  enabled=self.warm_start, stats=stats):
                 retval = self._run_trial(
                     call_params, "{}/{}".format(exp_dir, leader_id),
                     reporter)
@@ -516,7 +509,7 @@ class TrialExecutor:
             try:
                 with warm.trial_scope(trial_id=tid,
                                       enabled=self.warm_start,
-                                      stats=stats, fresh_state=False):
+                                      stats=stats):
                     retval = self._run_trial(call_params, lane_dir,
                                              reporter)
                 metric = util.handle_return_val(

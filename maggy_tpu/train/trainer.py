@@ -85,95 +85,50 @@ def init_train_state(
         slot = _warm.WarmSlot(None)
     params, opt_state, shardings, _hit, _ikey = _init_state_via_slot(
         slot, model, tx, rng, example_inputs, mesh, strategy,
-        init_kwargs, allow_buffers=False)
+        init_kwargs)
     return params, opt_state, shardings
 
 
-def _reinit_wrapper(entry):
-    """The donating re-init program: fresh VALUES from the entry's
-    initializer, written into the retired trial's DONATED memory."""
-    init_unboxed = entry.init_unboxed
+def _opt_init_program(tx, psub, param_shardings, mesh):
+    """The family's jitted ``tx.init``. Under jit the moments' ``zeros_like``
+    no longer sees where the parameters live, so the program says it:
+    sub-trees shaped like the parameters shard as the parameters do (where
+    an eager ``tx.init`` puts them), every other leaf is replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    def reinit(r, old):
-        del old  # donated: recycled memory, fresh values
-        return init_unboxed(r)
+    pleaves, pdef = jax.tree_util.tree_flatten(psub)
+    pshapes = [x.shape for x in pleaves]
 
-    return jax.jit(reinit, out_shardings=entry.shardings,
-                   donate_argnums=(1,))
+    def like_params(x):
+        leaves, treedef = jax.tree_util.tree_flatten(x)
+        return treedef == pdef and [np.shape(v) for v in leaves] == pshapes
 
+    replicated = NamedSharding(mesh, P())
+    out_shardings = jax.tree_util.tree_map(
+        lambda x: param_shardings if like_params(x) else replicated,
+        jax.eval_shape(tx.init, psub), is_leaf=like_params)
 
-def _ensure_reinit(entry):
-    """The entry's donating re-init, lazily built (once, under the build
-    lock) when neither the prebuild thread nor an earlier trial already
-    has. A consumer arriving while the prebuild is mid-compile waits on
-    the lock and gets the prebuilt executable instead of compiling its
-    own."""
-    fn = entry.reinit_jit
-    if fn is not None:
-        return fn
-    with entry.reinit_lock:
-        if entry.reinit_jit is None:
-            entry.reinit_jit = _reinit_wrapper(entry)
-        return entry.reinit_jit
+    def init_opt_state(p):
+        return tx.init(p)
 
-
-def _prebuild_reinit_async(entry, rng) -> None:
-    """AOT-compile the donating re-init on a background thread,
-    overlapping the program family's FIRST (cold) trial — so the first
-    WARM trial's init() finds the program ready instead of paying its
-    one-time trace+compile (the init_ms spike). Lowering is against
-    ABSTRACT inputs (ShapeDtypeStructs carrying the entry's shardings),
-    so the prebuild allocates no device memory next to the live trial's
-    state. Strictly an optimization: any failure — including the
-    compiled executable later rejecting a call — leaves the lazy inline
-    path (and its fresh-init fallback) intact.
-    ``MAGGY_TPU_PREBUILD_REINIT=0`` disables it."""
-    import os as _os
-
-    if _os.environ.get("MAGGY_TPU_PREBUILD_REINIT", "1") == "0" \
-            or entry.abstract is None:
-        return
-
-    def target():
-        from maggy_tpu.train import warm as _warm
-
-        try:
-            rng_abs = jax.ShapeDtypeStruct(rng.shape, rng.dtype)
-            old_abs = jax.tree_util.tree_map(
-                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                                  sharding=s),
-                entry.abstract, entry.shardings)
-            with entry.reinit_lock:
-                if entry.reinit_jit is not None:
-                    return
-                entry.reinit_jit = _reinit_wrapper(entry).lower(
-                    rng_abs, old_abs).compile()
-                entry.reinit_prebuilt = True
-            _warm._count("reinit_prebuilds")
-        except Exception:  # noqa: BLE001 - prebuild is an optimization
-            pass
-
-    import threading as _threading
-
-    _threading.Thread(target=target, daemon=True,
-                      name="reinit-prebuild").start()
+    return jax.jit(init_opt_state, out_shardings=out_shardings)
 
 
 def _init_state_via_slot(slot, model, tx, rng, example_inputs, mesh,
-                         strategy, init_kwargs, allow_buffers: bool = True):
-    """Warm-slot init: get-or-build the per-input-shape init entry (jitted
-    initializer + shardings — ``jax.eval_shape`` and the unboxing pass run
-    once per program+shape, not once per trial), then initialize fresh
-    state. When the slot holds the previous trial's retired buffers and
-    ``allow_buffers``, the re-init DONATES them: XLA writes the fresh
-    values into the retired trial's memory (no alloc churn, no transient
-    double-residency on a packed HBM), and — for a matching swept-optimizer
-    family — the opt_state is rebuilt the same way with only the traced
-    hyperparameters rebound to this trial's values.
+                         strategy, init_kwargs):
+    """The one init sequence, cold, warm and vectorized: get-or-build the
+    per-input-shape init entry (jitted initializer + shardings —
+    ``jax.eval_shape`` and the unboxing pass run once per program+shape,
+    not once per trial), run the initializer, then the optimizer init. A
+    swept-optimizer family's ``tx.init`` is jitted once an entry (an eager
+    one is a dispatch a leaf) and its traced hyperparameters are rebound
+    to this trial's values; a family-less transform inits eagerly. A cold
+    and a warm trial differ only in whether the two programs were already
+    built.
 
-    Returns (params, opt_state, shardings, warm_hit, init_key). Every
-    reuse path recomputes VALUES from ``rng``/``tx`` — state is never
-    inherited across trials, only memory and executables are.
+    Returns (params, opt_state, shardings, warm_hit, init_key). VALUES
+    always come from ``rng``/``tx``: the entry holds programs, never a
+    trial's state.
     """
     from maggy_tpu.train import warm as _warm
 
@@ -186,75 +141,36 @@ def _init_state_via_slot(slot, model, tx, rng, example_inputs, mesh,
             variables = model.init(r, *example_inputs, **init_kwargs)
             return {k: v for k, v in variables.items() if k != "losses"}
 
-        abstract = jax.eval_shape(init_fn, rng)
-        plain_abstract, shardings = _unbox_and_specs(abstract, mesh, strategy)
+        _, shardings = _unbox_and_specs(
+            jax.eval_shape(init_fn, rng), mesh, strategy)
 
-        def init_unboxed(r):
+        # The function's name is the program's: ``jit_init_variables`` in
+        # the profiler's ``XLA Modules``.
+        def init_variables(r):
             plain, _ = _unbox_and_specs(init_fn(r), mesh, strategy)
             return plain
 
         return _warm._InitEntry(
-            jax.jit(init_unboxed, out_shardings=shardings), init_unboxed,
-            shardings, abstract=plain_abstract)
+            jax.jit(init_variables, out_shardings=shardings), shardings)
 
     entry, hit = slot.init_entry(ikey, build)
-    if not hit and allow_buffers and slot.key is not None:
-        # First trial of a shared program family: compile the donating
-        # re-init CONCURRENTLY with the trial (ROADMAP item 3 follow-up),
-        # so the family's first WARM trial no longer pays its one-time
-        # trace+compile inside init() — the init_ms spike the journal's
-        # ttfm breakdown shows today.
-        _prebuild_reinit_async(entry, rng)
     family = _warm.opt_family(tx)
-    if allow_buffers:
-        retired = entry.take_retired()
-    else:
-        entry.drop_retired()
-        retired = None
-    params = opt_state = None
     with mesh:
-        if retired is not None:
-            old_vars, old_opt, old_family = retired
-            try:
-                params = _ensure_reinit(entry)(rng, old_vars)
-            except Exception:  # noqa: BLE001 - donation is an optimization
-                params = None
-                # A PREBUILT executable that rejects concrete calls
-                # (layout/sharding mismatch vs its abstract lowering)
-                # must not shadow the lazy jit path forever: evict it so
-                # the next trial rebuilds inline and donation recovers.
-                with entry.reinit_lock:
-                    if entry.reinit_prebuilt:
-                        entry.reinit_jit = None
-                        entry.reinit_prebuilt = False
-            if params is not None and family is not None \
-                    and old_family == family:
-                try:
-                    if entry.opt_family != family \
-                            or entry.opt_reinit_jit is None:
-                        entry.opt_tx, entry.opt_family = tx, family
-                        first_tx = tx
-
-                        def opt_reinit(p, old):
-                            del old  # donated
-                            return first_tx.init(p)
-
-                        entry.opt_reinit_jit = jax.jit(
-                            opt_reinit, donate_argnums=(1,))
-                    psub = params["params"] if "params" in params else params
-                    # The cached re-init traced the family's FIRST
-                    # transform, so its hyperparam constants must be
-                    # rebound to THIS trial's swept values.
-                    opt_state = _warm.rebind_hyperparams(
-                        entry.opt_reinit_jit(psub, old_opt),
-                        _warm.swept_info(tx)["hparams"])
-                except Exception:  # noqa: BLE001
-                    opt_state = None
-        if params is None:
-            params = entry.init_jit(rng)
-        if opt_state is None:
-            opt_state = tx.init(
-                params["params"] if "params" in params else params)
+        params = entry.init_jit(rng)
+        nested = "params" in params
+        psub = params["params"] if nested else params
+        if family is None:
+            opt_state = tx.init(psub)
+        else:
+            opt_init = entry.opt_init
+            if opt_init is None or opt_init[0] != family:
+                psh = entry.shardings
+                opt_init = entry.opt_init = (family, _opt_init_program(
+                    tx, psub, psh["params"] if nested else psh, mesh))
+            # The program traced the family's FIRST transform: rebind its
+            # hyperparameter constants to THIS trial's swept values.
+            opt_state = _warm.rebind_hyperparams(
+                opt_init[1](psub), _warm.swept_info(tx)["hparams"])
     from maggy_tpu.parallel.sharding import apply_zero_sharding
 
     opt_state = apply_zero_sharding(
@@ -312,7 +228,8 @@ def build_step_fn(
             {"params": params, **aux}, opt_state, loss
 
     # The function's name is the program's: ``jit_train_step`` in the
-    # profiler's ``XLA Modules`` (the warm re-init is ``jit_reinit``).
+    # profiler's ``XLA Modules`` (a trial's init is ``jit_init_variables``
+    # and ``jit_init_opt_state``).
     return train_step
 
 
@@ -419,8 +336,9 @@ class Trainer:
     (model config, mesh topology, strategy, loss_fn, train_kwargs, and the
     optimizer family for ``swept_transform`` transforms) — and trials whose
     identity matches reuse one warm slot (train/warm.py): the jitted+
-    AOT-compiled step, the computed shardings, and the previous trial's
-    retired state buffers (consumed by a donating re-init). Build the
+    AOT-compiled step, the computed shardings and the two init programs.
+    The slot holds programs only; a trial's state lives in its Trainer
+    and is freed with it. Build the
     optimizer with ``swept_transform`` so hyperparameters ride in
     opt_state and the whole sweep compiles once; a plain transform keys by
     object identity (never shared across objects — its constants are baked
@@ -497,7 +415,6 @@ class Trainer:
         self.variables = None
         self.opt_state = None
         self.shardings = None
-        _warm.register_trainer(self)
 
     def init(self, rng, example_inputs, init_kwargs=None):
         from maggy_tpu.train import warm as _warm
@@ -506,12 +423,10 @@ class Trainer:
         self._step_num = 0
         with _warm.span("init"):
             if self._slot is not None:
-                allow = self._warm_enabled and not _warm.fresh_state_only()
                 (self.variables, self.opt_state, self.shardings, hit,
                  self._init_ikey) = _init_state_via_slot(
                     self._slot, self.model, self.tx, rng, example_inputs,
-                    self.mesh, self.strategy, init_kwargs,
-                    allow_buffers=allow)
+                    self.mesh, self.strategy, init_kwargs)
                 _warm.record_warm_event(hit)
                 _warm.note_compile(warm=bool(hit))
             else:
@@ -530,25 +445,6 @@ class Trainer:
                 "silently run the FIRST trial's optimizer constants.",
                 stacklevel=2)
         return self
-
-    def retire_to_warm_cache(self) -> None:
-        """Hand this trainer's state buffers to its warm slot's init entry:
-        the next repeat-shape trial's re-init DONATES them — fresh values
-        into recycled memory. Called by the executor's trial scope at
-        trial end; after it, ``variables``/``opt_state`` are None (their
-        buffers now belong to the slot and will be invalidated by the
-        donation)."""
-        slot = self._slot
-        if slot is None or self.variables is None or self._init_ikey is None:
-            return
-        from maggy_tpu.train import warm as _warm
-
-        entry = slot.get_init(self._init_ikey)
-        if entry is not None:
-            entry.store_retired(self.variables, self.opt_state,
-                                _warm.opt_family(self.tx))
-            self.variables = None
-            self.opt_state = None
 
     def place_batch(self, batch: Dict[str, Any]):
         from maggy_tpu.parallel.sharding import cached_batch_sharding

@@ -14,20 +14,19 @@ This module is the mechanism; `train/trainer.py` is the policy:
 - ``WarmCache`` — a bounded (LRU, default 4 programs) per-process registry
   of ``WarmSlot`` objects keyed by program identity. A long-lived fleet
   runner serving many experiments must not grow without bound; evicting a
-  slot drops its executables and retired buffers. ``clear()`` empties it
-  (exported as ``maggy_tpu.train.clear_warm``).
-- ``WarmSlot`` — everything a repeat-shape trial can reuse: the jitted
-  step, per-shape AOT-compiled executables, per-input-shape init entries
-  (jitted initializer + computed shardings, so ``jax.eval_shape`` +
-  unboxing are skipped), and the *retired state buffers* of the previous
-  trial, re-consumed by a donating re-initialization (fresh VALUES, same
-  memory).
+  slot drops its executables. ``clear()`` empties it (exported as
+  ``maggy_tpu.train.clear_warm``).
+- ``WarmSlot`` — what a repeat-shape trial can reuse, all of it identity
+  and none of it value: the jitted step, per-shape AOT-compiled
+  executables (the K-lane vectorized one among them, under a key that
+  carries the lane count), and per-input-shape init entries (jitted
+  initializer + computed shardings, so ``jax.eval_shape`` + unboxing are
+  skipped, and the family's jitted optimizer init). A slot never holds an
+  array of a trial: a trial's state is freed when its ``Trainer`` is
+  dropped.
 - **Trial scope** — the executor wraps each trial in ``trial_scope`` so
-  warm behavior follows ``config.warm_start``, compile telemetry lands in
-  the trial's ``RunnerStats``, and a trial arriving with
-  ``ctx.resume_step``/``restore_parent`` never consumes retired buffers
-  (``fresh_state=True``): checkpoint state must be restored explicitly,
-  not inherited.
+  warm behavior follows ``config.warm_start`` and compile telemetry lands
+  in the trial's ``RunnerStats``.
 - **Counters** — warm-slot hits/misses and the persistent XLA compilation
   cache's hits/misses, counted through ``jax.monitoring`` event listeners
   (the warm cache emits ``/maggy_tpu/warm_slot/{hit,miss}`` events; JAX
@@ -35,7 +34,6 @@ This module is the mechanism; `train/trainer.py` is the policy:
   attributed to the current thread's trial scope (per-runner stats shipped
   on heartbeats) and mirrored in process-global counters for library use.
 
-``MAGGY_TPU_WARM_START=0`` disables the warm default process-wide;
 ``MAGGY_TPU_WARM_SLOTS`` overrides the LRU bound.
 """
 
@@ -74,14 +72,12 @@ _listener_installed = False
 # --------------------------------------------------------------- trial scope
 
 class _TrialScope:
-    __slots__ = ("trial_id", "enabled", "stats", "fresh_state", "trainers")
+    __slots__ = ("trial_id", "enabled", "stats")
 
-    def __init__(self, trial_id, enabled, stats, fresh_state):
+    def __init__(self, trial_id, enabled, stats):
         self.trial_id = trial_id
         self.enabled = enabled
         self.stats = stats
-        self.fresh_state = fresh_state
-        self.trainers: list = []
 
 
 def current_scope() -> Optional[_TrialScope]:
@@ -92,15 +88,12 @@ class trial_scope:
     """Context manager the trial executor wraps around one train_fn call.
 
     Arms the thread's warm behavior (``enabled`` mirrors
-    ``config.warm_start``; ``fresh_state=True`` for resumed/promoted
-    trials forbids retired-buffer reuse) and routes compile telemetry to
-    ``stats`` (a ``RunnerStats``). On exit, every Trainer the trial built
-    retires its state buffers into its warm slot so the NEXT trial's
-    donating re-init can consume them."""
+    ``config.warm_start``) and routes compile telemetry to ``stats`` (a
+    ``RunnerStats``)."""
 
     def __init__(self, trial_id: Optional[str] = None, enabled: bool = True,
-                 stats=None, fresh_state: bool = False):
-        self._scope = _TrialScope(trial_id, enabled, stats, fresh_state)
+                 stats=None):
+        self._scope = _TrialScope(trial_id, enabled, stats)
 
     def __enter__(self) -> "_TrialScope":
         self._prev = getattr(_local, "scope", None)
@@ -108,43 +101,15 @@ class trial_scope:
         return self._scope
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        scope = self._scope
         _local.scope = self._prev
-        if not scope.enabled:
-            return
-        for trainer in scope.trainers:
-            try:
-                trainer.retire_to_warm_cache()
-            except Exception:  # noqa: BLE001 - retirement is an optimization
-                pass
 
 
 def enabled() -> bool:
     """Is the warm path armed for this thread? The trial scope's flag when
-    inside one (``config.warm_start``), else the process default
-    (``MAGGY_TPU_WARM_START`` != "0" — read at call time so process pools
-    inherit it through the environment)."""
+    inside one (``config.warm_start``); outside a trial it is on, and
+    ``Trainer(warm_start=False)`` is the way to turn it off."""
     scope = current_scope()
-    if scope is not None:
-        return scope.enabled
-    return os.environ.get("MAGGY_TPU_WARM_START", "1") != "0"
-
-
-def fresh_state_only() -> bool:
-    """True when the current trial resumes a checkpoint (its own or a
-    promoted parent's): the warm slot's retired buffers must not be
-    consumed — reused jits are fine, inherited state is not."""
-    scope = current_scope()
-    return scope is not None and scope.fresh_state
-
-
-def register_trainer(trainer) -> None:
-    """Called by ``Trainer.__init__``: the trial scope retires this
-    trainer's buffers at trial end. No-op outside a scope (library users
-    may call ``trainer.retire_to_warm_cache()`` themselves)."""
-    scope = current_scope()
-    if scope is not None and scope.enabled:
-        scope.trainers.append(trainer)
+    return scope.enabled if scope is not None else True
 
 
 def note_compile(**fields: Any) -> None:
@@ -269,9 +234,9 @@ def opt_family(tx) -> Optional[tuple]:
 def rebind_hyperparams(opt_state, hparams: Dict[str, Any]):
     """Return ``opt_state`` with the injected-hyperparameter leaves
     (``optax.inject_hyperparams`` state anywhere inside a chain) replaced
-    by ``hparams``' values, preserving leaf dtypes. The rebind step of
-    buffer-donating re-init: the cached re-init traced the FIRST trial's
-    transform, so its constants must be overwritten with this trial's."""
+    by ``hparams``' values, preserving leaf dtypes. The init entry's jitted
+    optimizer init traced the family's FIRST transform, so its constants
+    are overwritten with this trial's."""
     import jax.numpy as jnp
 
     def rebind(state):
@@ -299,104 +264,32 @@ def rebind_hyperparams(opt_state, hparams: Dict[str, Any]):
 # -------------------------------------------------------------- cache/slots
 
 class _InitEntry:
-    """Per-(program, input-shape) reusable init state: the jitted
+    """Per-(program, input-shape) reusable init programs: the jitted
     initializer, the computed shardings (skipping eval_shape + unboxing on
-    reuse), the lazily built donating re-init, and the single retired
-    buffer cell the next trial consumes."""
+    reuse), and the family's jitted optimizer init."""
 
-    __slots__ = ("init_jit", "init_unboxed", "shardings", "abstract",
-                 "reinit_jit", "reinit_lock", "reinit_prebuilt",
-                 "opt_tx", "opt_family", "opt_reinit_jit", "retired", "lock")
+    __slots__ = ("init_jit", "shardings", "opt_init")
 
-    def __init__(self, init_jit, init_unboxed, shardings, abstract=None):
+    def __init__(self, init_jit, shardings):
         self.init_jit = init_jit
-        self.init_unboxed = init_unboxed
         self.shardings = shardings
-        # Unboxed abstract state tree (ShapeDtypeStructs) — what the
-        # background re-init prebuild lowers against, so it never touches
-        # device memory.
-        self.abstract = abstract
-        self.reinit_jit = None
-        # Serializes the donating re-init build between the concurrent
-        # prebuild thread (spawned with the family's FIRST trial) and the
-        # first WARM trial's inline fallback: one trace+compile, the
-        # loser waits on the winner's program.
-        self.reinit_lock = threading.Lock()
-        self.reinit_prebuilt = False
-        # First transform of the family seen on this entry: its (pure)
-        # init is what the donating opt re-init traces; the per-trial
-        # hyperparam values are rebound after.
-        self.opt_tx = None
-        self.opt_family = None
-        self.opt_reinit_jit = None
-        self.retired: Optional[tuple] = None  # guarded-by: lock
-        self.lock = threading.Lock()
-
-    def store_retired(self, variables, opt_state, family) -> None:
-        with self.lock:
-            self.retired = (variables, opt_state, family)
-
-    def take_retired(self) -> Optional[tuple]:
-        """Pop the retired buffers (at most one consumer: they are DONATED
-        to the re-init, so a second taker would read deleted arrays)."""
-        with self.lock:
-            retired, self.retired = self.retired, None
-            return retired
-
-    def drop_retired(self) -> None:
-        with self.lock:
-            self.retired = None
-
-
-class _VmapEntry:
-    """Per-(program, K-lanes, input-shape) vectorized warm state: the ONE
-    AOT-compiled K-lane vmapped step executable every block of the family
-    shares, and the STACKED retired state buffers of the previous block —
-    consumed by the next block's donating re-init exactly like the scalar
-    ``_InitEntry.retired`` cell, generalized across the lane axis."""
-
-    __slots__ = ("vstep", "lanes", "retired", "lock")
-
-    def __init__(self, lanes: int):
-        self.lanes = lanes
-        self.vstep = None  # guarded-by: lock  # compiled K-lane executable
-        self.retired: Optional[tuple] = None  # guarded-by: lock
-        self.lock = threading.Lock()
-
-    def ensure_vstep(self, build: Callable[[], Any]):
-        with self.lock:
-            if self.vstep is None:
-                self.vstep = build()
-            return self.vstep
-
-    def store_retired(self, stacked_vars, stacked_opt, family) -> None:
-        with self.lock:
-            self.retired = (stacked_vars, stacked_opt, family)
-
-    def take_retired(self) -> Optional[tuple]:
-        """Pop the stacked retired buffers (single consumer: they are
-        DONATED to the block re-init, a second taker would read deleted
-        arrays)."""
-        with self.lock:
-            retired, self.retired = self.retired, None
-            return retired
-
-    def drop_retired(self) -> None:
-        with self.lock:
-            self.retired = None
+        # (family, jitted ``tx.init`` of the first transform of that
+        # family seen here), one tuple so that runner threads sharing the
+        # slot read and replace it whole. The per-trial hyperparameter
+        # values are rebound after the call.
+        self.opt_init: Optional[tuple] = None
 
 
 class WarmSlot:
     """One program family's warm state. ``step_jit`` is shared by every
     trial of the family (jax.jit re-traces per input shape internally);
     ``compiled`` holds the AOT-split executables per shape so repeat
-    trials skip trace AND compile; ``inits`` holds per-input-shape init
-    entries; ``vmaps`` holds per-(lanes, shape) vectorized entries (the
-    K-lane executables + stacked retired buffers of vectorized blocks,
-    train/vmap.py)."""
+    trials skip trace AND compile (a vectorized block's K-lane
+    executable too, train/vmap.py); ``inits`` holds per-input-shape init
+    entries."""
 
     __slots__ = ("key", "lock", "step_jit", "compiled", "inits", "aot_ok",
-                 "aot_lock", "vmaps")
+                 "aot_lock")
 
     def __init__(self, key):
         self.key = key
@@ -404,27 +297,12 @@ class WarmSlot:
         self.step_jit = None  # guarded-by: lock
         self.compiled: "OrderedDict[str, Any]" = OrderedDict()  # guarded-by: lock
         self.inits: "OrderedDict[Any, _InitEntry]" = OrderedDict()  # guarded-by: lock
-        self.vmaps: "OrderedDict[Any, _VmapEntry]" = OrderedDict()  # guarded-by: lock
         self.aot_ok = True
         # Serializes AOT lower+compile per slot: N thread-pooled runners
         # whose first trials race the same program must produce ONE
         # compile, not N concurrent ones (the plain-jit path gets the
         # same guarantee from pjit's internal cache locking).
         self.aot_lock = threading.Lock()
-
-    def vmap_entry(self, key, lanes: int) -> "_VmapEntry":
-        """Get-or-create the vectorized entry for one (lanes, shape)
-        signature; bounded by the same per-slot LRU as ``compiled``."""
-        with self.lock:
-            entry = self.vmaps.get(key)
-            if entry is None or entry.lanes != lanes:
-                entry = _VmapEntry(lanes)
-                self.vmaps[key] = entry
-                while len(self.vmaps) > PER_SLOT_SHAPES:
-                    self.vmaps.popitem(last=False)
-            else:
-                self.vmaps.move_to_end(key)
-            return entry
 
     def ensure_step(self, build: Callable[[], Any]):
         with self.lock:
@@ -514,8 +392,7 @@ def warm_cache() -> WarmCache:
 
 
 def clear_warm() -> None:
-    """Drop every warm slot (compiled executables, shardings, retired
-    buffers). The explicit unbounded-growth escape hatch for long-lived
-    fleet runners, and the isolation reset tests/benches use between A/B
-    arms. Exported as ``maggy_tpu.train.clear_warm``."""
+    """Drop every warm slot (compiled executables, shardings). The
+    explicit unbounded-growth escape hatch for long-lived fleet runners,
+    and the isolation reset tests/benches use between A/B arms. Exported as ``maggy_tpu.train.clear_warm``."""
     _CACHE.clear()

@@ -131,15 +131,6 @@ class TestForkCheckpoint:
 
 
 class TestContextFork:
-    def test_fresh_state_rule_learns_fork(self):
-        from maggy_tpu.core.executors.context import info_needs_fresh_state
-
-        assert not info_needs_fresh_state({})
-        assert info_needs_fresh_state({"resume_step": 3})
-        assert info_needs_fresh_state({"parent": "abc"})
-        assert info_needs_fresh_state(
-            {"forked_from": {"trial": "abc", "step": 3}})
-
     def test_ctx_stage_fork(self, tmp_path):
         from maggy_tpu.core.environment import EnvSing
         from maggy_tpu.core.executors.context import TrialContext

@@ -21,7 +21,6 @@ Covers every layer of the gang path:
   CPU fleet with utilization and gang-vs-reference parity gates.
 """
 
-import time
 
 import pytest
 
@@ -677,134 +676,6 @@ class TestGangChaosInvariant:
                   trigger={"on_phase": "gang_assembled"})  # ok
         with pytest.raises(ValueError, match="runner fault"):
             FaultSpec("kill_gang_member", trigger={"nth": 1})
-
-
-# ----------------------------------------------------------- warm prebuild
-
-
-def _prebuild_loss(logits, b):
-    # Module-level on purpose: Trainer's auto program key includes the
-    # loss by object identity, so a per-call lambda would give every
-    # trainer a private slot and no cross-trial warm sharing.
-    from maggy_tpu.train.trainer import cross_entropy_loss
-
-    return cross_entropy_loss(logits, b["labels"])
-
-
-_PREBUILD_MODEL = None
-
-
-def _prebuild_trainer(lr):
-    import jax
-    import jax.numpy as jnp
-    import optax
-    import flax.linen as nn
-
-    from maggy_tpu.parallel.mesh import make_mesh
-    from maggy_tpu.train.trainer import Trainer, swept_transform
-
-    global _PREBUILD_MODEL
-    if _PREBUILD_MODEL is None:
-        # One model INSTANCE for every trainer (same reason as
-        # _prebuild_loss: program-key identity).
-        class MLP(nn.Module):
-            @nn.compact
-            def __call__(self, x):
-                return nn.Dense(10)(jnp.tanh(nn.Dense(32)(x)))
-
-        _PREBUILD_MODEL = MLP()
-    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
-    return Trainer(_PREBUILD_MODEL,
-                   swept_transform(optax.sgd, learning_rate=lr),
-                   _prebuild_loss, mesh)
-
-
-class TestReinitPrebuild:
-    @pytest.mark.timeout(120)
-    def test_prebuild_overlaps_first_trial_and_preserves_values(self):
-        import numpy as np
-        import jax
-
-        from maggy_tpu.train import warm
-
-        warm.clear_warm()
-        rng = jax.random.PRNGKey(0)
-        x = jax.numpy.ones((8, 16))
-        tr1 = _prebuild_trainer(0.1).init(rng, (x,))
-        ref = jax.tree_util.tree_map(lambda a: np.asarray(a).copy(),
-                                     tr1.variables)
-        entry = tr1._slot.get_init(tr1._init_ikey)
-        deadline = time.time() + 60
-        while not entry.reinit_prebuilt and time.time() < deadline:
-            time.sleep(0.05)
-        assert entry.reinit_prebuilt
-        assert entry.reinit_jit is not None
-        tr1.retire_to_warm_cache()
-        # First WARM trial: consumes the prebuilt donating re-init —
-        # and the recycled-memory init must be value-identical to a
-        # cold init from the same rng.
-        t0 = time.perf_counter()
-        tr2 = _prebuild_trainer(0.2).init(rng, (x,))
-        warm_ms = (time.perf_counter() - t0) * 1e3
-        for a, b in zip(jax.tree_util.tree_leaves(ref),
-                        jax.tree_util.tree_leaves(tr2.variables)):
-            assert np.allclose(a, np.asarray(b))
-        # Generous CPU bound: the point is it did not re-trace/compile
-        # the init program (cold is ~2000 ms on this proxy).
-        assert warm_ms < 1000, warm_ms
-        warm.clear_warm()
-
-    def test_failed_prebuilt_executable_evicted(self):
-        """A prebuilt AOT executable that rejects concrete calls must be
-        evicted on first failure so the lazy jit path (and donation)
-        recovers — not shadow it forever."""
-        import jax
-
-        from maggy_tpu.train import warm
-
-        warm.clear_warm()
-        rng = jax.random.PRNGKey(0)
-        x = jax.numpy.ones((8, 16))
-        tr1 = _prebuild_trainer(0.1).init(rng, (x,))
-        entry = tr1._slot.get_init(tr1._init_ikey)
-
-        def boom(*a, **k):
-            raise RuntimeError("layout mismatch")
-
-        with entry.reinit_lock:
-            entry.reinit_jit = boom
-            entry.reinit_prebuilt = True
-        tr1.retire_to_warm_cache()
-        tr2 = _prebuild_trainer(0.2).init(rng, (x,))  # falls back fresh
-        assert tr2.variables is not None
-        assert not entry.reinit_prebuilt and entry.reinit_jit is None
-        # The next warm trial rebuilds the lazy jit and donates again.
-        tr2.retire_to_warm_cache()
-        tr3 = _prebuild_trainer(0.3).init(rng, (x,))
-        assert tr3.variables is not None
-        assert entry.reinit_jit is not None
-        warm.clear_warm()
-
-    def test_prebuild_disabled_by_env(self, monkeypatch):
-        import jax
-
-        from maggy_tpu.train import warm
-
-        monkeypatch.setenv("MAGGY_TPU_PREBUILD_REINIT", "0")
-        warm.clear_warm()
-        before = warm.counters().get("reinit_prebuilds", 0)
-        tr = _prebuild_trainer(0.1).init(jax.random.PRNGKey(0),
-                                         (jax.numpy.ones((4, 16)),))
-        entry = tr._slot.get_init(tr._init_ikey)
-        time.sleep(0.3)
-        assert not entry.reinit_prebuilt
-        assert warm.counters().get("reinit_prebuilds", 0) == before
-        # The lazy inline path still works.
-        tr.retire_to_warm_cache()
-        tr2 = _prebuild_trainer(0.2).init(jax.random.PRNGKey(0),
-                                          (jax.numpy.ones((4, 16)),))
-        assert tr2.variables is not None
-        warm.clear_warm()
 
 
 # ----------------------------------------------------------------- e2e soak

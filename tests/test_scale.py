@@ -194,7 +194,8 @@ class TestSharedServerBookkeeping:
                 pass
             assert _wait_until(
                 lambda: not shared._buffers and not shared._conn_server)
-            assert handled == [0]
+            assert 2 not in handled
+            assert handled in ([], [0])
             sock.close()
         finally:
             shared.stop()

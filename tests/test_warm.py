@@ -5,11 +5,10 @@ ROADMAP item 3. The tentpole claims under test:
 
 - program identity is derived automatically (model config + mesh +
   strategy + swept-optimizer family) and repeat-shape trials share one
-  warm slot — the compiled step, the shardings, and the retired state
-  buffers consumed by a donating re-init;
+  warm slot — the compiled step, the shardings and the two init programs;
 - the warm path NEVER leaks state: a warm trial's losses are bit-identical
-  to a cold runner's (buffers recycle, values recompute), a resumed/
-  promoted trial never consumes retired buffers, and warm_start=False
+  to a cold runner's (the slot holds programs, never a trial's arrays;
+  cold, warm and vectorized run one init sequence), and warm_start=False
   reproduces the legacy build-per-trial behavior;
 - the opaque ttfm splits into journaled phases (init/trace/compile/
   first_step) with warm + persistent-cache hit rates, replayable from the
@@ -56,7 +55,7 @@ def make_trainer(lr, warm_start=None, step_key=None, tx=None):
                    step_key=step_key)
 
 
-def run_trial(lr, steps=3, warm_start=None, retire=True):
+def run_trial(lr, steps=3, warm_start=None):
     tr = make_trainer(lr, warm_start=warm_start)
     tr.init(jax.random.key(0), EXAMPLE)
     losses = []
@@ -64,8 +63,6 @@ def run_trial(lr, steps=3, warm_start=None, retire=True):
         batch = tr.place_batch({"inputs": (jnp.asarray(X),),
                                 "labels": jnp.asarray(Y)})
         losses.append(float(tr.step(batch)))
-    if retire:
-        tr.retire_to_warm_cache()
     return tr, losses
 
 
@@ -247,7 +244,7 @@ class TestNoStateLeak:
 
     def test_warm_trials_match_cold_bitwise(self):
         _, w1 = run_trial(3e-3)
-        _, w2 = run_trial(1e-3)          # warm: donated buffers + rebind
+        _, w2 = run_trial(1e-3)          # warm: same programs + rebind
         _, w3 = run_trial(7e-4)
         _, c1 = run_trial(3e-3, warm_start=False)
         _, c2 = run_trial(1e-3, warm_start=False)
@@ -256,46 +253,188 @@ class TestNoStateLeak:
         assert w2 == c2, "stale params leaked through the warm slot"
         assert w3 == c3
 
-    def test_warm_hit_counted_and_buffers_consumed(self):
+    def test_warm_hit_counted_and_slot_holds_no_trial_array(self):
+        import gc
+        import weakref
+
         c0 = warm.counters()
-        t1, _ = run_trial(3e-3)
-        slot = t1._slot
-        entry = slot.get_init(t1._init_ikey)
-        assert entry is not None and entry.retired is not None
-        assert t1.variables is None, "retired trainer must drop its refs"
+        with warm.trial_scope(trial_id="t1", enabled=True):
+            t1, _ = run_trial(3e-3)
+            slot, ikey = t1._slot, t1._init_ikey
+            leaves = [weakref.ref(x) for x in jax.tree_util.tree_leaves(
+                (t1.variables, t1.opt_state))]
+            del t1
+        # The trial is over and its Trainer dropped: nothing the slot
+        # keeps (step, executables, init entry) reaches an array of it.
+        gc.collect()
+        assert leaves and all(ref() is None for ref in leaves), \
+            "the warm slot keeps a finished trial's state alive"
+        assert slot.get_init(ikey) is not None
         t2, _ = run_trial(1e-3)
-        assert entry.retired is not None, "trial 2 should re-retire"
+        assert t2._slot is slot
         delta = {k: warm.counters()[k] - c0[k] for k in c0}
         assert delta["warm_hits"] == 1 and delta["warm_misses"] == 1
 
-    def test_fresh_state_scope_skips_retired_buffers(self):
+    def test_resumed_trial_reuses_slot_and_starts_bit_fresh(self):
+        from maggy_tpu.core.executors.context import TrialContext
+
         t1, _ = run_trial(3e-3)
-        entry = t1._slot.get_init(t1._init_ikey)
-        assert entry.retired is not None
-        with warm.trial_scope(trial_id="resumed", enabled=True,
-                              fresh_state=True):
+        ctx = TrialContext("resumed", "/nowhere/resumed", "/nowhere", {},
+                           info={"resume_step": 3})
+        assert ctx.resume_step == 3
+        with warm.trial_scope(trial_id=ctx.trial_id, enabled=True):
             t2 = make_trainer(1e-3)
             t2.init(jax.random.key(0), EXAMPLE)
-            # A resume/promotion trial restores a checkpoint: the previous
-            # trial's buffers are DROPPED (memory freed), never donated
-            # into its state...
-            assert entry.retired is None
-            # ...it still reuses the compiled program...
+            # A resume/promotion trial restores a checkpoint over its
+            # init: it reuses the compiled programs...
             assert t2._slot is t1._slot
-            # ...and its pre-restore values are a bit-fresh init.
+            assert t2._slot.get_init(t2._init_ikey) is \
+                t1._slot.get_init(t1._init_ikey)
+            # ...and its pre-restore state is a bit-fresh init, whatever
+            # the trial before it trained.
             t_cold = make_trainer(1e-3, warm_start=False)
             t_cold.init(jax.random.key(0), EXAMPLE)
-            for a, b in zip(jax.tree_util.tree_leaves(t2.variables),
-                            jax.tree_util.tree_leaves(t_cold.variables)):
+            for a, b in zip(
+                    jax.tree_util.tree_leaves((t2.variables, t2.opt_state)),
+                    jax.tree_util.tree_leaves((t_cold.variables,
+                                               t_cold.opt_state))):
                 np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        # At scope exit the RESUMED trial's own buffers retire normally —
-        # the next plain trial may donate them.
-        assert entry.retired is not None
 
     def test_scope_disabled_forces_legacy(self):
         with warm.trial_scope(trial_id="t", enabled=False):
             t = make_trainer(1e-3)
         assert t._slot is None
+
+
+def _init_programs(slot):
+    """The jitted callables a slot's init entries hold."""
+    out = []
+    for entry in slot.inits.values():
+        out.append(entry.init_jit)
+        if entry.opt_init is not None:
+            out.append(entry.opt_init[1])
+    return out
+
+
+_FAMILIES = {
+    "adamw": (optax.adamw, {"weight_decay": 1e-4}),
+    "sgd_momentum": (optax.sgd, {"momentum": 0.9}),
+}
+
+
+class TestOneInitSequence:
+    """Cold, warm and vectorized trials run ONE init sequence: the entry's
+    jitted initializer, then the family's jitted optimizer init with this
+    trial's hyperparameters rebound."""
+
+    def test_cold_warm_and_vmap_build_the_same_two_programs(self):
+        from maggy_tpu.train import VmapTrainer
+
+        cold = make_trainer(3e-3).init(jax.random.key(0), EXAMPLE)
+        slot = cold._slot
+        programs = _init_programs(slot)
+        assert len(programs) == 2  # one initializer, one optimizer init
+        warm_tr = make_trainer(1e-3).init(jax.random.key(0), EXAMPLE)
+        vt = VmapTrainer(MODEL, optax.adam,
+                         [{"learning_rate": 1e-3}, {"learning_rate": 5e-3}],
+                         loss_fn, mesh1())
+        vt.init(jax.random.key(0), EXAMPLE)
+        assert warm_tr._slot is slot and vt._slot is slot
+        after = _init_programs(slot)
+        assert len(after) == 2
+        assert all(a is b for a, b in zip(programs, after))
+        # ...each traced and compiled once, by the cold trial.
+        assert [fn._cache_size() for fn in after] == [1, 1]
+        assert [fn.__name__ for fn in after] == ["init_variables",
+                                                 "init_opt_state"]
+
+    @pytest.mark.parametrize("family", sorted(_FAMILIES))
+    def test_opt_state_is_an_eager_init_with_own_hyperparams(self, family):
+        factory, statics = _FAMILIES[family]
+
+        def trainer(lr):
+            return make_trainer(lr, tx=swept_transform(
+                factory, learning_rate=lr, **statics))
+
+        cold = trainer(3e-3).init(jax.random.key(0), EXAMPLE)
+        warm_tr = trainer(1e-4).init(jax.random.key(0), EXAMPLE)
+        assert warm_tr._slot is cold._slot
+        for tr, lr in ((cold, 3e-3), (warm_tr, 1e-4)):
+            eager = tr.tx.init(tr.variables["params"])
+            assert jax.tree_util.tree_structure(tr.opt_state) == \
+                jax.tree_util.tree_structure(eager)
+            for a, b in zip(jax.tree_util.tree_leaves(tr.opt_state),
+                            jax.tree_util.tree_leaves(eager)):
+                assert a.dtype == b.dtype
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            assert float(tr.opt_state.hyperparams["learning_rate"]) == \
+                pytest.approx(lr)
+
+    def test_jitted_opt_init_shards_moments_like_an_eager_one(self):
+        """Under jit ``zeros_like`` no longer sees where the parameters
+        live; the program has to say it, or an fsdp trial's moments land
+        whole on one device. One step only: the AOT step's outputs do not come back in the
+        shardings it was lowered for on a multi-device mesh (ROADMAP
+        B-I 2), which is not this test's subject."""
+        from maggy_tpu.models import BertConfig, BertEncoder
+
+        mesh = make_mesh({"fsdp": 4}, devices=jax.devices()[:4])
+        tr = Trainer(BertEncoder(BertConfig.tiny()),
+                     swept_transform(optax.adam, learning_rate=1e-3),
+                     loss_fn, mesh, strategy="fsdp")
+        tokens = jnp.asarray(RNG.integers(0, 128, size=(8, 16)), jnp.int32)
+        tr.init(jax.random.key(0), (tokens[:1],))
+        sharded = 0
+        for moment in (tr.opt_state.inner_state[0].mu,
+                       tr.opt_state.inner_state[0].nu):
+            for p, m in zip(jax.tree_util.tree_leaves(tr.variables["params"]),
+                            jax.tree_util.tree_leaves(moment)):
+                assert m.sharding.is_equivalent_to(p.sharding, p.ndim)
+                sharded += not p.sharding.is_fully_replicated
+        assert sharded, "no parameter was sharded; nothing was exercised"
+        cold = [x.sharding for x in jax.tree_util.tree_leaves(tr.opt_state)]
+        batch = tr.place_batch({"inputs": (tokens,),
+                                "labels": jnp.asarray(Y[:8])})
+        assert np.isfinite(float(tr.step(batch)))
+        warm_tr = Trainer(tr.model,
+                          swept_transform(optax.adam, learning_rate=5e-3),
+                          loss_fn, mesh, strategy="fsdp")
+        warm_tr.init(jax.random.key(0), (tokens[:1],))
+        assert warm_tr._slot is tr._slot
+        for sh, x in zip(cold, jax.tree_util.tree_leaves(warm_tr.opt_state)):
+            assert x.sharding.is_equivalent_to(sh, x.ndim)
+
+    def test_family_less_transform_inits_eagerly(self):
+        tr = make_trainer(None, tx=optax.adam(1e-3))
+        tr.init(jax.random.key(0), EXAMPLE)
+        assert [fn.__name__ for fn in _init_programs(tr._slot)] == \
+            ["init_variables"]
+
+    def test_enabled_ignores_the_retired_environment_switch(self,
+                                                            monkeypatch):
+        monkeypatch.setenv("MAGGY_TPU_WARM_START", "0")
+        assert warm.enabled()
+        assert make_trainer(1e-3)._slot is not None
+        with warm.trial_scope(trial_id="t", enabled=False):
+            assert not warm.enabled()
+
+    def test_sweep_starts_no_prebuild_thread(self, monkeypatch):
+        import threading
+
+        started = []
+        real_start = threading.Thread.start
+
+        def start(self):
+            started.append(self.name)
+            real_start(self)
+
+        monkeypatch.setattr(threading.Thread, "start", start)
+        for lr in (3e-3, 1e-3, 7e-4):
+            with warm.trial_scope(trial_id=str(lr), enabled=True):
+                run_trial(lr, steps=1)
+        assert "reinit-prebuild" not in started
+        assert not [t for t in threading.enumerate()
+                    if t.name == "reinit-prebuild"]
 
 
 class TestRunnerStatsCompile:
@@ -735,7 +874,7 @@ def asha_warm_train_fn(lr, budget=1, reporter=None, ctx=None):
     tr = make_trainer(lr)
     tr.init(jax.random.key(0), EXAMPLE)
     parent = ctx.parent_trial_id
-    assert ctx.needs_fresh_state == (parent is not None)
+    assert not hasattr(ctx, "needs_fresh_state")
     if parent is not None:
         tr.variables = _load_tree(
             os.path.join(ctx.exp_dir, parent, "final_params.npz"),
@@ -753,6 +892,36 @@ def asha_warm_train_fn(lr, budget=1, reporter=None, ctx=None):
     with open(os.path.join(ctx.trial_dir, "warm_record.json"), "w") as f:
         json.dump({"lr": lr, "parent": parent, "steps": steps,
                    "losses": losses}, f)
+    return {"metric": -losses[-1]}
+
+
+def fork_warm_train_fn(lr, budget=1, reporter=None, ctx=None):
+    """ASHA trial that checkpoints (orbax, through ``ctx``), so that the
+    driver dispatches a promotion as a checkpoint FORK: the child lands on
+    a warm slot with ``forked_from`` and ``resume_step`` set, inits like
+    any trial and restores the staged checkpoint over that."""
+    tr = make_trainer(lr)
+    tr.init(jax.random.key(0), EXAMPLE)
+    start = 0
+    if ctx.resume_step is not None:
+        live = {"variables": tr.variables, "opt_state": tr.opt_state}
+        state = ctx.restore_checkpoint(jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding), live))
+        tr.variables, tr.opt_state = state["variables"], state["opt_state"]
+        start = ctx.resume_step + 1
+    total = int(2 * (ctx.budget or 1))
+    losses = []
+    for step in range(start, total):
+        batch = tr.place_batch({"inputs": (jnp.asarray(X),),
+                                "labels": jnp.asarray(Y)})
+        losses.append(float(tr.step(batch)))
+        reporter.broadcast(-losses[-1], step=step)
+    ctx.save_checkpoint(total - 1, {"variables": tr.variables,
+                                    "opt_state": tr.opt_state})
+    with open(os.path.join(ctx.trial_dir, "warm_record.json"), "w") as f:
+        json.dump({"lr": lr, "start": start, "total": total,
+                   "losses": losses, "forked_from": ctx.forked_from}, f)
     return {"metric": -losses[-1]}
 
 
@@ -804,6 +973,36 @@ class TestWarmNeverLeaksAcrossDispatch:
             cold = self._cold_losses(rec["lr"], rec["steps"],
                                      start_params_path=start)
             assert rec["losses"] == cold, \
+                "trial {} diverged from cold run".format(trial_id)
+
+    def test_forked_trials_on_warm_runner_match_cold(self, local_env):
+        from maggy_tpu import OptimizationConfig, Searchspace, experiment
+        from maggy_tpu.optimizers.asha import Asha
+
+        config = OptimizationConfig(
+            name="fork_warm", num_trials=6,
+            optimizer=Asha(reduction_factor=2, resource_min=1,
+                           resource_max=4),
+            searchspace=Searchspace(lr=("DOUBLE", [1e-4, 5e-3])),
+            direction="max", num_workers=1, hb_interval=0.05, seed=3,
+            es_policy="none",
+        )
+        experiment.lagom(fork_warm_train_fn, config)
+        records = {}
+        for path in glob.glob(os.path.join(_exp_dir(local_env), "*",
+                                           "warm_record.json")):
+            with open(path) as f:
+                records[os.path.basename(os.path.dirname(path))] = \
+                    json.load(f)
+        forked = [r for r in records.values() if r["forked_from"]]
+        assert forked and all(r["start"] > 0 for r in forked), \
+            "no promotion forked; the scenario was not exercised"
+        for trial_id, rec in records.items():
+            # A fork continues its parent (same lr, same seed, same
+            # batch), so a cold from-scratch run of the whole budget is
+            # the oracle for the steps the child ran.
+            cold = self._cold_losses(rec["lr"], rec["total"])
+            assert rec["losses"] == cold[rec["start"]:], \
                 "trial {} diverged from cold run".format(trial_id)
 
     def test_preempt_resume_on_warm_runner_matches_cold(self, local_env,
