@@ -5,8 +5,11 @@ TPU-first hot-op design the BERT/Llama baseline configs need:
 
 - `flash_attention`: Pallas TPU kernels — tiled online-softmax forward and
   a two-kernel backward (dK/dV streaming Q tiles, dQ streaming K/V tiles),
-  fp32 accumulators in VMEM scratch, causal block skipping, O(tile) VMEM
-  and no S x S materialization in either direction. Natively supports:
+  fp32 accumulators in VMEM scratch, O(tile) VMEM and no S x S
+  materialization in either direction. Under a mask that empties tiles by
+  their indices (causal, a description) a kernel's grid walks only the tiles
+  that hold a visible pair (`tile_walk`: a schedule in SMEM), and a tile in
+  which every pair is visible computes no mask. Natively supports:
     * GQA — K/V carry Hkv < H heads and are NEVER repeat-expanded: the
       query heads are viewed as [B, Hkv, rep, S, D] and the kv BlockSpec
       index maps simply ignore the rep axis, so each kv tile is fetched
@@ -17,8 +20,8 @@ TPU-first hot-op design the BERT/Llama baseline configs need:
     * mask *descriptions* with per-query structure (`BlockDiffusionMask`:
       the block-diffusion training mask over a noised and a clean copy of
       the sequence) — computed per tile from indices as the causal mask is;
-      the tiles a description empties are skipped (`_tile_runs`) and the
-      plan counts only those that run.
+      the tiles a description empties are no steps of the walk
+      (`_tile_runs`) and the plan counts only those that run.
     * Sq != Sk, with bottom-right-aligned causal masking (offset = Sk-Sq),
       e.g. decode windows / ring-attention shards.
     * head_dim >= 64. At D 64 (BERT-base) two heads of an MHA layer share
@@ -55,10 +58,12 @@ masks and every accumulator are float32.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+import operator
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 # `plans_traced` is re-exported: PR 24's tests and callers take it from here.
@@ -224,31 +229,61 @@ def _structure_tile_mask(s, q0, k0, structure, q_axis=0):
                      NEG_INF)
 
 
-def _tile_runs(q0, blk_q, k0, blk_k, offset, causal, structure,
-               lo=min, hi=max):
+def _tile_parts(q0, blk_q, k0, blk_k, structure):
+    """A tile under a `BlockDiffusionMask`, from indices alone: the data
+    blocks (first, last) of the noised and of the clean part of its queries
+    and of its keys, and whether it has each part (where it has not, first
+    > last and the pair means nothing)."""
+    L, b = structure.length, structure.block
+
+    def parts(x0, blk):
+        noised = (x0 // b, (np.minimum(x0 + blk, L) - 1) // b)
+        clean = ((np.maximum(x0, L) - L) // b, (x0 + blk - 1 - L) // b)
+        return noised, clean, x0 < L, x0 + blk > L
+
+    return parts(q0, blk_q) + parts(k0, blk_k)
+
+
+def _tile_runs(q0, blk_q, k0, blk_k, offset, causal, structure):
     """Whether the [blk_q, blk_k] tile at (q0, k0) holds a visible pair, from
     indices alone: None where every tile does (no causal mask and no mask
-    description), else a boolean the kernels skip on and `_blocks_run`
-    counts. Works on Python ints, numpy arrays (``lo=np.minimum``) and the
-    kernels' traced program ids (``lo=jnp.minimum``) alike."""
+    description), else a boolean: the tiles `tile_walk` schedules and
+    `_blocks_run` counts. On Python ints and numpy arrays alike."""
     runs = None
     if causal:
         # Tiles strictly above the diagonal see nothing.
         runs = k0 < q0 + blk_q + offset
     if structure is not None:
-        L, b = structure.length, structure.block
-        # Data positions [first, last] of the tile's noised and clean parts,
-        # as block indices; a part is empty where first > last.
-        qn0, qn1 = q0 // b, (lo(q0 + blk_q, L) - 1) // b
-        qc0, qc1 = (hi(q0, L) - L) // b, (q0 + blk_q - 1 - L) // b
-        kn0, kn1 = k0 // b, (lo(k0 + blk_k, L) - 1) // b
-        kc0, kc1 = (hi(k0, L) - L) // b, (k0 + blk_k - 1 - L) // b
-        q_n, q_c = q0 < L, q0 + blk_q > L
-        k_n, k_c = k0 < L, k0 + blk_k > L
+        (qn0, qn1), (_, qc1), q_n, q_c, (kn0, kn1), (kc0, _), k_n, k_c = \
+            _tile_parts(q0, blk_q, k0, blk_k, structure)
         seen = (q_n & k_n & (qn0 <= kn1) & (kn0 <= qn1)) \
             | (q_n & k_c & (kc0 < qn1)) | (q_c & k_c & (kc0 <= qc1))
         runs = seen if runs is None else runs & seen
     return runs
+
+
+def _tile_whole(q0, blk_q, k0, blk_k, offset, causal, structure):
+    """Whether EVERY pair of the tile is visible, from indices alone (beside
+    `_tile_runs`, the same rules with "all" for "any"): such a tile needs no
+    index mask, the select would be all-true. Under a description each part
+    of the queries must see the whole of each part of the keys: noised on
+    noised one block both, noised on clean the keys' last block before the
+    queries' first, clean on clean up to it, clean on noised never."""
+    whole = True
+    if causal:
+        # The tile's last key is visible to its first query.
+        whole = k0 + blk_k - 1 <= q0 + offset
+    if structure is not None:
+        (qn0, qn1), (qc0, _), q_n, q_c, (kn0, kn1), (_, kc1), k_n, k_c = \
+            _tile_parts(q0, blk_q, k0, blk_k, structure)
+
+        def if_both(a, b, then):  # a pair of parts the tile does not have
+            return np.logical_not(a & b) | then  # asks nothing
+
+        whole = whole & np.logical_not(q_c & k_n) \
+            & if_both(q_n, k_n, (qn0 == kn1) & (kn0 == qn1)) \
+            & if_both(q_n, k_c, kc1 < qn0) & if_both(q_c, k_c, kc1 <= qc0)
+    return whole
 
 
 # ------------------------------------------------------------------ tile plan
@@ -350,18 +385,102 @@ def step_vmem_bytes(kernel: str, tiles: KernelTiles, D: int, itemsize: int,
     return tiles_io + scratch + temps
 
 
+def _tiles_at(Sq: int, Sk: int, blk_q: int, blk_k: int, predicate, causal,
+              structure):
+    """``predicate`` (`_tile_runs`, `_tile_whole`) over every tile of one
+    head, [q-blocks, k-blocks], or None where it gives None."""
+    nq, nk = Sq // blk_q, Sk // blk_k
+    found = predicate(np.arange(nq)[:, None] * blk_q, blk_q,
+                      np.arange(nk)[None, :] * blk_k, blk_k, Sk - Sq, causal,
+                      structure)
+    return None if found is None else np.broadcast_to(found, (nq, nk))
+
+
 def _blocks_run(Sq: int, Sk: int, blk_q: int, blk_k: int, causal: bool,
                 structure: Optional[BlockDiffusionMask] = None) -> int:
     """Tiles of one head that do work: all of them, or under a causal mask
     those the diagonal reaches, or under a mask description those that hold
-    a visible pair (the kernels skip the rest on the same `_tile_runs`)."""
-    import numpy as np
+    a visible pair (the tiles `tile_walk` schedules: the same `_tile_runs`)."""
+    runs = _tiles_at(Sq, Sk, blk_q, blk_k, _tile_runs, causal, structure)
+    return (Sq // blk_q) * (Sk // blk_k) if runs is None else int(runs.sum())
 
-    nq, nk = Sq // blk_q, Sk // blk_k
-    runs = _tile_runs(np.arange(nq)[:, None] * blk_q, blk_q,
-                      np.arange(nk)[None, :] * blk_k, blk_k, Sk - Sq, causal,
-                      structure, lo=np.minimum, hi=np.maximum)
-    return nq * nk if runs is None else int(np.sum(runs))
+
+# A step of a walk, in its ``flags``: what its tile is, and whether the step
+# opens and closes its accumulator.
+_EMPTY, _PARTIAL, _WHOLE, _KIND = 0, 1, 2, 3
+_FIRST, _LAST = 4, 8
+
+
+class TileWalk(NamedTuple):
+    """The grid steps of one kernel under a mask that empties tiles: per
+    step the q-block and k-block of its tile, the block of query heads it
+    belongs to (``rep``; the dK/dV kernel streams a K/V group's rep blocks
+    through one accumulator, the other two kernels have the rep axis on the
+    grid) and its ``flags`` (`_KIND`, `_FIRST`, `_LAST`). `table` is what
+    the kernel and its index maps read from SMEM."""
+    q_blk: tuple
+    k_blk: tuple
+    flags: tuple
+    rep: tuple
+
+    @property
+    def steps(self) -> int:
+        return len(self.flags)
+
+    def count(self, kind: int) -> int:
+        return sum(f & _KIND == kind for f in self.flags)
+
+    def table(self):
+        """The rows, one after the other, as one int32 vector: row i of
+        step t is at ``i * steps + t`` (`_walk_step`, `_walk_tile`)."""
+        return np.asarray(self, np.int32).reshape(-1)
+
+    def describe(self) -> str:
+        """``80+0 (24 partial)``: steps that run a tile + steps that only
+        initialise and finalise an accumulator no tile reaches, and of the
+        first how many run under the index mask."""
+        return "{}+{} ({} partial)".format(
+            self.steps - self.count(_EMPTY), self.count(_EMPTY),
+            self.count(_PARTIAL))
+
+
+@functools.lru_cache(maxsize=None)
+def tile_walk(kernel: str, Sq: int, Sk: int, blk_q: int, blk_k: int,
+              causal: bool, structure: Optional[BlockDiffusionMask],
+              reps: int = 1) -> Optional[TileWalk]:
+    """The steps ``kernel`` walks in place of a dense (q-block x k-block)
+    grid, or None where no tile is empty by its indices (no causal mask and
+    no description): the dense grid is the walk.
+
+    The tiles that hold a visible pair (`_tile_runs`), **in the order the
+    dense grid visits them**, so every accumulator sees the same terms in
+    the same order: ``fwd`` and ``dq`` by q-block, then ascending k-block;
+    ``dkdv`` by k-block, then rep block (``reps`` of them), then ascending
+    q-block. A tile in which every pair is visible (`_tile_whole`) is
+    marked whole, the others partial. An accumulator that no tile reaches
+    (under a causal mask with Sq > Sk the first query rows see no key) still
+    gets one step, marked empty, on the block the walk already holds, which
+    only initialises and finalises it: its ``out`` / ``dq`` block is written
+    (zeros, and the log-sum-exp of no key) as the dense grid wrote it. Every
+    k-block is seen by some query under both mask kinds."""
+    runs = _tiles_at(Sq, Sk, blk_q, blk_k, _tile_runs, causal, structure)
+    if runs is None:
+        return None
+    whole = _tiles_at(Sq, Sk, blk_q, blk_k, _tile_whole, causal, structure)
+    by_k = kernel == "dkdv"
+    if by_k:
+        runs, whole = runs.T, whole.T
+    rows = []  # (accumulator's block, streamed block, rep block, flags)
+    for acc in range(len(runs)):
+        steps = [(int(blk), rep, _WHOLE if whole[acc, blk] else _PARTIAL)
+                 for rep in range(reps) for blk in runs[acc].nonzero()[0]] \
+            or [(rows[-1][1] if rows else 0, 0, _EMPTY)]
+        for n, (blk, rep, kind) in enumerate(steps):
+            rows.append((acc, blk, rep, kind | _FIRST * (n == 0)
+                         | _LAST * (n == len(steps) - 1)))
+    acc_blk, blk, rep, flags = zip(*rows)
+    return TileWalk(blk if by_k else acc_blk, acc_blk if by_k else blk, flags,
+                    rep)
 
 
 def _divisors(n: int):
@@ -427,15 +546,18 @@ def _remember(plan: FlashPlan, Sq: int, Sk: int, causal: bool,
               structure: Optional[BlockDiffusionMask]) -> None:
     said = plan.describe()
     if structure is not None:
-        # The mask kind, its block length, and per kernel the tiles of a
-        # head that run of those there are.
-        said += "; block_diffusion b{} L{} tiles {}".format(
-            structure.block, structure.length, " ".join(
-                "{} {}/{}".format(
-                    name, _blocks_run(Sq, Sk, t.blk_q, t.blk_k, causal,
-                                      structure),
-                    (Sq // t.blk_q) * (Sk // t.blk_k))
-                for name, t in zip(plan._fields, plan)))
+        # The mask kind, its block length, per kernel the tiles of a head
+        # that run of those there are, and what its grid walks for them.
+        walks = [tile_walk(name, Sq, Sk, t.blk_q, t.blk_k, causal, structure)
+                 for name, t in zip(plan._fields, plan)]
+        said += "; block_diffusion b{} L{} tiles {}; walk {}".format(
+            structure.block, structure.length,
+            " ".join("{} {}/{}".format(
+                name, walk.steps - walk.count(_EMPTY),
+                (Sq // t.blk_q) * (Sk // t.blk_k))
+                for name, t, walk in zip(plan._fields, plan, walks)),
+            " ".join("{} {}".format(name, walk.describe())
+                     for name, walk in zip(plan._fields, walks)))
     remember_plan("flash", said)
 
 
@@ -517,19 +639,87 @@ def _scores(q, k_blk, q0, k0, causal, sm_scale, offset, mask_ref,
     return s
 
 
-def _where_tile_runs(qi, blk_q, kb, blk_k, offset, causal, structure, body):
-    """``body()`` on the tiles that hold a visible pair (`_tile_runs`): tiles
-    a causal mask or a mask description empties contribute nothing, so their
-    compute is skipped (the tile fetch still happens; cheap next to the MXU
-    work). With neither, the body runs unconditionally, as it always did."""
+class _Step(NamedTuple):
+    """Where one grid step of a kernel stands: the q-block and the k-block
+    of its tile, whether the step opens (``first()``) and closes
+    (``last()``) its accumulator, and the tile's kind (`_KIND` of a walk's
+    flags; None on the dense grid, where every tile runs under whatever
+    mask the kernel has). ``first`` and ``last`` are asked where the kernel
+    needs them, and in that order."""
+    qi: jax.Array
+    kb: jax.Array
+    first: Callable[[], jax.Array]
+    last: Callable[[], jax.Array]
+    kind: Optional[jax.Array] = None
+
+
+def _dense_step(q_axis: int, k_axis: int, streamed: tuple) -> _Step:
+    """A step of the dense grid: the blocks are program ids, and an
+    accumulator opens at the first and closes at the last program of the
+    ``streamed`` (sequential) axes. Ids, extents and comparisons are traced
+    where the kernels always traced them, so a kernel with nothing to walk
+    lowers to the Mosaic module it always did."""
     from jax.experimental import pallas as pl
 
-    runs = _tile_runs(qi * blk_q, blk_q, kb * blk_k, blk_k, offset, causal,
-                      structure, lo=jnp.minimum, hi=jnp.maximum)
-    if runs is None:
-        body()
+    ids = {a: pl.program_id(a) for a in sorted({q_axis, k_axis, *streamed})}
+    extents = []
+
+    def first():
+        extents.extend(pl.num_programs(a) for a in streamed)
+        return functools.reduce(operator.and_,
+                                (ids[a] == 0 for a in streamed))
+
+    def last():
+        return functools.reduce(operator.and_, (
+            ids[a] == n - 1 for a, n in zip(streamed, extents)))
+
+    return _Step(ids[q_axis], ids[k_axis], first, last)
+
+
+def _walk_step(walk_ref, axis: int) -> _Step:
+    """A step of a `TileWalk`, read from its table in SMEM: grid axis
+    ``axis`` counts the walk's steps."""
+    from jax.experimental import pallas as pl
+
+    t, steps = pl.program_id(axis), pl.num_programs(axis)
+    flags = walk_ref[2 * steps + t]
+    return _Step(walk_ref[t], walk_ref[steps + t],
+                 lambda: flags & _FIRST != 0, lambda: flags & _LAST != 0,
+                 flags & _KIND)
+
+
+def _step_and_refs(refs, causal, structure, q_axis, k_axis, streamed):
+    """(this grid step, the kernel's operand, result and scratch refs). A
+    kernel walks where its mask kind empties tiles by their indices: the
+    walk's table is then its first ref (scalar prefetch) and the walk's
+    steps the grid axis that follows the parallel ones the dense grid
+    begins with. Else the grid is dense, with the q-blocks, the k-blocks
+    and the ``streamed`` (sequential) axes where the arguments say."""
+    if causal or structure is not None:
+        return _walk_step(refs[0], min(q_axis, k_axis)), refs[1:]
+    return _dense_step(q_axis, k_axis, streamed), refs
+
+
+def _on_tile(step: _Step, body) -> None:
+    """``body(masked)`` on the step's tile. On the dense grid always, under
+    whatever mask the kernel has. On a walk by the tile's kind: a partial
+    tile under the index masks (causal, description), a whole one without
+    them (every pair of it is visible: the select was all-true, the values
+    are the same), and an empty step, which stands for an accumulator that
+    no tile reaches, not at all. A tile that holds no visible pair is not a
+    step of the walk: nothing is fetched for it and nothing stepped (on the
+    dense grid such a step cost its blocks' DMA and the step itself: in the
+    SDAR cell's training step 0.15 us forward, 0.56 us dQ and 1.5 us dK/dV,
+    a quarter of a dK/dV step that runs. What the missing mask buys is less:
+    nothing forward and 0.44 ms a layer backward there. PERF.md section 6,
+    PR 29)."""
+    from jax.experimental import pallas as pl
+
+    if step.kind is None:
+        body(True)
     else:
-        pl.when(runs)(body)
+        pl.when(step.kind == _PARTIAL)(lambda: body(True))
+        pl.when(step.kind == _WHOLE)(lambda: body(False))
 
 
 def _kv(ref, h, rows=slice(None)):
@@ -543,6 +733,8 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
     """One (b, head block, q-block, k-block) program: K/V stream through the
     grid's innermost (sequential) dimension, so VMEM holds one [blk_k, D]
     tile of K and V a head — sequence length is bounded by HBM, not VMEM.
+    Under a causal mask or a description that dimension is a `TileWalk`'s
+    steps, and the walk's table comes first among the refs.
     Online-softmax state (acc, running max, running sum) lives in VMEM
     scratch that persists across the k-block steps of each program group;
     where one k-block is the whole row (``one_pass``) there is no state to
@@ -555,6 +747,7 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
     """
     from jax.experimental import pallas as pl
 
+    step, refs = _step_and_refs(refs, causal, structure, 3, 4, (4,))
     q_ref, k_ref, v_ref = refs[:3]
     mask_ref = refs[3] if has_mask else None
     o_ref, lse_ref = refs[3 + has_mask:5 + has_mask]
@@ -562,15 +755,13 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
     slabs, blk_q, _ = q_ref.shape
     blk_k = k_ref.shape[-2]
     pack = lse_ref.shape[1]
-    qi = pl.program_id(3)
-    kb = pl.program_id(4)
     rows = _pass_rows(blk_q, blk_k)
 
-    def scores(h, c, j):
+    def scores(masked, h, c, j):
         return _scores(_lanes_of(j, pack, q_ref[h, _chunk(c, rows)]),
-                       k_ref[_kv(k_ref, h)], qi * blk_q + c * rows,
-                       kb * blk_k, causal, sm_scale, offset, mask_ref,
-                       structure)
+                       k_ref[_kv(k_ref, h)], step.qi * blk_q + c * rows,
+                       step.kb * blk_k, causal and masked, sm_scale, offset,
+                       mask_ref, structure if masked else None)
 
     def values(h, j, p):
         v_blk = _lanes_of(j, pack, v_ref[_kv(v_ref, h)])
@@ -579,11 +770,11 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
     each_chunk = functools.partial(_each_chunk, slabs, blk_q // rows)
 
     if one_pass:
-        def whole_rows(h, c):
+        def whole_rows(masked, h, c):
             r = _chunk(c, rows)
             out = None
             for j in range(pack):
-                s = scores(h, c, j)
+                s = scores(masked, h, c, j)
                 m = jnp.max(s, axis=1, keepdims=True)
                 p = jnp.exp(s - m)
                 l = jnp.sum(p, axis=1, keepdims=True)  # >= 1: the row's max
@@ -592,23 +783,23 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
                 lse_ref[h, j:j + 1, r] = _as_row(m + jnp.log(l))
             o_ref[h, r] = out.astype(o_ref.dtype)
 
-        each_chunk(whole_rows)
+        _on_tile(step, lambda masked: each_chunk(
+            functools.partial(whole_rows, masked)))
         return
 
     acc_ref, m_ref, l_ref = refs[5 + has_mask:]
-    num_kb = pl.num_programs(4)
 
-    @pl.when(kb == 0)
+    @pl.when(step.first())
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def contribute(h, c):
+    def contribute(masked, h, c):
         r = _chunk(c, rows)
         acc = acc_ref[h, r]
         for j in range(pack):
-            s = scores(h, c, j)
+            s = scores(masked, h, c, j)
             m_prev = m_ref[h, j, r][:, :1]
             l_prev = l_ref[h, j, r][:, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -623,10 +814,10 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
             l_ref[h, j, r] = jnp.broadcast_to(l_new, (rows, _LANES))
         acc_ref[h, r] = acc
 
-    _where_tile_runs(qi, blk_q, kb, blk_k, offset, causal, structure,
-                     lambda: each_chunk(contribute))
+    _on_tile(step, lambda masked: each_chunk(
+        functools.partial(contribute, masked)))
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(step.last())
     def _finalize():
         def finish(h, c):
             r = _chunk(c, rows)
@@ -670,6 +861,56 @@ def _q_side(hg, hr, inner):
     return (None, hg, None) + inner if hr == 1 else (None, None, hr) + inner
 
 
+# Where a tile's blocks lie in the kernels' operands, from its (b, g, r, qi,
+# kb): query-side tiles, K/V-side tiles, row statistics, the key-padding row.
+def _q_at(b, g, r, qi, kb):
+    return b, g, r, qi, 0
+
+
+def _kv_at(b, g, r, qi, kb):
+    return b, g, kb, 0
+
+
+def _stat_at(b, g, r, qi, kb):
+    return b, g, r, 0, qi
+
+
+def _mask_at(b, g, r, qi, kb):
+    return b, 0, kb
+
+
+def _walk_tile(steps: int):
+    """The (b, g, r, qi, kb) of a walk's step, from the step's program ids
+    and the walk's table: the rep axis is on the grid (forward, dQ) or in
+    the walk (dK/dV)."""
+    def to_tile(b, g, *ids):
+        *r, t, table = ids
+        rep, = r or (table[3 * steps + t],)
+        return b, g, rep, table[t], table[steps + t]
+
+    return to_tile
+
+
+def _kernel_grid(walk: Optional[TileWalk], lead: tuple, parallel: tuple,
+                 sequential: tuple, to_tile=lambda *ids: ids):
+    """How one kernel's grid is laid and read: (grid, dimension semantics,
+    the operands that go first as scalar prefetch, ``on``). With no walk the
+    grid is ``lead + parallel + sequential``, the last carrying the
+    accumulation, and ``to_tile`` turns a step's program ids into the
+    (b, g, r, qi, kb) of its tile (where they are not that already). With
+    one, a single sequential axis after ``lead`` counts the walk's steps
+    and the tile is read from the walk's table. ``on(at)`` is the index map
+    that takes ``at(b, g, r, qi, kb)``."""
+    prefetch = []
+    if walk is not None:
+        parallel, sequential = (), (walk.steps,)
+        prefetch, to_tile = [jnp.asarray(walk.table())], _walk_tile(walk.steps)
+    semantics = ("parallel",) * len(lead + parallel) \
+        + ("arbitrary",) * len(sequential)
+    return lead + parallel + sequential, semantics, prefetch, \
+        lambda at: lambda *ids: at(*to_tile(*ids))
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "tiles", "pack",
                                              "interpret", "structure"))
 def _flash_fwd(qg, kg, vg, mask, causal, tiles, pack, interpret,
@@ -687,17 +928,19 @@ def _flash_fwd(qg, kg, vg, mask, causal, tiles, pack, interpret,
     slabs = hg * hr
     offset = Sk - Sq
     sm_scale = 1.0 / ((D // pack) ** 0.5)
-    grid = (B, G // hg, R // hr, Sq // blk_q, Sk // blk_k)
+    grid, semantics, prefetch, on = _kernel_grid(
+        tile_walk("fwd", Sq, Sk, blk_q, blk_k, causal, structure),
+        (B, G // hg, R // hr), (Sq // blk_q,), (Sk // blk_k,))
 
     q_spec = pl.BlockSpec(_q_side(hg, hr, (blk_q, D)),
-                          lambda b, g, r, qi, kb: (b, g, r, qi, 0))
+                          on(_q_at))
     kv_spec = pl.BlockSpec(kv_lead + (blk_k, D),
-                           lambda b, g, r, qi, kb: (b, g, kb, 0))
+                           on(_kv_at))
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qg, kg, vg]
     if mask is not None:
         in_specs.append(pl.BlockSpec((None, 1, blk_k),
-                                     lambda b, g, r, qi, kb: (b, 0, kb)))
+                                     on(_mask_at)))
         operands.append(mask)
 
     # One k-block a row, and every query row sees a key: the softmax is
@@ -710,32 +953,34 @@ def _flash_fwd(qg, kg, vg, mask, causal, tiles, pack, interpret,
                                structure=structure)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            q_spec,
-            pl.BlockSpec(_q_side(hg, hr, (pack, blk_q)),
-                         lambda b, g, r, qi, kb: (b, g, r, 0, qi)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[
+                q_spec,
+                pl.BlockSpec(_q_side(hg, hr, (pack, blk_q)),
+                             on(_stat_at)),
+            ],
+            scratch_shapes=[] if one_pass else [
+                pltpu.VMEM((slabs, blk_q, D), jnp.float32),
+                pltpu.VMEM((slabs, pack, blk_q, _LANES), jnp.float32),
+                pltpu.VMEM((slabs, pack, blk_q, _LANES), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B, G, R, Sq, D), qg.dtype),
             jax.ShapeDtypeStruct((B, G, R, pack, Sq), jnp.float32),
         ],
-        scratch_shapes=[] if one_pass else [
-            pltpu.VMEM((slabs, blk_q, D), jnp.float32),
-            pltpu.VMEM((slabs, pack, blk_q, _LANES), jnp.float32),
-            pltpu.VMEM((slabs, pack, blk_q, _LANES), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
             # b/g/r/qi programs are independent (megacore-splittable); the
-            # k-block dimension carries the online-softmax accumulation and
-            # must run sequentially.
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "parallel", "arbitrary"),
+            # k-block dimension, or the walk, carries the online-softmax
+            # accumulation and must run sequentially.
+            dimension_semantics=semantics,
         ),
         interpret=interpret,
         name="flash_fwd",
-    )(*operands)
+    )(*prefetch, *operands)
     return out, lse
 
 
@@ -813,6 +1058,7 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset,
     owns its rows of dK and dV."""
     from jax.experimental import pallas as pl
 
+    step, refs = _step_and_refs(refs, causal, structure, 4, 2, (3, 4))
     if has_mask:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -824,19 +1070,15 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset,
     slabs, blk_q, _ = q_ref.shape
     blk_k = k_ref.shape[-2]
     pack = lse_ref.shape[1]
-    kb = pl.program_id(2)
-    r = pl.program_id(3)
-    qi = pl.program_id(4)
-    num_r = pl.num_programs(3)
-    num_qb = pl.num_programs(4)
+    qi, kb = step.qi, step.kb
     rows = _pass_rows(blk_k, blk_q)
 
-    @pl.when((r == 0) & (qi == 0))
+    @pl.when(step.first())
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def contribute(h, c):
+    def contribute(masked, h, c):
         keys = _chunk(c, rows)
         k_blk, v_blk = k_ref[_kv(k_ref, h, keys)], v_ref[_kv(v_ref, h, keys)]
         dk = dv = 0.0
@@ -844,10 +1086,10 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset,
             q = _lanes_of(j, pack, q_ref[h])
             do = _lanes_of(j, pack, do_ref[h])
             s = _dot(k_blk, q, _NT) * sm_scale
-            if causal:
+            if causal and masked:
                 s = _causal_tile_mask(s, qi * blk_q, kb * blk_k + c * rows,
                                       offset, q_axis=1)
-            if structure is not None:
+            if structure is not None and masked:
                 s = _structure_tile_mask(s, qi * blk_q, kb * blk_k + c * rows,
                                          structure, q_axis=1)
             if mask_ref is not None:  # the key mask as a [rows, 1] column
@@ -859,10 +1101,10 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset,
         dv_acc[_kv(dv_acc, h, keys)] += dv
         dk_acc[_kv(dk_acc, h, keys)] += dk
 
-    _where_tile_runs(qi, blk_q, kb, blk_k, offset, causal, structure,
-                     lambda: _each_chunk(slabs, blk_k // rows, contribute))
+    _on_tile(step, lambda masked: _each_chunk(
+        slabs, blk_k // rows, functools.partial(contribute, masked)))
 
-    @pl.when((r == num_r - 1) & (qi == num_qb - 1))
+    @pl.when(step.last())
     def _finalize():
         # ds lacked the scale; dK takes it once, on [blk_k, D].
         dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
@@ -876,6 +1118,7 @@ def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset,
     in VMEM."""
     from jax.experimental import pallas as pl
 
+    step, refs = _step_and_refs(refs, causal, structure, 3, 4, (4,))
     if has_mask:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
          dq_ref, dq_acc) = refs
@@ -887,24 +1130,23 @@ def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset,
     slabs, blk_q, _ = q_ref.shape
     blk_k = k_ref.shape[-2]
     pack = lse_ref.shape[1]
-    qi = pl.program_id(3)
-    kb = pl.program_id(4)
-    num_kb = pl.num_programs(4)
+    qi, kb = step.qi, step.kb
     rows = _pass_rows(blk_q, blk_k)
 
-    @pl.when(kb == 0)
+    @pl.when(step.first())
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def contribute(h, c):
+    def contribute(masked, h, c):
         r = _chunk(c, rows)
         k_blk, v_blk = k_ref[_kv(k_ref, h)], v_ref[_kv(v_ref, h)]
         dq = 0.0
         for j in range(pack):
             q = _lanes_of(j, pack, q_ref[h, r])
             do = _lanes_of(j, pack, do_ref[h, r])
-            s = _scores(q, k_blk, qi * blk_q + c * rows, kb * blk_k, causal,
-                        sm_scale, offset, mask_ref, structure)
+            s = _scores(q, k_blk, qi * blk_q + c * rows, kb * blk_k,
+                        causal and masked, sm_scale, offset, mask_ref,
+                        structure if masked else None)
             # lane->sublane relayout of the compact [1, rows] statistics
             p = jnp.exp(s - lse_ref[h, j:j + 1, r][0][:, None])
             ds = p * (_dot(do, v_blk, _NT)
@@ -912,10 +1154,10 @@ def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset,
             dq += _dot(ds.astype(q.dtype), _lanes_of(j, pack, k_blk), _NN)
         dq_acc[h, r] += dq
 
-    _where_tile_runs(qi, blk_q, kb, blk_k, offset, causal, structure,
-                     lambda: _each_chunk(slabs, blk_q // rows, contribute))
+    _on_tile(step, lambda masked: _each_chunk(
+        slabs, blk_q // rows, functools.partial(contribute, masked)))
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(step.last())
     def _finalize():
         dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
@@ -942,70 +1184,79 @@ def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, plan, pack,
     # --- dK/dV: grid (B, G, kb, r, qi); r+qi sequential, accumulating.
     blk_q, blk_k, _ = plan.dkdv
     hg, hr, kv_lead = _head_blocks(Sq, Sk, G, R, plan.dkdv, pack)
+    grid, semantics, prefetch, on = _kernel_grid(
+        tile_walk("dkdv", Sq, Sk, blk_q, blk_k, causal, structure, R // hr),
+        (B, G // hg), (Sk // blk_k,), (R // hr, Sq // blk_q),
+        lambda b, g, kb, r, qi: (b, g, r, qi, kb))
     q_by_inner = pl.BlockSpec(_q_side(hg, hr, (blk_q, D)),
-                              lambda b, g, kb, r, qi: (b, g, r, qi, 0))
+                              on(_q_at))
     kv_by_outer = pl.BlockSpec(kv_lead + (blk_k, D),
-                               lambda b, g, kb, r, qi: (b, g, kb, 0))
+                               on(_kv_at))
     stat_by_inner = pl.BlockSpec(_q_side(hg, hr, (pack, blk_q)),
-                                 lambda b, g, kb, r, qi: (b, g, r, 0, qi))
+                                 on(_stat_at))
     in_specs = [q_by_inner, kv_by_outer, kv_by_outer, q_by_inner,
                 stat_by_inner, stat_by_inner]
     operands = [qg, kg, vg, dog, lse, delta]
     if has_mask:
         in_specs.append(pl.BlockSpec((None, 1, blk_k),
-                                     lambda b, g, kb, r, qi: (b, 0, kb)))
+                                     on(_mask_at)))
         operands.append(mask)
     kv_acc = tuple(n for n in kv_lead if n is not None) + (blk_k, D)
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, **kernel_args),
-        grid=(B, G // hg, Sk // blk_k, R // hr, Sq // blk_q),
-        in_specs=in_specs,
-        out_specs=[kv_by_outer, kv_by_outer],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[kv_by_outer, kv_by_outer],
+            scratch_shapes=[
+                pltpu.VMEM(kv_acc, jnp.float32),
+                pltpu.VMEM(kv_acc, jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B, G, Sk, D), kg.dtype),
             jax.ShapeDtypeStruct((B, G, Sk, D), vg.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM(kv_acc, jnp.float32),
-            pltpu.VMEM(kv_acc, jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         name="flash_bwd_dkdv",
-    )(*operands)
+    )(*prefetch, *operands)
 
     # --- dQ: grid (B, G, r, qi, kb); kb sequential, accumulating.
     blk_q, blk_k, _ = plan.dq
     hg, hr, kv_lead = _head_blocks(Sq, Sk, G, R, plan.dq, pack)
+    grid, semantics, prefetch, on = _kernel_grid(
+        tile_walk("dq", Sq, Sk, blk_q, blk_k, causal, structure),
+        (B, G // hg, R // hr), (Sq // blk_q,), (Sk // blk_k,))
     q_spec = pl.BlockSpec(_q_side(hg, hr, (blk_q, D)),
-                          lambda b, g, r, qi, kb: (b, g, r, qi, 0))
+                          on(_q_at))
     kv_spec = pl.BlockSpec(kv_lead + (blk_k, D),
-                           lambda b, g, r, qi, kb: (b, g, kb, 0))
+                           on(_kv_at))
     stat_spec = pl.BlockSpec(_q_side(hg, hr, (pack, blk_q)),
-                             lambda b, g, r, qi, kb: (b, g, r, 0, qi))
+                             on(_stat_at))
     in_specs = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec]
     operands = [qg, kg, vg, dog, lse, delta]
     if has_mask:
         in_specs.append(pl.BlockSpec((None, 1, blk_k),
-                                     lambda b, g, r, qi, kb: (b, 0, kb)))
+                                     on(_mask_at)))
         operands.append(mask)
 
     (dq,) = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kernel_args),
-        grid=(B, G // hg, R // hr, Sq // blk_q, Sk // blk_k),
-        in_specs=in_specs,
-        out_specs=[q_spec],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[q_spec],
+            scratch_shapes=[pltpu.VMEM((hg * hr, blk_q, D), jnp.float32)],
+        ),
         out_shape=[jax.ShapeDtypeStruct((B, G, R, Sq, D), qg.dtype)],
-        scratch_shapes=[pltpu.VMEM((hg * hr, blk_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         name="flash_bwd_dq",
-    )(*operands)
+    )(*prefetch, *operands)
     return dq, dk, dv
 
 

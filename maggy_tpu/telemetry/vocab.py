@@ -253,10 +253,14 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: dkdv q512 k512 h4; dq q512 k512 h4``: per kernel the query and key tile
 #: and the heads a grid step covers; several shapes in one program are
 #: joined by `` | ``; under a mask description the plan also says the mask
-#: kind, its block length and per kernel the tiles of a head that run of
-#: those there are: ``; block_diffusion b4 L4096 tiles fwd 80/256 dkdv
-#: 80/256 dq 80/256``). Absent where the trial traced nothing (a warm
-#: trial) or the program holds no flash kernel. ``moe_plan``: what each
+#: kind, its block length, per kernel the tiles of a head that run of
+#: those there are, and what each kernel's grid walks for them (steps that
+#: run a tile + steps that only open and close an accumulator, and the
+#: tiles that need the index mask): ``; block_diffusion b4 L4096 tiles fwd
+#: 80/256 dkdv 80/256 dq 80/256; walk fwd 80+0 (24 partial) dkdv 80+0 (24
+#: partial) dq 80+0 (24 partial)``). Absent where the trial traced
+#: nothing (a warm trial) or the program holds no flash kernel.
+#: ``moe_plan``: what each
 #: dropless expert layer (`models.moe.ExpertShareMLP`) holds and how it
 #: multiplies (``experts 0+16/128 top8 rows 139264 chunk 2048 tile 512
 #: pallas_gmm``: first expert + experts held / routed over, pairs a token,

@@ -14,6 +14,7 @@ from maggy_tpu.ops import attention as att
 from maggy_tpu.ops.attention import (BlockDiffusionMask, FlashPlan,
                                      attention_reference,
                                      multi_head_attention, tile_plan)
+from test_flash_tiles import WALK_TENSORS, walk_bits
 
 TENSORS = ("out", "dq", "dk", "dv")
 CASES = {
@@ -94,6 +95,113 @@ def test_tiles_run_match_brute_force(L, block, blk_q, blk_k):
         == _brute_force_tiles(L, block, blk_q, blk_k)
 
 
+TILE_CASES = [
+    (256, 4, 128, 128), (192, 32, 128, 128), (192, 4, 128, 128),
+    (512, 4, 256, 128), (384, 64, 128, 256), (1024, 32, 512, 256),
+    (2048, 4, 512, 512), (256, 128, 128, 128)]
+
+
+def _dense_grid_walk(kernel, visible, blk_q, blk_k, reps):
+    """What the dense grid with a skip did, by brute force over the dense
+    [Sq, Sk] mask ``visible``: its steps in the grid's order, those whose
+    tile holds a visible pair kept, as (q-block, k-block, rep block, whole,
+    first of its accumulator, last of it)."""
+    nq, nk = visible.shape[0] // blk_q, visible.shape[1] // blk_k
+    tile = lambda qi, kb: visible[qi * blk_q:(qi + 1) * blk_q,  # noqa: E731
+                                  kb * blk_k:(kb + 1) * blk_k]
+    if kernel == "dkdv":  # grid (kb, r, qi)
+        groups = [[(qi, kb, r) for r in range(reps) for qi in range(nq)]
+                  for kb in range(nk)]
+    else:                 # grid (qi, kb), the rep blocks apart
+        groups = [[(qi, kb, 0) for kb in range(nk)] for qi in range(nq)]
+    steps = []
+    for group in groups:
+        kept = [(qi, kb, r) for qi, kb, r in group if tile(qi, kb).any()]
+        steps += [(qi, kb, r, bool(tile(qi, kb).all()), n == 0,
+                   n == len(kept) - 1) for n, (qi, kb, r) in enumerate(kept)]
+    return steps
+
+
+def _walk_steps(walk):
+    return [(q, k, r, f & att._KIND == att._WHOLE, bool(f & att._FIRST),
+             bool(f & att._LAST))
+            for q, k, f, r in zip(*walk) if f & att._KIND != att._EMPTY]
+
+
+@pytest.mark.parametrize("kernel", FlashPlan._fields)
+@pytest.mark.parametrize("L,block,blk_q,blk_k", TILE_CASES)
+def test_the_walk_is_the_dense_grid_without_its_empty_steps(
+        L, block, blk_q, blk_k, kernel):
+    """Same tiles, same order, whole and partial told apart exactly (a tile
+    that straddles ``length`` among them: L 192 in 128-tiles), and each
+    accumulator opened and closed once."""
+    mask = BlockDiffusionMask(L, block)
+    reps = 2 if kernel == "dkdv" else 1
+    walk = att.tile_walk(kernel, 2 * L, 2 * L, blk_q, blk_k, False, mask, reps)
+    want = _dense_grid_walk(kernel, np.asarray(mask.dense()), blk_q, blk_k,
+                            reps)
+    assert _walk_steps(walk) == want
+    assert walk.count(att._EMPTY) == 0  # no query row and no key is unseen
+    assert walk.steps == reps * att._blocks_run(2 * L, 2 * L, blk_q, blk_k,
+                                                False, mask)
+    # The table the kernel reads: the four rows one after the other.
+    assert walk.table().dtype == np.int32
+    assert walk.table().reshape(4, -1).tolist() == [list(r) for r in walk]
+
+
+@pytest.mark.parametrize("kernel", FlashPlan._fields)
+def test_the_cell_walks_80_steps_a_head_24_of_them_partial(kernel):
+    """Of a head's 16 x 16 512-tiles: 28 clean-clean and 28 noised-clean
+    ones below the diagonal are whole; the 8 + 8 diagonal ones and the 8
+    noised-noised are partial; 176 are not steps at all."""
+    walk = att.tile_walk(kernel, 8192, 8192, 512, 512, False,
+                         BlockDiffusionMask(4096, 4))
+    assert (walk.steps, walk.count(att._PARTIAL), walk.count(att._WHOLE),
+            walk.count(att._EMPTY)) == (80, 24, 56, 0)
+    assert walk.describe() == "80+0 (24 partial)"
+    # dK/dV streams a K/V group's two blocks of four query heads through
+    # each k-block's accumulator: 160 steps, 80 a block of heads.
+    both = att.tile_walk("dkdv", 8192, 8192, 512, 512, False,
+                         BlockDiffusionMask(4096, 4), 2)
+    assert both.steps == 160 and sum(
+        bool(f & att._FIRST) for f in both.flags) == 16
+
+
+DESCRIBED_WALKS = {
+    # name: L, block, H, Hkv, (blk_q, blk_k, heads); the forward's walk
+    "block32_mha_packed": ((256, 32, 2, 2, (128, 128, 2)), "8+0 (6 partial)"),
+    "block4_gqa_q256": ((512, 4, 4, 1, (256, 128, 4)), "16+0 (12 partial)"),
+    # Blocks as long as a tile: noised on noised is whole too.
+    "block128_gqa": ((256, 128, 4, 2, (128, 128, 2)), "6+0 (0 partial)"),
+    # The halves meet inside the third of five tiles.
+    "block32_straddles_a_tile": ((320, 32, 2, 1, (128, 128, 2)),
+                                 "15+0 (14 partial)"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _described_walk_bits(name):
+    (L, block, H, Hkv, tiles), _ = DESCRIBED_WALKS[name]
+    return walk_bits(2 * L, 2 * L, H, Hkv, tiles, False,
+                     structure=BlockDiffusionMask(L, block))
+
+
+@pytest.mark.parametrize("tensor", WALK_TENSORS)
+@pytest.mark.parametrize("name", sorted(DESCRIBED_WALKS))
+def test_whole_tiles_without_the_mask_are_bitwise_the_masked_ones(name,
+                                                                  tensor):
+    """``out``, ``lse`` and the three gradients of the walk against the
+    same walk with every tile under the description's mask, which is what
+    the dense grid computed until PR 29 (against the parent's own kernels,
+    once: PERF.md section 6, PR 29)."""
+    (L, block, _, _, (blk_q, blk_k, _)), said = DESCRIBED_WALKS[name]
+    assert att.tile_walk("fwd", 2 * L, 2 * L, blk_q, blk_k, False,
+                         BlockDiffusionMask(L, block)).describe() == said
+    found, i = _described_walk_bits(name), WALK_TENSORS.index(tensor)
+    assert np.isfinite(found["walk"][i]).all() and found["walk"][i].any()
+    np.testing.assert_array_equal(found["walk"][i], found["all_partial"][i])
+
+
 def test_the_cell_skips_176_of_256_tiles():
     """L 4096 in 512-tiles: 8 own-block tiles on the noised diagonal, 36 of
     noised queries on the clean past, 36 block-causal clean ones."""
@@ -144,7 +252,23 @@ def test_the_plan_says_the_mask_and_the_tiles_run():
         multi_head_attention(q, q, q, causal=False,
                              mask=BlockDiffusionMask(128, 4), force="flash")
     assert plans == ["fwd q256 k256 h2; dkdv q256 k256 h2; dq q256 k256 h2; "
-                     "block_diffusion b4 L128 tiles fwd 1/1 dkdv 1/1 dq 1/1"]
+                     "block_diffusion b4 L128 tiles fwd 1/1 dkdv 1/1 dq 1/1; "
+                     "walk fwd 1+0 (1 partial) dkdv 1+0 (1 partial) "
+                     "dq 1+0 (1 partial)"]
+
+
+def test_the_plan_of_the_cell_says_its_walk(monkeypatch):
+    said = []
+    monkeypatch.setattr(att, "remember_plan",
+                        lambda kind, text: said.append(text))
+    mask = BlockDiffusionMask(4096, 4)
+    att._remember(tile_plan(8192, 8192, 128, 32, 4, 2, False, False, mask),
+                  8192, 8192, False, mask)
+    assert said == [
+        "fwd q512 k512 h4; dkdv q512 k512 h4; dq q512 k512 h4; "
+        "block_diffusion b4 L4096 tiles fwd 80/256 dkdv 80/256 dq 80/256; "
+        "walk fwd 80+0 (24 partial) dkdv 80+0 (24 partial) "
+        "dq 80+0 (24 partial)"]
 
 
 def test_a_description_never_falls_back_silently_on_a_tpu(monkeypatch):

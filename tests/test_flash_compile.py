@@ -69,6 +69,51 @@ def test_forward_and_both_backward_kernels_compile(v5e_device, shape, tiles):
         "flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]
 
 
+WALKED = {
+    # name: B, S, H, Hkv, D, causal, description: the SDAR cell's own shape
+    # under its mask, and Llama's causal GQA.
+    "sdar_cell_under_its_description": (2, 8192, 32, 4, 128, False, (4096, 4)),
+    "llama_gqa_d128_causal": (1, 2048, 32, 8, 128, True, None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WALKED))
+def test_the_kernels_that_walk_their_tiles_compile(v5e_device, name):
+    """Under a mask that empties tiles the three kernels take a `TileWalk`'s
+    table as a scalar-prefetch operand (SMEM) and hold two bodies, one for
+    partial and one for whole tiles: both must pass Mosaic before a chip
+    call. The table is the custom call's first operand, four rows of the
+    walk's steps."""
+    from maggy_tpu.ops.attention import (_PARTIAL, _WHOLE, BlockDiffusionMask,
+                                         tile_walk)
+
+    B, S, H, Hkv, D, causal, described = WALKED[name]
+    mask = described and BlockDiffusionMask(*described)
+    plan = tile_plan(S, S, D, H, Hkv, 2, causal, False, mask)
+    q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=v5e_device)
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16,
+                              sharding=v5e_device)
+
+    def loss(q, k, v):
+        out = flash_attention_planned(q, k, v, None, causal, plan, False, mask)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
+        ).as_text()
+    assert _kernel_names(text) == ["flash_bwd_dkdv", "flash_bwd_dq",
+                                   "flash_fwd"]
+    calls = {_kernel_names(line)[0]: line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line}
+    for kernel, tiles in zip(plan._fields, plan):
+        reps = (H // Hkv) // tiles.heads if kernel == "dkdv" else 1
+        walk = tile_walk(kernel, S, S, tiles.blk_q, tiles.blk_k, causal, mask,
+                         reps)
+        assert walk.count(_WHOLE) and walk.count(_PARTIAL)
+        call = calls[{"fwd": "flash_fwd"}.get(kernel, "flash_bwd_" + kernel)]
+        assert "operand_layout_constraints={{s32[{}]".format(
+            4 * walk.steps) in call
+
+
 def test_the_three_kernels_carry_their_names(v5e_device):
     """A trace names a kernel after its HLO instruction, which takes the
     `pallas_call`'s ``name=``: the benchmark's readers find the forward,

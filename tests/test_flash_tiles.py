@@ -3,6 +3,7 @@ interpret mode (the fast lane's only numeric check of the kernels;
 tests/test_models.py is `slow`), and the plan itself as a pure function of
 the shape. tests/test_flash_compile.py holds the build for the v5e."""
 
+import contextlib
 import functools
 
 import jax
@@ -170,6 +171,134 @@ def test_long_loops_that_are_not_unrolled_compute_the_same(name, monkeypatch):
     att._flash_bwd.clear_cache()
     for got, want in zip(looped, unrolled):
         assert jnp.array_equal(got, want)
+
+
+# ------------------------------------------------------------- the walk
+
+
+@contextlib.contextmanager
+def every_tile_partial():
+    """The kernels traced anew with no tile marked whole: every tile that
+    runs does so under its index mask, as the dense grid ran them until
+    PR 29."""
+    def traced_anew():
+        att.tile_walk.cache_clear()
+        att._flash_fwd.clear_cache()
+        att._flash_bwd.clear_cache()
+
+    keep, att._tile_whole = att._tile_whole, lambda *a: False
+    traced_anew()
+    try:
+        yield
+    finally:
+        att._tile_whole = keep
+        traced_anew()
+
+
+WALK_TENSORS = ("out", "lse", "dq", "dk", "dv")
+
+
+def _walk_inputs(Sq, Sk, H, Hkv):
+    """q and the cotangent w [2, Sq, H, 64], k and v [2, Sk, Hkv, 64]."""
+    rng = np.random.default_rng(Sq + Sk + H)
+    q, w = (jnp.asarray(rng.normal(size=(2, Sq, H, 64)), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=(2, Sk, Hkv, 64)), jnp.float32)
+            for _ in range(2))
+    return q, w, k, v
+
+
+def walk_bits(Sq, Sk, H, Hkv, tiles, causal, masked=False, structure=None):
+    """{"walk" | "all_partial": (out, lse, dq, dk, dv)} at D 64, float32, in
+    interpret mode. D 64 because the scale is then 1/8: on the branch with
+    no select between them XLA:CPU contracts ``s * scale - m`` into one
+    fused multiply-add, which rounds once where the masked branch rounds
+    twice, unless the product is exact (at D 128 the two branches differ in
+    the last place on the CPU; Mosaic contracts nothing, and the chip read
+    the same bits at D 128: PERF.md section 6, PR 29)."""
+    q, w, k, v = _walk_inputs(Sq, Sk, H, Hkv)
+    keep = jnp.asarray(np.arange(Sk)[None, :]
+                       < np.array([[Sk - 40], [Sk // 2]])) if masked else None
+    plan = FlashPlan(*(KernelTiles(*tiles),) * 3)
+
+    def bits():
+        out, lse = att._flash_fwd_4d(q, k, v, keep, causal, plan.fwd, True,
+                                     structure)
+        _, vjp = jax.vjp(lambda q, k, v: att.flash_attention_planned(
+            q, k, v, keep, causal, plan, True, structure), q, k, v)
+        return tuple(np.asarray(x) for x in (out, lse) + vjp(w))
+
+    found = {"walk": bits()}
+    with every_tile_partial():
+        found["all_partial"] = bits()
+    return found
+
+
+CAUSAL_WALKS = {
+    # name: Sq, Sk, H, Hkv, (blk_q, blk_k, heads), key mask; the forward's
+    # walk: tiles + empty steps (partial)
+    "s512_gqa_two_heads_a_step": ((512, 512, 4, 2, (128, 128, 2), False),
+                                  "10+0 (4 partial)"),
+    "s512_mha_packed_q256_masked": ((512, 512, 4, 4, (256, 128, 4), True),
+                                    "6+0 (4 partial)"),
+    "sq128_sk384": ((128, 384, 2, 1, (128, 128, 1), False), "3+0 (1 partial)"),
+    # The first two q-blocks see no key: one empty step each.
+    "sq384_sk128": ((384, 128, 2, 2, (128, 128, 2), False), "1+2 (1 partial)"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _causal_walk_bits(name):
+    (Sq, Sk, H, Hkv, tiles, masked), _ = CAUSAL_WALKS[name]
+    return walk_bits(Sq, Sk, H, Hkv, tiles, True, masked)
+
+
+@pytest.mark.parametrize("name", sorted(CAUSAL_WALKS))
+def test_a_causal_mask_walks_the_tiles_the_diagonal_reaches(name):
+    (Sq, Sk, _, _, (blk_q, blk_k, _), _), said = CAUSAL_WALKS[name]
+    walk = att.tile_walk("fwd", Sq, Sk, blk_q, blk_k, True, None)
+    assert walk.describe() == said
+    assert walk.steps - walk.count(att._EMPTY) \
+        == att._blocks_run(Sq, Sk, blk_q, blk_k, True)
+    # Every q-block is opened and closed once, whether a tile reaches it or
+    # not, and every k-block is reached (dK/dV has no empty step).
+    assert sorted(q for q, f in zip(walk.q_blk, walk.flags)
+                  if f & att._FIRST) == list(range(Sq // blk_q))
+    assert sorted(q for q, f in zip(walk.q_blk, walk.flags)
+                  if f & att._LAST) == list(range(Sq // blk_q))
+    assert att.tile_walk("dkdv", Sq, Sk, blk_q, blk_k, True,
+                         None).count(att._EMPTY) == 0
+    assert att.tile_walk("fwd", Sq, Sk, blk_q, blk_k, False, None) is None
+
+
+@pytest.mark.parametrize("tensor", WALK_TENSORS)
+@pytest.mark.parametrize("name", sorted(CAUSAL_WALKS))
+def test_whole_causal_tiles_without_the_mask_are_bitwise_the_masked_ones(
+        name, tensor):
+    found, i = _causal_walk_bits(name), WALK_TENSORS.index(tensor)
+    assert np.isfinite(found["walk"][i]).all() and found["walk"][i].any()
+    np.testing.assert_array_equal(found["walk"][i], found["all_partial"][i])
+
+
+@pytest.mark.parametrize("name", ["sq128_sk384", "sq384_sk128"])
+def test_a_causal_walk_writes_every_block(name):
+    """Sq < Sk: every row sees a key. Sq > Sk: the first Sq - Sk rows see
+    none, their q-blocks are no tile's, and the walk's empty steps still
+    write them: zeros in ``out`` and ``dq`` (the reference averages V
+    there), the rest as the reference has it."""
+    (Sq, Sk, H, Hkv, _, _), _ = CAUSAL_WALKS[name]
+    q, w, k, v = _walk_inputs(Sq, Sk, H, Hkv)
+    seen = np.arange(Sq) + Sk - Sq >= 0
+    q_seen = jnp.asarray(seen, jnp.float32)[None, :, None, None]
+    want, vjp = jax.vjp(lambda q, k, v: attention_reference(
+        q, k, v, causal=True) * q_seen, q, k, v)
+    out, lse, *grads = _causal_walk_bits(name)["walk"]
+    for got, ref in zip([out] + grads, (want,) + vjp(w)):
+        assert np.isfinite(got).all()
+        assert np.abs(got - np.asarray(ref)).max() <= 1e-5 * np.abs(ref).max()
+    assert np.isfinite(lse).all()
+    assert not out[:, ~seen].any() and not grads[0][:, ~seen].any()
+    assert (~seen).sum() == max(0, Sq - Sk)
 
 
 # ------------------------------------------------------------- the plan
