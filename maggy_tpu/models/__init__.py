@@ -5,6 +5,8 @@
 - `bert`: BERT-base-style encoder (GLUE fine-tune HPO config)
 - `llama`: Llama-style decoder + LoRA (the LoRA-sweep config; flagship)
 - `sdar`: SDAR-MoE block-diffusion decoder holding a share of its experts
+- `nemotron_h`: Nemotron-H decoder from a pattern of state-space, expert and
+  attention blocks, holding a share of its routed experts
 - `surgery`: ablatable-module helpers for LOCO model surgery
 """
 
@@ -13,9 +15,10 @@ from maggy_tpu.models.resnet import ResNet
 from maggy_tpu.models.bert import BertEncoder, BertConfig
 from maggy_tpu.models.llama import Llama, LlamaConfig
 from maggy_tpu.models.moe import ExpertShareMLP, MoEMLP
+from maggy_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 from maggy_tpu.models.sdar import SdarMoe, SdarMoeConfig
 from maggy_tpu.models.vit import ViT, ViTConfig
 
 __all__ = ["MnistCNN", "MnistMLP", "ResNet", "BertEncoder", "BertConfig",
-           "Llama", "LlamaConfig", "MoEMLP", "ExpertShareMLP", "SdarMoe",
-           "SdarMoeConfig", "ViT", "ViTConfig"]
+           "Llama", "LlamaConfig", "MoEMLP", "ExpertShareMLP", "NemotronH",
+           "NemotronHConfig", "SdarMoe", "SdarMoeConfig", "ViT", "ViTConfig"]

@@ -2,11 +2,13 @@
 parallelism, and a dropless one that holds a share of the experts.
 
 `ExpertShareMLP` (second half of the file) is the dropless layer: it routes
-over all ``num_experts``, is TOLD which experts it holds, sorts the
-token-expert pairs by expert and multiplies each held expert's rows by that
-expert's weights in Pallas grouped matrix products (`moe_gmm_fwd`,
-`moe_gmm_dlhs`, `moe_gmm_drhs`). No capacity, no dropped token. On one chip
-it runs without its exchange; nothing stands in for absent chips.
+over all ``num_experts`` (softmax, or sigmoid scores with a bias only the
+choice sees), is TOLD which experts it holds, sorts the token-expert pairs
+by expert and multiplies each held expert's rows by that expert's weights
+(SwiGLU's three matrices or relu^2's two) in Pallas grouped matrix products
+(`moe_gmm_fwd`, `moe_gmm_dlhs`, `moe_gmm_drhs`), beside a shared expert
+where the model has one. No capacity, no dropped token. On one chip it runs
+without its exchange; nothing stands in for absent chips.
 
 `MoEMLP` (first half) is the older layer, kept because the ``dp_ep``
 strategy's tests (tests/test_parallel_strategies.py) shard it over an
@@ -34,6 +36,7 @@ parallel/sharding.logical_axis_rules("..._ep") maps them onto the mesh.
 from __future__ import annotations
 
 import functools
+import operator
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -172,6 +175,8 @@ VMEM_LIMIT = 32 * 2 ** 20
 #: whoever traces the step can note which of its instructions ran under
 #: each (``moe_ops`` of the ``compiled`` record).
 SCOPES = ("moe_routing", "moe_dispatch", "moe_experts", "moe_combine")
+#: The scope a layer with a shared expert opens beside them, and names too.
+SHARED_SCOPE = "moe_shared"
 
 #: The name `ExpertShareMLP` gives what its routing hands to `expert_ffn`:
 #: the pairs' gates and `grouped_layout`'s three, under 3 MB a layer at
@@ -191,18 +196,64 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def route_top_k(x, router, top_k: int, renormalize: bool):
-    """(expert ids [N, k] int32, gates [N, k] float32) of tokens x [N, D]:
-    softmax over ALL experts in float32 (the logits at full matmul
-    precision: a near-tie decides which expert a token takes), the ``top_k``
-    largest, renormalised over the chosen where ``renormalize``."""
+def route_top_k(x, router, top_k: int, renormalize: bool,
+                scoring: str = "softmax", bias=None, scale: float = 1.0):
+    """(expert ids [N, k] int32, gates [N, k] float32) of tokens x [N, D].
+    The scores are taken over ALL experts in float32 (the logits at full
+    matmul precision: a near-tie decides which expert a token takes).
+
+    ``scoring="softmax"``: the ``top_k`` largest probabilities, renormalised
+    over the chosen where ``renormalize``. ``scoring="sigmoid"`` (DeepSeek-V3's
+    router, with one group): each expert's score is a sigmoid of its own
+    logit; the CHOICE is of the ``top_k`` largest of ``score + bias`` (``bias``
+    [E], which a balance rule moves, `balance_pull`, and no gradient of the
+    model's loss does), the GATES are
+    the chosen experts' scores without the bias, over their sum (plus 1e-20)
+    where ``renormalize``, times ``scale``."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, ids = jax.lax.top_k(probs, top_k)
-    if renormalize:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, ids = jax.lax.top_k(probs, top_k)
+        if renormalize:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores if bias is None else scores + jax.lax.stop_gradient(
+            bias.astype(jnp.float32))
+        ids = jax.lax.top_k(choice, top_k)[1]
+        gates = jnp.take_along_axis(scores, ids, axis=-1)
+        if renormalize:
+            gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    else:
+        raise ValueError("scoring is 'softmax' or 'sigmoid'; got {!r}".format(
+            scoring))
+    if scale != 1.0:
+        gates = gates * scale
     return ids.astype(jnp.int32), gates
+
+
+def balance_pull(ids, bias, num_experts: int):
+    """The balance rule of a sigmoid router's bias, as a term for the
+    objective: its VALUE is zero and its gradient with respect to ``bias``
+    [E] is each expert's load error, ``pairs that chose it / (pairs / E) -
+    1``, over ALL experts and this step's tokens (``ids`` [N, k]). Whatever
+    optimizer trains the model then lowers the bias of an expert that took
+    more than its share and raises that of one that took less, which is
+    DeepSeek-V3's auxiliary-loss-free rule (arXiv:2412.19437, section 2.1.2:
+    ``b_e -= gamma sign(load error)``) with the optimizer's step in gamma's
+    place: under Adam a persistent error moves the bias a learning rate a
+    step, one that changes sign hardly at all. The bias is outside the
+    model's own loss (only the choice sees it), so nothing else pulls on it."""
+    N, k = ids.shape
+    load = jnp.sum(ids[:, :, None] == jnp.arange(num_experts), axis=(0, 1),
+                   dtype=jnp.float32)
+    # Named with the routing, so a rematerialised layer that keeps
+    # `REMAT_KEEP` does not route again for the backward pass's sake.
+    error = checkpoint_name(jax.lax.stop_gradient(
+        load * (num_experts / (N * k)) - 1.0), REMAT_KEEP[0])
+    bias = bias.astype(jnp.float32)
+    return jnp.sum(error * (bias - jax.lax.stop_gradient(bias)))
 
 
 def buffer_rows(tokens: int, top_k: int, held: int,
@@ -399,23 +450,62 @@ def _silu_mul(gate, up):
     return gate * jax.nn.sigmoid(gate) * up
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
-def expert_ffn(x, w_gate, w_up, w_down, pair_gate, row_pair, tile_group,
-               tiles_used, top_k: int, chunk_rows: int, tile_rows: int,
-               interpret: bool):
+class _SwiGLU:
+    """``act = silu(x W_gate) * (x W_up)``: two matrices into the width."""
+
+    into = ("gate_proj", "up_proj")
+    act = staticmethod(_silu_mul)
+
+    @staticmethod
+    def dpre(dact, gate, up):
+        """``dact`` back through the activation, to each pre-activation
+        (float32, as they come out of their products)."""
+        sig = jax.nn.sigmoid(gate)
+        return (dact * up * sig * (1.0 + gate * (1.0 - sig)),
+                dact * gate * sig)
+
+
+class _Relu2:
+    """``act = relu(x W_up) ** 2``: one matrix into the width, no gate
+    matrix."""
+
+    into = ("up_proj",)
+
+    @staticmethod
+    def act(up):
+        return jnp.square(jax.nn.relu(up.astype(jnp.float32)))
+
+    @staticmethod
+    def dpre(dact, up):
+        return (dact * 2.0 * jax.nn.relu(up),)
+
+
+#: The expert kinds `expert_ffn` knows: the matrices that lead into the
+#: expert's width (by their parameter names), the activation over their
+#: products and its way back. The matrix out of the width is every kind's
+#: last weight.
+EXPERT_KINDS = {"swiglu": _SwiGLU, "relu2": _Relu2}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def expert_ffn(x, weights, pair_gate, row_pair, tile_group, tiles_used,
+               top_k: int, chunk_rows: int, tile_rows: int, interpret: bool,
+               kind: str = "swiglu"):
     """``out[n] = sum over n's pairs in the buffer of gate * W_down_e
-    (silu(x_n W_gate_e) * (x_n W_up_e))``: x [N, D], the held experts'
-    weights [G, D, F], [G, D, F], [G, F, D], ``pair_gate`` [N * k] float32,
-    and `grouped_layout`'s three. The buffer's used rows are walked in
-    chunks of ``chunk_rows`` (a `fori_loop` whose trip count is the used
-    rows over the chunk, so the work follows the rows routed here and the
-    live memory is one chunk's): gather the chunk's rows of x, three
-    grouped products, and a gate-weighted scatter-add back to the tokens.
-    Its own VJP (below) keeps x, the weights and the indices, and
+    act_e(x_n)``: x [N, D]; ``weights`` the held experts' matrices, those
+    into the width [G, D, F] first and ``W_down`` [G, F, D] last (``kind``
+    ``"swiglu"``: gate, up, down, ``act = silu(x W_gate) * (x W_up)``;
+    ``"relu2"``: up, down, ``act = relu(x W_up) ** 2``); ``pair_gate``
+    [N * k] float32, and `grouped_layout`'s three. The buffer's used rows
+    are walked in chunks of ``chunk_rows`` (a `fori_loop` whose trip count
+    is the used rows over the chunk, so the work follows the rows routed
+    here and the live memory is one chunk's): gather the chunk's rows of x,
+    the kind's grouped products, and a gate-weighted scatter-add back to the
+    tokens. Its own VJP (below) keeps x, the weights and the indices, and
     recomputes a chunk's activations beside their gradients."""
-    out, _ = _expert_ffn_fwd(x, w_gate, w_up, w_down, pair_gate, row_pair,
-                             tile_group, tiles_used, top_k, chunk_rows,
-                             tile_rows, interpret)
+    out, _ = _expert_ffn_fwd(x, weights, pair_gate, row_pair, tile_group,
+                             tiles_used, top_k, chunk_rows, tile_rows,
+                             interpret, kind)
     return out
 
 
@@ -423,12 +513,13 @@ def _chunks(tiles_used, chunk_rows, tile_rows):
     return -(-(tiles_used * tile_rows) // chunk_rows)
 
 
-def _expert_ffn_fwd(x, w_gate, w_up, w_down, pair_gate, row_pair, tile_group,
-                    tiles_used, top_k, chunk_rows, tile_rows, interpret):
+def _expert_ffn_fwd(x, weights, pair_gate, row_pair, tile_group, tiles_used,
+                    top_k, chunk_rows, tile_rows, interpret, kind):
     dtype = x.dtype
+    act_of = EXPERT_KINDS[kind].act
     gmm = functools.partial(_gmm, transpose_rhs=False, name="moe_gmm_fwd",
                             tile_rows=tile_rows, interpret=interpret)
-    wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+    *w_in, wd = (w.astype(dtype) for w in weights)
 
     def chunk(c, out):
         ck = _Chunk(c, pair_gate, row_pair, tile_group, tiles_used, top_k,
@@ -436,8 +527,8 @@ def _expert_ffn_fwd(x, w_gate, w_up, w_down, pair_gate, row_pair, tile_group,
         with jax.named_scope("moe_dispatch"):
             xg = x[ck.token]
         with jax.named_scope("moe_experts"):
-            act = _silu_mul(gmm(xg, wg, ck.tile_group, ck.tiles_left),
-                            gmm(xg, wu, ck.tile_group, ck.tiles_left))
+            act = act_of(*(gmm(xg, w, ck.tile_group, ck.tiles_left)
+                           for w in w_in))
             y = gmm(act.astype(dtype), wd, ck.tile_group, ck.tiles_left)
         with jax.named_scope("moe_combine"):
             return out.at[ck.token].add(
@@ -446,46 +537,45 @@ def _expert_ffn_fwd(x, w_gate, w_up, w_down, pair_gate, row_pair, tile_group,
     out = jax.lax.fori_loop(
         0, _chunks(tiles_used, chunk_rows, tile_rows), chunk,
         jnp.zeros(x.shape, jnp.float32))
-    return out.astype(dtype), (x, w_gate, w_up, w_down, pair_gate, row_pair,
-                               tile_group, tiles_used)
+    return out.astype(dtype), (x, weights, pair_gate, row_pair, tile_group,
+                               tiles_used)
 
 
-def _expert_ffn_bwd(top_k, chunk_rows, tile_rows, interpret, res, dout):
-    x, w_gate, w_up, w_down, pair_gate, row_pair, tile_group, tiles_used = res
+def _expert_ffn_bwd(top_k, chunk_rows, tile_rows, interpret, kind, res, dout):
+    x, weights, pair_gate, row_pair, tile_group, tiles_used = res
     dtype = x.dtype
     P = pair_gate.shape[0]
+    expert = EXPERT_KINDS[kind]
     fwd = functools.partial(_gmm, transpose_rhs=False, name="moe_gmm_fwd",
                             tile_rows=tile_rows, interpret=interpret)
     dlhs = functools.partial(_gmm, transpose_rhs=True, name="moe_gmm_dlhs",
                              tile_rows=tile_rows, interpret=interpret)
     drhs = functools.partial(_gmm_drhs, tile_rows=tile_rows,
                              interpret=interpret)
-    wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
+    *w_in, wd = (w.astype(dtype) for w in weights)
     dout = dout.astype(dtype)
 
     def chunk(c, carry):
-        dx, dwg, dwu, dwd, dpair = carry
+        dx, dw_in, dwd, dpair = carry
         ck = _Chunk(c, pair_gate, row_pair, tile_group, tiles_used, top_k,
                     chunk_rows, tile_rows)
         tiles = (ck.tile_group, ck.tiles_left)
         with jax.named_scope("moe_dispatch"):
             xg, dog = x[ck.token], dout[ck.token]
         with jax.named_scope("moe_experts"):
-            gate = fwd(xg, wg, *tiles).astype(jnp.float32)
-            up = fwd(xg, wu, *tiles).astype(jnp.float32)
-            sig = jax.nn.sigmoid(gate)
-            act = gate * sig * up
+            pre = [fwd(xg, w, *tiles).astype(jnp.float32) for w in w_in]
+            act = expert.act(*pre)
             # y = act W_down, so d/d(gate of the pair) <y, dout> is
             # <act, dout W_down^T>, and d/d(act) is that times the gate.
             u = dlhs(dog, wd, *tiles).astype(jnp.float32)
             dgate_of_pair = jnp.sum(act * u, axis=-1)
             dact = u * ck.gate[:, None]
-            dgate = (dact * up * sig * (1.0 + gate * (1.0 - sig))).astype(dtype)
-            dup = (dact * gate * sig).astype(dtype)
-            dxg = dlhs(dgate, wg, *tiles).astype(jnp.float32) \
-                + dlhs(dup, wu, *tiles).astype(jnp.float32)
-            dwg = drhs(xg, dgate, *tiles, dwg)
-            dwu = drhs(xg, dup, *tiles, dwu)
+            dpre = [d.astype(dtype) for d in expert.dpre(dact, *pre)]
+            dxg = functools.reduce(operator.add, (
+                dlhs(d, w, *tiles).astype(jnp.float32)
+                for d, w in zip(dpre, w_in)))
+            dw_in = tuple(drhs(xg, d, *tiles, acc)
+                          for d, acc in zip(dpre, dw_in))
             dwd = drhs(act.astype(dtype),
                        (dog.astype(jnp.float32) * ck.gate[:, None]).astype(
                            dtype), *tiles, dwd)
@@ -493,23 +583,25 @@ def _expert_ffn_bwd(top_k, chunk_rows, tile_rows, interpret, res, dout):
             dx = dx.at[ck.token].add(dxg * ck.valid[:, None])
             dpair = dpair.at[ck.pair].set(dgate_of_pair, mode="drop",
                                           unique_indices=True)
-        return dx, dwg, dwu, dwd, dpair
+        return dx, dw_in, dwd, dpair
 
     zeros = lambda w: jnp.zeros(w.shape, jnp.float32)  # noqa: E731
-    dx, dwg, dwu, dwd, dpair = jax.lax.fori_loop(
+    dx, dw_in, dwd, dpair = jax.lax.fori_loop(
         0, _chunks(tiles_used, chunk_rows, tile_rows), chunk,
-        (jnp.zeros(x.shape, jnp.float32), zeros(w_gate), zeros(w_up),
-         zeros(w_down), jnp.zeros((P,), jnp.float32)))
-    return (dx.astype(x.dtype), dwg.astype(w_gate.dtype),
-            dwu.astype(w_up.dtype), dwd.astype(w_down.dtype),
-            dpair.astype(pair_gate.dtype), None, None, None)
+        (jnp.zeros(x.shape, jnp.float32),
+         tuple(zeros(w) for w in weights[:-1]), zeros(weights[-1]),
+         jnp.zeros((P,), jnp.float32)))
+    dweights = tuple(d.astype(w.dtype)
+                     for d, w in zip(dw_in + (dwd,), weights))
+    return (dx.astype(x.dtype), dweights, dpair.astype(pair_gate.dtype),
+            None, None, None)
 
 
 expert_ffn.defvjp(_expert_ffn_fwd, _expert_ffn_bwd)
 
 
 class ExpertShareMLP(nn.Module):
-    """Top-k routed SwiGLU experts, dropless, holding a share of them.
+    """Top-k routed experts, dropless, holding a share of them.
 
     x [B, S, D] -> [B, S, D]. The router scores all ``num_experts``; this
     layer holds ``experts_held`` of them from ``first_expert`` on (all, by
@@ -518,6 +610,24 @@ class ExpertShareMLP(nn.Module):
     an expert held elsewhere contribute nothing here. ``renormalize``
     divides a token's ``top_k`` gates by their sum. There is no capacity
     and no auxiliary loss.
+
+    The defaults are a softmax router over SwiGLU experts. ``scoring``
+    ``"sigmoid"`` scores each expert by a sigmoid of its own logit, chooses
+    by ``score + router_bias`` (a parameter that starts at zero and that no
+    gradient of the model's loss reaches) and gates by the scores alone,
+    times ``route_scale`` (`route_top_k`). ``balance_scale`` turns on the
+    bias's balance rule: the layer sows `balance_pull`'s zero-valued term
+    into the ``losses`` collection, which `Trainer` adds to the objective,
+    so the optimizer steps each expert's bias against its load error; and
+    the choice sees ``balance_scale x router_bias``, because an optimizer
+    moves a parameter about a learning rate a step and a score lives on
+    [0, 1]: at a learning rate of 3e-5, 512 makes a step of the bias at
+    most 0.015. Where ``losses`` is not mutable (a plain ``apply``) nothing
+    is sowed and the bias has no gradient at all. ``expert_kind`` ``"relu2"``
+    makes an expert two matrices, ``W_down relu(W_up x) ** 2``. ``shared_dim``
+    adds ONE shared expert of that width and the same kind, which every
+    token takes with gate 1 and every holder computes alike (under the scope
+    ``moe_shared``): where shares are added up it counts once.
 
     **A share held alone does not train the router.** Where the layer holds
     a part of the experts, its gates pass no gradient. Of a token's
@@ -545,6 +655,11 @@ class ExpertShareMLP(nn.Module):
     experts_held: Optional[int] = None
     first_expert: int = 0
     renormalize: bool = True
+    scoring: str = "softmax"
+    route_scale: float = 1.0
+    balance_scale: Optional[float] = None
+    expert_kind: str = "swiglu"
+    shared_dim: Optional[int] = None
     tile_rows: int = TILE_ROWS
     #: Scale of ``down_proj``'s initial values (a model that scales its
     #: residual branches' output projections by depth passes it).
@@ -564,38 +679,74 @@ class ExpertShareMLP(nn.Module):
         if not 0 <= self.first_expert <= E - G:
             raise ValueError("experts {}..{} are not among {}".format(
                 self.first_expert, self.first_expert + G - 1, E))
+        if self.expert_kind not in EXPERT_KINDS:
+            raise ValueError("expert_kind is one of {}; got {!r}".format(
+                sorted(EXPERT_KINDS), self.expert_kind))
+        kind = EXPERT_KINDS[self.expert_kind]
         N = B * S
         tm = self.tile_rows
         rows = buffer_rows(N, k, G, tm)
         # Whole chunks cover the buffer, so no slice of it runs off its end.
         chunk_rows = tm * max(t for t in range(1, CHUNK_TILES + 1)
                               if (rows // tm) % t == 0)
-        remember_plan("moe", "experts {}+{}/{} top{} rows {} chunk {} tile {} "
-                      "pallas_gmm".format(self.first_expert, G, E, k, rows,
-                                          chunk_rows, tm), SCOPES)
+        said = "experts {}+{}/{} top{} rows {} chunk {} tile {} pallas_gmm" \
+            .format(self.first_expert, G, E, k, rows, chunk_rows, tm)
+        if self.scoring != "softmax":
+            said += " {}+bias x{:g}".format(self.scoring, self.route_scale)
+        if self.balance_scale:
+            if self.scoring != "sigmoid":
+                raise ValueError("the balance rule moves a sigmoid router's "
+                                 "bias; scoring is {!r}".format(self.scoring))
+            said += " balance x{:g}".format(self.balance_scale)
+        if self.expert_kind != "swiglu":
+            said += " " + self.expert_kind
+        scopes = SCOPES
+        if self.shared_dim:
+            said += " shared {}".format(self.shared_dim)
+            scopes += (SHARED_SCOPE,)
+        remember_plan("moe", said, scopes)
 
         router = self.param("router", nn.with_logical_partitioning(
             nn.initializers.lecun_normal(), (self.embed_axis, None)),
             (D, E), self.param_dtype)
+        bias = None
+        if self.scoring == "sigmoid":
+            bias = self.param("router_bias", nn.with_logical_partitioning(
+                nn.initializers.zeros_init(), (None,)), (E,),
+                self.param_dtype)
 
-        def expert_param(name, shape, axes, scale=1.0):
+        def matrix(name, shape, axes, scale=1.0, batch_axis=()):
             return self.param(name, nn.with_logical_partitioning(
                 nn.initializers.variance_scaling(
                     scale ** 2, "fan_in", "truncated_normal",
-                    batch_axis=(0,)),
-                (EXPERT,) + axes), shape, self.param_dtype)
+                    batch_axis=batch_axis), axes), shape, self.param_dtype)
 
-        w_gate = expert_param("gate_proj", (G, D, F),
-                              (self.embed_axis, self.mlp_axis))
-        w_up = expert_param("up_proj", (G, D, F),
-                            (self.embed_axis, self.mlp_axis))
-        w_down = expert_param("down_proj", (G, F, D),
-                              (self.mlp_axis, self.embed_axis),
-                              self.down_init_scale)
+        def expert_weights(prefix, held, width):
+            """The kind's matrices into the width, then the one out of it:
+            stacked over the held experts, or one expert's (``held``
+            None)."""
+            lead = () if held is None else (held,)
+            axes = () if held is None else (EXPERT,)
+            batch = () if held is None else (0,)
+            return tuple(
+                matrix(prefix + name, lead + (D, width),
+                       axes + (self.embed_axis, self.mlp_axis),
+                       batch_axis=batch) for name in kind.into) + (
+                matrix(prefix + "down_proj", lead + (width, D),
+                       axes + (self.mlp_axis, self.embed_axis),
+                       self.down_init_scale, batch),)
+
+        weights = expert_weights("", G, F)
 
         xd = x.reshape(N, D).astype(self.dtype)
         with jax.named_scope("moe_routing"):
-            ids, gates = route_top_k(xd, router, k, self.renormalize)
+            ids, gates = route_top_k(
+                xd, router, k, self.renormalize, self.scoring,
+                bias * self.balance_scale if self.balance_scale else bias,
+                self.route_scale)
+            if self.balance_scale:
+                self.sow("losses", "router_balance",
+                         balance_pull(ids, bias, E))
             if G < E:  # a share held alone: see the class docstring
                 gates = jax.lax.stop_gradient(gates)
             # Which experts each token took, for whoever asks for the
@@ -604,6 +755,14 @@ class ExpertShareMLP(nn.Module):
             route = [checkpoint_name(r, REMAT_KEEP[0]) for r in (
                 gates.reshape(N * k),
                 *grouped_layout(ids, self.first_expert, G, tm))]
-        out = expert_ffn(xd, w_gate, w_up, w_down, *route, k, chunk_rows, tm,
-                         not _on_tpu())
+        out = expert_ffn(xd, weights, *route, k, chunk_rows, tm,
+                         not _on_tpu(), self.expert_kind)
+        if self.shared_dim:
+            *into, down = (w.astype(self.dtype) for w in expert_weights(
+                "shared_", None, self.shared_dim))
+            with jax.named_scope(SHARED_SCOPE):
+                act = kind.act(*(
+                    jnp.dot(xd, w, preferred_element_type=jnp.float32)
+                    for w in into))
+                out = out + jnp.dot(act.astype(self.dtype), down)
         return out.reshape(B, S, D)

@@ -267,8 +267,17 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: the index buffer's rows, the rows a chunk handles, the row tile, what
 #: multiplies the groups). ``moe_ops``: ``{scope: [HLO instruction names]}``
 #: of the step executable for the layer's `jax.named_scope`s
-#: (``moe_routing``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``),
-#: by which a trace's operations are told apart. ``remat_plan``: what a
+#: (``moe_routing``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``,
+#: and ``moe_shared`` where the layer has a shared expert; the plan then
+#: ends ``sigmoid+bias x2.5 relu2 shared 3712``: the scoring and its scale,
+#: the expert kind, the shared expert's width), by which a trace's
+#: operations are told apart. ``ssm_plan``: what each state-space mixer
+#: (`models.nemotron_h.Mamba2Mixer`) scans (``heads 64x64 groups 8 state 128
+#: conv 4 chunk 128 S 8192 xla_products``: heads x channels, groups, states,
+#: convolution taps, the scan's chunk, the length, what multiplies);
+#: ``ssm_ops``: the step's instructions under the mixer's scopes
+#: (``ssm_proj``, ``ssm_conv``, ``ssm_scan``, ``ssm_gate_norm``).
+#: ``remat_plan``: what a
 #: model whose layers are rematerialised keeps of each layer beside its
 #: input, by the names the parts give those values (``layer keeps
 #: flash_out flash_lse moe_route``, `models.sdar`: the forward kernel and
@@ -278,7 +287,8 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: ``<kind>_ops``), and the vocabulary checker holds these entries to the
 #: `remember_plan` calls.
 COMPILED_FIELDS = ("warm", "forked", "vmap_lanes", "first_dispatch",
-                   "flash_plan", "moe_plan", "moe_ops", "remat_plan")
+                   "flash_plan", "moe_plan", "moe_ops", "remat_plan",
+                   "ssm_plan", "ssm_ops")
 
 #: Health-engine event fields (``ev: "health"``).
 HEALTH_STATUSES = frozenset({"raised", "cleared", "started", "error"})

@@ -71,9 +71,11 @@ def test_forward_and_both_backward_kernels_compile(v5e_device, shape, tiles):
 
 WALKED = {
     # name: B, S, H, Hkv, D, causal, description: the SDAR cell's own shape
-    # under its mask, and Llama's causal GQA.
+    # under its mask, Llama's causal GQA, and the Nemotron cell's causal
+    # attention block (GQA 32/2 at S 8192: 36 of 64 tiles of 1024 run).
     "sdar_cell_under_its_description": (2, 8192, 32, 4, 128, False, (4096, 4)),
     "llama_gqa_d128_causal": (1, 2048, 32, 8, 128, True, None),
+    "nemotron_cell_gqa_32_2_causal_s8192": (2, 8192, 32, 2, 128, True, None),
 }
 
 
@@ -280,3 +282,54 @@ def test_a_rematerialised_sdar_step_holds_each_kernel_once_a_layer(
     assert collections.Counter(_kernel_names(text)) == {
         "flash_fwd": 2, "flash_bwd_dkdv": 2, "flash_bwd_dq": 2,
         "moe_gmm_fwd": 10, "moe_gmm_dlhs": 6, "moe_gmm_drhs": 6}
+
+
+def test_a_rematerialised_nemotron_step_holds_each_kernel_once_a_block(
+        v5e_device, monkeypatch):
+    """The model's own gradient through XLA:TPU, one block of each kind at
+    toy widths (heads of 128, GQA, a share of relu^2 experts): the blocks
+    keep `models.nemotron_h.REMAT_KEEP`, so the executable holds ONE
+    `flash_fwd` beside its two backward kernels, and the two-matrix experts
+    ask for two grouped products forward (one of them again in the backward
+    pass) where SwiGLU asks for three; the step's instructions carry the
+    state-space and the shared expert's scopes."""
+    import collections
+
+    import flax.linen as nn
+
+    from maggy_tpu.models import NemotronH, NemotronHConfig, moe
+    from maggy_tpu.ops import attention, ssd
+    from maggy_tpu.ops.losses import weighted_token_xent
+    from maggy_tpu.telemetry.hlo_scopes import ops_by_scope
+
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    cfg = NemotronHConfig(
+        vocab_size=512, hidden_dim=256, pattern="EM*", num_heads=4,
+        num_kv_heads=2, head_dim=128, mamba_heads=8, mamba_head_dim=64,
+        ssm_groups=2, ssm_state=128, moe_intermediate_dim=128,
+        shared_intermediate_dim=256, num_experts=8, top_k=2, experts_held=4)
+    assert cfg.remat
+    module, S = NemotronH(cfg), 256
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=v5e_device), tree)
+
+    tokens = jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=v5e_device)
+    weights = jax.ShapeDtypeStruct((1, S), jnp.float32, sharding=v5e_device)
+    params = abstract(nn.meta.unbox(jax.eval_shape(
+        module.init, jax.random.key(0), tokens))["params"])
+
+    def loss(p, tokens, targets, weights):
+        return weighted_token_xent(module.apply({"params": p}, tokens),
+                                   targets, weights)
+
+    text = jax.jit(jax.grad(loss)).lower(
+        params, tokens, tokens, weights).compile().as_text()
+    assert collections.Counter(_kernel_names(text)) == {
+        "flash_fwd": 1, "flash_bwd_dkdv": 1, "flash_bwd_dq": 1,
+        "moe_gmm_fwd": 3, "moe_gmm_dlhs": 2, "moe_gmm_drhs": 2}
+    scopes = ops_by_scope(text, ssd.SCOPES + moe.SCOPES + (moe.SHARED_SCOPE,))
+    assert set(scopes) == set(ssd.SCOPES + moe.SCOPES + (moe.SHARED_SCOPE,))
