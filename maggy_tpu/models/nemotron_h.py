@@ -61,9 +61,10 @@ from maggy_tpu.telemetry.plans import remember_plan
 
 #: What a rematerialised block keeps beside its input, by the names its
 #: mixer gives them: the flash kernel's output and log-sum-exp, the
-#: routing's indices and gates. A state-space block names nothing: the scan
-#: keeps its own inputs and makes the rest again (`ops.ssd`).
-REMAT_KEEP = attention.REMAT_KEEP + moe.REMAT_KEEP
+#: routing's indices and gates, the scan kernel's output (its gradient
+#: keeps the scan's inputs, which the block makes again, so `ssd_fwd` runs
+#: once; the XLA products name nothing and make the rest again, `ops.ssd`).
+REMAT_KEEP = attention.REMAT_KEEP + moe.REMAT_KEEP + ssd.REMAT_KEEP
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 #: The scale the q and k projections' initial values are drawn at, beside
@@ -177,8 +178,9 @@ class Mamba2Mixer(nn.Module):
         inner, f32 = H * P, jnp.float32
         conv_dim = inner + 2 * G * N
         remember_plan("ssm", "heads {}x{} groups {} state {} conv {} chunk {} "
-                      "S {} xla_products".format(H, P, G, N, K,
-                                                 cfg.chunk_size, S),
+                      "S {} {}".format(
+                          H, P, G, N, K, cfg.chunk_size, S,
+                          ssd.scan_plan(S, H, P, G, N, cfg.chunk_size)),
                       ssd.SCOPES)
 
         def vector(name, init, shape):

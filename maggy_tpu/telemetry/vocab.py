@@ -273,15 +273,19 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: the expert kind, the shared expert's width), by which a trace's
 #: operations are told apart. ``ssm_plan``: what each state-space mixer
 #: (`models.nemotron_h.Mamba2Mixer`) scans (``heads 64x64 groups 8 state 128
-#: conv 4 chunk 128 S 8192 xla_products``: heads x channels, groups, states,
-#: convolution taps, the scan's chunk, the length, what multiplies);
+#: conv 4 chunk 128 S 8192 pallas 1024``: heads x channels, groups, states,
+#: convolution taps, the scan's chunk, the length, what multiplies: on a
+#: TPU, at shapes they tile, the `ops.ssd` kernels and the positions one of
+#: their grid steps holds; elsewhere ``xla_products``);
 #: ``ssm_ops``: the step's instructions under the mixer's scopes
 #: (``ssm_proj``, ``ssm_conv``, ``ssm_scan``, ``ssm_gate_norm``).
 #: ``remat_plan``: what a
 #: model whose layers are rematerialised keeps of each layer beside its
 #: input, by the names the parts give those values (``layer keeps
 #: flash_out flash_lse moe_route``, `models.sdar`: the forward kernel and
-#: the routing then run once a step and not again in the backward pass);
+#: the routing then run once a step and not again in the backward pass;
+#: `models.nemotron_h` says ``block keeps flash_out flash_lse moe_route
+#: ssd_out``, the scan kernel's output with them);
 #: absent where the traced model keeps nothing by name. Plans and scopes
 #: reach the record through `telemetry.plans` (``<kind>_plan``,
 #: ``<kind>_ops``), and the vocabulary checker holds these entries to the

@@ -177,7 +177,7 @@ def _kernel_names(text):
     import re
 
     return sorted(
-        re.search(r"(?:flash|moe_gmm)_[a-z]+(?:_[a-z]+)*",
+        re.search(r"(?:flash|moe_gmm|ssd)_[a-z]+(?:_[a-z]+)*",
                   line.split(" = ")[0]).group(0)
         for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line)
@@ -284,26 +284,65 @@ def test_a_rematerialised_sdar_step_holds_each_kernel_once_a_layer(
         "moe_gmm_fwd": 10, "moe_gmm_dlhs": 6, "moe_gmm_drhs": 6}
 
 
+def test_the_scan_kernels_compile_at_the_cells_shape(v5e_device):
+    """`ssd_fwd`, `ssd_states` and `ssd_bwd` through Mosaic at the Nemotron
+    cell's own shape (B 2, S 8192, 64 heads of 64, 8 groups of 128 states,
+    chunks of 128, bfloat16), at the chunks a grid step `ssd_scan` takes
+    there: forward alone, and the gradient with respect to all six."""
+    from maggy_tpu.ops import ssd
+
+    B, S, H, P, G, N, chunk = 2, 8192, 64, 64, 8, 128, 128
+    step = ssd.chunks_a_step(S, H, P, G, N, chunk)
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_device)
+
+    args = (of((B, S, H, P), jnp.bfloat16), of((B, S, H), jnp.float32),
+            of((H,), jnp.float32), of((B, S, G, N), jnp.bfloat16),
+            of((B, S, G, N), jnp.bfloat16), of((H,), jnp.float32))
+
+    def scan(*a):
+        return ssd.kernel_scan(*a, chunk, step, False)  # never interpreted
+
+    def loss(*a):
+        return jnp.sum(scan(*a).astype(jnp.float32) ** 2)
+
+    forward = jax.jit(scan).lower(*args).compile()
+    assert _kernel_names(forward.as_text()) == ["ssd_fwd"]
+    backward = jax.jit(jax.grad(loss, tuple(range(6)))).lower(*args).compile()
+    assert _kernel_names(backward.as_text()) == [
+        "ssd_bwd", "ssd_fwd", "ssd_states"]
+
+
+@pytest.mark.parametrize("keeps,forwards", [(False, 2), (True, 1)],
+                         ids=["keeps_no_ssd_out", "keeps_the_names"])
 def test_a_rematerialised_nemotron_step_holds_each_kernel_once_a_block(
-        v5e_device, monkeypatch):
+        v5e_device, monkeypatch, keeps, forwards):
     """The model's own gradient through XLA:TPU, one block of each kind at
-    toy widths (heads of 128, GQA, a share of relu^2 experts): the blocks
-    keep `models.nemotron_h.REMAT_KEEP`, so the executable holds ONE
-    `flash_fwd` beside its two backward kernels, and the two-matrix experts
-    ask for two grouped products forward (one of them again in the backward
-    pass) where SwiGLU asks for three; the step's instructions carry the
-    state-space and the shared expert's scopes."""
+    toy widths (heads of 128, GQA, a share of relu^2 experts, a scan the
+    kernels tile): the blocks keep `models.nemotron_h.REMAT_KEEP`, so the
+    executable holds ONE `flash_fwd` beside its two backward kernels and ONE
+    `ssd_fwd` beside `ssd_states` and `ssd_bwd` (two with `ssd_out` out of
+    what a block keeps: the rematerialised block runs it again), and the
+    two-matrix experts ask for two grouped products forward (one of them
+    again in the backward pass) where SwiGLU asks for three; the step's
+    instructions carry the state-space and the shared expert's scopes, and
+    every scan kernel, forward and backward, is under ``ssm_scan``."""
     import collections
 
     import flax.linen as nn
 
-    from maggy_tpu.models import NemotronH, NemotronHConfig, moe
+    from maggy_tpu.models import NemotronH, NemotronHConfig, moe, nemotron_h
     from maggy_tpu.ops import attention, ssd
     from maggy_tpu.ops.losses import weighted_token_xent
     from maggy_tpu.telemetry.hlo_scopes import ops_by_scope
 
     monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
     monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ssd, "_tpu_backend", lambda: True)
+    if not keeps:
+        monkeypatch.setattr(nemotron_h, "REMAT_KEEP",
+                            attention.REMAT_KEEP + moe.REMAT_KEEP)
     cfg = NemotronHConfig(
         vocab_size=512, hidden_dim=256, pattern="EM*", num_heads=4,
         num_kv_heads=2, head_dim=128, mamba_heads=8, mamba_head_dim=64,
@@ -330,6 +369,12 @@ def test_a_rematerialised_nemotron_step_holds_each_kernel_once_a_block(
         params, tokens, tokens, weights).compile().as_text()
     assert collections.Counter(_kernel_names(text)) == {
         "flash_fwd": 1, "flash_bwd_dkdv": 1, "flash_bwd_dq": 1,
-        "moe_gmm_fwd": 3, "moe_gmm_dlhs": 2, "moe_gmm_drhs": 2}
+        "moe_gmm_fwd": 3, "moe_gmm_dlhs": 2, "moe_gmm_drhs": 2,
+        "ssd_fwd": forwards, "ssd_states": 1, "ssd_bwd": 1}
     scopes = ops_by_scope(text, ssd.SCOPES + moe.SCOPES + (moe.SHARED_SCOPE,))
     assert set(scopes) == set(ssd.SCOPES + moe.SCOPES + (moe.SHARED_SCOPE,))
+    assert sorted(name.split(".")[0] for name in scopes["ssm_scan"]
+                  if "ssd_" in name) == (
+        ["ssd_bwd"] + ["ssd_fwd"] * forwards + ["ssd_states"])
+    assert not [line for line in text.splitlines()  # no loop over groups
+                if " while(" in line and "ssm_scan" in line]

@@ -19,6 +19,8 @@ ENTRY %main (x: f32[8]) -> f32[8] {
   %fusion.3 = f32[8] fusion(%x), kind=kLoop, calls=%fused_computation.7, metadata={op_name="jit(f)/layer_0/moe/moe_experts/mul"}
   %moe_gmm_fwd.5 = f32[8] custom-call(%fusion.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/layer_0/moe/while/body/moe_experts/moe_gmm_fwd"}
   %gte.1 = f32[8] get-tuple-element(%t), index=0, metadata={op_name="jit(f)/moe_dispatch/gte"}
+  %ssd_bwd.4 = (bf16[2,8,64]{2,1,0:T(8,128)(2,1)}, /*index=1*/f32[2,8]{1,0:T(8,128)}) custom-call(%x, %gte.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/transpose(jvp(M))/block_1/mixer/ssm_scan/ssd_bwd/pallas_call"}
+  %while.6 = (s32[], f32[8]{0}) while(%t), condition=%c, body=%b, metadata={op_name="jit(f)/ssm_scan/while"}
   %fusion.4 = f32[8] fusion(%x), kind=kLoop, calls=%fused_computation.8, metadata={op_name="jit(f)/moe_dispatch/gather"}
   ROOT %copy.2 = f32[8] copy(%fusion.4), metadata={op_name="jit(f)/optimizer/copy"}
 }
@@ -32,6 +34,12 @@ def test_top_level_instructions_by_innermost_scope():
     # scope without an instruction is left out.
     assert found == {"moe_dispatch": ["fusion.4"],
                      "moe_experts": ["fusion.3", "moe_gmm_fwd.5"]}
+
+
+def test_a_kernel_with_several_outputs_is_read_and_a_loop_is_not():
+    """`ssd_bwd` writes six arrays, so its type is a tuple; a ``while``'s
+    time is its body's instructions'."""
+    assert ops_by_scope(TEXT, ("ssm_scan",)) == {"ssm_scan": ["ssd_bwd.4"]}
 
 
 def test_no_scope_no_names():

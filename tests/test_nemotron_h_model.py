@@ -137,7 +137,7 @@ def test_the_step_says_its_plans_and_names_its_scopes():
         "sigmoid+bias x2.5 relu2 shared 40"]
     assert said.scopes["moe"] == moe.SCOPES + (moe.SHARED_SCOPE,)
     assert said.plans["remat"] == [
-        "block keeps flash_out flash_lse moe_route"]
+        "block keeps flash_out flash_lse moe_route ssd_out"]
 
 
 def test_a_pattern_is_letters_of_the_three_kinds():

@@ -1,7 +1,8 @@
-"""`ops.ssd`: the chunked state-space scan against the recurrence itself
-(position by position) and against the quadratic masked form: three forms,
-one answer, forward and every gradient; and the causal depthwise
-convolution against a shifted sum."""
+"""`ops.ssd`: the chunked state-space scan, as XLA products and as the
+Pallas kernels (interpret mode), against the recurrence itself (position by
+position) and against the quadratic masked form: three forms, one answer,
+forward and every gradient; which of the two multiplies where; and the
+causal depthwise convolution against a shifted sum."""
 
 import functools
 
@@ -12,7 +13,8 @@ import pytest
 
 from maggy_tpu.ops import ssd
 
-B, H, P, G, N = 2, 4, 8, 2, 16
+B = 2
+DIMS = (4, 8, 2, 16)  # H, P, G, N: toy widths, which only XLA's products take
 ARGS = ("x", "dt", "A", "B", "C", "D")
 
 
@@ -31,7 +33,8 @@ def quadratic(x, dt, A, Bm, Cm, D):
     return jnp.einsum("blsh,bshp->blhp", weights, x) + D[:, None] * x
 
 
-def inputs(chunks: int, chunk: int, seed: int = 0):
+def inputs(chunks: int, chunk: int, seed: int = 0, dims=DIMS):
+    H, P, G, N = dims
     S = chunks * chunk
     k = jax.random.split(jax.random.key(seed), 6)
     return (jax.random.normal(k[0], (B, S, H, P)),
@@ -42,15 +45,23 @@ def inputs(chunks: int, chunk: int, seed: int = 0):
             jax.random.normal(k[5], (H,)))
 
 
+def chunked(chunk: int, step):
+    """The chunked form: XLA's products (``step`` None, what `ssd_scan` runs
+    off a TPU), or the kernels with ``step`` chunks a grid step."""
+    if step is None:
+        return functools.partial(ssd.ssd_scan, chunk=chunk)
+    return lambda *a: ssd.kernel_scan(*a, chunk, step, True)
+
+
 @functools.lru_cache(maxsize=None)
-def three_forms(chunks: int, chunk: int):
+def three_forms(chunks: int, chunk: int, dims=DIMS, step=None):
     """(value, gradients of a scalar of it) of each form."""
-    args = inputs(chunks, chunk)
+    args = inputs(chunks, chunk, dims=dims)
     w = jax.random.normal(jax.random.key(9), args[0].shape)
     out = {}
     with jax.default_matmul_precision("highest"):
         for name, form in (
-                ("chunked", functools.partial(ssd.ssd_scan, chunk=chunk)),
+                ("chunked", chunked(chunk, step)),
                 ("recurrence", ssd.ssd_reference), ("quadratic", quadratic)):
             out[name] = jax.value_and_grad(
                 lambda *a, form=form: jnp.sum(form(*a) * w),
@@ -63,35 +74,113 @@ def close(got, want, tol=2e-5):
         <= tol * max(float(jnp.abs(want).max()), 1e-30)
 
 
-SHAPES = [(3, 8), (8, 8), (3, 16), (8, 16)]  # S of 3 and of 8 chunks
+# chunks, chunk, (H, P, G, N), chunks a grid step. XLA's products at S of 3
+# and of 8 chunks; then shapes the kernels tile, in interpret mode: two heads
+# a 128-lane slab, a grid step of two chunks, four heads a slab and an odd
+# count of chunks, a head a slab, one group and four chunks a step.
+SHAPES = [(chunks, chunk, DIMS, None)
+          for chunks, chunk in [(3, 8), (8, 8), (3, 16), (8, 16)]] + [
+    (2, 128, (4, 64, 2, 128), 1), (4, 128, (4, 64, 2, 128), 2),
+    (3, 128, (8, 32, 2, 128), 1), (2, 128, (4, 128, 2, 128), 2),
+    (4, 128, (2, 64, 1, 128), 4)]
+
+
+def shape_id(shape):
+    chunks, chunk, (H, P, G, N), step = shape
+    return "{}x{}_h{}x{}_g{}_n{}_{}".format(
+        chunks, chunk, H, P, G, N,
+        "xla" if step is None else "kernels{}".format(step))
 
 
 @pytest.mark.parametrize("other", ["recurrence", "quadratic"])
-@pytest.mark.parametrize("chunks,chunk", SHAPES)
-def test_the_chunked_scan_is_the_other_forms_forward(chunks, chunk, other):
-    forms = three_forms(chunks, chunk)
+@pytest.mark.parametrize("shape", SHAPES, ids=shape_id)
+def test_the_chunked_scan_is_the_other_forms_forward(shape, other):
+    forms = three_forms(*shape)
+    chunks, chunk, (H, P, _, _), _ = shape
     assert forms["chunked"][2].shape == (B, chunks * chunk, H, P)
-    close(forms["chunked"][2], forms[other][2])
+    # At S of 512 the quadratic form's own float32 sums are 2e-5 from the
+    # recurrence; the kernels stay within 4e-6 of it.
+    close(forms["chunked"][2], forms[other][2],
+          2e-5 if chunks * chunk < 256 else 5e-5)
 
 
 @pytest.mark.parametrize("arg", range(6), ids=ARGS)
-@pytest.mark.parametrize("chunks,chunk", SHAPES)
-def test_every_gradient_of_the_chunked_scan_is_the_recurrences(chunks, chunk,
-                                                               arg):
-    forms = three_forms(chunks, chunk)
+@pytest.mark.parametrize("shape", SHAPES, ids=shape_id)
+def test_every_gradient_of_the_chunked_scan_is_the_recurrences(shape, arg):
+    forms = three_forms(*shape)
     close(forms["chunked"][1][arg], forms["recurrence"][1][arg], 1e-4)
     close(forms["quadratic"][1][arg], forms["recurrence"][1][arg], 1e-4)
 
 
-def test_bfloat16_operands_stay_near_the_float32_scan():
+@pytest.mark.parametrize("shape", [SHAPES[0], SHAPES[5]], ids=shape_id)
+def test_bfloat16_operands_stay_near_the_float32_scan(shape):
     """What the model runs: x, B and C in bfloat16, the decays in float32."""
-    x, dt, A, Bm, Cm, D = inputs(4, 8, seed=3)
+    chunks, chunk, dims, step = shape
+    x, dt, A, Bm, Cm, D = inputs(chunks, chunk, seed=3, dims=dims)
     want = ssd.ssd_reference(x, dt, A, Bm, Cm, D)
     bf = jnp.bfloat16
-    got = ssd.ssd_scan(x.astype(bf), dt, A, Bm.astype(bf), Cm.astype(bf), D,
-                       chunk=8)
+    got = chunked(chunk, step)(x.astype(bf), dt, A, Bm.astype(bf),
+                               Cm.astype(bf), D)
     assert got.dtype == bf
     close(got.astype(jnp.float32), want, 3e-2)
+
+
+@pytest.mark.parametrize("shape", [SHAPES[0], SHAPES[5]], ids=shape_id)
+def test_bfloat16_gradients_stay_near_the_float32_scans(shape):
+    """... and every gradient, the cotangent in bfloat16 too."""
+    chunks, chunk, dims, step = shape
+    args = inputs(chunks, chunk, seed=3, dims=dims)
+    w = jax.random.normal(jax.random.key(9), args[0].shape)
+    bf = jnp.bfloat16
+    low = tuple(a.astype(bf) if i in (0, 3, 4) else a
+                for i, a in enumerate(args))
+    want = jax.grad(lambda *a: jnp.sum(ssd.ssd_reference(*a) * w),
+                    argnums=tuple(range(6)))(*args)
+    got = jax.grad(
+        lambda *a: jnp.sum(chunked(chunk, step)(*a).astype(jnp.float32) * w),
+        argnums=tuple(range(6)))(*low)
+    for g, w_ in zip(got, want):
+        assert g.dtype == jnp.float32 or g.dtype == bf
+        assert float(jnp.linalg.norm(g.astype(jnp.float32) - w_)) \
+            <= 3e-2 * float(jnp.linalg.norm(w_))
+
+
+TILING = (8192, 64, 64, 8, 128, 128)  # S, H, P, G, N, chunk: the cell's
+NOT_TILING = [(32, 4, 8, 2, 16, 8),   # `NemotronHConfig.tiny`
+              (8192, 64, 64, 8, 64, 128),    # a state of half a tile
+              (8192, 64, 64, 8, 128, 64),    # a chunk of half a tile
+              (8192, 8, 48, 8, 128, 128)]    # heads that fill no slab
+
+
+def test_off_a_tpu_the_scan_is_xlas_products(monkeypatch):
+    """The path rides on the backend and the shapes: here (a CPU) every
+    shape takes XLA's products and says so; on a TPU the shapes that tile
+    take the kernels, the others still the products."""
+    assert ssd.scan_plan(*TILING) == "xla_products"
+    assert ssd.chunks_a_step(*TILING) == 8
+    args = inputs(4, 128, dims=(4, 64, 2, 128))
+    text = str(jax.make_jaxpr(functools.partial(ssd.ssd_scan, chunk=128))(
+        *args))
+    assert "pallas_call" not in text and "custom_vjp" not in text
+    assert (ssd.ssd_scan(*args) == ssd._xla_scan(*args, chunk=128)).all()
+
+    monkeypatch.setattr(ssd, "_tpu_backend", lambda: True)
+    assert ssd.scan_plan(*TILING) == "pallas 1024"
+    assert ssd.scan_plan(3 * 128, 64, 64, 8, 128, 128) == "pallas 128"
+    text = str(jax.make_jaxpr(functools.partial(ssd.ssd_scan, chunk=128))(
+        *args))
+    assert "pallas_call" in text and "ssd_fwd" in text
+    tiny = inputs(4, 8)
+    text = str(jax.make_jaxpr(functools.partial(ssd.ssd_scan, chunk=8))(
+        *tiny))
+    assert "pallas_call" not in text
+
+
+@pytest.mark.parametrize("shape", NOT_TILING, ids=str)
+def test_shapes_the_kernels_cannot_tile_take_xlas_products(shape, monkeypatch):
+    monkeypatch.setattr(ssd, "_tpu_backend", lambda: True)
+    assert ssd.chunks_a_step(*shape) is None
+    assert ssd.scan_plan(*shape) == "xla_products"
 
 
 def test_the_scan_takes_whole_chunks_and_whole_groups():
@@ -101,17 +190,27 @@ def test_the_scan_takes_whole_chunks_and_whole_groups():
     with pytest.raises(ValueError, match="whole groups"):
         ssd.ssd_scan(x, dt, A, Bm[:, :, :1].repeat(3, 2), Cm[:, :, :1].repeat(
             3, 2), D, chunk=8)
+    assert ssd.chunks_a_step(3 * 128, 64, 64, 8, 128, 256) is None
+    assert ssd.chunks_a_step(8192, 64, 64, 6, 128, 128) is None
 
 
-def test_the_backward_pass_keeps_inputs_and_no_per_position_state():
+@pytest.mark.parametrize("shape", [(6, 8, DIMS, None),
+                                   (4, 128, (4, 64, 2, 128), 2)],
+                         ids=shape_id)
+def test_the_backward_pass_keeps_inputs_and_no_per_position_state(shape):
     """The gradient's jaxpr holds no value of a position's [P, N] state
-    for every position, and nothing of [S, S]."""
-    chunk, chunks = 8, 6
+    for every position, and nothing of [S, S]; the kernels' rule keeps the
+    op's inputs and nothing else."""
+    chunks, chunk, dims, step = shape
+    H, P, G, N = dims
     S = chunk * chunks
-    args = inputs(chunks, chunk)
+    args = inputs(chunks, chunk, dims=dims)
     jaxpr = jax.make_jaxpr(jax.grad(
-        lambda *a: jnp.sum(ssd.ssd_scan(*a, chunk=chunk)),
+        lambda *a: jnp.sum(chunked(chunk, step)(*a)),
         argnums=(0, 1, 3, 4)))(*args)
+    if step is not None:
+        _, kept = ssd._kernel_scan_fwd(*args, chunk, step, True)
+        assert all(k is a for k, a in zip(kept, args)) and len(kept) == 6
 
     def sizes(j):
         for eqn in j.eqns:
