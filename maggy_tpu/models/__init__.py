@@ -7,6 +7,8 @@
 - `sdar`: SDAR-MoE block-diffusion decoder holding a share of its experts
 - `nemotron_h`: Nemotron-H decoder from a pattern of state-space, expert and
   attention blocks, holding a share of its routed experts
+- `ouro`: Ouro looped decoder: one stack of sandwich-norm layers applied
+  several times over shared weights, an exit with a gate after every pass
 - `surgery`: ablatable-module helpers for LOCO model surgery
 """
 
@@ -16,9 +18,11 @@ from maggy_tpu.models.bert import BertEncoder, BertConfig
 from maggy_tpu.models.llama import Llama, LlamaConfig
 from maggy_tpu.models.moe import ExpertShareMLP, MoEMLP
 from maggy_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+from maggy_tpu.models.ouro import Ouro, OuroConfig
 from maggy_tpu.models.sdar import SdarMoe, SdarMoeConfig
 from maggy_tpu.models.vit import ViT, ViTConfig
 
 __all__ = ["MnistCNN", "MnistMLP", "ResNet", "BertEncoder", "BertConfig",
            "Llama", "LlamaConfig", "MoEMLP", "ExpertShareMLP", "NemotronH",
-           "NemotronHConfig", "SdarMoe", "SdarMoeConfig", "ViT", "ViTConfig"]
+           "NemotronHConfig", "Ouro", "OuroConfig", "SdarMoe",
+           "SdarMoeConfig", "ViT", "ViTConfig"]

@@ -10,10 +10,13 @@ sweep (BASELINE configs[4]).
 ``chunked_softmax_xent`` computes the exact same loss while only ever
 materializing ``[N, vocab_chunk]`` logits: a `lax.scan` over vocab chunks
 maintains online logsumexp statistics (the flash-attention trick applied to
-the classifier head), and `jax.checkpoint` on the scan body re-derives each
-chunk's logits in the backward instead of stashing them. Peak logits
-memory drops from O(N·V) to O(N·chunk) in both passes; the matmuls stay
-MXU-shaped ([N,H] x [H,chunk], fp32 accumulation).
+the classifier head), and the backward pass re-derives each chunk's logits
+from the kept log-sum-exp instead of stashing them. Peak logits memory
+drops from O(N·V) to O(N·chunk) in both passes; the matmuls stay MXU-shaped
+([N,H] x [H,chunk], fp32 accumulation). It is the mean of
+``chunked_token_nll``, the per-row likelihood, which a loss that weighs
+rows differently reads (`models.ouro`: four exits, each position's
+cross-entropy weighed by that position's exit probability).
 
 Sharding: designed for dp/fsdp meshes (vocab replicated, embed sharded —
 the flagship layout). Under tp the head's vocab dim is sharded over
@@ -23,44 +26,48 @@ serialize the ring).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 
-@jax.named_scope("chunked_ce")
-def chunked_softmax_xent(h, kernel, targets, vocab_chunk: int = 16384):
-    """Mean softmax cross-entropy of ``h @ kernel`` against ``targets``,
-    without materializing the full [N, V] logits.
-
-    h: [N, H] activations (any float dtype; products accumulate fp32).
-    kernel: [H, V] classifier weights.
-    targets: [N] int class ids in [0, V).
-
-    Numerically equivalent to
-    ``-mean(log_softmax((h @ kernel).astype(f32))[i, targets[i]])``.
-    """
-    N, H = h.shape
-    V = kernel.shape[1]
+def _vocab_chunks(V: int, vocab_chunk: int):
+    """(chunk width, the chunks' first global columns)."""
     vocab_chunk = int(min(vocab_chunk, V))
     num_chunks = -(-V // vocab_chunk)
-    col = jnp.arange(vocab_chunk)
-    tgt = targets.astype(jnp.int32)
+    return vocab_chunk, jnp.arange(num_chunks, dtype=jnp.int32) * vocab_chunk
+
+
+def _chunk_logits(h, kernel, c0, vocab_chunk: int):
+    """One chunk's float32 logits [N, chunk], the slice's first column and
+    which of its columns the chunk OWNS.
+
+    The final ragged chunk slides its START back (dynamic_slice-style clamp)
+    rather than padding the kernel: jnp.pad would materialize a second
+    full-size [H, V'] copy of the head, defeating the HBM point. The owned
+    mask keeps each column counted exactly once: the chunk owns global
+    columns [c0, c0+chunk) intersected with [0, V)."""
+    V = kernel.shape[1]
+    cs = jnp.minimum(c0, V - vocab_chunk)
+    Wk = jax.lax.dynamic_slice_in_dim(kernel, cs, vocab_chunk, axis=1)
+    # bf16 MXU matmul with fp32 accumulation: same numerics contract as the
+    # dense head (llama.py casts the head to activation dtype).
+    logits = jnp.dot(h, Wk.astype(h.dtype),
+                     preferred_element_type=jnp.float32)
+    gcol = cs + jnp.arange(vocab_chunk)  # global column of each slice column
+    return logits, Wk, cs, (gcol >= c0) & (gcol < V)
+
+
+def _token_nll_stats(h, kernel, tgt, vocab_chunk: int):
+    """(log-sum-exp [N], target logit [N]) by the online scan over vocab
+    chunks; at most one [N, chunk] block of logits is live."""
+    N = h.shape[0]
+    vocab_chunk, starts = _vocab_chunks(kernel.shape[1], vocab_chunk)
 
     def body(carry, c0):
         m, s, t = carry
-        # The final ragged chunk slides its START back (dynamic_slice-style
-        # clamp) rather than padding the kernel — jnp.pad would materialize
-        # a second full-size [H, V'] copy of the head, defeating the HBM
-        # point. Masking below keeps each column counted exactly once: the
-        # chunk OWNS global columns [c0, c0+chunk) ∩ [0, V).
-        cs = jnp.minimum(c0, V - vocab_chunk)
-        Wk = jax.lax.dynamic_slice_in_dim(kernel, cs, vocab_chunk, axis=1)
-        # bf16 MXU matmul with fp32 accumulation — same numerics contract
-        # as the dense head (llama.py casts the head to activation dtype).
-        logits = jnp.dot(h, Wk.astype(h.dtype),
-                         preferred_element_type=jnp.float32)
-        gcol = cs + col  # global column index of each slice column
-        owned = (gcol >= c0) & (gcol < V)
+        logits, _, cs, owned = _chunk_logits(h, kernel, c0, vocab_chunk)
         logits = jnp.where(owned[None, :], logits, -jnp.inf)
         m_new = jnp.maximum(m, logits.max(axis=-1))
         s = s * jnp.exp(m - m_new) + \
@@ -75,11 +82,83 @@ def chunked_softmax_xent(h, kernel, targets, vocab_chunk: int = 16384):
     init = (jnp.full((N,), -jnp.inf, jnp.float32),
             jnp.zeros((N,), jnp.float32),
             jnp.zeros((N,), jnp.float32))
-    starts = jnp.arange(num_chunks, dtype=jnp.int32) * vocab_chunk
-    # checkpoint: the backward re-derives each chunk's logits instead of
-    # keeping num_chunks * [N, chunk] residuals alive.
-    (m, s, t), _ = jax.lax.scan(jax.checkpoint(body), init, starts)
-    return jnp.mean(m + jnp.log(s) - t)
+    (m, s, t), _ = jax.lax.scan(body, init, starts)
+    return m + jnp.log(s), t
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _token_nll(h, kernel, tgt, vocab_chunk):
+    lse, t = _token_nll_stats(h, kernel, tgt, vocab_chunk)
+    return lse - t
+
+
+def _token_nll_fwd(h, kernel, tgt, vocab_chunk):
+    lse, t = _token_nll_stats(h, kernel, tgt, vocab_chunk)
+    return lse - t, (h, kernel, tgt, lse)
+
+
+def _token_nll_bwd(vocab_chunk, res, g):
+    """Each chunk's logits are derived again from ``h`` and the kernel (the
+    forward kept the log-sum-exp and no logits): ``d logits = g (softmax -
+    onehot)``, in the activation dtype for the two products, ``dh`` summed in
+    float32 over the chunks and each chunk's ``dW`` added into its columns."""
+    h, kernel, tgt, lse = res
+    vocab_chunk, starts = _vocab_chunks(kernel.shape[1], vocab_chunk)
+    g = g.astype(jnp.float32)
+
+    def body(carry, c0):
+        dh, dW = carry
+        logits, Wk, cs, owned = _chunk_logits(h, kernel, c0, vocab_chunk)
+        hit = (tgt - cs)[:, None] == jnp.arange(vocab_chunk)[None, :]
+        d = g[:, None] * (jnp.exp(logits - lse[:, None]) - hit)
+        d = jnp.where(owned[None, :], d, 0.0).astype(h.dtype)
+        dh = dh + jnp.dot(d, Wk.astype(h.dtype).T,
+                          preferred_element_type=jnp.float32)
+        dWk = jnp.dot(h.T, d, preferred_element_type=jnp.float32)
+        seen = jax.lax.dynamic_slice_in_dim(dW, cs, vocab_chunk, axis=1)
+        dW = jax.lax.dynamic_update_slice_in_dim(dW, seen + dWk, cs, axis=1)
+        return (dh, dW), None
+
+    (dh, dW), _ = jax.lax.scan(
+        body, (jnp.zeros(h.shape, jnp.float32),
+               jnp.zeros(kernel.shape, jnp.float32)), starts)
+    return dh.astype(h.dtype), dW.astype(kernel.dtype), None
+
+
+_token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
+
+
+def chunked_token_nll(h, kernel, targets, vocab_chunk: int = 16384):
+    """Per-row negative log-likelihood ``logsumexp(h @ kernel) - (h @
+    kernel)[i, targets[i]]``, float32 [N], without materializing the [N, V]
+    logits in either pass.
+
+    h: [N, H] activations (any float dtype; products accumulate fp32).
+    kernel: [H, V] classifier weights.
+    targets: [N] int class ids in [0, V).
+
+    Forward, a `lax.scan` over vocab chunks keeps online logsumexp
+    statistics and the target's logit; it keeps the log-sum-exp and nothing
+    else, and the backward pass makes each chunk's logits again and takes
+    the softmax straight from the kept log-sum-exp. That is a custom VJP
+    because autodiff through the checkpointed scan differentiates the
+    running maximum and the rescaled sums instead, and feeds float32
+    cotangents to the two backward products: at 32,768 rows against 49,152
+    columns on a v5e it took 211 ms where this takes 188 (PERF.md section 6,
+    PR 32), for 0.26 GB more of temporaries (the float32 ``dh`` carry)."""
+    return _token_nll(h, kernel, targets.astype(jnp.int32), int(vocab_chunk))
+
+
+@jax.named_scope("chunked_ce")
+def chunked_softmax_xent(h, kernel, targets, vocab_chunk: int = 16384):
+    """Mean softmax cross-entropy of ``h @ kernel`` against ``targets``,
+    without materializing the full [N, V] logits: the mean of
+    `chunked_token_nll`.
+
+    Numerically equivalent to
+    ``-mean(log_softmax((h @ kernel).astype(f32))[i, targets[i]])``.
+    """
+    return jnp.mean(chunked_token_nll(h, kernel, targets, vocab_chunk))
 
 
 def chunked_next_token_loss(hidden, kernel, tokens, vocab_chunk: int = 16384):

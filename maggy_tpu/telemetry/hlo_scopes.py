@@ -18,9 +18,12 @@ from typing import Dict, Iterable, List
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(")
 _INSTRUCTION = re.compile(
     r"^\s*(?:ROOT )?%([\w.\-]+) = \S+ ([\w\-]+)\(.*op_name=\"([^\"]*)\"")
-#: A kernel with several outputs: its type is a tuple, which holds spaces.
-_KERNEL = re.compile(
-    r"^\s*(?:ROOT )?%([\w.\-]+) = \(.*?\) (custom-call)\(.*op_name=\"([^\"]*)\"")
+#: A kernel, a fusion or a sort with several outputs (`ssd_bwd`; a norm that
+#: keeps its statistics, a product that also reduces its rows; keys sorted
+#: with their values): its type is a tuple, which holds spaces.
+_SEVERAL_OUTPUTS = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = \(.*?\) (custom-call|fusion|sort)\("
+    r".*op_name=\"([^\"]*)\"")
 #: Opcodes that move nothing on the device and are never a trace event.
 _NO_EVENT = frozenset({"get-tuple-element", "constant", "bitcast",
                        "parameter", "tuple"})
@@ -31,9 +34,12 @@ def ops_by_scope(hlo_text: str, scopes: Iterable[str]) -> Dict[str, List[str]]:
     ``scopes`` as a component, by scope (the innermost where they nest).
     Instructions inside fused computations are left out: the fusion that
     calls them is the device's operation, and it carries the ``op_name`` of
-    what it fused. Of the instructions whose result is a tuple only a
-    custom call is read (a kernel that writes several arrays, `ssd_bwd`):
-    a ``while`` is its body's instructions, which are read themselves."""
+    what it fused. Of the instructions whose result is a tuple a custom call,
+    a fusion and a sort are read, each one device operation (a kernel that
+    writes several arrays, `ssd_bwd`; XLA gives a norm's statistics and a
+    product's row reductions a second output; a router sorts keys with
+    their values). A ``while`` is its body's instructions, which are read
+    themselves."""
     wanted = tuple(scopes)
     found: Dict[str, List[str]] = {s: [] for s in wanted}
     fused = False
@@ -44,7 +50,7 @@ def ops_by_scope(hlo_text: str, scopes: Iterable[str]) -> Dict[str, List[str]]:
             continue
         if fused or "op_name=" not in line:
             continue
-        inst = _INSTRUCTION.match(line) or _KERNEL.match(line)
+        inst = _INSTRUCTION.match(line) or _SEVERAL_OUTPUTS.match(line)
         if not inst or inst.group(2) in _NO_EVENT:
             continue
         parts = inst.group(3).split("/")

@@ -279,6 +279,13 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: their grid steps holds; elsewhere ``xla_products``);
 #: ``ssm_ops``: the step's instructions under the mixer's scopes
 #: (``ssm_proj``, ``ssm_conv``, ``ssm_scan``, ``ssm_gate_norm``).
+#: ``loop_plan``: what a looped model (`models.ouro.Ouro`) runs (``4 passes
+#: x 6 layers heads 16x128 S 4096 head chunks vocab 4096 x 12 over 32768
+#: rows``: passes x layers held, the attention heads, the length, and the
+#: fused head-and-loss's chunks over the exits' rows); ``loop_ops``: the
+#: step's instructions under the model's scopes (``loop_attn``,
+#: ``loop_mlp``, ``exit_norm``, ``exit_gate``, ``exit_head``), a layer's
+#: once a pass and the head's once a chunk.
 #: ``remat_plan``: what a
 #: model whose layers are rematerialised keeps of each layer beside its
 #: input, by the names the parts give those values (``layer keeps
@@ -292,7 +299,7 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: `remember_plan` calls.
 COMPILED_FIELDS = ("warm", "forked", "vmap_lanes", "first_dispatch",
                    "flash_plan", "moe_plan", "moe_ops", "remat_plan",
-                   "ssm_plan", "ssm_ops")
+                   "ssm_plan", "ssm_ops", "loop_plan", "loop_ops")
 
 #: Health-engine event fields (``ev: "health"``).
 HEALTH_STATUSES = frozenset({"raised", "cleared", "started", "error"})

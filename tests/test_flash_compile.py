@@ -72,10 +72,13 @@ def test_forward_and_both_backward_kernels_compile(v5e_device, shape, tiles):
 WALKED = {
     # name: B, S, H, Hkv, D, causal, description: the SDAR cell's own shape
     # under its mask, Llama's causal GQA, and the Nemotron cell's causal
-    # attention block (GQA 32/2 at S 8192: 36 of 64 tiles of 1024 run).
+    # attention block (GQA 32/2 at S 8192: 36 of 64 tiles of 1024 run), and
+    # the Ouro cell's causal attention WITHOUT grouping (16 query and 16 K/V
+    # heads of 128 at S 4096: 10 of 16 tiles of 1024 run, a head a step).
     "sdar_cell_under_its_description": (2, 8192, 32, 4, 128, False, (4096, 4)),
     "llama_gqa_d128_causal": (1, 2048, 32, 8, 128, True, None),
     "nemotron_cell_gqa_32_2_causal_s8192": (2, 8192, 32, 2, 128, True, None),
+    "ouro_cell_mha_16_16_causal_s4096": (2, 4096, 16, 16, 128, True, None),
 }
 
 

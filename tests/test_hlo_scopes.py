@@ -21,6 +21,8 @@ ENTRY %main (x: f32[8]) -> f32[8] {
   %gte.1 = f32[8] get-tuple-element(%t), index=0, metadata={op_name="jit(f)/moe_dispatch/gte"}
   %ssd_bwd.4 = (bf16[2,8,64]{2,1,0:T(8,128)(2,1)}, /*index=1*/f32[2,8]{1,0:T(8,128)}) custom-call(%x, %gte.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/transpose(jvp(M))/block_1/mixer/ssm_scan/ssd_bwd/pallas_call"}
   %while.6 = (s32[], f32[8]{0}) while(%t), condition=%c, body=%b, metadata={op_name="jit(f)/ssm_scan/while"}
+  %fusion.11 = (f32[8]{0:T(1024)S(1)}, bf16[8,4]{1,0:T(8,128)(2,1)}) fusion(%x, %gte.1), kind=kOutput, calls=%fused_computation.9, metadata={op_name="jit(f)/exit_head/while/body/dot_general"}
+  %sort.3 = (f32[8]{0}, s32[8]{0}) sort(%x, %gte.1), dimensions={0}, is_stable=true, to_apply=%compare, metadata={op_name="jit(f)/sorted_pairs/sort"}
   %fusion.4 = f32[8] fusion(%x), kind=kLoop, calls=%fused_computation.8, metadata={op_name="jit(f)/moe_dispatch/gather"}
   ROOT %copy.2 = f32[8] copy(%fusion.4), metadata={op_name="jit(f)/optimizer/copy"}
 }
@@ -40,6 +42,19 @@ def test_a_kernel_with_several_outputs_is_read_and_a_loop_is_not():
     """`ssd_bwd` writes six arrays, so its type is a tuple; a ``while``'s
     time is its body's instructions'."""
     assert ops_by_scope(TEXT, ("ssm_scan",)) == {"ssm_scan": ["ssd_bwd.4"]}
+
+
+def test_a_fusion_or_a_sort_with_several_outputs_is_read():
+    """A product that also reduces its rows, or keys sorted with their
+    values, is one device operation with a tuple for a type: read like any
+    other, for every kind."""
+    assert ops_by_scope(TEXT, ("exit_head", "ssm_scan", "sorted_pairs")) == {
+        "exit_head": ["fusion.11"], "ssm_scan": ["ssd_bwd.4"],
+        "sorted_pairs": ["sort.3"]}
+    with plans.traced() as said:
+        plans.remember_plan("loop", "4 passes", ("exit_head",))
+    assert plans.notes(said, Compiled(TEXT))["loop_ops"] == {
+        "exit_head": ["fusion.11"]}
 
 
 def test_no_scope_no_names():
