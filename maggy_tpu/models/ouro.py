@@ -31,7 +31,16 @@ and every shared weight through all T of its uses.
 holds L layers and never T x L, and the program holds T x L layer bodies
 (XLA adds a weight's T gradient products). Every layer APPLICATION is
 rematerialised on its own (``remat``), so a step keeps T x L inputs for L
-layers of weights.
+layers of weights, and beside each input what `REMAT_KEEP` names: the flash
+kernel's out and lse, and the application's five hidden-wide products: q
+and k after rope and v (the arrays the attention's backward rule reads),
+``o_proj``'s output and ``down_proj``'s (the inputs of the two after-norms).
+At the published widths that is 7 x 2048 bfloat16 values and 16 float32 lse
+a token and application, 28,736 bytes (the input, out and lse alone are
+8,256). The backward pass makes again the two intermediate-wide products,
+``gate_proj`` and ``up_proj`` (2 x 5632 values a token, which nothing has
+the memory to keep), the four norms, the SwiGLU product and no other matrix
+product: neither the five kept ones, nor rope, nor the forward kernel.
 
 **Initial values.** Embedding rows unit normal, every matrix LeCun-normal,
 every norm's scale one (the after-norms make a branch's size its scale's,
@@ -50,6 +59,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from maggy_tpu.models.llama import (EMBED, HEADS, KV, MLP, VOCAB, LoRADense,
                                     RMSNorm, rope)
@@ -64,8 +74,13 @@ from maggy_tpu.telemetry.plans import remember_plan
 #: with its plan, so the step's instructions under each are ``loop_ops`` of
 #: the ``compiled`` record.
 LOOP_SCOPES = ("loop_attn", "loop_mlp", "exit_norm", "exit_gate", "exit_head")
-#: What a rematerialised layer application keeps beside its input.
-REMAT_KEEP = attention.REMAT_KEEP
+#: What a rematerialised layer application keeps beside its input: the flash
+#: kernel's two, and the five products whose output is hidden-wide, named in
+#: `OuroLayer` where the backward pass reads them (q and k after rope, v,
+#: and the outputs of ``o_proj`` and ``down_proj``). The two
+#: intermediate-wide products (``gate_proj``, ``up_proj``) are made again.
+REMAT_KEEP = attention.REMAT_KEEP + (
+    "loop_q", "loop_k", "loop_v", "loop_o_proj", "loop_down_proj")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,22 +149,28 @@ class OuroLayer(nn.Module):
                 return dense(n * cfg.head_dim, axes, name)(u).reshape(
                     B, S, n, cfg.head_dim)
 
-            q = rope(heads(cfg.num_heads, (EMBED, HEADS), "q_proj"),
-                     positions, cfg.rope_theta)
-            k = rope(heads(cfg.num_kv_heads, (EMBED, KV), "k_proj"),
-                     positions, cfg.rope_theta)
-            v = heads(cfg.num_kv_heads, (EMBED, KV), "v_proj")
+            q = checkpoint_name(
+                rope(heads(cfg.num_heads, (EMBED, HEADS), "q_proj"),
+                     positions, cfg.rope_theta), "loop_q")
+            k = checkpoint_name(
+                rope(heads(cfg.num_kv_heads, (EMBED, KV), "k_proj"),
+                     positions, cfg.rope_theta), "loop_k")
+            v = checkpoint_name(
+                heads(cfg.num_kv_heads, (EMBED, KV), "v_proj"), "loop_v")
             out = attention.multi_head_attention(q, k, v, causal=True)
-            out = dense(cfg.hidden_dim, (HEADS, EMBED), "o_proj")(
-                out.reshape(B, S, cfg.num_heads * cfg.head_dim))
+            out = checkpoint_name(
+                dense(cfg.hidden_dim, (HEADS, EMBED), "o_proj")(
+                    out.reshape(B, S, cfg.num_heads * cfg.head_dim)),
+                "loop_o_proj")
             h = x + norm("attn_after_norm")(out)
         with jax.named_scope("loop_mlp"):
             u = norm("mlp_norm")(h)
             gated = jax.nn.silu(dense(cfg.intermediate_dim, (EMBED, MLP),
                                       "gate_proj")(u)) \
                 * dense(cfg.intermediate_dim, (EMBED, MLP), "up_proj")(u)
-            return h + norm("mlp_after_norm")(
-                dense(cfg.hidden_dim, (MLP, EMBED), "down_proj")(gated))
+            return h + norm("mlp_after_norm")(checkpoint_name(
+                dense(cfg.hidden_dim, (MLP, EMBED), "down_proj")(gated),
+                "loop_down_proj"))
 
 
 class OuroStack(nn.Module):

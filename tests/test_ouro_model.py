@@ -206,6 +206,57 @@ def test_passes_over_shared_layers_are_an_unshared_stack_of_copies(remat):
             err_msg=jax.tree_util.keystr(path))
 
 
+def _loss_and_grad(model):
+    family = _family()
+    module, batch, params = _seeded(model)
+
+    def loss(p):
+        return family.loss(module.apply({"params": p}, *batch["inputs"]),
+                           batch)
+
+    return jax.value_and_grad(loss), params
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_a_rematerialised_application_makes_two_products_again(remat):
+    """The products in the gradient's program. Without remat 169: 7 forward
+    and 14 backward an application (and XLA attention's 2 and 4 on a CPU,
+    where no flash kernel runs), the heads and the gates. A rematerialised
+    application adds 4: ``gate_proj`` and ``up_proj``, the two that
+    `REMAT_KEEP` does not name, and XLA attention's two, which no
+    ``flash_out`` names here. (Keeping nothing it added 9.)"""
+    fn, params = _loss_and_grad(dict(MODEL, remat=remat))
+    assert str(jax.make_jaxpr(fn)(params)).count("dot_general") \
+        == 169 + (4 * T * L if remat else 0)
+
+
+@pytest.mark.parametrize("activations, tolerance", [
+    ("float32", 2e-4), ("bfloat16", 0.05)])
+def test_what_an_application_keeps_changes_no_value(activations, tolerance):
+    """Loss and every gradient leaf of the rematerialised model against the
+    model that rematerialises nothing: a kept value is the one the backward
+    pass would have made again. Not bit for bit on a CPU, where XLA fuses
+    the two programs differently and so rounds to bfloat16 in different
+    places: the worst leaf reads 4e-7 of its largest entry in float32, and
+    0.018 in bfloat16 (0.027 when the application kept the flash kernel's
+    two alone), whose limit is the one the bfloat16 forward pass is held
+    to against the reference."""
+    model = dict(MODEL, activation_dtype=activations)
+
+    def run(remat):
+        fn, params = _loss_and_grad(dict(model, remat=remat))
+        return jax.jit(fn)(params)
+
+    (want_loss, want), (loss, grad) = run(False), run(True)
+    assert abs(float(loss) - float(want_loss)) < 1e-5 * abs(float(want_loss))
+    leaves = jax.tree_util.tree_leaves_with_path(grad)
+    assert len(leaves) == 4 + 1 + L * 11
+    for (path, got), ref in zip(leaves, jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            got, ref, atol=tolerance * float(jnp.abs(ref).max()) + 1e-8,
+            err_msg=jax.tree_util.keystr(path))
+
+
 def test_the_tree_holds_the_layers_once_rematerialised_or_not():
     trees = {}
     for remat in (False, True):
@@ -296,7 +347,8 @@ def test_the_model_says_its_loop_and_what_an_application_keeps():
         "x 3 over 192 rows"]
     assert said.scopes["loop"] == ouro_model.LOOP_SCOPES
     assert said.plans["remat"] == [
-        "layer application keeps flash_out flash_lse"]
+        "layer application keeps flash_out flash_lse loop_q loop_k loop_v "
+        "loop_o_proj loop_down_proj"]
 
 
 def test_the_scopes_name_the_compiled_steps_instructions():

@@ -3,8 +3,9 @@ the ``compiled`` record, at toy size on the CPU. Off the TPU attention is
 XLA's and the expert layer's grouped products run in interpret mode, so the
 routing's name is the one that is kept here; the flash names are counted in
 ``test_block_diffusion_flash.py`` and, through XLA:TPU, in
-``test_flash_compile.py``. A file of its own so that the test workers share
-the model tests' time."""
+``test_flash_compile.py``. The ``compiled`` record's ``remat_plan`` is read
+here for `models.ouro.Ouro` too. A file of its own so that the test workers
+share the model tests' time."""
 
 import os
 import sys
@@ -73,36 +74,54 @@ def test_what_the_rematerialised_layers_keep_changes_no_bit(case, mode):
                for g in routers)
 
 
+#: family: (its module and config in `maggy_tpu.models`, what its batches
+#: ask of the model's keys, the plan beside ``remat_plan`` and how it
+#: starts, what a rematerialised trial's record says).
+RECORD_CASES = {
+    "sdar_moe": ("SdarMoe", "SdarMoeConfig", MODEL,
+                 "moe_plan", "experts 2+4/8 top2",
+                 "layer keeps flash_out flash_lse moe_route"),
+    "ouro": ("Ouro", "OuroConfig",
+             {"vocab_size": 96, "exit_entropy_beta": 0.05},
+             "loop_plan", "3 passes x 2 layers",
+             "layer application keeps flash_out flash_lse loop_q loop_k "
+             "loop_v loop_o_proj loop_down_proj"),
+}
+
+
 @pytest.mark.parametrize("remat", [True, False])
-def test_the_compiled_record_says_what_the_layers_keep(remat):
+@pytest.mark.parametrize("family_name", sorted(RECORD_CASES))
+def test_the_compiled_record_says_what_the_layers_keep(family_name, remat):
     """A trial that traces a rematerialised model notes ``remat_plan``
-    beside ``moe_plan``; one whose model rematerialises nothing has none."""
+    beside its family's own plan; one whose model rematerialises nothing
+    has none."""
     import optax
 
-    from maggy_tpu.models import SdarMoe, SdarMoeConfig
+    from maggy_tpu import models
     from maggy_tpu.parallel import make_mesh
     from maggy_tpu.telemetry.runnerstats import RunnerStats, span
     from maggy_tpu.train import Trainer, clear_warm, swept_transform, warm
 
-    family = spec.load_module("families", "sdar_moe")
-    batch = family.batches(MODEL, 2, 32, seed=7, n=1)[0]
+    module, config, model, plan, starts, keeps = RECORD_CASES[family_name]
+    module = getattr(models, module)(
+        getattr(models, config).tiny(remat=remat))
+    family = spec.load_module("families", family_name)
+    batch = family.batches(model, 2, 32, seed=7, n=1)[0]
     stats = RunnerStats()
     stats.trial_start("t1")
     clear_warm()
     with warm.trial_scope(trial_id="t1", stats=stats), \
             span("trial", stats=stats, trial_id="t1"):
         trainer = Trainer(
-            SdarMoe(SdarMoeConfig.tiny(remat=remat)),
-            swept_transform(optax.adamw, learning_rate=1e-3), family.loss,
-            make_mesh({"data": 1}, devices=jax.devices()[:1]))
+            module, swept_transform(optax.adamw, learning_rate=1e-3),
+            family.loss, make_mesh({"data": 1}, devices=jax.devices()[:1]))
         trainer.init(jax.random.key(0), batch["inputs"])
         assert np.isfinite(float(trainer.step(trainer.place_batch(batch))))
     stats.trial_end("t1")
     clear_warm()
     (compiled,) = stats.snapshot_delta()["compile_events"]
-    assert compiled["moe_plan"].startswith("experts 2+4/8 top2")
+    assert compiled[plan].startswith(starts)
     if remat:
-        assert compiled["remat_plan"] == \
-            "layer keeps flash_out flash_lse moe_route"
+        assert compiled["remat_plan"] == keeps
     else:
         assert "remat_plan" not in compiled
