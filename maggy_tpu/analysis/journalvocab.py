@@ -21,7 +21,14 @@ of either is opened somewhere. So are the fields a traced part of the
 program gives the ``compiled`` record: a ``remember_plan("kind", ...)``
 call stands for ``kind_plan`` (and ``kind_ops`` where it names scopes),
 which must be in ``COMPILED_FIELDS``, and every ``*_plan`` / ``*_ops``
-entry there is said by some call.
+entry there is said by some call; a module-level ``*_FIELDS`` tuple
+(``plans.STEP_FIELDS``) is a table of fields its module writes into the
+record, and says each. And the `jax.named_scope`s: every literal name a
+``named_scope(...)`` call or decorator opens (a module-level string constant
+is read through) and every entry of a module-level ``*SCOPES`` tuple is in
+``STEP_SCOPES``, and every entry of ``STEP_SCOPES`` is opened by some call:
+a scope the step's reading (``step_ops``) does not know would hide its
+operations under ``unscoped``.
 
 ``# vocab-ok: <reason>`` on the emit/consume line suppresses.
 """
@@ -81,6 +88,8 @@ class Vocab:
                 | self.sets.get("ANNOTATION_NAMES", set())
         if name == "compiled_field":
             return self.sets.get("COMPILED_FIELDS", set())
+        if name == "step_scope":
+            return self.sets.get("STEP_SCOPES", set())
         return set()
 
 
@@ -189,12 +198,28 @@ def _collect_emits(index: PackageIndex, vocab_mod
                             kw.arg == "scopes" for kw in node.keywords):
                         out.append(("compiled_field", kind + "_ops", mod,
                                     node.lineno))
+            elif name == "named_scope":
+                scope = node.args[0] if node.args else None
+                if isinstance(scope, ast.Name):  # SHARED_SCOPE = "..."
+                    scope = _module_constant(mod, scope.id)
+                if scope is not None and _is_str(scope):
+                    out.append(("step_scope", scope.value, mod, node.lineno))
             elif name == "mark":
                 # SpanTracker.mark(trial, "phase") — the facade's inner
                 # edge; literal phases here are emits too.
                 if len(node.args) >= 2 and _is_str(node.args[1]):
                     out.append(("phase", node.args[1].value, mod,
                                 node.lineno))
+        # Tables: the record's fields a module writes (plans.STEP_FIELDS),
+        # the scopes a model names to its readers (moe.SCOPES).
+        for node in mod.tree.body:
+            if isinstance(node, ast.Assign) and len(node.targets) == 1 \
+                    and isinstance(node.targets[0], ast.Name):
+                table = node.targets[0].id
+                family = "compiled_field" if table.endswith("_FIELDS") \
+                    else "scope_table" if table.endswith("SCOPES") else None
+                for lit in sorted(family and _literal_set(node.value) or ()):
+                    out.append((family, lit, mod, node.lineno))
         # Raw journal records: dict literals carrying an "ev" key (the
         # Telemetry facade's internal _record paths).
         for node in ast.walk(mod.tree):
@@ -215,6 +240,16 @@ def _collect_emits(index: PackageIndex, vocab_mod
 
 def _is_str(node) -> bool:
     return isinstance(node, ast.Constant) and isinstance(node.value, str)
+
+
+def _module_constant(mod: ModuleInfo, name: str):
+    """The value node of a module-level ``name = ...``, or None."""
+    for node in mod.tree.body:
+        if isinstance(node, ast.Assign) and len(node.targets) == 1 \
+                and isinstance(node.targets[0], ast.Name) \
+                and node.targets[0].id == name:
+            return node.value
+    return None
 
 
 def _is_meta(mod: ModuleInfo) -> bool:
@@ -352,6 +387,15 @@ def check(index: PackageIndex) -> List["Finding"]:
     emits = _collect_emits(index, vocab.mod)
     emitted_by_family: Dict[str, Set[str]] = {}
     for fam, lit, mod, line in emits:
+        if fam == "scope_table":  # listed, not opened
+            if "STEP_SCOPES" in vocab.sets \
+                    and lit not in vocab.family("step_scope"):
+                emit_finding(mod, line,
+                             "scope {!r} of a scopes table is not in "
+                             "STEP_SCOPES (telemetry/vocab.py)".format(lit))
+            continue
+        if fam == "step_scope" and "STEP_SCOPES" not in vocab.sets:
+            continue
         emitted_by_family.setdefault(fam, set()).add(lit)
         if lit not in vocab.family(fam):
             emit_finding(mod, line,
@@ -363,7 +407,8 @@ def check(index: PackageIndex) -> List["Finding"]:
                           ("EVENT_KINDS", "kind"),
                           ("REQUEUE_REASONS", "reason"),
                           ("SPAN_NAMES", "span_name"),
-                          ("ANNOTATION_NAMES", "span_name")):
+                          ("ANNOTATION_NAMES", "span_name"),
+                          ("STEP_SCOPES", "step_scope")):
         for entry in sorted(vocab.sets.get(set_name, set())):
             if entry not in emitted_by_family.get(fam, set()):
                 emit_finding(vocab.mod, vocab.lines.get(entry, 1),
@@ -376,7 +421,8 @@ def check(index: PackageIndex) -> List["Finding"]:
                 emitted_by_family.get("compiled_field", set()):
             emit_finding(vocab.mod, vocab.lines.get(entry, 1),
                          "vocabulary entry {!r} (COMPILED_FIELDS) is said "
-                         "by no remember_plan call".format(entry))
+                         "by no remember_plan call and no *_FIELDS "
+                         "table".format(entry))
 
     for fam, lit, mod, line in _collect_consumes(index, vocab.mod):
         if lit not in vocab.family(fam):

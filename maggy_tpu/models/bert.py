@@ -61,19 +61,21 @@ class EncoderLayer(nn.Module):
         cfg = self.cfg
         B, S, _ = x.shape
         head_dim = cfg.hidden_dim // cfg.num_heads
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln_attn")(x).astype(cfg.dtype)
-        q = _dense(cfg.hidden_dim, (EMBED, HEADS), cfg, "q_proj")(h)
-        k = _dense(cfg.hidden_dim, (EMBED, HEADS), cfg, "k_proj")(h)
-        v = _dense(cfg.hidden_dim, (EMBED, HEADS), cfg, "v_proj")(h)
-        shape4 = (B, S, cfg.num_heads, head_dim)
-        att = multi_head_attention(
-            q.reshape(shape4), k.reshape(shape4), v.reshape(shape4),
-            causal=False, mask=pad_mask[:, None, None, :])
-        att = att.reshape(B, S, cfg.hidden_dim)
-        att = _dense(cfg.hidden_dim, (HEADS, EMBED), cfg, "o_proj")(att)
-        if cfg.dropout > 0:
-            att = nn.Dropout(cfg.dropout, deterministic=not train)(att)
-        x = x + att
+        with jax.named_scope("attn"):
+            h = nn.LayerNorm(dtype=jnp.float32, name="ln_attn")(x).astype(
+                cfg.dtype)
+            q = _dense(cfg.hidden_dim, (EMBED, HEADS), cfg, "q_proj")(h)
+            k = _dense(cfg.hidden_dim, (EMBED, HEADS), cfg, "k_proj")(h)
+            v = _dense(cfg.hidden_dim, (EMBED, HEADS), cfg, "v_proj")(h)
+            shape4 = (B, S, cfg.num_heads, head_dim)
+            att = multi_head_attention(
+                q.reshape(shape4), k.reshape(shape4), v.reshape(shape4),
+                causal=False, mask=pad_mask[:, None, None, :])
+            att = att.reshape(B, S, cfg.hidden_dim)
+            att = _dense(cfg.hidden_dim, (HEADS, EMBED), cfg, "o_proj")(att)
+            if cfg.dropout > 0:
+                att = nn.Dropout(cfg.dropout, deterministic=not train)(att)
+            x = x + att
         with jax.named_scope("mlp"):
             h = nn.LayerNorm(dtype=jnp.float32, name="ln_mlp")(x).astype(
                 cfg.dtype)
@@ -82,7 +84,7 @@ class EncoderLayer(nn.Module):
             h = _dense(cfg.hidden_dim, (MLP, EMBED), cfg, "fc_out")(h)
             if cfg.dropout > 0:
                 h = nn.Dropout(cfg.dropout, deterministic=not train)(h)
-        return x + h
+            return x + h
 
 
 class BertEncoder(nn.Module):
@@ -100,15 +102,19 @@ class BertEncoder(nn.Module):
         pos_emb = self.param("pos_embedding", nn.with_logical_partitioning(
             nn.initializers.normal(0.02), (None, EMBED)),
             (cfg.max_seq_len, cfg.hidden_dim), cfg.param_dtype)
-        x = tok_emb.astype(cfg.dtype)[tokens] + pos_emb[None, :S].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = tok_emb.astype(cfg.dtype)[tokens] \
+                + pos_emb[None, :S].astype(cfg.dtype)
         for i in range(cfg.num_layers):
             x = EncoderLayer(cfg, name="layer_{}".format(i))(
                 x, attention_mask.astype(bool), train=train)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
-        # [CLS] pooling + classification head (GLUE fine-tune shape).
-        # (EMBED, None), not (EMBED, EMBED): one PartitionSpec must not name
-        # the same mesh axis twice under fsdp strategies.
-        pooled = nn.tanh(_dense(cfg.hidden_dim, (EMBED, None), cfg, "pooler")(
-            x[:, 0].astype(cfg.dtype)))
-        return _dense(cfg.num_classes, (EMBED, None), cfg, "classifier")(
-            pooled).astype(jnp.float32)
+        with jax.named_scope("head"):
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
+            # [CLS] pooling + classification head (GLUE fine-tune shape).
+            # (EMBED, None), not (EMBED, EMBED): one PartitionSpec must not
+            # name the same mesh axis twice under fsdp strategies.
+            pooled = nn.tanh(_dense(
+                cfg.hidden_dim, (EMBED, None), cfg, "pooler")(
+                x[:, 0].astype(cfg.dtype)))
+            return _dense(cfg.num_classes, (EMBED, None), cfg, "classifier")(
+                pooled).astype(jnp.float32)

@@ -246,13 +246,14 @@ class NemotronHAttention(nn.Module):
             return _projection(cfg, n * cfg.head_dim, axes, name, scale)(
                 x).reshape(B, S, n, cfg.head_dim)
 
-        out = attention.multi_head_attention(
-            heads(cfg.num_heads, (EMBED, HEADS), "q_proj", QK_INIT_SCALE),
-            heads(cfg.num_kv_heads, (EMBED, KV), "k_proj", QK_INIT_SCALE),
-            heads(cfg.num_kv_heads, (EMBED, KV), "v_proj"), causal=True)
-        return _projection(cfg, cfg.hidden_dim, (HEADS, EMBED), "o_proj",
-                           cfg.residual_scale)(
-            out.reshape(B, S, cfg.num_heads * cfg.head_dim))
+        with jax.named_scope("attn"):
+            out = attention.multi_head_attention(
+                heads(cfg.num_heads, (EMBED, HEADS), "q_proj", QK_INIT_SCALE),
+                heads(cfg.num_kv_heads, (EMBED, KV), "k_proj", QK_INIT_SCALE),
+                heads(cfg.num_kv_heads, (EMBED, KV), "v_proj"), causal=True)
+            return _projection(cfg, cfg.hidden_dim, (HEADS, EMBED), "o_proj",
+                               cfg.residual_scale)(
+                out.reshape(B, S, cfg.num_heads * cfg.head_dim))
 
 
 def _experts(cfg: NemotronHConfig, name: str) -> moe.ExpertShareMLP:
@@ -280,8 +281,11 @@ class NemotronHBlock(nn.Module):
         mixer = {MAMBA: lambda: Mamba2Mixer(cfg, name="mixer"),
                  ATTENTION: lambda: NemotronHAttention(cfg, name="mixer"),
                  EXPERTS: lambda: _experts(cfg, "mixer")}[self.kind]()
-        return x + mixer(
-            RMSNorm(cfg.norm_eps, cfg.param_dtype, name="norm")(x))
+        # A mixer names its own parts inside; the norm, the residual and
+        # what a mixer does between its parts are the block's.
+        with jax.named_scope("block"):
+            return x + mixer(
+                RMSNorm(cfg.norm_eps, cfg.param_dtype, name="norm")(x))
 
 
 class NemotronH(nn.Module):
@@ -295,7 +299,8 @@ class NemotronH(nn.Module):
         emb = self.param("embedding", nn.with_logical_partitioning(
             nn.initializers.normal(1.0), (VOCAB, EMBED)),
             (cfg.vocab_size, cfg.hidden_dim), cfg.param_dtype)
-        x = emb.astype(cfg.dtype)[tokens]
+        with jax.named_scope("embed"):
+            x = emb.astype(cfg.dtype)[tokens]
         block_cls = NemotronHBlock
         if cfg.remat:
             remember_plan("remat", "block keeps " + " ".join(REMAT_KEEP))
@@ -305,9 +310,10 @@ class NemotronH(nn.Module):
                     *REMAT_KEEP))
         for i, kind in enumerate(cfg.pattern):
             x = block_cls(cfg, kind, name="block_{}".format(i))(x)
-        x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
         head = self.param("lm_head", nn.with_logical_partitioning(
             nn.initializers.lecun_normal(), (EMBED, VOCAB)),
             (cfg.hidden_dim, cfg.vocab_size), cfg.param_dtype)
-        return jnp.dot(x, head.astype(cfg.dtype),
-                       preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
+            return jnp.dot(x, head.astype(cfg.dtype),
+                           preferred_element_type=jnp.float32)

@@ -237,7 +237,8 @@ class Ouro(nn.Module):
         emb = self.param("embedding", nn.with_logical_partitioning(
             nn.initializers.normal(1.0), (VOCAB, EMBED)),
             (cfg.vocab_size, cfg.hidden_dim), cfg.param_dtype)
-        x = emb.astype(cfg.dtype)[tokens]
+        with jax.named_scope("embed"):
+            x = emb.astype(cfg.dtype)[tokens]
         stack, states = OuroStack(cfg, name="stack"), []
         for _ in range(T):
             x = stack(x, positions)

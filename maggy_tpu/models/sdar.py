@@ -152,9 +152,10 @@ class SdarLayer(nn.Module):
         cfg = self.cfg
         mask = attention.BlockDiffusionMask(x.shape[1] // 2,
                                             cfg.block_length)
-        h = x + SdarAttention(cfg, name="attn")(
-            RMSNorm(cfg.norm_eps, cfg.param_dtype, name="attn_norm")(x),
-            positions, mask)
+        with jax.named_scope("attn"):
+            h = x + SdarAttention(cfg, name="attn")(
+                RMSNorm(cfg.norm_eps, cfg.param_dtype, name="attn_norm")(x),
+                positions, mask)
         experts = moe.ExpertShareMLP(
             hidden_dim=cfg.hidden_dim,
             intermediate_dim=cfg.moe_intermediate_dim,
@@ -163,8 +164,11 @@ class SdarLayer(nn.Module):
             renormalize=cfg.norm_topk_prob,
             down_init_scale=cfg.residual_scale, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="moe")
-        return h + experts(
-            RMSNorm(cfg.norm_eps, cfg.param_dtype, name="mlp_norm")(h))
+        # The expert layer names its own parts inside; its norm, its loop
+        # of rounds and the residual are the block's.
+        with jax.named_scope("block"):
+            return h + experts(
+                RMSNorm(cfg.norm_eps, cfg.param_dtype, name="mlp_norm")(h))
 
 
 class SdarMoe(nn.Module):
@@ -196,7 +200,8 @@ class SdarMoe(nn.Module):
         emb = self.param("embedding", nn.with_logical_partitioning(
             embedding_init, (VOCAB, EMBED)),
             (cfg.vocab_size, cfg.hidden_dim), cfg.param_dtype)
-        x = emb.astype(cfg.dtype)[tokens]
+        with jax.named_scope("embed"):
+            x = emb.astype(cfg.dtype)[tokens]
         layer_cls = SdarLayer
         if cfg.remat:
             remember_plan("remat", "layer keeps " + " ".join(REMAT_KEEP))
@@ -206,10 +211,11 @@ class SdarMoe(nn.Module):
                     *REMAT_KEEP))
         for i in range(cfg.num_layers):
             x = layer_cls(cfg, name="layer_{}".format(i))(x, positions)
-        x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(
-            x[:, :L])
         head = self.param("lm_head", nn.with_logical_partitioning(
             nn.initializers.normal(0.02), (EMBED, VOCAB)),
             (cfg.hidden_dim, cfg.vocab_size), cfg.param_dtype)
-        return jnp.dot(x, head.astype(cfg.dtype),
-                       preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(
+                x[:, :L])
+            return jnp.dot(x, head.astype(cfg.dtype),
+                           preferred_element_type=jnp.float32)

@@ -20,6 +20,7 @@ import dataclasses
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from maggy_tpu.models.bert import BertConfig, EncoderLayer, _dense
@@ -79,32 +80,35 @@ class ViT(nn.Module):
                     cfg.image_size, images.shape[1], images.shape[2]))
         # Patch embedding: a stride-p conv == one [p*p*C, D] matmul per
         # patch; XLA lowers it straight onto the MXU.
-        x = nn.Conv(
-            cfg.hidden_dim, kernel_size=(p, p), strides=(p, p),
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="patch_embed",
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), (None, None, None, EMBED)),
-            bias_init=nn.with_logical_partitioning(
-                nn.initializers.zeros_init(), (EMBED,)),
-        )(images.astype(cfg.dtype))
-        x = x.reshape(B, cfg.num_patches, cfg.hidden_dim)
-        cls = self.param(
-            "cls_token", nn.with_logical_partitioning(
-                nn.initializers.zeros_init(), (None, None, EMBED)),
-            (1, 1, cfg.hidden_dim), cfg.param_dtype)
-        x = jnp.concatenate(
-            [jnp.broadcast_to(cls.astype(cfg.dtype),
-                              (B, 1, cfg.hidden_dim)), x], axis=1)
-        pos = self.param(
-            "pos_embedding", nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), (None, EMBED)),
-            (cfg.num_patches + 1, cfg.hidden_dim), cfg.param_dtype)
-        x = x + pos[None].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = nn.Conv(
+                cfg.hidden_dim, kernel_size=(p, p), strides=(p, p),
+                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                name="patch_embed",
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.normal(0.02), (None, None, None, EMBED)),
+                bias_init=nn.with_logical_partitioning(
+                    nn.initializers.zeros_init(), (EMBED,)),
+            )(images.astype(cfg.dtype))
+            x = x.reshape(B, cfg.num_patches, cfg.hidden_dim)
+            cls = self.param(
+                "cls_token", nn.with_logical_partitioning(
+                    nn.initializers.zeros_init(), (None, None, EMBED)),
+                (1, 1, cfg.hidden_dim), cfg.param_dtype)
+            x = jnp.concatenate(
+                [jnp.broadcast_to(cls.astype(cfg.dtype),
+                                  (B, 1, cfg.hidden_dim)), x], axis=1)
+            pos = self.param(
+                "pos_embedding", nn.with_logical_partitioning(
+                    nn.initializers.normal(0.02), (None, EMBED)),
+                (cfg.num_patches + 1, cfg.hidden_dim), cfg.param_dtype)
+            x = x + pos[None].astype(cfg.dtype)
         enc = self.cfg.encoder_cfg()
         mask = jnp.ones((B, cfg.num_patches + 1), bool)
         for i in range(cfg.num_layers):
             x = EncoderLayer(enc, name="layer_{}".format(i))(
                 x, mask, train=train)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
-        return _dense(cfg.num_classes, (EMBED, None), enc, "head")(
-            x[:, 0].astype(cfg.dtype)).astype(jnp.float32)
+        with jax.named_scope("head"):
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
+            return _dense(cfg.num_classes, (EMBED, None), enc, "head")(
+                x[:, 0].astype(cfg.dtype)).astype(jnp.float32)

@@ -297,9 +297,50 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: reach the record through `telemetry.plans` (``<kind>_plan``,
 #: ``<kind>_ops``), and the vocabulary checker holds these entries to the
 #: `remember_plan` calls.
+#: ``step_ops`` and ``step_mixed`` (`plans.STEP_FIELDS`, for every program
+#: `Trainer` compiles, whether a kind spoke or not): every device operation
+#: of the step program under exactly one **part** ``"<scopes>:<pass>"``,
+#: the `STEP_SCOPES` on its ``op_name`` path outermost first joined by
+#: ``/`` (``unscoped`` where it holds none) and ``fwd``, ``bwd``, ``remat``
+#: (a forward pass made again in the backward one) or, under ``optimizer``,
+#: ``update``. ``step_ops`` = ``{part: [HLO instruction names]}``: plain
+#: operations, and the fusions whose bodies hold one part. ``step_mixed`` =
+#: ``{instruction: [[part, flops, bytes], ...]}``: the fusions whose bodies
+#: hold several (a weight's gradient product fused with that weight's
+#: update), first the part of the fusion's own name, with each part's
+#: product FLOPs and directly read and written bytes by the text's shapes,
+#: raw: a reader divides the fusion's measured time by them
+#: (`hlo_scopes.Program.step_parts`; ``docs/telemetry.md``).
 COMPILED_FIELDS = ("warm", "forked", "vmap_lanes", "first_dispatch",
                    "flash_plan", "moe_plan", "moe_ops", "remat_plan",
-                   "ssm_plan", "ssm_ops", "loop_plan", "loop_ops")
+                   "ssm_plan", "ssm_ops", "loop_plan", "loop_ops",
+                   "step_ops", "step_mixed")
+
+#: Every `jax.named_scope` the package opens, a closed list (this module
+#: imports no model; the vocabulary checker holds the list to the
+#: `named_scope` calls and to the models' ``*SCOPES`` tuples, both ways). A
+#: scope is a component of the ``op_name`` of every operation traced under
+#: it, and with it of the operation's part in ``step_ops``.
+#: ``loss_and_grad`` is the frame around the model's passes and names no
+#: part; ``optimizer`` is a part whose pass is ``update``.
+STEP_SCOPES = (
+    "loss_and_grad", "optimizer",     # train/trainer.py, the step's halves
+    "loss",                           # ... the loss function and its sum
+    "embed",                          # token / patch and position embedding
+    "attn",                           # an attention sub-layer's norm,
+                                      #   projections, rope and residual
+    "attention",                      # ops/attention.py, inside ``attn``
+    "mlp",                            # models/bert.py, the dense sub-layer
+    "block",                          # a block's norm and residual where
+                                      #   the mixer names its own parts
+    "head",                           # final norm and the head's product
+    "chunked_ce", "weighted_ce",      # ops/losses.py
+    "moe_routing", "moe_dispatch", "moe_experts", "moe_combine",
+    "moe_shared",                     # models/moe.py SCOPES, SHARED_SCOPE
+    "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",  # ops/ssd.py
+    "loop_attn", "loop_mlp", "exit_norm", "exit_gate",
+    "exit_head",                      # models/ouro.py LOOP_SCOPES
+)
 
 #: Health-engine event fields (``ev: "health"``).
 HEALTH_STATUSES = frozenset({"raised", "cleared", "started", "error"})
@@ -315,6 +356,7 @@ ALL_REASONS = REQUEUE_REASONS | LEASE_END_REASONS | PROFILE_REASONS
 __all__ = [
     "SPAN_PHASES", "EVENT_KINDS", "REQUEUE_REASONS", "PROFILE_REASONS",
     "GOODPUT_BUCKETS", "SPAN_NAMES", "ANNOTATION_NAMES", "COMPILED_FIELDS",
+    "STEP_SCOPES",
     "EXPERIMENT_PHASES", "RUNNER_PHASES", "WORKER_PHASES",
     "FLEET_PHASES", "FLEET_EXPERIMENT_PHASES", "LEASE_PHASES",
     "LEASE_END_REASONS", "AGENT_PHASES", "CHAOS_KINDS",

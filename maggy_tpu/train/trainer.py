@@ -206,11 +206,13 @@ def build_step_fn(
             mutable = (list(aux.keys()) if has_aux_collections else []) + ["losses"]
             out, updates = model.apply(
                 vs, *batch["inputs"], mutable=mutable, **train_kwargs)
-            loss = loss_fn(out, batch)
-            # Sowed auxiliary losses (MoE load balancing etc.) join the
-            # objective; they are scalars, summed over all sow sites.
-            for leaf in jax.tree_util.tree_leaves(updates.pop("losses", {})):
-                loss = loss + jnp.sum(leaf)
+            with jax.named_scope("loss"):
+                loss = loss_fn(out, batch)
+                # Sowed auxiliary losses (MoE load balancing etc.) join the
+                # objective; they are scalars, summed over all sow sites.
+                for leaf in jax.tree_util.tree_leaves(
+                        updates.pop("losses", {})):
+                    loss = loss + jnp.sum(leaf)
             return loss, updates
 
         with jax.named_scope("loss_and_grad"):

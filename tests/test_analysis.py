@@ -463,6 +463,67 @@ def trace_parts(scopes):
             assert any(finding in f.message for f in out)
             assert len(out) == (2 if said == "rematt" else 1)
 
+    SCOPE_VOCAB = VOCAB_FIXTURE + '''
+COMPILED_FIELDS = ("warm", "step_ops", "step_mixed")
+STEP_SCOPES = ("optimizer", "moe_experts", "moe_shared")
+'''
+    SCOPE_CALLS = EMIT_CLEAN + '''
+import jax
+STEP_FIELDS = ("step_ops", {mixed!r})
+SCOPES = ("moe_experts", {listed!r})
+SHARED_SCOPE = {shared!r}
+
+@jax.named_scope("optimizer")
+def update(x):
+    with jax.named_scope({opened!r}):
+        x = x + 1
+    with jax.named_scope(SHARED_SCOPE):
+        return x
+'''
+
+    @pytest.mark.parametrize("changed,finding", [
+        ({}, None),
+        ({"opened": "moe_expert"},  # opened, and in no list
+         "emitted step_scope 'moe_expert' is not in"),
+        ({"shared": "moe_shard"},  # ... through a module constant
+         "emitted step_scope 'moe_shard' is not in"),
+        ({"opened": "optimizer"},  # listed, and nothing opens it
+         "entry 'moe_experts' (STEP_SCOPES) is never emitted"),
+        ({"listed": "moe_combine"},  # a model's table names a stranger
+         "scope 'moe_combine' of a scopes table is not in STEP_SCOPES"),
+        ({"mixed": "step_mixd"},  # a field the vocabulary does not hold
+         "emitted compiled_field 'step_mixd' is not in"),
+    ])
+    def test_scopes_and_step_fields_are_held_both_ways(
+            self, tmp_path, changed, finding):
+        # ``step_ops`` ends like a kind's field and no `remember_plan` says
+        # it: the ``*_FIELDS`` table of the module that writes it does.
+        said = {"mixed": "step_mixed", "listed": "moe_shared",
+                "shared": "moe_shared", "opened": "moe_experts", **changed}
+        paths = [_write(tmp_path, "vocab.py", self.SCOPE_VOCAB),
+                 _write(tmp_path, "code.py", self.SCOPE_CALLS.format(**said))]
+        out = _findings(analyze_paths(paths, checkers=("journalvocab",)),
+                        "journalvocab")
+        if finding is None:
+            assert out == []
+        else:
+            assert any(finding in f.message for f in out), \
+                [f.message for f in out]
+
+    def test_the_package_opens_every_scope_it_lists(self):
+        # Every `jax.named_scope` of the package is in STEP_SCOPES and the
+        # other way round (the package run below has no finding), and the
+        # models' tables are parts of the one list.
+        from maggy_tpu.models import moe, ouro
+        from maggy_tpu.ops import ssd
+        from maggy_tpu.telemetry import plans, vocab
+
+        assert set(moe.SCOPES + (moe.SHARED_SCOPE,) + ssd.SCOPES
+                   + ouro.LOOP_SCOPES) <= set(vocab.STEP_SCOPES)
+        assert set(plans.STEP_FIELDS) <= set(vocab.COMPILED_FIELDS)
+        report = run_analysis(checkers=("journalvocab",))
+        assert report["summary"] == {"journalvocab": 0}
+
     def test_the_package_says_every_plan_it_lists(self):
         # The model's `remember_plan("remat", ...)` is what `remat_plan`
         # in COMPILED_FIELDS stands on: without the entry the package run
