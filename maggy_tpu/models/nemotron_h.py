@@ -9,10 +9,12 @@ no positional embedding anywhere: the state-space blocks carry the order.
 
 - ``M`` (`Mamba2Mixer`; SSD, arXiv:2405.21060), H heads of P channels, G
   groups of N states: ``[z | xBC | dt] = u W_in``; ``xBC`` through a causal
-  depthwise convolution of ``conv_kernel`` taps and a silu; split into
-  ``x [H, P]``, ``B [G, N]``, ``C [G, N]``; ``dt = softplus(dt + dt_bias)``,
-  ``A = -exp(A_log)``; the scan ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t
-  B_t^T``, ``y_t = h_t C_t + D x_t`` (`ops.ssd.ssd_scan`, in chunks);
+  depthwise convolution of ``conv_kernel`` taps and a silu
+  (`ops.ssd.conv_silu`, which reads xBC where the projection wrote it);
+  split into ``x [H, P]``, ``B [G, N]``, ``C [G, N]``; ``dt = softplus(dt +
+  dt_bias)``, ``A = -exp(A_log)``; the scan ``h_t = exp(dt_t A) h_{t-1} +
+  dt_t x_t B_t^T``, ``y_t = h_t C_t + D x_t`` (`ops.ssd.ssd_scan`, in
+  chunks);
   ``y = GroupRMSNorm(y * silu(z))``, gate first and then the norm over each
   of the G groups of ``H P / G`` channels; ``y W_out``.
 - ``*`` (`NemotronHAttention`): q, k, v without bias and WITHOUT rope,
@@ -178,9 +180,10 @@ class Mamba2Mixer(nn.Module):
         inner, f32 = H * P, jnp.float32
         conv_dim = inner + 2 * G * N
         remember_plan("ssm", "heads {}x{} groups {} state {} conv {} chunk {} "
-                      "S {} {}".format(
+                      "S {} {} {}".format(
                           H, P, G, N, K, cfg.chunk_size, S,
-                          ssd.scan_plan(S, H, P, G, N, cfg.chunk_size)),
+                          ssd.scan_plan(S, H, P, G, N, cfg.chunk_size),
+                          ssd.conv_plan(S, conv_dim, inner)),
                       ssd.SCOPES)
 
         def vector(name, init, shape):
@@ -203,12 +206,13 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("ssm_proj"):
             zxbcdt = _projection(cfg, inner + conv_dim + H, (EMBED, MLP),
                                  "in_proj")(u)
-        z, xBC, dt = jnp.split(zxbcdt, (inner, inner + conv_dim), axis=-1)
+        z, dt = zxbcdt[..., :inner], zxbcdt[..., inner + conv_dim:]
         with jax.named_scope("ssm_conv"):
-            xBC = jax.nn.silu(ssd.causal_conv1d(
-                xBC, vector("conv_kernel", taps_init, (K, conv_dim)),
+            # xBC read where the projection wrote it (`ssd.conv_silu`).
+            xBC = ssd.conv_silu(
+                zxbcdt, vector("conv_kernel", taps_init, (K, conv_dim)),
                 vector("conv_bias", nn.initializers.zeros_init(),
-                       (conv_dim,)))).astype(cfg.dtype)
+                       (conv_dim,)), inner).astype(cfg.dtype)
         x, Bm, Cm = jnp.split(xBC, (inner, inner + G * N), axis=-1)
         with jax.named_scope("ssm_scan"):
             dt = jax.nn.softplus(dt.astype(f32) + vector(

@@ -74,6 +74,27 @@ and 34 MB of chunk states a group, where all 64 heads at once would hold
 output `REMAT_KEEP`, so a rematerialised caller that keeps it runs
 `ssd_fwd` once.
 
+**The convolution that feeds it** (`conv_silu`: ``silu(causal_conv1d(xBC,
+w, b))``, 4 taps over [B, S, 6144] in the Nemotron cell), chosen the same
+way (`conv_plan` says ``conv pallas <rows>x<cols>`` or ``conv xla``):
+
+- on a TPU, where the channels and their first column tile by 128 lanes and
+  S by a strip of 16 rows (`conv_tile`): two Pallas kernels under a
+  `jax.custom_vjp` (`kernel_conv`), each a pass over the data bound by its
+  bytes. `ssd_conv_fwd` reads a [rows, cols] block of xBC IN PLACE in the
+  in-projection's output (a column block's index is offset by the start),
+  and a strip of 16 rows before it (zero at the sequence's start, per
+  batch row), applies the taps and the bias in float32 in
+  `causal_conv1d`'s order and the silu, and writes the input dtype.
+  `ssd_conv_bwd` reads the block, the strips before and after it, dy and
+  dy's strip after; makes the pre-activation again, ``g = dy silu'(pre)``
+  (zero past the sequence's end), writes ``dx_t = sum_j w_j g_{t + K - 1 -
+  j}`` and sums dw and db in a float32 VMEM scratch over a column block's
+  batch rows and blocks. Its residuals are the inputs; dx is padded to the
+  wider array's width, which XLA joins to dz and ddt inside the
+  in-projection's backward products.
+- elsewhere: `causal_conv1d` of the slice and XLA's silu.
+
 The kernels carry ``name=``s beginning ``ssd_``, and the caller's
 `jax.named_scope` in their ``op_name``, forward and backward.
 """
@@ -662,3 +683,303 @@ def ssd_scan(x, dt, A, B, C, D, chunk: int = 128):
     f32 = jnp.float32
     return kernel_scan(x, dt.astype(f32), A.astype(f32), B, C, D.astype(f32),
                        chunk, k, False)
+
+
+# ------------------------------------------------------ convolution kernels
+#
+# ``silu(causal_conv1d(x, w, b))`` in one pass over x, where x is a column
+# range of a wider array (the mixer's in-projection output), read in place.
+# A grid step holds a [rows, cols] block of one batch row; its arithmetic
+# walks the block in strips of `_STRIP` rows (one bfloat16 tile, two float32
+# vreg rows), a tap's shift by k rows being a roll of the strip and of the
+# strip before it, one row of the roll chosen from each. The rows before the
+# block (and, for the gradient, after it) come as a halo, a second block of
+# one strip over the same array, zero past either end of the sequence.
+
+#: Rows of a strip and of a halo: one bfloat16 (16, 128) tile.
+_STRIP = 16
+#: The largest block a convolution kernel's grid step holds: rows of the
+#: sequence by channels (`conv_tile`).
+_CONV_ROWS, _CONV_COLS = 512, 1024
+#: Lanes of a strip the kernels' arithmetic takes at a time.
+_PASS_LANES = 512
+
+
+def conv_tile(S: int, C: int, start: int) -> Optional[tuple]:
+    """The convolution kernels' block at these shapes, (rows, cols), or
+    None where they cannot tile: C channels from column ``start`` of a
+    wider array, S positions. Columns are whole 128-lane tiles that divide
+    both C and ``start`` (so a block reads the wider array in place); rows
+    a power of two of at least a strip that divides S."""
+    if C % _LANES or start % _LANES:
+        return None
+    rows = next((r for r in (_CONV_ROWS, 256, 128, 64, 32, _STRIP)
+                 if S % r == 0), None)
+    cols = next(c for c in (_CONV_COLS, 512, 256, _LANES)
+                if C % c == 0 and start % c == 0)
+    return None if rows is None else (rows, cols)
+
+
+def _kernel_tile(S, C, start) -> Optional[tuple]:
+    """`conv_tile` where the kernels run at all: on a TPU."""
+    return conv_tile(S, C, start) if _tpu_backend() else None
+
+
+def conv_plan(S: int, C: int, start: int) -> str:
+    """What runs `conv_silu` at these shapes here: ``conv pallas`` and the
+    block ``<rows>x<cols>``, or ``conv xla``."""
+    tile = _kernel_tile(S, C, start)
+    return "conv xla" if tile is None else "conv pallas {}x{}".format(*tile)
+
+
+def _shifted(v, before, k: int):
+    """Rows t - k of a strip: ``v`` where t >= k, the rows ``before`` it
+    (the previous strip) where t < k. Rows t + k of a strip ``g`` are rows
+    t - (strip - k) of the strip after it, ``_shifted(after, g, strip -
+    k)``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if k == 0:
+        return v
+    row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    return jnp.where(row < k, pltpu.roll(before, k, 0), pltpu.roll(v, k, 0))
+
+
+def _passes(cols: int):
+    """A strip's columns in passes of `_PASS_LANES`: static lane slices, so
+    that a pass's values stay in the vector registers."""
+    lanes = min(cols, _PASS_LANES)
+    return [slice(c, c + lanes) for c in range(0, cols, lanes)]
+
+
+def _taps(v, before, K: int):
+    """``x_{t - (K - 1) + j}`` of a strip for j = 0 .. K - 1."""
+    return [_shifted(v, before, K - 1 - j) for j in range(K)]
+
+
+def _pre(xs, w_ref, b_ref, cs):
+    """``b + sum_j w_j x_{t - (K - 1) + j}`` over a strip's columns ``cs``
+    from its `_taps`, float32, in `causal_conv1d`'s order."""
+    pre = jnp.broadcast_to(b_ref[:, cs], xs[0].shape)
+    for j, x in enumerate(xs):
+        pre = pre + w_ref[j:j + 1, cs] * x
+    return pre
+
+
+def _rows_before(x_ref, before_ref, before_rows, cs):
+    """The strip before a strip's columns ``cs``, float32: the halo for
+    strip 0 (zero in the sequence's first block), else the block's own."""
+    from jax.experimental import pallas as pl
+
+    if before_rows is None:
+        return jnp.where(pl.program_id(2) == 0, 0.0,
+                         before_ref[:, cs].astype(jnp.float32))
+    return x_ref[before_rows, cs].astype(jnp.float32)
+
+
+def _each_strip(n: int, body):
+    """``body(rows, before_rows)`` for strips 0 .. n - 1 of a block, in
+    order, ``before_rows`` None for strip 0 (whose rows before are the
+    halo): strip 0, then the others as a loop on the core."""
+    from jax.experimental import pallas as pl
+
+    def strip(s, _):
+        at = pl.multiple_of(s * _STRIP, _STRIP)
+        body(pl.ds(at, _STRIP), pl.ds(at - _STRIP, _STRIP))
+
+    body(pl.ds(0, _STRIP), None)
+    if n > 1:
+        jax.lax.fori_loop(1, n, strip, None)
+
+
+def _conv_fwd_kernel(x_ref, before_ref, w_ref, b_ref, y_ref):
+    """grid (C / cols, B, S / rows): y = silu(pre), pre from the block's
+    rows and the halo before it (zero at the sequence's start)."""
+
+    def strip(rows, before_rows):
+        for cs in _passes(x_ref.shape[1]):
+            before = _rows_before(x_ref, before_ref, before_rows, cs)
+            pre = _pre(_taps(x_ref[rows, cs].astype(jnp.float32), before,
+                             w_ref.shape[0]), w_ref, b_ref, cs)
+            y_ref[rows, cs] = jax.nn.silu(pre).astype(y_ref.dtype)
+
+    _each_strip(x_ref.shape[0] // _STRIP, strip)
+
+
+def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref,
+                     w_ref, b_ref, dx_ref, dwb_ref, g, acc):
+    """grid (C / cols, B, S / rows), a column block's batch rows and
+    blocks in order. A strip's taps make ``g`` = dy silu'(pre) and add
+    dw_j += sum_t g_t x_{t - (K - 1) + j}, db += sum_t g_t into ``acc``
+    [K + 1, 8, cols] (written as [K + 1, cols] once the column block's last
+    step is done); ``g`` is kept over the block's rows and one strip after
+    (zero past the sequence's end), from which dx_t = sum_j w_j g_{t + K -
+    1 - j}."""
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    K = w_ref.shape[0]
+    n = x_ref.shape[0] // _STRIP
+    i, b = pl.program_id(2), pl.program_id(1)
+    first, last = i == 0, i == pl.num_programs(2) - 1
+
+    @pl.when(first & (b == 0))
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    def grad(xs, dy, cs):
+        pre = _pre(xs, w_ref, b_ref, cs)
+        sig = jax.nn.sigmoid(pre)
+        return dy.astype(f32) * sig * (1.0 + pre * (1.0 - sig))
+
+    def fold(v):  # [strip, lanes] -> [8, lanes]: whole vregs added
+        return v[:8] + v[8:]
+
+    def grad_strip(rows, before_rows):
+        for cs in _passes(x_ref.shape[1]):
+            xs = _taps(x_ref[rows, cs].astype(f32), _rows_before(
+                x_ref, before_ref, before_rows, cs), K)
+            gc = grad(xs, dy_ref[rows, cs], cs)
+            g[rows, cs] = gc
+            for j in range(K):
+                acc[j, :, cs] += fold(gc * xs[j])
+            acc[K, :, cs] += fold(gc)
+
+    _each_strip(n, grad_strip)
+    tail = slice((n - 1) * _STRIP, n * _STRIP)
+    for cs in _passes(x_ref.shape[1]):
+        g[n * _STRIP:, cs] = jnp.where(last, 0.0, grad(
+            _taps(after_ref[:, cs].astype(f32), x_ref[tail, cs].astype(f32),
+                  K), dy_after_ref[:, cs], cs))
+
+    def dx_strip(rows, _):
+        ahead_rows = pl.ds(rows.start + _STRIP, _STRIP)
+        for cs in _passes(x_ref.shape[1]):
+            gc, ga = g[rows, cs], g[ahead_rows, cs]
+            dx = w_ref[K - 1:K, cs] * gc
+            for k in range(1, K):
+                dx = dx + w_ref[K - 1 - k:K - k, cs] * _shifted(
+                    ga, gc, _STRIP - k)
+            dx_ref[rows, cs] = dx.astype(dx_ref.dtype)
+
+    _each_strip(n, dx_strip)
+
+    @pl.when(last & (b == pl.num_programs(1) - 1))
+    def _():
+        dwb_ref[...] = jnp.sum(acc[...], axis=1)
+
+
+def _conv_specs(S, start, rows, cols):
+    """The blocks of the convolution kernels' operands, grid (C / cols, B,
+    S / rows): a block of x in place in the wider array, the strip before
+    it and after it (clamped inside the sequence; the kernels mask what is
+    past its ends), a block of a [B, S, C] array, and a column block of
+    the [K, C] taps and of a [1, C] row."""
+    from jax.experimental import pallas as pl
+
+    if start % cols or rows % _STRIP or S % rows:
+        raise ValueError("a block of {} x {} from column {} does not tile S "
+                         "{}".format(rows, cols, start, S))
+    at, per, strips = start // cols, rows // _STRIP, S // _STRIP
+    return {
+        "x": pl.BlockSpec((None, rows, cols), lambda j, b, i: (b, i, at + j)),
+        "before": pl.BlockSpec(
+            (None, _STRIP, cols),
+            lambda j, b, i: (b, jnp.maximum(i * per - 1, 0), at + j)),
+        "after": pl.BlockSpec(
+            (None, _STRIP, cols),
+            lambda j, b, i: (b, jnp.minimum((i + 1) * per, strips - 1),
+                             at + j)),
+        "y": pl.BlockSpec((None, rows, cols), lambda j, b, i: (b, i, j)),
+        "y_after": pl.BlockSpec(
+            (None, _STRIP, cols),
+            lambda j, b, i: (b, jnp.minimum((i + 1) * per, strips - 1), j)),
+        "col": lambda k: pl.BlockSpec((k, cols), lambda j, b, i: (0, j)),
+    }
+
+
+_CONV_STATIC = ("start", "rows", "cols", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_CONV_STATIC)
+def _conv_forward(x, w, b, start: int, rows: int, cols: int,
+                  interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    Bt, S, _ = x.shape
+    K, C = w.shape
+    spec = _conv_specs(S, start, rows, cols)
+    return pl.pallas_call(
+        _conv_fwd_kernel, grid=(C // cols, Bt, S // rows),
+        in_specs=[spec["x"], spec["before"], spec["col"](K), spec["col"](1)],
+        out_specs=spec["y"],
+        out_shape=jax.ShapeDtypeStruct((Bt, S, C), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3),
+        interpret=interpret, name="ssd_conv_fwd",
+    )(x, x, w.astype(jnp.float32), b.astype(jnp.float32).reshape(1, C))
+
+
+@functools.partial(jax.jit, static_argnames=_CONV_STATIC)
+def _conv_backward(x, w, b, dy, start: int, rows: int, cols: int,
+                   interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    Bt, S, width = x.shape
+    K, C = w.shape
+    f32 = jnp.float32
+    spec = _conv_specs(S, start, rows, cols)
+    dx, dwb = pl.pallas_call(
+        _conv_bwd_kernel, grid=(C // cols, Bt, S // rows),
+        in_specs=[spec["x"], spec["before"], spec["after"], spec["y"],
+                  spec["y_after"], spec["col"](K), spec["col"](1)],
+        out_specs=[spec["y"], spec["col"](K + 1)],
+        out_shape=[jax.ShapeDtypeStruct((Bt, S, C), x.dtype),
+                   jax.ShapeDtypeStruct((K + 1, C), f32)],
+        scratch_shapes=[pltpu.VMEM((rows + _STRIP, cols), f32),
+                        pltpu.VMEM((K + 1, 8, cols), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret, name="ssd_conv_bwd",
+    )(x, x, x, dy, dy, w.astype(f32), b.astype(f32).reshape(1, C))
+    dx = jax.lax.pad(dx, jnp.zeros((), dx.dtype),
+                     ((0, 0, 0), (0, 0, 0), (start, width - start - C, 0)))
+    return dx, dwb[:K].astype(w.dtype), dwb[K].astype(b.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def kernel_conv(x, w, b, start: int, rows: int, cols: int,
+                interpret: bool = False):
+    """`conv_silu` as Pallas kernels, a grid step a [rows, cols] block
+    (shapes that `conv_tile` takes): `ssd_conv_fwd`, and for the gradient
+    `ssd_conv_bwd`, whose residuals are the inputs."""
+    return _conv_forward(x, w, b, start=start, rows=rows, cols=cols,
+                         interpret=interpret)
+
+
+def _kernel_conv_fwd(x, w, b, start, rows, cols, interpret):
+    return _conv_forward(x, w, b, start=start, rows=rows, cols=cols,
+                         interpret=interpret), (x, w, b)
+
+
+def _kernel_conv_bwd(start, rows, cols, interpret, inputs, dy):
+    return _conv_backward(*inputs, dy, start=start, rows=rows, cols=cols,
+                          interpret=interpret)
+
+
+kernel_conv.defvjp(_kernel_conv_fwd, _kernel_conv_bwd)
+
+
+def conv_silu(x, w, b, start: int = 0):
+    """``silu(causal_conv1d(...))`` of the C = ``w.shape[1]`` channels of x
+    [B, S, width] from column ``start``, in x's dtype: [B, S, C]. On a TPU,
+    at shapes the kernels tile (`conv_tile`), the Pallas kernels, reading x
+    in place; else `causal_conv1d` of the slice and XLA's silu."""
+    S, C = x.shape[1], w.shape[1]
+    tile = _kernel_tile(S, C, start)
+    if tile is None:
+        xs = jax.lax.slice_in_dim(x, start, start + C, axis=2)
+        return jax.nn.silu(causal_conv1d(xs, w, b)).astype(x.dtype)
+    return kernel_conv(x, w, b, start, *tile, False)

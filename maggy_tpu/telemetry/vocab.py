@@ -273,10 +273,12 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: the expert kind, the shared expert's width), by which a trace's
 #: operations are told apart. ``ssm_plan``: what each state-space mixer
 #: (`models.nemotron_h.Mamba2Mixer`) scans (``heads 64x64 groups 8 state 128
-#: conv 4 chunk 128 S 8192 pallas 1024``: heads x channels, groups, states,
-#: convolution taps, the scan's chunk, the length, what multiplies: on a
-#: TPU, at shapes they tile, the `ops.ssd` kernels and the positions one of
-#: their grid steps holds; elsewhere ``xla_products``);
+#: conv 4 chunk 128 S 8192 pallas 1024 conv pallas 512x1024``: heads x
+#: channels, groups, states, convolution taps, the scan's chunk, the length,
+#: what multiplies: on a TPU, at shapes they tile, the `ops.ssd` kernels and
+#: the positions one of their grid steps holds; elsewhere ``xla_products``;
+#: then what convolves: ``conv pallas`` and the block of `ssd_conv_fwd` and
+#: `ssd_conv_bwd`, rows by channels, or ``conv xla``);
 #: ``ssm_ops``: the step's instructions under the mixer's scopes
 #: (``ssm_proj``, ``ssm_conv``, ``ssm_scan``, ``ssm_gate_norm``).
 #: ``loop_plan``: what a looped model (`models.ouro.Ouro`) runs (``4 passes

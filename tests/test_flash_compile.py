@@ -139,6 +139,38 @@ def test_the_three_kernels_carry_their_names(v5e_device):
         "flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]
 
 
+def test_the_convolution_kernels_compile_at_the_cells_shape(v5e_device):
+    """`ssd_conv_fwd` and `ssd_conv_bwd` through Mosaic at the Nemotron
+    cell's own shape (B 2, S 8192, xBC the 6,144 columns from 4,096 of the
+    in-projection's 10,304, bfloat16; taps and bias float32), at the block
+    `conv_silu` takes there: forward alone, and the gradient with respect to
+    all three, dx at the wider array's width."""
+    from maggy_tpu.ops import ssd
+
+    B, S, width, start, C, K = 2, 8192, 10304, 4096, 6144, 4
+    rows, cols = ssd.conv_tile(S, C, start)
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_device)
+
+    args = (of((B, S, width), jnp.bfloat16), of((K, C), jnp.float32),
+            of((C,), jnp.float32))
+
+    def conv(*a):
+        return ssd.kernel_conv(*a, start, rows, cols, False)
+
+    def loss(*a):
+        return jnp.sum(conv(*a).astype(jnp.float32) ** 2)
+
+    forward = jax.jit(conv).lower(*args).compile()
+    assert _kernel_names(forward.as_text()) == ["ssd_conv_fwd"]
+    backward = jax.jit(jax.grad(loss, (0, 1, 2))).lower(*args)
+    assert [a.shape for a in backward.out_info] == [
+        (B, S, width), (K, C), (C,)]
+    assert _kernel_names(backward.compile().as_text()) == [
+        "ssd_conv_bwd", "ssd_conv_fwd"]
+
+
 @pytest.mark.parametrize("keeps,forwards", [(False, 2), (True, 1)],
                          ids=["keeps_nothing", "keeps_the_names"])
 def test_a_checkpointed_layer_compiles_with_one_forward_kernel(
@@ -326,11 +358,13 @@ def test_a_rematerialised_nemotron_step_holds_each_kernel_once_a_block(
     kernels tile): the blocks keep `models.nemotron_h.REMAT_KEEP`, so the
     executable holds ONE `flash_fwd` beside its two backward kernels and ONE
     `ssd_fwd` beside `ssd_states` and `ssd_bwd` (two with `ssd_out` out of
-    what a block keeps: the rematerialised block runs it again), and the
-    two-matrix experts ask for two grouped products forward (one of them
-    again in the backward pass) where SwiGLU asks for three; the step's
-    instructions carry the state-space and the shared expert's scopes, and
-    every scan kernel, forward and backward, is under ``ssm_scan``."""
+    what a block keeps: the rematerialised block runs it again), TWO
+    `ssd_conv_fwd` beside `ssd_conv_bwd` (the convolution's output is made
+    again, never kept), and the two-matrix experts ask for two grouped
+    products forward (one of them again in the backward pass) where SwiGLU
+    asks for three; the step's instructions carry the state-space and the
+    shared expert's scopes, every scan kernel, forward and backward, is
+    under ``ssm_scan`` and every convolution kernel under ``ssm_conv``."""
     import collections
 
     import flax.linen as nn
@@ -373,11 +407,14 @@ def test_a_rematerialised_nemotron_step_holds_each_kernel_once_a_block(
     assert collections.Counter(_kernel_names(text)) == {
         "flash_fwd": 1, "flash_bwd_dkdv": 1, "flash_bwd_dq": 1,
         "moe_gmm_fwd": 3, "moe_gmm_dlhs": 2, "moe_gmm_drhs": 2,
-        "ssd_fwd": forwards, "ssd_states": 1, "ssd_bwd": 1}
+        "ssd_fwd": forwards, "ssd_states": 1, "ssd_bwd": 1,
+        "ssd_conv_fwd": 2, "ssd_conv_bwd": 1}
     scopes = ops_by_scope(text, ssd.SCOPES + moe.SCOPES + (moe.SHARED_SCOPE,))
     assert set(scopes) == set(ssd.SCOPES + moe.SCOPES + (moe.SHARED_SCOPE,))
     assert sorted(name.split(".")[0] for name in scopes["ssm_scan"]
                   if "ssd_" in name) == (
         ["ssd_bwd"] + ["ssd_fwd"] * forwards + ["ssd_states"])
+    assert sorted(name.split(".")[0] for name in scopes["ssm_conv"]
+                  if "ssd_" in name) == ["ssd_conv_bwd"] + ["ssd_conv_fwd"] * 2
     assert not [line for line in text.splitlines()  # no loop over groups
                 if " while(" in line and "ssm_scan" in line]

@@ -130,7 +130,8 @@ def test_the_step_says_its_plans_and_names_its_scopes():
     with traced() as said:
         jax.eval_shape(module.init, jax.random.key(0), tokens)
     assert said.plans["ssm"] == [
-        "heads 4x8 groups 2 state 16 conv 4 chunk 8 S 16 xla_products"]
+        "heads 4x8 groups 2 state 16 conv 4 chunk 8 S 16 xla_products "
+        "conv xla"]
     assert said.scopes["ssm"] == ssd.SCOPES
     assert said.plans["moe"] == [
         "experts 2+4/8 top2 rows 2560 chunk 512 tile 512 pallas_gmm "

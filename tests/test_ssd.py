@@ -251,3 +251,155 @@ def test_the_convolution_never_sees_the_next_position(t):
     seen = np.abs(np.asarray(jac)[:, 0]).sum(axis=(0, 2)) > 0     # [S]
     assert seen[t] and not seen[t + 1:].any()
     assert not seen[:max(t - 3, 0)].any()
+
+
+# The convolution kernels (interpret mode) against `causal_conv1d`, its silu
+# and the cast: x [B, S, width] holds the C channels from column ``start``.
+# S, width, start, C, rows, cols: three blocks of four strips, at column 128
+# of a wider array and two column blocks; two blocks of two strips reading
+# the whole array; four blocks of one strip each.
+CONV_SHAPES = [(192, 640, 128, 256, 64, 128), (64, 256, 0, 256, 32, 256),
+               (64, 384, 256, 128, 16, 128)]
+
+
+def conv_inputs(S, width, C, dtype=jnp.bfloat16, seed=0):
+    k = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(k[0], (B, S, width)).astype(dtype),
+            jax.random.uniform(k[1], (4, C), minval=-0.5, maxval=0.5),
+            0.1 * jax.random.normal(k[2], (C,)),
+            jax.random.normal(k[3], (B, S, C)).astype(dtype))
+
+
+def conv_forms(start, rows, cols):
+    def xla(x, w, b):
+        return jax.nn.silu(ssd.causal_conv1d(
+            x[..., start:start + w.shape[1]], w, b)).astype(x.dtype)
+
+    return xla, lambda x, w, b: ssd.kernel_conv(x, w, b, start, rows, cols,
+                                                True)
+
+
+def conv_id(shape):
+    return "S{}_w{}_at{}_c{}_{}x{}".format(*shape)
+
+
+def within_a_rounding(got, want):
+    """Each entry within one bfloat16 rounding of the other form's."""
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    assert got.dtype == want.dtype
+    assert bool((jnp.abs(got - want)
+                 <= 2.0 ** -7 * jnp.abs(want) + 1e-6).all())
+
+
+@pytest.mark.parametrize("shape", CONV_SHAPES, ids=conv_id)
+def test_the_convolution_kernel_is_the_xla_forms_forward(shape):
+    S, width, start, C, rows, cols = shape
+    x, w, b, _ = conv_inputs(S, width, C)
+    xla, kernel = conv_forms(start, rows, cols)
+    got = kernel(x, w, b)
+    assert got.shape == (B, S, C) and got.dtype == jnp.bfloat16
+    within_a_rounding(got, xla(x, w, b))
+
+
+@pytest.mark.parametrize("shape", CONV_SHAPES, ids=conv_id)
+def test_every_gradient_of_the_convolution_kernel_is_the_xla_forms(shape):
+    """dx (in x's dtype, the wider array's width, zero outside the
+    columns), dw and db (float32) against autodiff of the XLA form."""
+    S, width, start, C, rows, cols = shape
+    x, w, b, dy = conv_inputs(S, width, C)
+    xla, kernel = conv_forms(start, rows, cols)
+    want = jax.vjp(xla, x, w, b)[1](dy)
+    got = jax.vjp(kernel, x, w, b)[1](dy)
+    assert got[0].shape == x.shape and got[0].dtype == x.dtype
+    outside = np.ones(width, bool)
+    outside[start:start + C] = False
+    assert not np.asarray(got[0])[..., outside].any()
+    within_a_rounding(got[0][..., start:start + C],
+                      want[0][..., start:start + C])
+    for g, w_ in zip(got[1:], want[1:]):
+        assert g.dtype == jnp.float32
+        close(g, w_, 1e-5)
+
+
+@pytest.mark.parametrize("t", [15, 16, 31, 32])
+def test_the_convolution_kernel_never_sees_the_next_position(t):
+    """Position t's output moves with x_{t-3} .. x_t and with nothing else,
+    of its own batch row: t beside the edge of a block of 16 rows. And
+    dy_t's gradient reaches x_{t-3} .. x_t alone."""
+    S, width, start, C, rows, cols = 48, 256, 128, 128, 16, 128
+    x, w, b, _ = conv_inputs(S, width, C, jnp.float32)
+    _, kernel = conv_forms(start, rows, cols)
+    y = kernel(x, w, b)
+    for moved, seen in [(t + 1, False), (t, True), (t - 3, True),
+                        (t - 4, False)]:
+        if not 0 <= moved < S:
+            continue
+        y2 = kernel(x.at[:, moved].add(1.0), w, b)
+        assert bool((y2[:, t] != y[:, t]).any()) == seen, moved
+    other_row = kernel(x.at[1].add(1.0), w, b)
+    assert bool((other_row[0] == y[0]).all())
+    dy = jnp.zeros((B, S, C)).at[0, t].set(1.0)
+    dx = np.asarray(jax.vjp(kernel, x, w, b)[1](dy)[0])
+    reached = np.abs(dx).sum(axis=2) > 0                      # [B, S]
+    assert not reached[1].any()
+    assert reached[0, max(t - 3, 0):t + 1].all()
+    assert not reached[0, :max(t - 3, 0)].any()
+    assert not reached[0, t + 1:].any()
+
+
+def test_the_convolution_kernels_keep_their_inputs_alone():
+    x, w, b, _ = conv_inputs(64, 384, 128)
+    _, kept = ssd._kernel_conv_fwd(x, w, b, 256, 16, 128, True)
+    assert len(kept) == 3 and all(k is a for k, a in zip(kept, (x, w, b)))
+
+
+CONV_TILING = (8192, 6144, 4096)  # S, C, start: the cell's xBC in zxbcdt
+CONV_NOT_TILING = [(8192, 6144 + 64, 4096),  # channels of half a tile
+                   (8192, 6144, 4096 + 64),  # a start inside a tile
+                   (8200, 6144, 4096),       # a length of no whole strips
+                   (16, 96, 32)]             # `NemotronHConfig.tiny`
+
+
+def test_off_a_tpu_the_convolution_is_xlas(monkeypatch):
+    """The path rides on the backend and the shapes, as the scan's: here
+    every shape takes XLA's form and says ``conv xla``; on a TPU, the
+    cell's shape takes the kernels, a block of 512 rows by 1024 columns."""
+    assert ssd.conv_plan(*CONV_TILING) == "conv xla"
+    assert ssd.conv_tile(*CONV_TILING) == (512, 1024)
+    x, w, b, _ = conv_inputs(64, 384, 128)
+    text = str(jax.make_jaxpr(functools.partial(ssd.conv_silu, start=256))(
+        x, w, b))
+    assert "pallas_call" not in text and "custom_vjp" not in text
+    xla, _ = conv_forms(256, 16, 128)
+    assert bool((ssd.conv_silu(x, w, b, 256) == xla(x, w, b)).all())
+
+    monkeypatch.setattr(ssd, "_tpu_backend", lambda: True)
+    assert ssd.conv_plan(*CONV_TILING) == "conv pallas 512x1024"
+    assert ssd.conv_plan(8192, 1024, 512) == "conv pallas 512x512"
+    text = str(jax.make_jaxpr(functools.partial(ssd.conv_silu, start=256))(
+        x, w, b))
+    assert "pallas_call" in text and "ssd_conv_fwd" in text
+
+
+@pytest.mark.parametrize("shape", CONV_NOT_TILING, ids=str)
+def test_shapes_the_convolution_kernels_cannot_tile_take_xlas_form(
+        shape, monkeypatch):
+    monkeypatch.setattr(ssd, "_tpu_backend", lambda: True)
+    assert ssd.conv_tile(*shape) is None
+    assert ssd.conv_plan(*shape) == "conv xla"
+
+
+def test_passes_of_fewer_lanes_give_the_kernels_answer(monkeypatch):
+    """A strip's columns taken in passes of 128 lanes (four a block):
+    forward and every gradient as the XLA form's. A shape of its own, so
+    that no kernel traced at the default passes is reused."""
+    monkeypatch.setattr(ssd, "_PASS_LANES", 128)
+    S, width, start, C, rows, cols = 96, 1280, 512, 512, 32, 512
+    x, w, b, dy = conv_inputs(S, width, C, seed=5)
+    xla, kernel = conv_forms(start, rows, cols)
+    within_a_rounding(kernel(x, w, b), xla(x, w, b))
+    want = jax.vjp(xla, x, w, b)[1](dy)
+    got = jax.vjp(kernel, x, w, b)[1](dy)
+    within_a_rounding(got[0], want[0])
+    for g, w_ in zip(got[1:], want[1:]):
+        close(g, w_, 1e-5)
