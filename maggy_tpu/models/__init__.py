@@ -9,6 +9,9 @@
   attention blocks, holding a share of its routed experts
 - `ouro`: Ouro looped decoder: one stack of sandwich-norm layers applied
   several times over shared weights, an exit with a gate after every pass
+- `smallthinker`: SmallThinker decoder: windowed and global (NoPE) attention
+  in one period, ReGLU experts routed from the layer's input before
+  attention, holding a share of its experts
 - `surgery`: ablatable-module helpers for LOCO model surgery
 """
 
@@ -20,9 +23,11 @@ from maggy_tpu.models.moe import ExpertShareMLP, MoEMLP
 from maggy_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 from maggy_tpu.models.ouro import Ouro, OuroConfig
 from maggy_tpu.models.sdar import SdarMoe, SdarMoeConfig
+from maggy_tpu.models.smallthinker import SmallThinker, SmallThinkerConfig
 from maggy_tpu.models.vit import ViT, ViTConfig
 
 __all__ = ["MnistCNN", "MnistMLP", "ResNet", "BertEncoder", "BertConfig",
            "Llama", "LlamaConfig", "MoEMLP", "ExpertShareMLP", "NemotronH",
            "NemotronHConfig", "Ouro", "OuroConfig", "SdarMoe",
-           "SdarMoeConfig", "ViT", "ViTConfig"]
+           "SdarMoeConfig", "SmallThinker", "SmallThinkerConfig", "ViT",
+           "ViTConfig"]

@@ -5,9 +5,9 @@ parallelism, and a dropless one that holds a share of the experts.
 over all ``num_experts`` (softmax, or sigmoid scores with a bias only the
 choice sees), is TOLD which experts it holds, sorts the token-expert pairs
 by expert and multiplies each held expert's rows by that expert's weights
-(SwiGLU's three matrices or relu^2's two) in Pallas grouped matrix products
-(`moe_gmm_fwd`, `moe_gmm_dlhs`, `moe_gmm_drhs`), beside a shared expert
-where the model has one. No capacity, no dropped token. On one chip it runs
+(SwiGLU's or ReGLU's three matrices, relu^2's two) in Pallas grouped matrix
+products (`moe_gmm_fwd`, `moe_gmm_dlhs`, `moe_gmm_drhs`), beside a shared
+expert where the model has one. No capacity, no dropped token. On one chip it runs
 without its exchange; nothing stands in for absent chips.
 
 `MoEMLP` (first half) is the older layer, kept because the ``dp_ep``
@@ -480,11 +480,26 @@ class _Relu2:
         return (dact * 2.0 * jax.nn.relu(up),)
 
 
+class _ReGLU:
+    """``act = relu(x W_gate) * (x W_up)``: two matrices into the width, the
+    gate through a relu."""
+
+    into = ("gate_proj", "up_proj")
+
+    @staticmethod
+    def act(gate, up):
+        return jax.nn.relu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+
+    @staticmethod
+    def dpre(dact, gate, up):
+        return dact * up * (gate > 0), dact * jax.nn.relu(gate)
+
+
 #: The expert kinds `expert_ffn` knows: the matrices that lead into the
 #: expert's width (by their parameter names), the activation over their
 #: products and its way back. The matrix out of the width is every kind's
 #: last weight.
-EXPERT_KINDS = {"swiglu": _SwiGLU, "relu2": _Relu2}
+EXPERT_KINDS = {"swiglu": _SwiGLU, "relu2": _Relu2, "reglu": _ReGLU}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
@@ -495,7 +510,8 @@ def expert_ffn(x, weights, pair_gate, row_pair, tile_group, tiles_used,
     act_e(x_n)``: x [N, D]; ``weights`` the held experts' matrices, those
     into the width [G, D, F] first and ``W_down`` [G, F, D] last (``kind``
     ``"swiglu"``: gate, up, down, ``act = silu(x W_gate) * (x W_up)``;
-    ``"relu2"``: up, down, ``act = relu(x W_up) ** 2``); ``pair_gate``
+    ``"relu2"``: up, down, ``act = relu(x W_up) ** 2``; ``"reglu"``: gate,
+    up, down, ``act = relu(x W_gate) * (x W_up)``); ``pair_gate``
     [N * k] float32, and `grouped_layout`'s three. The buffer's used rows
     are walked in chunks of ``chunk_rows`` (a `fori_loop` whose trip count
     is the used rows over the chunk, so the work follows the rows routed
@@ -624,7 +640,8 @@ class ExpertShareMLP(nn.Module):
     [0, 1]: at a learning rate of 3e-5, 512 makes a step of the bias at
     most 0.015. Where ``losses`` is not mutable (a plain ``apply``) nothing
     is sowed and the bias has no gradient at all. ``expert_kind`` ``"relu2"``
-    makes an expert two matrices, ``W_down relu(W_up x) ** 2``. ``shared_dim``
+    makes an expert two matrices, ``W_down relu(W_up x) ** 2``; ``"reglu"``
+    three, ``W_down (relu(x W_gate) * (x W_up))``. ``shared_dim``
     adds ONE shared expert of that width and the same kind, which every
     token takes with gate 1 and every holder computes alike (under the scope
     ``moe_shared``): where shares are added up it counts once.
@@ -670,7 +687,11 @@ class ExpertShareMLP(nn.Module):
     mlp_axis: str = "mlp"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, route_from=None):
+        """``route_from`` [B, S, D], where given, is what the router scores
+        in place of ``x`` (a model whose router reads the layer's input
+        before attention, `models.smallthinker`); the experts transform
+        ``x`` either way."""
         from maggy_tpu.telemetry.plans import remember_plan
 
         B, S, D = x.shape
@@ -700,6 +721,8 @@ class ExpertShareMLP(nn.Module):
             said += " balance x{:g}".format(self.balance_scale)
         if self.expert_kind != "swiglu":
             said += " " + self.expert_kind
+        if route_from is not None:
+            said += " route pre-attn"
         scopes = SCOPES
         if self.shared_dim:
             said += " shared {}".format(self.shared_dim)
@@ -739,9 +762,11 @@ class ExpertShareMLP(nn.Module):
         weights = expert_weights("", G, F)
 
         xd = x.reshape(N, D).astype(self.dtype)
+        scored = xd if route_from is None \
+            else route_from.reshape(N, D).astype(self.dtype)
         with jax.named_scope("moe_routing"):
             ids, gates = route_top_k(
-                xd, router, k, self.renormalize, self.scoring,
+                scored, router, k, self.renormalize, self.scoring,
                 bias * self.balance_scale if self.balance_scale else bias,
                 self.route_scale)
             if self.balance_scale:

@@ -19,9 +19,10 @@ TPU-first hot-op design the BERT/Llama baseline configs need:
       mask shape, streamed as one [1, blk_k] tile per k-block.
     * mask *descriptions* with per-query structure (`BlockDiffusionMask`:
       the block-diffusion training mask over a noised and a clean copy of
-      the sequence) — computed per tile from indices as the causal mask is;
-      the tiles a description empties are no steps of the walk
-      (`_tile_runs`) and the plan counts only those that run.
+      the sequence; `CausalWindow`: a sliding window under the causal
+      mask) — computed per tile from indices as the causal mask is; the
+      tiles a description empties are no steps of the walk (`_tile_runs`)
+      and the plan counts only those that run.
     * Sq != Sk, with bottom-right-aligned causal masking (offset = Sk-Sq),
       e.g. decode windows / ring-attention shards.
     * head_dim >= 64. At D 64 (BERT-base) two heads of an MHA layer share
@@ -217,16 +218,43 @@ def _block_diffusion_visible(q_pos, k_pos, mask: BlockDiffusionMask):
     return (k_as_clean <= upto) | (k_as_noised == own)
 
 
+class CausalWindow(NamedTuple):
+    """A *description* of the sliding-window causal mask, which the kernels
+    compute per tile from indices: query i sees key j iff ``i - window < j
+    <= i`` (Sq = Sk), its own position and the ``window - 1`` before it.
+
+    The upper edge is the kernels' causal mask (the description goes with
+    ``causal=True``); the description adds the lower one. Tiles wholly
+    below the band are no steps of the walk (`_tile_runs`), so a layer
+    under a window of W at S positions walks about ``W / S`` of the causal
+    walk's tiles. Hashable: it is a static argument of the kernels' jits."""
+    window: int
+
+    def dense(self, length: int) -> jnp.ndarray:
+        """The [length, length] keep-mask, for `attention_reference`."""
+        pos = jnp.arange(length)
+        return (pos[None, :] <= pos[:, None]) \
+            & _window_visible(pos[:, None], pos[None, :], self)
+
+
+def _window_visible(q_pos, k_pos, mask: CausalWindow):
+    """The window's lower edge on a tile of positions (the causal mask is
+    the upper one)."""
+    return k_pos > q_pos - mask.window
+
+
 def _structure_tile_mask(s, q0, k0, structure, q_axis=0):
-    """The block-diffusion mask for a score tile whose first query is ``q0``
-    and first key ``k0``, [queries, keys] or, with ``q_axis=1``, transposed."""
+    """The mask description's mask for a score tile whose first query is
+    ``q0`` and first key ``k0``, [queries, keys] or, with ``q_axis=1``,
+    transposed."""
     rows, cols = s.shape
     q_shape = (rows, 1) if q_axis == 0 else (1, cols)
     k_shape = (1, cols) if q_axis == 0 else (rows, 1)
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, q_shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, k_shape, 1 - q_axis)
-    return jnp.where(_block_diffusion_visible(q_pos, k_pos, structure), s,
-                     NEG_INF)
+    visible = _window_visible if isinstance(structure, CausalWindow) \
+        else _block_diffusion_visible
+    return jnp.where(visible(q_pos, k_pos, structure), s, NEG_INF)
 
 
 def _tile_parts(q0, blk_q, k0, blk_k, structure):
@@ -248,12 +276,20 @@ def _tile_runs(q0, blk_q, k0, blk_k, offset, causal, structure):
     """Whether the [blk_q, blk_k] tile at (q0, k0) holds a visible pair, from
     indices alone: None where every tile does (no causal mask and no mask
     description), else a boolean: the tiles `tile_walk` schedules and
-    `_blocks_run` counts. On Python ints and numpy arrays alike."""
+    `_blocks_run` counts. On Python ints and numpy arrays alike. A
+    `CausalWindow` comes with the causal mask and adds its lower edge."""
     runs = None
     if causal:
         # Tiles strictly above the diagonal see nothing.
         runs = k0 < q0 + blk_q + offset
-    if structure is not None:
+    if isinstance(structure, CausalWindow):
+        # Nor do tiles wholly below the band: the tile's last key is past
+        # its first query's window. (j - i takes every value between the
+        # tile's least and its largest, so meeting both edges is meeting
+        # the band.)
+        seen = k0 + blk_k - 1 > q0 - structure.window
+        runs = seen if runs is None else runs & seen
+    elif structure is not None:
         (qn0, qn1), (_, qc1), q_n, q_c, (kn0, kn1), (kc0, _), k_n, k_c = \
             _tile_parts(q0, blk_q, k0, blk_k, structure)
         seen = (q_n & k_n & (qn0 <= kn1) & (kn0 <= qn1)) \
@@ -273,7 +309,10 @@ def _tile_whole(q0, blk_q, k0, blk_k, offset, causal, structure):
     if causal:
         # The tile's last key is visible to its first query.
         whole = k0 + blk_k - 1 <= q0 + offset
-    if structure is not None:
+    if isinstance(structure, CausalWindow):
+        # And its first key to its last query.
+        whole = whole & (k0 > q0 + blk_q - 1 - structure.window)
+    elif structure is not None:
         (qn0, qn1), (qc0, _), q_n, q_c, (kn0, kn1), (_, kc1), k_n, k_c = \
             _tile_parts(q0, blk_q, k0, blk_k, structure)
 
@@ -546,12 +585,17 @@ def _remember(plan: FlashPlan, Sq: int, Sk: int, causal: bool,
               structure: Optional[BlockDiffusionMask]) -> None:
     said = plan.describe()
     if structure is not None:
-        # The mask kind, its block length, per kernel the tiles of a head
-        # that run of those there are, and what its grid walks for them.
+        # The mask kind and its size (a window's, or the block length and
+        # the data length), per kernel the tiles of a head that run of those
+        # there are, and what its grid walks for them.
         walks = [tile_walk(name, Sq, Sk, t.blk_q, t.blk_k, causal, structure)
                  for name, t in zip(plan._fields, plan)]
-        said += "; block_diffusion b{} L{} tiles {}; walk {}".format(
-            structure.block, structure.length,
+        kind = "window w{}".format(structure.window) \
+            if isinstance(structure, CausalWindow) \
+            else "block_diffusion b{} L{}".format(structure.block,
+                                                  structure.length)
+        said += "; {} tiles {}; walk {}".format(
+            kind,
             " ".join("{} {}/{}".format(
                 name, walk.steps - walk.count(_EMPTY),
                 (Sq // t.blk_q) * (Sk // t.blk_k))
@@ -1384,7 +1428,8 @@ def multi_head_attention(q, k, v, causal: bool = True, mask=None,
 
     q: [B,Sq,H,D], k/v: [B,Sk,Hkv,D]. ``mask`` is a keep-mask tensor that
     broadcasts against [B,H,Sq,Sk], or a mask *description*
-    (`BlockDiffusionMask`) that the kernels compute from indices. ``force``
+    (`BlockDiffusionMask`; `CausalWindow`, with ``causal``) that the kernels
+    compute from indices. ``force``
     in {"flash", "reference"} overrides dispatch (tests). Flash handles GQA
     natively (no kv repeat), no mask, key-padding masks ([B,1,1,Sk] or
     [Sk]), causal masks, mask descriptions, Sq != Sk, and head_dim >= 64 at
@@ -1398,13 +1443,21 @@ def multi_head_attention(q, k, v, causal: bool = True, mask=None,
     Hkv = k.shape[2]
     if H % Hkv != 0:
         raise ValueError("H={} not divisible by Hkv={}".format(H, Hkv))
-    structure = mask if isinstance(mask, BlockDiffusionMask) else None
-    if structure is not None:
+    structure = mask if isinstance(mask, (BlockDiffusionMask, CausalWindow)) \
+        else None
+    if isinstance(structure, CausalWindow):
+        if not causal or Sq != Sk or structure.window < 1:
+            raise ValueError(
+                "{} is a causal mask over Sq = Sk with a window of at least "
+                "one position; got causal={}, Sq={}, Sk={}".format(
+                    structure, causal, Sq, Sk))
+    elif structure is not None:
         if Sq != Sk or Sq != 2 * structure.length \
                 or structure.length % structure.block:
             raise ValueError(
                 "{} describes Sq = Sk = {} in whole blocks; got Sq={}, "
                 "Sk={}".format(structure, 2 * structure.length, Sq, Sk))
+    if structure is not None:
         pad_mask, mask_ok = None, True
     else:
         pad_mask, mask_ok = _key_padding_mask(mask, B, Sk)
@@ -1426,7 +1479,9 @@ def multi_head_attention(q, k, v, causal: bool = True, mask=None,
         use_flash = force is None and _tpu_backend() and tiles_ok \
             and not _flash_disabled()
     if not use_flash:
-        if structure is not None:
+        if isinstance(structure, CausalWindow):
+            mask = structure.dense(Sq)
+        elif structure is not None:
             mask = structure.dense()
         return attention_reference(q, k, v, causal=causal, mask=mask)
     plan = tile_plan(Sq, Sk, D, H, Hkv, q.dtype.itemsize, causal,

@@ -342,6 +342,8 @@ STEP_SCOPES = (
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",  # ops/ssd.py
     "loop_attn", "loop_mlp", "exit_norm", "exit_gate",
     "exit_head",                      # models/ouro.py LOOP_SCOPES
+    "attn_global", "attn_window",     # models/smallthinker.py ATTN_SCOPES:
+                                      #   a layer's attention by its kind
 )
 
 #: Health-engine event fields (``ev: "health"``).

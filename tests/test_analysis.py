@@ -514,12 +514,13 @@ def update(x):
         # Every `jax.named_scope` of the package is in STEP_SCOPES and the
         # other way round (the package run below has no finding), and the
         # models' tables are parts of the one list.
-        from maggy_tpu.models import moe, ouro
+        from maggy_tpu.models import moe, ouro, smallthinker
         from maggy_tpu.ops import ssd
         from maggy_tpu.telemetry import plans, vocab
 
         assert set(moe.SCOPES + (moe.SHARED_SCOPE,) + ssd.SCOPES
-                   + ouro.LOOP_SCOPES) <= set(vocab.STEP_SCOPES)
+                   + ouro.LOOP_SCOPES + smallthinker.ATTN_SCOPES) \
+            <= set(vocab.STEP_SCOPES)
         assert set(plans.STEP_FIELDS) <= set(vocab.COMPILED_FIELDS)
         report = run_analysis(checkers=("journalvocab",))
         assert report["summary"] == {"journalvocab": 0}

@@ -79,6 +79,10 @@ WALKED = {
     "llama_gqa_d128_causal": (1, 2048, 32, 8, 128, True, None),
     "nemotron_cell_gqa_32_2_causal_s8192": (2, 8192, 32, 2, 128, True, None),
     "ouro_cell_mha_16_16_causal_s4096": (2, 4096, 16, 16, 128, True, None),
+    # The SmallThinker cell's windowed layer: GQA 28/4, a group of 7, under
+    # a window of 4096 at S 16384 (70 of 256 tiles of 1024 run).
+    "smallthinker_cell_gqa_28_4_window_s16384": (1, 16384, 28, 4, 128, True,
+                                                 "window 4096"),
 }
 
 
@@ -90,10 +94,12 @@ def test_the_kernels_that_walk_their_tiles_compile(v5e_device, name):
     call. The table is the custom call's first operand, four rows of the
     walk's steps."""
     from maggy_tpu.ops.attention import (_PARTIAL, _WHOLE, BlockDiffusionMask,
-                                         tile_walk)
+                                         CausalWindow, tile_walk)
 
     B, S, H, Hkv, D, causal, described = WALKED[name]
-    mask = described and BlockDiffusionMask(*described)
+    mask = described and (CausalWindow(int(described.split()[1]))
+                          if isinstance(described, str)
+                          else BlockDiffusionMask(*described))
     plan = tile_plan(S, S, D, H, Hkv, 2, causal, False, mask)
     q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=v5e_device)
     kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16,
@@ -418,3 +424,56 @@ def test_a_rematerialised_nemotron_step_holds_each_kernel_once_a_block(
                   if "ssd_" in name) == ["ssd_conv_bwd"] + ["ssd_conv_fwd"] * 2
     assert not [line for line in text.splitlines()  # no loop over groups
                 if " while(" in line and "ssm_scan" in line]
+
+
+def test_a_rematerialised_smallthinker_step_holds_each_kernel_once_a_layer(
+        v5e_device, monkeypatch):
+    """The model's own gradient through XLA:TPU, one global and one windowed
+    layer at toy widths (heads of 128, a group of 7, a share of ReGLU
+    experts routed before attention): each layer keeps its flash kernel's
+    out and lse and its routing, so the executable holds ONE `flash_fwd` a
+    layer beside its two backward kernels, and the experts' three matrices
+    ask for three grouped products forward, two of them again in the
+    backward pass. Each layer's three kernels are under its kind's scope,
+    ``attn_global`` or ``attn_window``, which the cell's readers take them
+    by."""
+    import collections
+
+    import flax.linen as nn
+
+    from maggy_tpu.models import SmallThinker, SmallThinkerConfig, moe
+    from maggy_tpu.ops import attention
+    from maggy_tpu.telemetry.hlo_scopes import ops_by_scope
+
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    cfg = SmallThinkerConfig(
+        vocab_size=512, hidden_dim=256, num_heads=7, num_kv_heads=1,
+        head_dim=128, moe_intermediate_dim=128, num_experts=8, top_k=2,
+        experts_held=4, sliding_window=128, sliding_window_layout=(0, 1),
+        rope_layout=(0, 1), head_chunk=256)
+    assert cfg.remat
+    module, S = SmallThinker(cfg), 512
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=v5e_device), tree)
+
+    tokens = jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=v5e_device)
+    params = abstract(nn.meta.unbox(jax.eval_shape(
+        module.init, jax.random.key(0), tokens, tokens))["params"])
+
+    def loss(p, tokens, targets):
+        return jnp.mean(module.apply({"params": p}, tokens, targets))
+
+    text = jax.jit(jax.grad(loss)).lower(params, tokens, tokens).compile() \
+        .as_text()
+    assert collections.Counter(_kernel_names(text)) == {
+        "flash_fwd": 2, "flash_bwd_dkdv": 2, "flash_bwd_dq": 2,
+        "moe_gmm_fwd": 10, "moe_gmm_dlhs": 6, "moe_gmm_drhs": 6}
+    scopes = ops_by_scope(text, ("attn_global", "attn_window"))
+    for scope in ("attn_global", "attn_window"):
+        assert sorted(name.split(".")[0] for name in scopes[scope]
+                      if "flash_" in name) == [
+            "flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]

@@ -4,7 +4,7 @@ record's ``step_ops`` once or in ``step_mixed`` once, none in both, and
 what is left ``unscoped`` is what XLA made without a name (relayouts,
 copies) and the few paths listed here.
 
-The families are the benchmark's five, built as its cells build them
+The families are the benchmark's six, built as its cells build them
 (``benchmark/families``) at each configuration's ``rehearse`` preset.
 """
 
@@ -58,6 +58,15 @@ FAMILIES = {
              # stack's gradients: the model's, outside every scope it names.
              ("jvp(Ouro)/concatenate", "transpose(jvp(Ouro))/split",
               "stack/add_any", "jvp(Ouro)/broadcast_in_dim")),
+    "smallthinker": ("smallthinker-21b-a3b.ntp-steady-s16384",
+                     {"embed", "attn_global", "attn_global/attention",
+                      "attn_window", "attn_window/attention", "block",
+                      "block/moe_routing", "block/moe_experts",
+                      "block/moe_combine", "head", "loss", "optimizer"},
+                     # The causal mask of XLA's attention, hoisted out of
+                     # the model as a constant of the step.
+                     ("jit(tril)/ge", "jit(tril)/add",
+                      "jit(_where)/broadcast_in_dim")),
 }
 
 
