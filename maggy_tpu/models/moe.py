@@ -6,8 +6,10 @@ over all ``num_experts`` (softmax, or sigmoid scores with a bias only the
 choice sees), is TOLD which experts it holds, sorts the token-expert pairs
 by expert and multiplies each held expert's rows by that expert's weights
 (SwiGLU's or ReGLU's three matrices, relu^2's two) in Pallas grouped matrix
-products (`moe_gmm_fwd`, `moe_gmm_dlhs`, `moe_gmm_drhs`), beside a shared
-expert where the model has one. No capacity, no dropped token. On one chip it runs
+products (`moe_gmm_fwd`, `moe_gmm_dlhs`, `moe_gmm_drhs`), and adds the rows
+back to their tokens in Pallas too where the shapes tile (`moe_combine_fwd`,
+`moe_combine_bwd`), beside a shared expert where the model has one. No
+capacity, no dropped token. On one chip it runs
 without its exchange; nothing stands in for absent chips.
 
 `MoEMLP` (first half) is the older layer, kept because the ``dp_ep``
@@ -151,7 +153,9 @@ class MoEMLP(nn.Module):
 # at a multiple of ``TILE_ROWS``, so a tile of rows belongs to one expert and
 # a grouped product is a tiled matmul whose weight block is looked up per
 # row tile. The buffer exists as INDICES only ([rows] int32); the rows
-# themselves are gathered, multiplied and scattered back a chunk at a time.
+# themselves are gathered and multiplied a chunk at a time, and scattered
+# back a chunk at a time or, where the combine kernels run, kept in buffer
+# order for the layer and added back to the tokens once.
 
 #: Rows of one tile of the grouped products.
 TILE_ROWS = 512
@@ -168,6 +172,12 @@ BLOCK_CAP = 512
 #: against a [2048, 512] weight block, double-buffered, and the float32
 #: result) is 9 MiB; a v5e's default of 16 MiB is raised for headroom.
 VMEM_LIMIT = 32 * 2 ** 20
+#: Tokens one grid step of the combine kernels (`moe_combine_fwd`,
+#: `moe_combine_bwd`) owns, where the tokens tile by it (`combine_plan`).
+COMBINE_TOKENS = 256
+#: Rows of the buffer one DMA of the combine kernels moves: a whole tile of
+#: packed bfloat16 rows, so every window starts where such a tile does.
+COMBINE_WINDOW = 16
 
 
 #: The `jax.named_scope`s of the layer, in the order a token meets them.
@@ -179,8 +189,9 @@ SCOPES = ("moe_routing", "moe_dispatch", "moe_experts", "moe_combine")
 SHARED_SCOPE = "moe_shared"
 
 #: The name `ExpertShareMLP` gives what its routing hands to `expert_ffn`:
-#: the pairs' gates and `grouped_layout`'s three, under 3 MB a layer at
-#: 16,384 positions. A caller that rematerialises the layer and keeps it
+#: the pairs' gates, `grouped_layout`'s three and, where the combine is the
+#: kernels', `combine_runs`' four, under 5 MB a layer at 16,384 positions.
+#: A caller that rematerialises the layer and keeps it
 #: (`save_only_these_names`) scores, selects and sorts once a step.
 REMAT_KEEP = ("moe_route",)
 
@@ -302,6 +313,49 @@ def grouped_layout(ids, first_expert: int, held: int, tile_rows: int):
     tile_group = jnp.minimum(  # experts whose tiles end at or before it
         jnp.sum(tile[:, None] >= tile_ends[None, :], axis=1), held - 1)
     return row_pair, tile_group.astype(jnp.int32), tiles_used
+
+
+def combine_plan(tokens: int, width: int, tile_rows: int):
+    """(tokens, columns) one grid step of the combine kernels owns: up to
+    `COMBINE_TOKENS` tokens and `_block` of the width. None where they do
+    not tile by 128, or a window would cross a row tile, and the combine is
+    XLA's scatter-add."""
+    if tokens % 128 or width % 128 or tile_rows % COMBINE_WINDOW:
+        return None
+    return _block(tokens, COMBINE_TOKENS), _block(width)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def combine_runs(ids, first_expert: int, held: int, tile_rows: int,
+                 token_block: int):
+    """Where the rows of each block of ``token_block`` tokens lie in
+    `grouped_layout`'s buffer. Its sort is stable, so one expert's rows
+    stand in token order, and a token's experts are distinct: the rows of
+    held expert g whose tokens lie in block b are ONE run of the buffer,
+    at most a row a token.
+
+    Returns ``row_of`` [held, N] int32 (the row of token n's pair with
+    expert g, -1 where n did not choose g), ``slot_of`` [held, N] int32
+    (which of n's ``k`` choices g is, ``k`` where none), and
+    ``run_start``, ``run_len`` [N / token_block * held] int32 (the run of
+    block b and expert g at ``b * held + g``). Dense work over tokens x
+    held experts, no sort; jitted, so a step's layers trace and lower it
+    once."""
+    N, k = ids.shape
+    chose = (ids - first_expert).T[None] == jnp.arange(held)[:, None, None]
+    hit = jnp.any(chose, axis=1)
+    slot_of = jnp.where(hit, jnp.sum(jnp.where(
+        chose, jnp.arange(k)[:, None], 0), axis=1), k)
+    per = hit.reshape(held, N // token_block, token_block).astype(jnp.int32)
+    tiles = -(-jnp.sum(per, axis=(1, 2)) // tile_rows)
+    first_row = (jnp.cumsum(tiles) - tiles) * tile_rows
+    run_len = jnp.sum(per, axis=2)
+    run_start = first_row[:, None] + jnp.cumsum(run_len, axis=1) - run_len
+    row_of = jnp.where(per > 0,
+                       run_start[..., None] + jnp.cumsum(per, axis=2) - per,
+                       -1).reshape(held, N)
+    return (row_of, slot_of.astype(jnp.int32), run_start.T.reshape(-1),
+            run_len.T.reshape(-1))
 
 
 def _gmm(lhs, rhs, tile_group, tiles_left, *, transpose_rhs: bool, name: str,
@@ -426,6 +480,173 @@ def _gmm_drhs(lhs, dout, tile_group, tiles_left, acc, *, tile_rows: int,
     )(tile_group, tiles_left, lhs, dout, acc)
 
 
+def _split3(y):
+    """float32 ``y`` as three bfloat16 terms whose sum, taken as
+    ``(hi + mid) + lo`` in float32, is ``y`` exactly."""
+    hi = y.astype(jnp.bfloat16)
+    rest = y - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("tokens", "out_dtype", "name", "interpret"))
+def _combine(sources, scale, row_of, run_start, run_len, *, tokens: int,
+             out_dtype, name: str, interpret: bool):
+    """``out[n] = sum over n's rows r of scale[g, n] * sum_s sources[s][r]``
+    (g the expert of row r), summed in float32 and written once: the
+    scatter-add of the buffer's rows back to the tokens as a gather.
+
+    ``sources`` [rows, D] in buffer order; ``scale`` [G, N] float32 or None
+    (every factor 1); ``row_of``, ``run_start``, ``run_len`` as
+    `combine_runs` gives them. Grid (token blocks, column blocks). A step
+    walks its block's runs in a `fori_loop` over the held experts and,
+    inside, over the `COMBINE_WINDOW`-row windows a run spans, starting a
+    DMA of each into a slot; when the slots are full, and at the end, it
+    waits for them and adds the batch into its float32 accumulator as one
+    product on the MXU, a 0/1 matrix (``row_of`` against the window's rows)
+    times the rows. Nothing in the body is repeated per held expert, so it
+    lowers to the same program whatever their number.
+
+    The 0/1 product is exact for bfloat16 rows. Rows that are scaled, or
+    not bfloat16, are scaled in float32 and enter as three bfloat16 terms
+    (`_split3`): a token with one row gets ``scale * row`` to the bit, one
+    with several their sum up to float32 rounding.
+
+    A `jax.jit` of its own: the layers of a step that call it at one shape
+    share one trace and one lowering of the kernel, so the set-up a step
+    costs does not grow with its layers."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    D = sources[0].shape[1]
+    G, N = row_of.shape
+    tn, W = _block(D), COMBINE_WINDOW
+    slots = tokens // W + G  # a row a token, and one partial window a run
+    exact = scale is None and all(s.dtype == jnp.bfloat16 for s in sources)
+    S, terms = len(sources), len(sources) if exact else 3
+    f32 = jnp.float32
+
+    def kernel(start_ref, len_ref, row_ref, *refs):
+        scale_ref, refs = (None, refs) if scale is None else (refs[0],
+                                                             refs[1:])
+        src, (out_ref, acc, stack, mat, split, meta, sem) = refs[:S], refs[S:]
+        b, c = pl.program_id(0), pl.program_id(1)
+        col = pl.multiple_of(c * tn, tn)
+        terms_ref = stack if exact else split
+
+        def copies(i, start):
+            return [pltpu.make_async_copy(
+                src[s].at[pl.ds(start, W), pl.ds(col, tn)],
+                stack.at[s, pl.ds(pl.multiple_of(i * W, W), W)], sem.at[s, i])
+                for s in range(S)]
+
+        def flush(used):
+            def take(i, _):
+                start, lo, hi, g = (meta[4 * i + f] for f in range(4))
+                start = pl.multiple_of(start, W)
+                for cp in copies(i, start):
+                    cp.wait()
+                rows = pl.ds(pl.multiple_of(i * W, W), W)
+                match = row_ref[pl.ds(g, 1), :] == start + \
+                    jax.lax.broadcasted_iota(jnp.int32, (W, tokens), 0)
+                mat[rows, :] = match.astype(mat.dtype)
+                r = jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
+                keep = (r >= lo) & (r < hi)  # the run's rows, nothing else
+                if exact:
+                    for s in range(S):
+                        stack[s, rows, :] = jnp.where(keep, stack[s, rows, :],
+                                                      0)
+                    return 0
+                y = functools.reduce(operator.add, (
+                    stack[s, rows, :].astype(f32) for s in range(S)))
+                if scale is not None:
+                    y = y * jnp.sum(jnp.where(
+                        match, scale_ref[pl.ds(g, 1), :], 0.0), axis=1,
+                        keepdims=True)
+                for t, part in enumerate(_split3(jnp.where(keep, y, 0.0))):
+                    split[t, rows, :] = part
+                return 0
+
+            def blank(i, _):
+                mat[pl.ds(pl.multiple_of(i * W, W), W), :] = jnp.zeros(
+                    (W, tokens), mat.dtype)
+                return 0
+
+            jax.lax.fori_loop(0, used, take, 0)
+            jax.lax.fori_loop(used, slots, blank, 0)
+            m = mat[...].T
+            acc[...] += functools.reduce(operator.add, (
+                jnp.dot(m, terms_ref[t], preferred_element_type=f32)
+                for t in range(terms)))
+
+        @pl.when((b == 0) & (c == 0))
+        def _():  # what a blank slot holds is finite from here on
+            terms_ref[...] = jnp.zeros_like(terms_ref)
+
+        acc[...] = jnp.zeros_like(acc)
+
+        def run(g, k):
+            start = start_ref[b * G + g]
+            n = len_ref[b * G + g]
+            first = start - start % W
+
+            def window(j, k):
+                at = pl.multiple_of(first + j * W, W)
+                for cp in copies(k, at):
+                    cp.start()
+                meta[4 * k] = at
+                meta[4 * k + 1] = jnp.maximum(start, at) - at
+                meta[4 * k + 2] = jnp.minimum(start + n, at + W) - at
+                meta[4 * k + 3] = g
+                k = k + 1
+
+                @pl.when(k == slots)
+                def _():
+                    flush(slots)
+
+                return jnp.where(k == slots, 0, k)
+
+            count = jnp.where(n > 0, (start + n - first + W - 1) // W, 0)
+            return jax.lax.fori_loop(0, count, window, k)
+
+        left = jax.lax.fori_loop(0, G, run, 0)
+
+        @pl.when(left > 0)
+        def _():
+            flush(left)
+
+        out_ref[...] = acc[...].astype(out_ref.dtype)
+
+    by_block = pl.BlockSpec((G, tokens), lambda b, c, *_: (0, b))
+    scratch = [
+        pltpu.VMEM((tokens, tn), f32),
+        pltpu.VMEM((S, slots * W, tn), sources[0].dtype),
+        pltpu.VMEM((slots * W, tokens), jnp.bfloat16),
+        pltpu.VMEM((1, W, tn) if exact else (3, slots * W, tn), jnp.bfloat16),
+        pltpu.SMEM((4 * slots,), jnp.int32),
+        pltpu.SemaphoreType.DMA((S, slots)),
+    ]
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N // tokens, D // tn),
+            in_specs=[by_block] * (1 + (scale is not None))
+            + [pl.BlockSpec(memory_space=pl.ANY)] * S,
+            out_specs=pl.BlockSpec((tokens, tn), lambda b, c, *_: (b, c)),
+            scratch_shapes=scratch,
+        ),
+        out_shape=jax.ShapeDtypeStruct((N, D), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=name,
+    )(run_start, run_len, row_of,
+      *(() if scale is None else (scale,)), *sources)
+
+
 class _Chunk:
     """One chunk of the index buffer, as the forward and the backward pass
     both need it: the rows' tokens and gates, and the chunk's tiles."""
@@ -502,8 +723,8 @@ class _ReGLU:
 EXPERT_KINDS = {"swiglu": _SwiGLU, "relu2": _Relu2, "reglu": _ReGLU}
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
-def expert_ffn(x, weights, pair_gate, row_pair, tile_group, tiles_used,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
+def expert_ffn(x, weights, pair_gate, row_pair, tile_group, tiles_used, runs,
                top_k: int, chunk_rows: int, tile_rows: int, interpret: bool,
                kind: str = "swiglu"):
     """``out[n] = sum over n's pairs in the buffer of gate * W_down_e
@@ -517,10 +738,16 @@ def expert_ffn(x, weights, pair_gate, row_pair, tile_group, tiles_used,
     is the used rows over the chunk, so the work follows the rows routed
     here and the live memory is one chunk's): gather the chunk's rows of x,
     the kind's grouped products, and a gate-weighted scatter-add back to the
-    tokens. Its own VJP (below) keeps x, the weights and the indices, and
-    recomputes a chunk's activations beside their gradients."""
+    tokens. Where ``runs`` holds `combine_runs`' four, the products' rows
+    are kept in buffer order instead (in their own dtype, in a buffer that
+    is never cleared: only rows the loop wrote are read) and one kernel,
+    `moe_combine_fwd`, adds them back to the tokens after the loop (and
+    `moe_combine_bwd` the gradient's rows in the VJP); where it is empty,
+    XLA's scatter-add does, a chunk at a time. Its own VJP (below)
+    keeps x, the weights and the indices, and recomputes a chunk's
+    activations beside their gradients."""
     out, _ = _expert_ffn_fwd(x, weights, pair_gate, row_pair, tile_group,
-                             tiles_used, top_k, chunk_rows, tile_rows,
+                             tiles_used, runs, top_k, chunk_rows, tile_rows,
                              interpret, kind)
     return out
 
@@ -529,8 +756,28 @@ def _chunks(tiles_used, chunk_rows, tile_rows):
     return -(-(tiles_used * tile_rows) // chunk_rows)
 
 
+def _kept(row_pair, x, n):
+    """``n`` buffers of the layer's rows, one a product, left uncleared."""
+    return tuple(jax.lax.empty(row_pair.shape + x.shape[1:], x.dtype)
+                 for _ in range(n))
+
+
+def _keep(kept, rows, c, chunk_rows):
+    """Chunk c's ``rows`` into the kept buffers, at the chunk's rows."""
+    return tuple(jax.lax.dynamic_update_slice_in_dim(k, r, c * chunk_rows, 0)
+                 for k, r in zip(kept, rows))
+
+
+def _combine_kept(kept, scale, runs, out_dtype, name, interpret):
+    row_of, _, run_start, run_len = runs
+    G, N = row_of.shape
+    return _combine(kept, scale, row_of, run_start, run_len,
+                    tokens=N * G // run_start.shape[0], out_dtype=out_dtype,
+                    name=name, interpret=interpret)
+
+
 def _expert_ffn_fwd(x, weights, pair_gate, row_pair, tile_group, tiles_used,
-                    top_k, chunk_rows, tile_rows, interpret, kind):
+                    runs, top_k, chunk_rows, tile_rows, interpret, kind):
     dtype = x.dtype
     act_of = EXPERT_KINDS[kind].act
     gmm = functools.partial(_gmm, transpose_rhs=False, name="moe_gmm_fwd",
@@ -547,18 +794,28 @@ def _expert_ffn_fwd(x, weights, pair_gate, row_pair, tile_group, tiles_used,
                            for w in w_in))
             y = gmm(act.astype(dtype), wd, ck.tile_group, ck.tiles_left)
         with jax.named_scope("moe_combine"):
+            if runs:
+                return _keep(out, (y,), c, chunk_rows)
             return out.at[ck.token].add(
                 y.astype(jnp.float32) * ck.gate[:, None])
 
     out = jax.lax.fori_loop(
         0, _chunks(tiles_used, chunk_rows, tile_rows), chunk,
-        jnp.zeros(x.shape, jnp.float32))
+        _kept(row_pair, x, 1) if runs else jnp.zeros(x.shape, jnp.float32))
+    if runs:
+        with jax.named_scope("moe_combine"):
+            slot_of = runs[1]  # each pair's gate where it lies, else 0
+            scale = jnp.sum(jnp.where(
+                slot_of[..., None] == jnp.arange(top_k),
+                pair_gate.reshape(-1, top_k), 0.0), axis=-1)
+            out = _combine_kept(out, scale, runs, dtype, "moe_combine_fwd",
+                                interpret)
     return out.astype(dtype), (x, weights, pair_gate, row_pair, tile_group,
-                               tiles_used)
+                               tiles_used, runs)
 
 
 def _expert_ffn_bwd(top_k, chunk_rows, tile_rows, interpret, kind, res, dout):
-    x, weights, pair_gate, row_pair, tile_group, tiles_used = res
+    x, weights, pair_gate, row_pair, tile_group, tiles_used, runs = res
     dtype = x.dtype
     P = pair_gate.shape[0]
     expert = EXPERT_KINDS[kind]
@@ -587,16 +844,18 @@ def _expert_ffn_bwd(top_k, chunk_rows, tile_rows, interpret, kind, res, dout):
             dgate_of_pair = jnp.sum(act * u, axis=-1)
             dact = u * ck.gate[:, None]
             dpre = [d.astype(dtype) for d in expert.dpre(dact, *pre)]
-            dxg = functools.reduce(operator.add, (
-                dlhs(d, w, *tiles).astype(jnp.float32)
-                for d, w in zip(dpre, w_in)))
+            dxg = [dlhs(d, w, *tiles) for d, w in zip(dpre, w_in)]
+            if not runs:  # summed here; the kernel sums what it keeps
+                dxg = functools.reduce(operator.add, (
+                    d.astype(jnp.float32) for d in dxg))
             dw_in = tuple(drhs(xg, d, *tiles, acc)
                           for d, acc in zip(dpre, dw_in))
             dwd = drhs(act.astype(dtype),
                        (dog.astype(jnp.float32) * ck.gate[:, None]).astype(
                            dtype), *tiles, dwd)
         with jax.named_scope("moe_combine"):
-            dx = dx.at[ck.token].add(dxg * ck.valid[:, None])
+            dx = _keep(dx, dxg, c, chunk_rows) if runs \
+                else dx.at[ck.token].add(dxg * ck.valid[:, None])
             dpair = dpair.at[ck.pair].set(dgate_of_pair, mode="drop",
                                           unique_indices=True)
         return dx, dw_in, dwd, dpair
@@ -604,13 +863,18 @@ def _expert_ffn_bwd(top_k, chunk_rows, tile_rows, interpret, kind, res, dout):
     zeros = lambda w: jnp.zeros(w.shape, jnp.float32)  # noqa: E731
     dx, dw_in, dwd, dpair = jax.lax.fori_loop(
         0, _chunks(tiles_used, chunk_rows, tile_rows), chunk,
-        (jnp.zeros(x.shape, jnp.float32),
+        (_kept(row_pair, x, len(w_in)) if runs
+         else jnp.zeros(x.shape, jnp.float32),
          tuple(zeros(w) for w in weights[:-1]), zeros(weights[-1]),
          jnp.zeros((P,), jnp.float32)))
+    if runs:  # the gate is in the rows already
+        with jax.named_scope("moe_combine"):
+            dx = _combine_kept(dx, None, runs, x.dtype, "moe_combine_bwd",
+                               interpret)
     dweights = tuple(d.astype(w.dtype)
                      for d, w in zip(dw_in + (dwd,), weights))
     return (dx.astype(x.dtype), dweights, dpair.astype(pair_gate.dtype),
-            None, None, None)
+            None, None, None, None)
 
 
 expert_ffn.defvjp(_expert_ffn_fwd, _expert_ffn_bwd)
@@ -727,6 +991,9 @@ class ExpertShareMLP(nn.Module):
         if self.shared_dim:
             said += " shared {}".format(self.shared_dim)
             scopes += (SHARED_SCOPE,)
+        combine = combine_plan(N, D, tm) if _on_tpu() else None
+        said += " combine " + ("pallas {}x{}".format(*combine) if combine
+                               else "xla")
         remember_plan("moe", said, scopes)
 
         router = self.param("router", nn.with_logical_partitioning(
@@ -780,7 +1047,10 @@ class ExpertShareMLP(nn.Module):
             route = [checkpoint_name(r, REMAT_KEEP[0]) for r in (
                 gates.reshape(N * k),
                 *grouped_layout(ids, self.first_expert, G, tm))]
-        out = expert_ffn(xd, weights, *route, k, chunk_rows, tm,
+            runs = tuple(checkpoint_name(r, REMAT_KEEP[0]) for r in
+                         combine_runs(ids, self.first_expert, G, tm,
+                                      combine[0])) if combine else ()
+        out = expert_ffn(xd, weights, *route, runs, k, chunk_rows, tm,
                          not _on_tpu(), self.expert_kind)
         if self.shared_dim:
             *into, down = (w.astype(self.dtype) for w in expert_weights(

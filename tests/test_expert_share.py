@@ -144,7 +144,8 @@ def test_the_layer_says_what_it_holds_and_which_scopes_are_its_own():
     # 22 tiles of 8 rows; a round takes the most tiles, up to CHUNK_TILES,
     # that divide them.
     assert said.plans == {
-        "moe": ["experts 2+2/8 top2 rows 176 chunk 16 tile 8 pallas_gmm"]}
+        "moe": ["experts 2+2/8 top2 rows 176 chunk 16 tile 8 pallas_gmm "
+                "combine xla"]}
     assert said.scopes == {"moe": moe.SCOPES}
 
 

@@ -218,7 +218,7 @@ def _kernel_names(text):
     import re
 
     return sorted(
-        re.search(r"(?:flash|moe_gmm|ssd)_[a-z]+(?:_[a-z]+)*",
+        re.search(r"(?:flash|moe_gmm|moe_combine|ssd)_[a-z]+(?:_[a-z]+)*",
                   line.split(" = ")[0]).group(0)
         for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line)
@@ -285,7 +285,9 @@ def test_a_rematerialised_sdar_step_holds_each_kernel_once_a_layer(
     layers keep `models.sdar.REMAT_KEEP`, so the executable holds ONE
     `flash_fwd` a layer beside its two backward kernels (two before PR 27);
     the grouped products are what the expert layer's own VJP asks for
-    either way (three forward, two of them again in the backward pass)."""
+    either way (three forward, two of them again in the backward pass), and
+    the combine is one kernel a pass (`moe_combine_fwd`, `moe_combine_bwd`),
+    the forward one not made again."""
     import collections
 
     import flax.linen as nn
@@ -322,7 +324,8 @@ def test_a_rematerialised_sdar_step_holds_each_kernel_once_a_layer(
         params, tokens, targets, weights).compile().as_text()
     assert collections.Counter(_kernel_names(text)) == {
         "flash_fwd": 2, "flash_bwd_dkdv": 2, "flash_bwd_dq": 2,
-        "moe_gmm_fwd": 10, "moe_gmm_dlhs": 6, "moe_gmm_drhs": 6}
+        "moe_gmm_fwd": 10, "moe_gmm_dlhs": 6, "moe_gmm_drhs": 6,
+        "moe_combine_fwd": 2, "moe_combine_bwd": 2}
 
 
 def test_the_scan_kernels_compile_at_the_cells_shape(v5e_device):
@@ -368,7 +371,8 @@ def test_a_rematerialised_nemotron_step_holds_each_kernel_once_a_block(
     `ssd_conv_fwd` beside `ssd_conv_bwd` (the convolution's output is made
     again, never kept), and the two-matrix experts ask for two grouped
     products forward (one of them again in the backward pass) where SwiGLU
-    asks for three; the step's instructions carry the state-space and the
+    asks for three, one combine kernel a pass; the step's instructions
+    carry the state-space and the
     shared expert's scopes, every scan kernel, forward and backward, is
     under ``ssm_scan`` and every convolution kernel under ``ssm_conv``."""
     import collections
@@ -413,6 +417,7 @@ def test_a_rematerialised_nemotron_step_holds_each_kernel_once_a_block(
     assert collections.Counter(_kernel_names(text)) == {
         "flash_fwd": 1, "flash_bwd_dkdv": 1, "flash_bwd_dq": 1,
         "moe_gmm_fwd": 3, "moe_gmm_dlhs": 2, "moe_gmm_drhs": 2,
+        "moe_combine_fwd": 1, "moe_combine_bwd": 1,
         "ssd_fwd": forwards, "ssd_states": 1, "ssd_bwd": 1,
         "ssd_conv_fwd": 2, "ssd_conv_bwd": 1}
     scopes = ops_by_scope(text, ssd.SCOPES + moe.SCOPES + (moe.SHARED_SCOPE,))
@@ -434,7 +439,8 @@ def test_a_rematerialised_smallthinker_step_holds_each_kernel_once_a_layer(
     out and lse and its routing, so the executable holds ONE `flash_fwd` a
     layer beside its two backward kernels, and the experts' three matrices
     ask for three grouped products forward, two of them again in the
-    backward pass. Each layer's three kernels are under its kind's scope,
+    backward pass, and one combine kernel a pass. Each layer's three flash
+    kernels are under its kind's scope,
     ``attn_global`` or ``attn_window``, which the cell's readers take them
     by."""
     import collections
@@ -471,9 +477,88 @@ def test_a_rematerialised_smallthinker_step_holds_each_kernel_once_a_layer(
         .as_text()
     assert collections.Counter(_kernel_names(text)) == {
         "flash_fwd": 2, "flash_bwd_dkdv": 2, "flash_bwd_dq": 2,
-        "moe_gmm_fwd": 10, "moe_gmm_dlhs": 6, "moe_gmm_drhs": 6}
+        "moe_gmm_fwd": 10, "moe_gmm_dlhs": 6, "moe_gmm_drhs": 6,
+        "moe_combine_fwd": 2, "moe_combine_bwd": 2}
     scopes = ops_by_scope(text, ("attn_global", "attn_window"))
     for scope in ("attn_global", "attn_window"):
         assert sorted(name.split(".")[0] for name in scopes[scope]
                       if "flash_" in name) == [
             "flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]
+
+
+#: The expert cells' layers: width, held experts, pairs a token, matrices
+#: into the width (SDAR's SwiGLU, Nemotron's relu^2, SmallThinker's ReGLU),
+#: at the 16,384 positions each step puts through a layer.
+COMBINE_CELLS = {"sdar": (2048, 16, 8, 2), "nemotron": (2688, 8, 6, 1),
+                 "smallthinker": (2560, 8, 6, 2)}
+
+
+def _combine_call(v5e_device, width, held, top_k, products, which):
+    """The lowered combine kernel of one pass at a cell's shapes."""
+    from maggy_tpu.models import moe
+
+    N = 16384
+    rows = moe.buffer_rows(N, top_k, held)
+    tokens, _ = moe.combine_plan(N, width, moe.TILE_ROWS)
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_device)
+
+    table = (of((held, N), jnp.int32),) + (
+        of((N // tokens * held,), jnp.int32),) * 2
+    rows_in = (of((rows, width), jnp.bfloat16),) * (
+        1 if which == "fwd" else products)
+    scale = of((held, N), jnp.float32) if which == "fwd" else None
+
+    def combine(row_of, start, length, scale, *rows_in):
+        return moe._combine(rows_in, scale, row_of, start, length,
+                            tokens=tokens, out_dtype=jnp.bfloat16,
+                            name="moe_combine_" + which, interpret=False)
+
+    return jax.jit(combine).lower(*table, scale, *rows_in)
+
+
+@pytest.mark.parametrize("cell", sorted(COMBINE_CELLS))
+def test_the_combine_kernels_compile_at_the_cells_shapes(v5e_device, cell):
+    """`moe_combine_fwd` (the gate-weighted rows) and `moe_combine_bwd`
+    (the products of the gradient's rows, one a matrix into the width)
+    through Mosaic at each expert cell's width, held experts and buffer,
+    over its 16,384 positions."""
+    for which in ("fwd", "bwd"):
+        text = _combine_call(v5e_device, *COMBINE_CELLS[cell],
+                             which).compile().as_text()
+        assert _kernel_names(text) == ["moe_combine_" + which]
+
+
+def _mosaic_text(lowered):
+    """The Mosaic module of a lowered program's one kernel, as text, with
+    every number masked: what its body holds, whatever its shapes."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+    from jax.experimental.mosaic.dialects import tpu
+
+    body, = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                       lowered.as_text())
+    ctx = mlir.JaxIrContext()
+    ctx.append_dialect_registry(mlir.upstream_dialects)
+    ctx.load_all_available_dialects()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        return re.sub(r"\d+", "0", str(ir.Module.parse(
+            base64.b64decode(body))))
+
+
+def test_the_combine_kernel_does_not_grow_with_the_held_experts(v5e_device):
+    """The set-up a layer costs is its program's: a body that walked the
+    held experts in Python would lower once per expert and grow with them,
+    on every run, cached or not. The forward kernel's Mosaic module is the
+    same text at 4 held experts as at 16, numbers aside."""
+    width, _, top_k, products = COMBINE_CELLS["sdar"]
+    four, sixteen = (_mosaic_text(_combine_call(
+        v5e_device, width, held, top_k, products, "fwd")) for held in (4, 16))
+    assert len(four) == len(sixteen)
+    assert four.count("\n") == sixteen.count("\n")

@@ -135,7 +135,7 @@ def test_the_step_says_its_plans_and_names_its_scopes():
     assert said.scopes["ssm"] == ssd.SCOPES
     assert said.plans["moe"] == [
         "experts 2+4/8 top2 rows 2560 chunk 512 tile 512 pallas_gmm "
-        "sigmoid+bias x2.5 relu2 shared 40"]
+        "sigmoid+bias x2.5 relu2 shared 40 combine xla"]
     assert said.scopes["moe"] == moe.SCOPES + (moe.SHARED_SCOPE,)
     assert said.plans["remat"] == [
         "block keeps flash_out flash_lse moe_route ssd_out"]

@@ -167,7 +167,7 @@ def test_the_step_says_its_plans():
         jax.eval_shape(module.init, jax.random.key(0), tokens, tokens)
     assert said.plans["moe"] == [
         "experts 2+4/8 top2 rows 2560 chunk 512 tile 512 pallas_gmm reglu "
-        "route pre-attn"]
+        "route pre-attn combine xla"]
     assert said.scopes["moe"] == moe.SCOPES
     assert said.plans["remat"] == ["layer keeps flash_out flash_lse moe_route"]
 
