@@ -17,6 +17,7 @@ is green-field TPU-first design:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -102,19 +103,40 @@ class RMSNorm(nn.Module):
         return _rms_norm(x, w.astype(x.dtype), self.eps)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     """Rotary position embedding over the last (head_dim) axis.
 
-    x: [B, S, H, D]; positions: [B, S].
+    x: [B, S, H, D]; positions: [B, S]. The gradient is the turn back,
+    `_turn` with the sines negated: autodiff's values bit for bit, made
+    from the cotangent in x's dtype. (Autodiff's starts from a float32
+    cast of it, which XLA then copies into the layout it gives the
+    halves: twice the bytes of the same copy in bfloat16.)
     """
+    return _turn(x, positions, theta, 1.0)
+
+
+def _turn(x, positions, theta, sign):
     d = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
     cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    sin = sign * jnp.sin(angles)[:, :, None, :]
+    # Split before the cast, for the same reason: XLA copies the halves.
+    x1, x2 = (h.astype(jnp.float32) for h in jnp.split(x, 2, axis=-1))
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def _rope_fwd(x, positions, theta):
+    return rope(x, positions, theta), positions
+
+
+def _rope_bwd(theta, positions, g):
+    return _turn(g, positions, theta, -1.0), None
+
+
+rope.defvjp(_rope_fwd, _rope_bwd)
 
 
 class LoRADense(nn.Module):

@@ -9,12 +9,16 @@ TPU-first hot-op design the BERT/Llama baseline configs need:
   materialization in either direction. Under a mask that empties tiles by
   their indices (causal, a description) a kernel's grid walks only the tiles
   that hold a visible pair (`tile_walk`: a schedule in SMEM), and a tile in
-  which every pair is visible computes no mask. Natively supports:
+  which every pair is visible computes no mask. Every kernel reads q, k,
+  v and dO where the projections wrote them, [B, S, H*D] (a free view of
+  [B, S, H, D]), and writes o, dQ, dK and dV there: a step's heads are a
+  run of columns (`_columns`, `_step_heads`), so XLA moves nothing in or
+  out of the kernels. Natively supports:
     * GQA — K/V carry Hkv < H heads and are NEVER repeat-expanded: the
-      query heads are viewed as [B, Hkv, rep, S, D] and the kv BlockSpec
-      index maps simply ignore the rep axis, so each kv tile is fetched
-      once per group and dK/dV accumulate across the group's rep
-      (sequential) grid dimension.
+      query heads of a K/V group are one run of columns, the kv BlockSpec
+      index maps ignore which of the group's heads a step holds, so each
+      kv tile is fetched once per group and dK/dV accumulate across the
+      group's (sequential) grid dimension.
     * key-padding masks ([B, Sk] keep-mask) — the BERT fine-tune config's
       mask shape, streamed as one [1, blk_k] tile per k-block.
     * mask *descriptions* with per-query structure (`BlockDiffusionMask`:
@@ -27,9 +31,10 @@ TPU-first hot-op design the BERT/Llama baseline configs need:
       e.g. decode windows / ring-attention shards.
     * head_dim >= 64. At D 64 (BERT-base) two heads of an MHA layer share
       a tile's 128 lanes (`lane_pack`), so every tile moves in whole rows.
-  Per-row statistics (log-sum-exp, and delta in the backward) are stored
-  COMPACTLY as [B, G, rep, pack, Sq] fp32 with q-rows on the lane dimension
-  (one [pack, blk_q] tile per q-block) — not broadcast to 128 lanes in HBM.
+  Per-row statistics (log-sum-exp, and delta in the backward, which the
+  dQ kernel makes) are stored COMPACTLY as [B, H/pack, pack, Sq] fp32 with
+  q-rows on the lane dimension (one [pack, blk_q] tile per q-block) — not
+  broadcast to 128 lanes in HBM.
 - `tile_plan`: what one grid step of each kernel covers, chosen from the
   shape: the [blk_q, blk_k] score tile (multiples of 128 that divide the
   lengths; the largest that a stated VMEM budget holds, smaller under a
@@ -126,40 +131,46 @@ def lane_pack(H: int, Hkv: int, D: int) -> int:
     return 2 if H == Hkv and H % 2 == 0 and 2 * D <= _LANES else 1
 
 
-def _grouped_q(x, Hkv, pack=1):
-    """[B,S,H,D] -> [B, Hkv, rep, S, D]: query heads grouped by the kv head
-    they share, so kv index maps can drop the rep axis (GQA without repeat).
-    With ``pack`` heads to a tile's lanes: [B, H/pack, 1, S, pack*D]."""
+def _columns(x):
+    """[B, S, H, D] -> [B, S, H*D]: the heads side by side in the columns,
+    as the projections wrote them (a free view). The kernels read and write
+    this layout: head ``h`` is columns ``[h*D, (h+1)*D)``, so the query
+    heads of K/V group ``g`` are one run of columns, ``[g*rep*D,
+    (g+1)*rep*D)``, and ``pack`` heads that share a tile's lanes are one
+    slab of ``pack*D`` of them."""
     B, S, H, D = x.shape
-    x = x.reshape(B, S, H // pack, pack * D)
-    rep = H // Hkv
-    return x.transpose(0, 2, 1, 3).reshape(B, Hkv // pack, rep, S, pack * D)
+    return x.reshape(B, S, H * D)
 
 
-def _grouped_kv(x, pack=1):
-    """[B,S,Hkv,D] -> [B, Hkv, S, D] ([B, Hkv/pack, S, pack*D])."""
-    B, S, Hkv, D = x.shape
-    return x.reshape(B, S, Hkv // pack, pack * D).transpose(0, 2, 1, 3)
-
-
-def _ungroup_q(x, pack=1):
-    """[B, Hkv, rep, S, D] -> [B,S,H,D]."""
-    B, G, R, S, D = x.shape
-    return x.reshape(B, G * R, S, D).transpose(0, 2, 1, 3).reshape(
-        B, S, G * R * pack, D // pack)
-
-
-def _grouped_stats(x, Hkv, pack=1):
-    """[B,H,Sq] row statistics -> [B, Hkv/pack, rep, pack, Sq] fp32, one
-    [pack, Sq] tile of rows to a [Sq, pack*D] slab of heads."""
+def _slab_stats(x, pack=1):
+    """[B, H, Sq] row statistics -> [B, H/pack, pack, Sq] fp32, one
+    [pack, Sq] tile of rows to a slab of ``pack`` heads."""
     B, H, Sq = x.shape
-    return x.reshape(B, Hkv // pack, H // Hkv, pack, Sq).astype(jnp.float32)
+    return x.reshape(B, H // pack, pack, Sq).astype(jnp.float32)
 
 
-def _ungroup_kv(x, pack=1):
-    """[B, Hkv, S, D] -> [B,S,Hkv,D]."""
-    B, G, S, D = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(B, S, G * pack, D // pack)
+def _step_heads(H: int, Hkv: int, D: int) -> tuple:
+    """The query heads one grid step may hold, fewest first. A step's query
+    heads are one run of columns and its K/V heads another, each a block
+    that Mosaic moves only if its width is whole 128-lane tiles or the
+    whole row: heads of a batch row (MHA, in whole slabs of `lane_pack`
+    heads), else query heads of one K/V group, which share one K/V head's
+    columns; where that head is narrower than a tile and not the whole row
+    (GQA at D 64), whole groups, each with its K/V head."""
+    pack, rep = lane_pack(H, Hkv, D), H // Hkv
+    within = [h for h in _divisors(rep if rep > 1 else H)
+              if h % pack == 0 and _moves(H, Hkv, D, h)]
+    return tuple(within or [rep * g for g in _divisors(Hkv)
+                            if _moves(H, Hkv, D, rep * g)])
+
+
+def _moves(H: int, Hkv: int, D: int, heads: int) -> bool:
+    """Whether Mosaic moves the blocks of a step of ``heads`` query heads:
+    the query side's columns and the K/V side's (one K/V head to a group's
+    heads) are each whole 128-lane tiles or the whole row."""
+    kv = max(1, heads // (H // Hkv))
+    return all(w % _LANES == 0 or w == row
+               for w, row in ((heads * D, H * D), (kv * D, Hkv * D)))
 
 
 def _causal_tile_mask(s, q0, k0, offset, q_axis=0):
@@ -395,17 +406,21 @@ def _pass_rows(rows: int, cols: int) -> int:
 
 
 def step_vmem_bytes(kernel: str, tiles: KernelTiles, D: int, itemsize: int,
-                    kv_shared: bool, pack: int = 1) -> int:
+                    kv_shared: bool, pack: int = 1,
+                    group: Optional[int] = None) -> int:
     """VMEM one grid step of ``kernel`` holds, from the shapes alone: every
     operand and result tile twice (the pipeline's double buffer), the
     float32 accumulators once, and the float32 scores, probabilities and
     their rounded copies of one pass over one head (passes and heads run
     one after the other). The last dimension pads to 128 lanes (``pack``
-    heads share them); a row of statistics or mask pads to 8 sublanes."""
+    heads share them); a row of statistics or mask pads to 8 sublanes.
+    Query heads that share K/V (``kv_shared``) hold one K/V head, or one a
+    ``group`` of them where a step holds whole groups (`_step_heads`)."""
     blk_q, blk_k, heads = tiles
     slabs = -(-heads // pack)  # [rows, lanes] tiles on the query side
     q_tile = slabs * blk_q * _lane_pad(pack * D)
-    kv_tile = (1 if kv_shared else slabs) * blk_k * _lane_pad(pack * D)
+    kv_slabs = max(1, heads // (group or heads)) if kv_shared else slabs
+    kv_tile = kv_slabs * blk_k * _lane_pad(pack * D)
     rows = 2 * 8 * 4 * (slabs * blk_q + blk_k)  # lse (delta) and the mask
     if kernel == "fwd":      # q, o | k, v | acc, m, l | s, p and p rounded
         tiles_io = 2 * itemsize * (2 * q_tile + 2 * kv_tile) + rows
@@ -415,8 +430,8 @@ def step_vmem_bytes(kernel: str, tiles: KernelTiles, D: int, itemsize: int,
         tiles_io = 2 * itemsize * (2 * q_tile + 4 * kv_tile) + 2 * rows
         scratch = 4 * 2 * kv_tile
         temps = _pass_rows(blk_k, blk_q) * blk_q * (3 * 4 + 2 * itemsize)
-    elif kernel == "dq":     # q, dO, dq | k, v | dq | s, dp, ds
-        tiles_io = 2 * itemsize * (3 * q_tile + 2 * kv_tile) + 2 * rows
+    elif kernel == "dq":     # q, dO, o, dq | k, v | dq | s, dp, ds
+        tiles_io = 2 * itemsize * (4 * q_tile + 2 * kv_tile) + 2 * rows
         scratch = 4 * q_tile
         temps = _pass_rows(blk_q, blk_k) * blk_k * (3 * 4 + itemsize)
     else:
@@ -545,14 +560,15 @@ def tile_plan(Sq: int, Sk: int, D: int, H: int, Hkv: int, itemsize: int,
     settles for a smaller pair as the sequence grows. Ties go to the larger
     tile along the axis the kernel accumulates over. Then a step takes on
     query heads (of one batch row, or of one K/V group, which share the K/V
-    tile) until stepping is under ``STEP_SHARE`` of it or VMEM is full.
-    ``has_mask`` moves nothing yet: a key-padding row is 4 KB a step. A mask
-    description (``structure``) weighs in as the causal mask does, through
-    the tiles it leaves to run."""
+    tile; whole groups where one group's columns are too narrow for
+    Mosaic to move: `_step_heads`) until stepping is under ``STEP_SHARE``
+    of it or VMEM is full. ``has_mask`` moves nothing yet: a key-padding
+    row is 4 KB a step. A mask description (``structure``) weighs in as
+    the causal mask does, through the tiles it leaves to run."""
     del has_mask
     kv_shared = H != Hkv
     pack = lane_pack(H, Hkv, D)
-    head_axis = H // Hkv if kv_shared else H
+    step_heads = _step_heads(H, Hkv, D)
     plan = []
     for kernel in FlashPlan._fields:
         def cost(pair):
@@ -563,15 +579,14 @@ def tile_plan(Sq: int, Sk: int, D: int, H: int, Hkv: int, itemsize: int,
 
         def fits(blk_q, blk_k, heads):
             return step_vmem_bytes(kernel, KernelTiles(blk_q, blk_k, heads),
-                                   D, itemsize, kv_shared, pack) <= VMEM_BUDGET
+                                   D, itemsize, kv_shared, pack,
+                                   H // Hkv) <= VMEM_BUDGET
 
         pairs = [(bq, bk) for bq in _tiles_of(Sq) for bk in _tiles_of(Sk)
-                 if fits(bq, bk, pack)] or [(_LANES, _LANES)]
+                 if fits(bq, bk, step_heads[0])] or [(_LANES, _LANES)]
         blk_q, blk_k = min(pairs, key=cost)
-        heads = pack
-        for h in _divisors(head_axis):
-            if h % pack:
-                continue
+        heads = step_heads[0]
+        for h in step_heads:
             if not fits(blk_q, blk_k, h):
                 break
             heads = h
@@ -607,14 +622,16 @@ def _remember(plan: FlashPlan, Sq: int, Sk: int, causal: bool,
 
 # -------------------------------------------------------------- pallas kernel
 #
-# Every kernel sees its query-side tiles (q, o, dO, dQ) as [slabs, rows, D]
-# and their row statistics (lse, delta) as [slabs, pack, rows]: a slab is one
-# [rows, D] tile of ``pack`` heads side by side in its lanes (`lane_pack`; one
-# head where pack is 1), and a step's heads are its slabs' heads. K/V-side
-# tiles (k, v, dK, dV) carry the slab axis when the heads are heads of one
-# batch row (MHA) and none when they are the query heads of one K/V group
-# (GQA), which share one tile. Slabs, row chunks and a slab's heads run one
-# after the other, so the [rows, blk_k] temporaries are one head's.
+# Every kernel reads its operands and writes its results where the model
+# keeps them, [B, S, H*D] (`_columns`): a step's query-side blocks (q, o,
+# dO, dQ) are [rows, slabs*width] and its K/V-side blocks (k, v, dK, dV)
+# [rows, kv_slabs*width], a slab being ``width`` = pack*D columns of
+# ``pack`` heads side by side in a tile's lanes (`lane_pack`; one head where
+# pack is 1). Query slab h reads K/V slab h // (slabs / kv_slabs) (`_Slabs`):
+# its own for MHA, the one K/V head for the query heads of one group. Row
+# statistics (lse, delta) are [slabs, pack, rows]. Slabs, row chunks and a
+# slab's heads run one after the other, so the [rows, blk_k] temporaries are
+# one head's.
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
@@ -625,9 +642,9 @@ def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _each(n, body):
-    """``body(i)`` for i in range(n), in order."""
-    if n <= _UNROLL:
+def _each(n, body, unroll=False):
+    """``body(i)`` for i in range(n), in order (unrolled where asked)."""
+    if n <= _UNROLL or unroll:
         for i in range(n):
             body(i)
     else:
@@ -662,9 +679,40 @@ def _as_row(col):
     return jnp.broadcast_to(col, (col.shape[0], _LANES)).T[:1]
 
 
-def _each_chunk(slabs, chunks, body):
+class _Slabs(NamedTuple):
+    """How a step's heads lie in its blocks' columns: ``count`` query-side
+    slabs of ``width`` columns each, and one K/V-side slab to each ``per``
+    of them."""
+    count: int
+    width: int
+    per: int
+
+    @classmethod
+    def of(cls, q_ref, k_ref, count):
+        width = q_ref.shape[-1] // count
+        return cls(count, width, count // (k_ref.shape[-1] // width))
+
+    def cols(self, h):
+        """The columns of slab ``h`` of a block."""
+        return _chunk(h, self.width)
+
+    def kv(self, h):
+        """The columns of the K/V-side slab that query-side slab ``h``
+        reads."""
+        return _chunk(h // self.per, self.width)
+
+    def each(self, body, n=None):
+        """``body(h)`` for each of the first ``n`` slabs (all of the query
+        side's), in order. A loop over them takes a traced ``h`` only where
+        a slab is whole lane tiles: Mosaic takes a traced column offset in
+        multiples of 128 alone."""
+        _each(self.count if n is None else n, body,
+              unroll=self.width % _LANES != 0)
+
+
+def _each_chunk(slabs: _Slabs, chunks, body):
     """``body(h, c)`` for every row chunk c of every slab h of a step."""
-    _each(slabs, lambda h: _each(chunks, lambda c: body(h, c)))
+    slabs.each(lambda h: _each(chunks, lambda c: body(h, c)))
 
 
 def _scores(q, k_blk, q0, k0, causal, sm_scale, offset, mask_ref,
@@ -766,12 +814,6 @@ def _on_tile(step: _Step, body) -> None:
         pl.when(step.kind == _WHOLE)(lambda: body(False))
 
 
-def _kv(ref, h, rows=slice(None)):
-    """Index of ``rows`` of head ``h`` in a K/V-side tile: its leading axis
-    carries the step's heads, or it has none and the heads share it."""
-    return (h, rows) if len(ref.shape) == 3 else (rows,)
-
-
 def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
                       structure=None):
     """One (b, head block, q-block, k-block) program: K/V stream through the
@@ -784,10 +826,10 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
     where one k-block is the whole row (``one_pass``) there is no state to
     keep and no scratch: the softmax is taken once.
 
-    Refs: q [slabs, BLK_Q, D]; k/v [slabs, BLK_K, D] or, for a GQA group,
-    [BLK_K, D]; (mask [1, BLK_K] int32); o [slabs, BLK_Q, D]; lse
+    Refs: q [BLK_Q, slabs*width]; k/v [BLK_K, kv_slabs*width] (`_Slabs`);
+    (mask [1, BLK_K] int32); o [BLK_Q, slabs*width]; lse
     [slabs, pack, BLK_Q] (q-rows on lanes — compact, no 128x pad); scratch
-    acc [slabs, BLK_Q, D], m/l [slabs, pack, BLK_Q, 128] fp32.
+    acc [slabs, BLK_Q, width], m/l [slabs, pack, BLK_Q, 128] fp32.
     """
     from jax.experimental import pallas as pl
 
@@ -796,22 +838,22 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
     mask_ref = refs[3] if has_mask else None
     o_ref, lse_ref = refs[3 + has_mask:5 + has_mask]
 
-    slabs, blk_q, _ = q_ref.shape
-    blk_k = k_ref.shape[-2]
-    pack = lse_ref.shape[1]
+    slabs, pack, blk_q = lse_ref.shape
+    sl = _Slabs.of(q_ref, k_ref, slabs)
+    blk_k = k_ref.shape[0]
     rows = _pass_rows(blk_q, blk_k)
 
     def scores(masked, h, c, j):
-        return _scores(_lanes_of(j, pack, q_ref[h, _chunk(c, rows)]),
-                       k_ref[_kv(k_ref, h)], step.qi * blk_q + c * rows,
+        return _scores(_lanes_of(j, pack, q_ref[_chunk(c, rows), sl.cols(h)]),
+                       k_ref[:, sl.kv(h)], step.qi * blk_q + c * rows,
                        step.kb * blk_k, causal and masked, sm_scale, offset,
                        mask_ref, structure if masked else None)
 
     def values(h, j, p):
-        v_blk = _lanes_of(j, pack, v_ref[_kv(v_ref, h)])
+        v_blk = _lanes_of(j, pack, v_ref[:, sl.kv(h)])
         return _dot(p.astype(v_blk.dtype), v_blk, _NN)
 
-    each_chunk = functools.partial(_each_chunk, slabs, blk_q // rows)
+    each_chunk = functools.partial(_each_chunk, sl, blk_q // rows)
 
     if one_pass:
         def whole_rows(masked, h, c):
@@ -825,7 +867,7 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
                 o = values(h, j, p) / l  # zero in the other head's lanes
                 out = o if out is None else out + o
                 lse_ref[h, j:j + 1, r] = _as_row(m + jnp.log(l))
-            o_ref[h, r] = out.astype(o_ref.dtype)
+            o_ref[r, sl.cols(h)] = out.astype(o_ref.dtype)
 
         _on_tile(step, lambda masked: each_chunk(
             functools.partial(whole_rows, masked)))
@@ -872,51 +914,53 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
                 out = o if out is None else out + o
                 lse_ref[h, j:j + 1, r] = _as_row(m_ref[h, j, r][:, :1]
                                                  + jnp.log(l_safe))
-            o_ref[h, r] = out.astype(o_ref.dtype)
+            o_ref[r, sl.cols(h)] = out.astype(o_ref.dtype)
 
         each_chunk(finish)
 
 
-def _head_blocks(Sq, Sk, G, R, tiles, pack):
-    """How a step's heads lie in [B, G, R, ...]: (slabs over G, slabs over
-    R, the K/V block's leading dims), a slab being a [rows, lanes] tile of
-    ``pack`` heads. MHA (R == 1) blocks the G axis and K/V with it; GQA
-    blocks the rep axis over one shared K/V tile. One head a step of
-    lane-packed heads is one slab, its two heads. Tiles or heads that do
-    not divide the shape are refused."""
+def _head_blocks(Sq, Sk, H, Hkv, D, tiles):
+    """How a step's heads lie in the columns: (G, R, hg, hr). A row holds G
+    K/V-side slabs (K/V heads; for MHA, slabs of `lane_pack` heads) and R
+    query-side slabs to each; a step holds hg of the first and hr of the
+    second: MHA (R == 1) blocks slabs with their K/V, GQA the query heads
+    of one group over its K/V head, or whole groups. A step takes the
+    fewest heads Mosaic moves (`_moves`) that are as many as asked or a
+    multiple of them. Tiles or heads that do not divide the shape are
+    refused."""
     if Sq % tiles.blk_q or Sk % tiles.blk_k:
         raise ValueError("tiles q{} k{} do not divide Sq={}, Sk={}".format(
             tiles.blk_q, tiles.blk_k, Sq, Sk))
-    slabs = -(-tiles.heads // pack)
+    pack, R = lane_pack(H, Hkv, D), H // Hkv
+    heads = -(-tiles.heads // pack) * pack
     if R == 1:
-        if G % slabs:
+        if H % heads:
             raise ValueError("{} heads a step do not divide H={}".format(
-                tiles.heads, G * pack))
-        return slabs, 1, (None, slabs)
-    if R % slabs:
+                tiles.heads, H))
+    elif R % heads and (heads % R or H % heads):
         raise ValueError("{} heads a step do not divide the {} query heads "
                          "of a K/V group".format(tiles.heads, R))
-    return 1, slabs, (None, None)
-
-
-def _q_side(hg, hr, inner):
-    """Block shape of a query-side operand [B, G, R, *inner]: the step's
-    heads on whichever axis carries them, the other squeezed."""
-    return (None, hg, None) + inner if hr == 1 else (None, None, hr) + inner
+    heads = next(h for h in range(heads, H + 1, heads)
+                 if H % h == 0 and (R % h == 0 or h % R == 0)
+                 and _moves(H, Hkv, D, h))
+    if R == 1:
+        return H // pack, 1, heads // pack, 1
+    return Hkv, R, max(1, heads // R), min(heads, R)
 
 
 # Where a tile's blocks lie in the kernels' operands, from its (b, g, r, qi,
-# kb): query-side tiles, K/V-side tiles, row statistics, the key-padding row.
-def _q_at(b, g, r, qi, kb):
-    return b, g, r, qi, 0
+# kb), ``reps`` blocks of query-side slabs to a K/V-side block: the query
+# side's columns, the K/V side's, the row statistics, the key-padding row.
+def _q_at(reps):
+    return lambda b, g, r, qi, kb: (b, qi, g * reps + r)
 
 
 def _kv_at(b, g, r, qi, kb):
-    return b, g, kb, 0
+    return b, kb, g
 
 
-def _stat_at(b, g, r, qi, kb):
-    return b, g, r, 0, qi
+def _stat_at(reps):
+    return lambda b, g, r, qi, kb: (b, g * reps + r, 0, qi)
 
 
 def _mask_at(b, g, r, qi, kb):
@@ -955,33 +999,34 @@ def _kernel_grid(walk: Optional[TileWalk], lead: tuple, parallel: tuple,
         lambda at: lambda *ids: at(*to_tile(*ids))
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "tiles", "pack",
+@functools.partial(jax.jit, static_argnames=("causal", "tiles", "heads",
                                              "interpret", "structure"))
-def _flash_fwd(qg, kg, vg, mask, causal, tiles, pack, interpret,
+def _flash_fwd(q, k, v, mask, causal, tiles, heads, interpret,
                structure=None):
-    """qg: [B,G,R,Sq,D]; kg/vg: [B,G,Sk,D], ``pack`` heads to the D lanes;
+    """q: [B,Sq,H*D]; k/v: [B,Sk,Hkv*D] (`_columns`), ``heads`` (H, Hkv);
     mask: [B,1,Sk] int32 or None.
-    Returns (out [B,G,R,Sq,D], lse [B,G,R,pack,Sq] fp32)."""
+    Returns (out [B,Sq,H*D], lse [B,H/pack,pack,Sq] fp32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, G, R, Sq, D = qg.shape
-    Sk = kg.shape[2]
+    B, Sq, _ = q.shape
+    Sk = k.shape[1]
+    H, Hkv = heads
+    D = q.shape[2] // H
+    pack = lane_pack(H, Hkv, D)
     blk_q, blk_k, _ = tiles
-    hg, hr, kv_lead = _head_blocks(Sq, Sk, G, R, tiles, pack)
-    slabs = hg * hr
+    G, R, hg, hr = _head_blocks(Sq, Sk, H, Hkv, D, tiles)
+    slabs, width = hg * hr, pack * D
     offset = Sk - Sq
-    sm_scale = 1.0 / ((D // pack) ** 0.5)
+    sm_scale = 1.0 / (D ** 0.5)
     grid, semantics, prefetch, on = _kernel_grid(
         tile_walk("fwd", Sq, Sk, blk_q, blk_k, causal, structure),
         (B, G // hg, R // hr), (Sq // blk_q,), (Sk // blk_k,))
 
-    q_spec = pl.BlockSpec(_q_side(hg, hr, (blk_q, D)),
-                          on(_q_at))
-    kv_spec = pl.BlockSpec(kv_lead + (blk_k, D),
-                           on(_kv_at))
+    q_spec = pl.BlockSpec((None, blk_q, slabs * width), on(_q_at(R // hr)))
+    kv_spec = pl.BlockSpec((None, blk_k, hg * width), on(_kv_at))
     in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qg, kg, vg]
+    operands = [q, k, v]
     if mask is not None:
         in_specs.append(pl.BlockSpec((None, 1, blk_k),
                                      on(_mask_at)))
@@ -1003,18 +1048,18 @@ def _flash_fwd(qg, kg, vg, mask, causal, tiles, pack, interpret,
             in_specs=in_specs,
             out_specs=[
                 q_spec,
-                pl.BlockSpec(_q_side(hg, hr, (pack, blk_q)),
-                             on(_stat_at)),
+                pl.BlockSpec((None, slabs, pack, blk_q),
+                             on(_stat_at(R // hr))),
             ],
             scratch_shapes=[] if one_pass else [
-                pltpu.VMEM((slabs, blk_q, D), jnp.float32),
+                pltpu.VMEM((slabs, blk_q, width), jnp.float32),
                 pltpu.VMEM((slabs, pack, blk_q, _LANES), jnp.float32),
                 pltpu.VMEM((slabs, pack, blk_q, _LANES), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, G, R, Sq, D), qg.dtype),
-            jax.ShapeDtypeStruct((B, G, R, pack, Sq), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((B, H // pack, pack, Sq), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             # b/g/r/qi programs are independent (megacore-splittable); the
@@ -1068,25 +1113,32 @@ def _canon_mask(mask, B, Sk):
     return m.astype(jnp.int32)
 
 
+def _flash_fwd_cols(q, k, v, mask, causal, tiles, interpret,
+                    structure=None):
+    """The forward kernel on q [B,Sq,H,D], k/v [B,Sk,Hkv,D], read in place:
+    (out [B,Sq,H*D], lse [B,H/pack,pack,Sq] fp32)."""
+    mask3 = _canon_mask(mask, q.shape[0], k.shape[1])
+    return _flash_fwd(_columns(q), _columns(k), _columns(v), mask3, causal,
+                      tiles, (q.shape[2], k.shape[2]), interpret, structure)
+
+
 def _flash_fwd_4d(q, k, v, mask, causal, tiles, interpret, structure=None):
-    B, Sq, H, D = q.shape
-    Hkv = k.shape[2]
-    pack = lane_pack(H, Hkv, D)
-    mask3 = _canon_mask(mask, B, k.shape[1])
-    out_g, lse = _flash_fwd(_grouped_q(q, Hkv, pack), _grouped_kv(k, pack),
-                            _grouped_kv(v, pack), mask3, causal, tiles, pack,
-                            interpret, structure)
-    return _ungroup_q(out_g, pack), lse
+    """`_flash_fwd_cols` with out as [B,Sq,H,D]."""
+    out, lse = _flash_fwd_cols(q, k, v, mask, causal, tiles, interpret,
+                               structure)
+    return out.reshape(q.shape), lse
 
 
 def _flash_fwd_rule(q, k, v, mask, causal, plan, interpret, structure=None):
-    out, lse = _flash_fwd_4d(q, k, v, mask, causal, plan.fwd, interpret,
-                             structure)
+    out, lse = _flash_fwd_cols(q, k, v, mask, causal, plan.fwd, interpret,
+                               structure)
     # Named here, so that the primal and the residuals are the named values:
     # naming the output in the caller would keep `out` and still run the
-    # kernel again for `lse`.
+    # kernel again for `lse`. What is named and kept is the kernel's own
+    # [B,Sq,H*D]: XLA lays out a [B,Sq,H,D] value kept across the pass on
+    # its own, and would copy it to and from the kernels' layout.
     out, lse = map(checkpoint_name, (out, lse), REMAT_KEEP)
-    return out, (q, k, v, mask, out, lse)
+    return out.reshape(q.shape), (q, k, v, mask, out, lse)
 
 
 def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset,
@@ -1111,9 +1163,9 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
         mask_ref = None
 
-    slabs, blk_q, _ = q_ref.shape
-    blk_k = k_ref.shape[-2]
-    pack = lse_ref.shape[1]
+    slabs, pack, blk_q = lse_ref.shape
+    sl = _Slabs.of(q_ref, k_ref, slabs)
+    blk_k = k_ref.shape[0]
     qi, kb = step.qi, step.kb
     rows = _pass_rows(blk_k, blk_q)
 
@@ -1124,11 +1176,11 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset,
 
     def contribute(masked, h, c):
         keys = _chunk(c, rows)
-        k_blk, v_blk = k_ref[_kv(k_ref, h, keys)], v_ref[_kv(v_ref, h, keys)]
+        k_blk, v_blk = k_ref[keys, sl.kv(h)], v_ref[keys, sl.kv(h)]
         dk = dv = 0.0
         for j in range(pack):
-            q = _lanes_of(j, pack, q_ref[h])
-            do = _lanes_of(j, pack, do_ref[h])
+            q = _lanes_of(j, pack, q_ref[:, sl.cols(h)])
+            do = _lanes_of(j, pack, do_ref[:, sl.cols(h)])
             s = _dot(k_blk, q, _NT) * sm_scale
             if causal and masked:
                 s = _causal_tile_mask(s, qi * blk_q, kb * blk_k + c * rows,
@@ -1142,38 +1194,40 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset,
             ds = p * (_dot(v_blk, do, _NT) - delta_ref[h, j:j + 1])
             dv += _dot(p.astype(do.dtype), do, _NN)  # head j's lanes alone
             dk += _dot(ds.astype(q.dtype), q, _NN)
-        dv_acc[_kv(dv_acc, h, keys)] += dv
-        dk_acc[_kv(dk_acc, h, keys)] += dk
+        dv_acc[h // sl.per, keys] += dv
+        dk_acc[h // sl.per, keys] += dk
 
     _on_tile(step, lambda masked: _each_chunk(
-        slabs, blk_k // rows, functools.partial(contribute, masked)))
+        sl, blk_k // rows, functools.partial(contribute, masked)))
 
     @pl.when(step.last())
     def _finalize():
-        # ds lacked the scale; dK takes it once, on [blk_k, D].
-        dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        def write(g):
+            # ds lacked the scale; dK takes it once, on [blk_k, width].
+            dk_ref[:, sl.cols(g)] = (dk_acc[g] * sm_scale).astype(dk_ref.dtype)
+            dv_ref[:, sl.cols(g)] = dv_acc[g].astype(dv_ref.dtype)
+
+        sl.each(write, dk_acc.shape[0])
 
 
 def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset,
                          structure=None):
     """grid (B, head block, r, qi, kb): one Q tile a head per program group;
     stream K/V tiles through the sequential kb dimension, accumulating dQ
-    in VMEM."""
+    in VMEM. When an accumulator opens, the kernel makes delta =
+    rowsum(dO o) of each head from the forward's output o: a second
+    result, which the steps that follow read back and the dK/dV kernel
+    reads after this one."""
     from jax.experimental import pallas as pl
 
     step, refs = _step_and_refs(refs, causal, structure, 3, 4, (4,))
-    if has_mask:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
-         dq_ref, dq_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, dq_acc) = refs
-        mask_ref = None
+    q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref = refs[:6]
+    mask_ref = refs[6] if has_mask else None
+    dq_ref, delta_ref, dq_acc = refs[6 + has_mask:]
 
-    slabs, blk_q, _ = q_ref.shape
-    blk_k = k_ref.shape[-2]
-    pack = lse_ref.shape[1]
+    slabs, pack, blk_q = lse_ref.shape
+    sl = _Slabs.of(q_ref, k_ref, slabs)
+    blk_k = k_ref.shape[0]
     qi, kb = step.qi, step.kb
     rows = _pass_rows(blk_q, blk_k)
 
@@ -1181,13 +1235,23 @@ def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
+        def make_delta(h, c):
+            r = _chunk(c, rows)
+            prod = do_ref[r, sl.cols(h)].astype(jnp.float32) \
+                * o_ref[r, sl.cols(h)].astype(jnp.float32)
+            for j in range(pack):
+                delta_ref[h, j:j + 1, r] = _as_row(jnp.sum(
+                    _lanes_of(j, pack, prod), axis=1, keepdims=True))
+
+        _each_chunk(sl, blk_q // rows, make_delta)
+
     def contribute(masked, h, c):
         r = _chunk(c, rows)
-        k_blk, v_blk = k_ref[_kv(k_ref, h)], v_ref[_kv(v_ref, h)]
+        k_blk, v_blk = k_ref[:, sl.kv(h)], v_ref[:, sl.kv(h)]
         dq = 0.0
         for j in range(pack):
-            q = _lanes_of(j, pack, q_ref[h, r])
-            do = _lanes_of(j, pack, do_ref[h, r])
+            q = _lanes_of(j, pack, q_ref[r, sl.cols(h)])
+            do = _lanes_of(j, pack, do_ref[r, sl.cols(h)])
             s = _scores(q, k_blk, qi * blk_q + c * rows, kb * blk_k,
                         causal and masked, sm_scale, offset, mask_ref,
                         structure if masked else None)
@@ -1199,53 +1263,93 @@ def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset,
         dq_acc[h, r] += dq
 
     _on_tile(step, lambda masked: _each_chunk(
-        slabs, blk_q // rows, functools.partial(contribute, masked)))
+        sl, blk_q // rows, functools.partial(contribute, masked)))
 
     @pl.when(step.last())
     def _finalize():
-        dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+        def write(h):
+            dq_ref[:, sl.cols(h)] = (dq_acc[h] * sm_scale).astype(dq_ref.dtype)
+
+        sl.each(write)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "plan", "pack",
+@functools.partial(jax.jit, static_argnames=("causal", "plan", "heads",
                                              "interpret", "structure"))
-def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, plan, pack,
+def _flash_bwd(q, k, v, do, lse, out, mask, causal, plan, heads,
                interpret, structure=None):
-    """Pallas flash backward. qg/dog: [B,G,R,Sq,D]; kg/vg: [B,G,Sk,D],
-    ``pack`` heads to the D lanes; lse/delta: [B,G,R,pack,Sq] fp32
-    (compact); mask: [B,1,Sk] int32 or None.
-    Returns (dq [B,G,R,Sq,D], dk/dv [B,G,Sk,D])."""
+    """Pallas flash backward: dQ (which makes delta from dO and out), then
+    dK/dV. q/do/out: [B,Sq,H*D]; k/v: [B,Sk,Hkv*D] (`_columns`), ``heads``
+    (H, Hkv); lse: [B,H/pack,pack,Sq] fp32 (compact); mask: [B,1,Sk] int32
+    or None. Returns (dq [B,Sq,H*D], dk/dv [B,Sk,Hkv*D])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, G, R, Sq, D = qg.shape
-    Sk = kg.shape[2]
+    B, Sq, _ = q.shape
+    Sk = k.shape[1]
+    H, Hkv = heads
+    D = q.shape[2] // H
+    pack = lane_pack(H, Hkv, D)
+    width = pack * D
     offset = Sk - Sq
-    sm_scale = 1.0 / ((D // pack) ** 0.5)
+    sm_scale = 1.0 / (D ** 0.5)
     has_mask = mask is not None
     kernel_args = dict(causal=causal, sm_scale=sm_scale, has_mask=has_mask,
                        offset=offset, structure=structure)
 
-    # --- dK/dV: grid (B, G, kb, r, qi); r+qi sequential, accumulating.
-    blk_q, blk_k, _ = plan.dkdv
-    hg, hr, kv_lead = _head_blocks(Sq, Sk, G, R, plan.dkdv, pack)
+    def specs(tiles, on, R, hg, hr):
+        """The blocks of the query side, the K/V side and the statistics."""
+        return (pl.BlockSpec((None, tiles.blk_q, hg * hr * width),
+                             on(_q_at(R // hr))),
+                pl.BlockSpec((None, tiles.blk_k, hg * width), on(_kv_at)),
+                pl.BlockSpec((None, hg * hr, pack, tiles.blk_q),
+                             on(_stat_at(R // hr))))
+
+    # --- dQ: grid (B, G, r, qi, kb); kb sequential, accumulating.
+    blk_q, blk_k, _ = plan.dq
+    G, R, hg, hr = _head_blocks(Sq, Sk, H, Hkv, D, plan.dq)
     grid, semantics, prefetch, on = _kernel_grid(
-        tile_walk("dkdv", Sq, Sk, blk_q, blk_k, causal, structure, R // hr),
-        (B, G // hg), (Sk // blk_k,), (R // hr, Sq // blk_q),
-        lambda b, g, kb, r, qi: (b, g, r, qi, kb))
-    q_by_inner = pl.BlockSpec(_q_side(hg, hr, (blk_q, D)),
-                              on(_q_at))
-    kv_by_outer = pl.BlockSpec(kv_lead + (blk_k, D),
-                               on(_kv_at))
-    stat_by_inner = pl.BlockSpec(_q_side(hg, hr, (pack, blk_q)),
-                                 on(_stat_at))
-    in_specs = [q_by_inner, kv_by_outer, kv_by_outer, q_by_inner,
-                stat_by_inner, stat_by_inner]
-    operands = [qg, kg, vg, dog, lse, delta]
+        tile_walk("dq", Sq, Sk, blk_q, blk_k, causal, structure),
+        (B, G // hg, R // hr), (Sq // blk_q,), (Sk // blk_k,))
+    q_spec, kv_spec, stat_spec = specs(plan.dq, on, R, hg, hr)
+    in_specs = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, q_spec]
+    operands = [q, k, v, do, lse, out]
     if has_mask:
         in_specs.append(pl.BlockSpec((None, 1, blk_k),
                                      on(_mask_at)))
         operands.append(mask)
-    kv_acc = tuple(n for n in kv_lead if n is not None) + (blk_k, D)
+
+    dq, delta = pl.pallas_call(
+        functools.partial(_flash_bwd_dq_kernel, **kernel_args),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[q_spec, stat_spec],
+            scratch_shapes=[pltpu.VMEM((hg * hr, blk_q, width),
+                                       jnp.float32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        interpret=interpret,
+        name="flash_bwd_dq",
+    )(*prefetch, *operands)
+
+    # --- dK/dV: grid (B, G, kb, r, qi); r+qi sequential, accumulating.
+    blk_q, blk_k, _ = plan.dkdv
+    G, R, hg, hr = _head_blocks(Sq, Sk, H, Hkv, D, plan.dkdv)
+    grid, semantics, prefetch, on = _kernel_grid(
+        tile_walk("dkdv", Sq, Sk, blk_q, blk_k, causal, structure, R // hr),
+        (B, G // hg), (Sk // blk_k,), (R // hr, Sq // blk_q),
+        lambda b, g, kb, r, qi: (b, g, r, qi, kb))
+    q_by_inner, kv_by_outer, stat_by_inner = specs(plan.dkdv, on, R, hg, hr)
+    in_specs = [q_by_inner, kv_by_outer, kv_by_outer, q_by_inner,
+                stat_by_inner, stat_by_inner]
+    operands = [q, k, v, do, lse, delta]
+    if has_mask:
+        in_specs.append(pl.BlockSpec((None, 1, blk_k),
+                                     on(_mask_at)))
+        operands.append(mask)
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, **kernel_args),
@@ -1255,74 +1359,34 @@ def _flash_bwd(qg, kg, vg, dog, lse, delta, mask, causal, plan, pack,
             in_specs=in_specs,
             out_specs=[kv_by_outer, kv_by_outer],
             scratch_shapes=[
-                pltpu.VMEM(kv_acc, jnp.float32),
-                pltpu.VMEM(kv_acc, jnp.float32),
+                pltpu.VMEM((hg, blk_k, width), jnp.float32),
+                pltpu.VMEM((hg, blk_k, width), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, G, Sk, D), kg.dtype),
-            jax.ShapeDtypeStruct((B, G, Sk, D), vg.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         name="flash_bwd_dkdv",
     )(*prefetch, *operands)
-
-    # --- dQ: grid (B, G, r, qi, kb); kb sequential, accumulating.
-    blk_q, blk_k, _ = plan.dq
-    hg, hr, kv_lead = _head_blocks(Sq, Sk, G, R, plan.dq, pack)
-    grid, semantics, prefetch, on = _kernel_grid(
-        tile_walk("dq", Sq, Sk, blk_q, blk_k, causal, structure),
-        (B, G // hg, R // hr), (Sq // blk_q,), (Sk // blk_k,))
-    q_spec = pl.BlockSpec(_q_side(hg, hr, (blk_q, D)),
-                          on(_q_at))
-    kv_spec = pl.BlockSpec(kv_lead + (blk_k, D),
-                           on(_kv_at))
-    stat_spec = pl.BlockSpec(_q_side(hg, hr, (pack, blk_q)),
-                             on(_stat_at))
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec]
-    operands = [qg, kg, vg, dog, lse, delta]
-    if has_mask:
-        in_specs.append(pl.BlockSpec((None, 1, blk_k),
-                                     on(_mask_at)))
-        operands.append(mask)
-
-    (dq,) = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **kernel_args),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=[q_spec],
-            scratch_shapes=[pltpu.VMEM((hg * hr, blk_q, D), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((B, G, R, Sq, D), qg.dtype)],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(*prefetch, *operands)
     return dq, dk, dv
 
 
 def _flash_bwd_rule(causal, plan, interpret, structure, res, g):
-    """Flash backward as two Pallas kernels (dK/dV then dQ), recomputing
-    probabilities from the saved log-sum-exp — the S x S matrix never
-    materializes and VMEM holds one tile pair a head at a time."""
+    """Flash backward as two Pallas kernels (dQ, which makes delta on the
+    way, then dK/dV), recomputing probabilities from the saved log-sum-exp
+    — the S x S matrix never materializes and VMEM holds one tile pair a
+    head at a time."""
     q, k, v, mask, out, lse = res
-    B, Sq, H, D = q.shape
-    Hkv = k.shape[2]
-    pack = lane_pack(H, Hkv, D)
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)  # [B,Sq,H]
-    mask3 = _canon_mask(mask, B, k.shape[1])
-    dqg, dkg, dvg = _flash_bwd(
-        _grouped_q(q, Hkv, pack), _grouped_kv(k, pack), _grouped_kv(v, pack),
-        _grouped_q(g, Hkv, pack), lse,
-        _grouped_stats(delta.transpose(0, 2, 1), Hkv, pack), mask3,
-        causal, plan, pack, interpret, structure)
-    return (_ungroup_q(dqg, pack).astype(q.dtype),
-            _ungroup_kv(dkg, pack).astype(k.dtype),
-            _ungroup_kv(dvg, pack).astype(v.dtype),
+    mask3 = _canon_mask(mask, q.shape[0], k.shape[1])
+    dq, dk, dv = _flash_bwd(
+        _columns(q), _columns(k), _columns(v), _columns(g), lse, out, mask3,
+        causal, plan, (q.shape[2], k.shape[2]), interpret, structure)
+    return (dq.reshape(q.shape).astype(q.dtype),
+            dk.reshape(k.shape).astype(k.dtype),
+            dv.reshape(v.shape).astype(v.dtype),
             None)
 
 
@@ -1345,24 +1409,23 @@ def flash_block_fwd(q, k, v, causal: bool = True, blk_q: int = 128,
     return out, lse.reshape(B, H, Sq)
 
 
-def flash_block_bwd(q, k, v, do, lse, delta, causal: bool = True,
+def flash_block_bwd(q, k, v, do, lse, out, causal: bool = True,
                     blk_q: int = 128, blk_k: int = 128,
                     interpret: bool = False):
     """One block of the ring-attention backward: given the GLOBAL per-row
-    log-sum-exp and delta = sum(dO*O), each (Q shard, K/V shard) pair's
-    gradient contribution is independent and additive — p recomputed from
-    the global lse is the true global probability for this block.
-    lse/delta: [B,H,Sq] fp32. Returns (dq, dk, dv) fp32."""
-    Hkv = k.shape[2]
-    pack = lane_pack(q.shape[2], Hkv, q.shape[3])
-    dqg, dkg, dvg = _flash_bwd(
-        _grouped_q(q, Hkv, pack), _grouped_kv(k, pack), _grouped_kv(v, pack),
-        _grouped_q(do, Hkv, pack), _grouped_stats(lse, Hkv, pack),
-        _grouped_stats(delta, Hkv, pack), None,
-        causal, FlashPlan.explicit(blk_q, blk_k), pack, interpret)
-    return (_ungroup_q(dqg, pack).astype(jnp.float32),
-            _ungroup_kv(dkg, pack).astype(jnp.float32),
-            _ungroup_kv(dvg, pack).astype(jnp.float32))
+    log-sum-exp and output, each (Q shard, K/V shard) pair's gradient
+    contribution is independent and additive — p recomputed from the
+    global lse is the true global probability for this block, and delta =
+    sum(dO*O) is made from the global output. lse: [B,H,Sq] fp32; out:
+    [B,Sq,H,D]. Returns (dq, dk, dv) fp32."""
+    H, Hkv = q.shape[2], k.shape[2]
+    dq, dk, dv = _flash_bwd(
+        _columns(q), _columns(k), _columns(v), _columns(do),
+        _slab_stats(lse, lane_pack(H, Hkv, q.shape[3])), _columns(out), None,
+        causal, FlashPlan.explicit(blk_q, blk_k), (H, Hkv), interpret)
+    return (dq.reshape(q.shape).astype(jnp.float32),
+            dk.reshape(k.shape).astype(jnp.float32),
+            dv.reshape(v.shape).astype(jnp.float32))
 
 
 # ----------------------------------------------------------------- dispatch

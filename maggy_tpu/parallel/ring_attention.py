@@ -193,10 +193,8 @@ def _ring_flash_bwd_rule(axis_name, n, causal, interpret, res, do):
     home. dQ accumulates locally."""
     q_blk, k_blk, v_blk, out, lse = res
     idx = jax.lax.axis_index(axis_name)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1)  # [B,H,Sq]
 
-    dq, dk, dv = flash_block_bwd(q_blk, k_blk, v_blk, do, lse, delta,
+    dq, dk, dv = flash_block_bwd(q_blk, k_blk, v_blk, do, lse, out,
                                  causal=causal, interpret=interpret)
     k_cur, v_cur, dk_cur, dv_cur = _rotate(
         [k_blk, v_blk, dk, dv], axis_name, n)
@@ -208,7 +206,7 @@ def _ring_flash_bwd_rule(axis_name, n, causal, interpret, res, do):
         def attend(args):
             dq, dk_cur, dv_cur = args
             dq2, dk2, dv2 = flash_block_bwd(
-                q_blk, k_cur, v_cur, do, lse, delta, causal=False,
+                q_blk, k_cur, v_cur, do, lse, out, causal=False,
                 interpret=interpret)
             return dq + dq2, dk_cur + dk2, dv_cur + dv2
 

@@ -562,3 +562,159 @@ def test_the_combine_kernel_does_not_grow_with_the_held_experts(v5e_device):
         v5e_device, width, held, top_k, products, "fwd")) for held in (4, 16))
     assert len(four) == len(sixteen)
     assert four.count("\n") == sixteen.count("\n")
+
+
+#: The kernels' operands and results by position (a walk's table, the first
+#: operand where a kernel walks, aside): what each one reads and writes.
+KERNEL_ROLES = {
+    "flash_fwd": (("q", "k", "v"), ("o",)),
+    "flash_bwd_dq": (("q", "k", "v", "do", "lse", "o"), ("dq",)),
+    "flash_bwd_dkdv": (("q", "k", "v", "do", "lse", "delta"), ("dk", "dv")),
+}
+
+
+def _boundary_relayouts(text):
+    """({kernel: count}, {(kernel, role): relayouts}) of an optimised HLO
+    module: the copies and transposes (and fusions of data movement alone)
+    that stand between the flash kernels and what produces their operands
+    or consumes their results, through bitcasts and tuple elements."""
+    import collections
+    import re
+
+    inst, users = {}, collections.defaultdict(list)
+    bodies, body = collections.defaultdict(set), None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            body = head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+|\(.*?\)) ([a-z\-]+)\((.*?)\)"
+                     r"(, .*)?$", line)
+        if not m:
+            continue
+        name, typ, op, args, rest = m.groups()
+        calls = re.search(r"calls=%([\w.\-]+)", rest or "")
+        index = re.search(r"index=(\d+)", rest or "")
+        inst[name] = (typ, op, re.findall(r"%([\w.\-]+)", args),
+                      calls and calls.group(1), index and int(index.group(1)))
+        bodies[body].add(op)
+        for operand in inst[name][2]:
+            users[operand].append(name)
+    movement = {"parameter", "copy", "transpose", "bitcast", "reshape",
+                "get-tuple-element", "tuple"}
+
+    def relayout(name):
+        _, op, _, calls, _ = inst[name]
+        return op in ("copy", "transpose") or op == "fusion" and bool(
+            bodies[calls] & {"copy", "transpose"}) \
+            and bodies[calls] <= movement
+
+    kernels, found = collections.Counter(), collections.Counter()
+    for name, (typ, op, operands, _, _) in inst.items():
+        kernel = re.match(r"(?:\w*?_)?(flash_(?:fwd|bwd_dq|bwd_dkdv))"
+                          r"(?:\.\d+)?$", name)
+        if op != "custom-call" or not kernel:
+            continue
+        kernel = kernel.group(1)
+        kernels[kernel] += 1
+        if inst[operands[0]][0].startswith("s32["):  # the walk's table
+            operands = operands[1:]
+        reads, writes = KERNEL_ROLES[kernel]
+        todo = [(o, role, "in") for o, role in zip(operands, reads)]
+        todo += [(u, writes[inst[u][4]], "out") for u in users[name]
+                 if inst[u][4] is not None and inst[u][4] < len(writes)]
+        seen = set()
+        while todo:
+            at, role, way = todo.pop()
+            if (at, role) in seen:
+                continue
+            seen.add((at, role))
+            if inst[at][1] in ("bitcast", "get-tuple-element"):
+                todo += [(x, role, way)
+                         for x in (inst[at][2] if way == "in" else users[at])]
+            elif relayout(at):
+                found[(kernel, role)] += 1
+    return kernels, found
+
+
+def _cell_layer_step(name):
+    """(module, example arguments, loss, attention applications a step, what
+    rope turns) of one kernel cell's model at its layer's width and its
+    batch and sequence: its hidden size and heads, one period deep, with a
+    share of experts and a small vocabulary. (XLA lays out what feeds the
+    kernels by the sizes too: at B 1 and S 1,024 it puts Ouro's v
+    projection S minor, at the cell's B 2 and S 4,096 it does not.)"""
+    from maggy_tpu.models import (BertConfig, BertEncoder, NemotronH,
+                                  NemotronHConfig, SdarMoe, SdarMoeConfig,
+                                  SmallThinker, SmallThinkerConfig)
+    from maggy_tpu.models.ouro import Ouro, OuroConfig
+
+    experts = dict(moe_intermediate_dim=128, num_experts=8, top_k=2,
+                   experts_held=4)
+
+    def total(out):
+        return jnp.sum(out.astype(jnp.float32))
+
+    if name == "bert":
+        module = BertEncoder(BertConfig(num_layers=1, dropout=0.0))
+        return module, (((64, 512), jnp.int32), ((64, 512), jnp.bool_)), \
+            total, 1, ()
+    if name == "sdar":  # q and k: per-head norm, then rope
+        module = SdarMoe(SdarMoeConfig(vocab_size=512, num_layers=1,
+                                       mask_token_id=511, **experts))
+        return module, (((2, 8192), jnp.int32),), total, 1, ("q", "k")
+    if name == "ouro":  # two passes over one layer
+        module = Ouro(OuroConfig(vocab_size=512, num_layers=1,
+                                 intermediate_dim=256, total_ut_steps=2,
+                                 head_chunk=256))
+        return module, (((2, 4096), jnp.int32),) * 2, jnp.mean, 2, \
+            ("q", "k")
+    if name == "smallthinker":  # one global (no rope), one windowed layer
+        module = SmallThinker(SmallThinkerConfig(
+            vocab_size=512, sliding_window_layout=(0, 1), rope_layout=(0, 1),
+            head_chunk=256, **experts))
+        return module, (((1, 16384), jnp.int32),) * 2, jnp.mean, 2, \
+            ("q", "k")
+    module = NemotronH(NemotronHConfig(
+        vocab_size=512, pattern="*", shared_intermediate_dim=256, **experts))
+    return module, (((2, 8192), jnp.int32),), total, 1, ()
+
+
+@pytest.mark.parametrize("name", ["bert", "sdar", "ouro", "smallthinker",
+                                  "nemotron"])
+def test_the_cells_steps_hold_no_relayout_at_the_kernels(
+        v5e_device, monkeypatch, name):
+    """Each kernel cell's model at its layer's width (hidden size, heads,
+    head size), its gradient through XLA:TPU with its own remat: the three
+    kernels read q, k, v and dO where the projections wrote them and write
+    o, dQ, dK and dV where the model reads them, so no copy or transpose
+    stands between them and the model on v, o, dO or dV, nor on the row
+    statistics. Where rope turns q and k, XLA lays out its fusion on its
+    own (S minor, for its halves) and copies at q, k, dQ and dK remain:
+    those, and only those, are the model's (PERF.md section 7). Without
+    rope nothing stands there. Each kernel runs once an application of the
+    attention (forward once: the layers keep `REMAT_KEEP`)."""
+    import flax.linen as nn
+
+    from maggy_tpu.models import moe
+    from maggy_tpu.ops import attention, ssd
+
+    monkeypatch.setattr(attention, "_tpu_backend", lambda: True)
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ssd, "_tpu_backend", lambda: True)
+    module, shapes, reduce, applications, turned = _cell_layer_step(name)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_device)
+            for shape, dtype in shapes]
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e_device),
+        nn.meta.unbox(jax.eval_shape(module.init, jax.random.key(0),
+                                     *args))["params"])
+
+    def loss(p, *args):
+        return reduce(module.apply({"params": p}, *args))
+
+    text = jax.jit(jax.grad(loss)).lower(params, *args).compile().as_text()
+    kernels, found = _boundary_relayouts(text)
+    assert kernels == {kernel: applications for kernel in KERNEL_ROLES}
+    model_side = {role for role in turned} | {"d" + role for role in turned}
+    assert {role for _, role in found} <= model_side, found
