@@ -34,7 +34,11 @@ TPU-first hot-op design the BERT/Llama baseline configs need:
   Per-row statistics (log-sum-exp, and delta in the backward, which the
   dQ kernel makes) are stored COMPACTLY as [B, H/pack, pack, Sq] fp32 with
   q-rows on the lane dimension (one [pack, blk_q] tile per q-block) — not
-  broadcast to 128 lanes in HBM.
+  broadcast to 128 lanes in HBM. The forward and the dK/dV kernel take
+  their scores keys-major, [keys, queries], so the statistics are used
+  and made as the rows they are stored as: the forward's running max and
+  sum are reductions over sublanes, its output accumulates transposed
+  (o^T += V^T p^T) and turns back once a q-block, as it is written.
 - `tile_plan`: what one grid step of each kernel covers, chosen from the
   shape: the [blk_q, blk_k] score tile (multiples of 128 that divide the
   lengths; the largest that a stated VMEM budget holds, smaller under a
@@ -360,8 +364,10 @@ class FlashPlan(NamedTuple):
         return cls(*(KernelTiles(blk_q, blk_k),) * 3)
 
     def describe(self) -> str:
-        """``fwd q512 k512 h4; dkdv q512 k512 h2; dq q512 k512 h4``."""
+        """``fwd q512 k512 h4 kq; dkdv q512 k512 h2; dq q512 k512 h4``: the
+        forward's word ``kq`` says it takes its scores keys-major."""
         return "; ".join("{} q{} k{} h{}".format(name, *tiles)
+                         + " kq" * (name == "fwd")
                          for name, tiles in zip(self._fields, self))
 
 
@@ -395,6 +401,27 @@ def _lane_pad(n: int) -> int:
     return -(-n // _LANES) * _LANES
 
 
+#: Query columns one pass of the forward takes at most: it takes its scores
+#: keys-major, every key of the tile against a run of query columns, and
+#: that run is the N of both its products (K Q^T and V^T p^T). A v5e reads
+#: 1.60 us a 512-tile and head at 128 columns, 1.41 at 256 and 1.19 at 512
+#: (SmallThinker's global layer, 1024-tiles; PERF.md section 6).
+_FWD_COLS = 512
+
+
+def _fwd_cols(blk_q: int, blk_k: int, itemsize: int, spare: int) -> int:
+    """Query columns of a forward pass: `_pass_rows`' run (what
+    `step_vmem_bytes` reckons), widened to the most multiples of 128 that
+    divide ``blk_q``, up to `_FWD_COLS`, whose wider scores, probabilities
+    and rounded copy fit in ``spare`` bytes: what `VMEM_BUDGET` leaves
+    beside the step."""
+    base = _pass_rows(blk_q, blk_k)
+    fit = [c for c in range(base, min(blk_q, _FWD_COLS) + 1, _LANES)
+           if blk_q % c == 0
+           and (c - base) * blk_k * (2 * 4 + itemsize) <= spare]
+    return max(fit, default=base)
+
+
 def _pass_rows(rows: int, cols: int) -> int:
     """Rows of a [rows, cols] tile one pass of a kernel's inner loop takes:
     the most multiples of 128 that divide ``rows`` and keep the pass within
@@ -412,8 +439,10 @@ def step_vmem_bytes(kernel: str, tiles: KernelTiles, D: int, itemsize: int,
     operand and result tile twice (the pipeline's double buffer), the
     float32 accumulators once, and the float32 scores, probabilities and
     their rounded copies of one pass over one head (passes and heads run
-    one after the other). The last dimension pads to 128 lanes (``pack``
-    heads share them); a row of statistics or mask pads to 8 sublanes.
+    one after the other; the forward widens its passes into what
+    `VMEM_BUDGET` leaves, `_fwd_cols`). The last dimension pads to 128
+    lanes (``pack`` heads share them); a row of statistics or mask pads
+    to 8 sublanes.
     Query heads that share K/V (``kv_shared``) hold one K/V head, or one a
     ``group`` of them where a step holds whole groups (`_step_heads`)."""
     blk_q, blk_k, heads = tiles
@@ -422,9 +451,9 @@ def step_vmem_bytes(kernel: str, tiles: KernelTiles, D: int, itemsize: int,
     kv_slabs = max(1, heads // (group or heads)) if kv_shared else slabs
     kv_tile = kv_slabs * blk_k * _lane_pad(pack * D)
     rows = 2 * 8 * 4 * (slabs * blk_q + blk_k)  # lse (delta) and the mask
-    if kernel == "fwd":      # q, o | k, v | acc, m, l | s, p and p rounded
+    if kernel == "fwd":      # q, o | k, v | o^T, m, l | s, p and p rounded
         tiles_io = 2 * itemsize * (2 * q_tile + 2 * kv_tile) + rows
-        scratch = 4 * (q_tile + 2 * heads * blk_q * _LANES)
+        scratch = 4 * (q_tile + 2 * 8 * slabs * blk_q)
         temps = _pass_rows(blk_q, blk_k) * blk_k * (2 * 4 + itemsize)
     elif kernel == "dkdv":   # q, dO | k, v, dk, dv | dk, dv | s, dp, ds
         tiles_io = 2 * itemsize * (2 * q_tile + 4 * kv_tile) + 2 * rows
@@ -635,6 +664,7 @@ def _remember(plan: FlashPlan, Sq: int, Sk: int, causal: bool,
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
 def _dot(a, b, dims):
@@ -715,20 +745,29 @@ def _each_chunk(slabs: _Slabs, chunks, body):
     slabs.each(lambda h: _each(chunks, lambda c: body(h, c)))
 
 
-def _scores(q, k_blk, q0, k0, causal, sm_scale, offset, mask_ref,
-            structure=None):
-    """[rows, blk_k] float32 logits of the queries from ``q0`` on against the
-    keys from ``k0`` on: Q K^T on the operands as they are, scaled after the
-    dot (forward and backward alike, so that the backward's p is the
-    forward's), then the masks."""
-    s = _dot(q, k_blk, _NT) * sm_scale
+def _scores(q, k_blk, q0, k0, causal, sm_scale, offset, keep,
+            structure=None, q_axis=0):
+    """[rows, cols] float32 logits of the queries from ``q0`` on against the
+    keys from ``k0`` on: Q K^T on the operands as they are, or with
+    ``q_axis=1`` K Q^T, keys-major; scaled after the dot (every kernel
+    alike, so that the backward's p is the forward's), then the masks.
+    ``keep`` is the key-padding mask as it broadcasts against the tile (a
+    [1, keys] row, or a [keys, 1] column keys-major), or None."""
+    s = (_dot(q, k_blk, _NT) if q_axis == 0 else _dot(k_blk, q, _NT)) \
+        * sm_scale
     if causal:
-        s = _causal_tile_mask(s, q0, k0, offset)
+        s = _causal_tile_mask(s, q0, k0, offset, q_axis)
     if structure is not None:
-        s = _structure_tile_mask(s, q0, k0, structure)
-    if mask_ref is not None:
-        s = jnp.where(mask_ref[...] != 0, s, NEG_INF)
+        s = _structure_tile_mask(s, q0, k0, structure, q_axis)
+    if keep is not None:
+        s = jnp.where(keep != 0, s, NEG_INF)
     return s
+
+
+def _key_column(mask_ref, keys=slice(None)):
+    """The key-padding mask's ``keys`` as the [keys, 1] column a keys-major
+    tile takes (a lane-to-sublane relayout of the stored row), or None."""
+    return None if mask_ref is None else mask_ref[:, keys][0][:, None]
 
 
 class _Step(NamedTuple):
@@ -815,21 +854,32 @@ def _on_tile(step: _Step, body) -> None:
 
 
 def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
-                      structure=None):
+                      cols, structure=None):
     """One (b, head block, q-block, k-block) program: K/V stream through the
     grid's innermost (sequential) dimension, so VMEM holds one [blk_k, D]
     tile of K and V a head — sequence length is bounded by HBM, not VMEM.
     Under a causal mask or a description that dimension is a `TileWalk`'s
     steps, and the walk's table comes first among the refs.
-    Online-softmax state (acc, running max, running sum) lives in VMEM
-    scratch that persists across the k-block steps of each program group;
-    where one k-block is the whole row (``one_pass``) there is no state to
-    keep and no scratch: the softmax is taken once.
+
+    The scores are taken keys-major, as the dK/dV kernel takes them: a pass
+    covers ``cols`` query columns (`_fwd_cols`) against every key of the
+    tile, s^T = K Q^T as [blk_k, cols], so the row statistics (running max
+    and sum) are reductions over sublanes, elementwise across vector
+    registers, and rows [1, cols] as lse is stored; no lane reduction and
+    no broadcast column. The output accumulates transposed, o^T += V^T p^T,
+    the product taking V transposed (a transposed-LHS dot, which read as
+    fast on the chip as a V^T made once a step in VMEM), and turns back
+    once a q-block, when it is written; of a pair of packed heads, each
+    owns half of o^T's rows. Online-softmax state (o^T, running max,
+    running sum) lives in VMEM scratch that persists across the k-block
+    steps of each program group; where one k-block is the whole row
+    (``one_pass``) there is no state to keep and no scratch: the softmax
+    is taken once.
 
     Refs: q [BLK_Q, slabs*width]; k/v [BLK_K, kv_slabs*width] (`_Slabs`);
     (mask [1, BLK_K] int32); o [BLK_Q, slabs*width]; lse
     [slabs, pack, BLK_Q] (q-rows on lanes — compact, no 128x pad); scratch
-    acc [slabs, BLK_Q, width], m/l [slabs, pack, BLK_Q, 128] fp32.
+    acc (o^T) [slabs, width, BLK_Q], m/l [slabs, pack, BLK_Q] fp32.
     """
     from jax.experimental import pallas as pl
 
@@ -841,36 +891,47 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
     slabs, pack, blk_q = lse_ref.shape
     sl = _Slabs.of(q_ref, k_ref, slabs)
     blk_k = k_ref.shape[0]
-    rows = _pass_rows(blk_q, blk_k)
+    head = functools.partial(_chunk, rows=sl.width // pack)  # rows of o^T
 
-    def scores(masked, h, c, j):
-        return _scores(_lanes_of(j, pack, q_ref[_chunk(c, rows), sl.cols(h)]),
-                       k_ref[:, sl.kv(h)], step.qi * blk_q + c * rows,
+    def scores(masked, keep, h, c, j):
+        return _scores(_lanes_of(j, pack, q_ref[_chunk(c, cols), sl.cols(h)]),
+                       k_ref[:, sl.kv(h)], step.qi * blk_q + c * cols,
                        step.kb * blk_k, causal and masked, sm_scale, offset,
-                       mask_ref, structure if masked else None)
+                       keep, structure if masked else None, q_axis=1)
 
     def values(h, j, p):
-        v_blk = _lanes_of(j, pack, v_ref[:, sl.kv(h)])
-        return _dot(p.astype(v_blk.dtype), v_blk, _NN)
+        """V^T p^T: head j's rows of o^T, [D, cols], V taken transposed."""
+        v_blk = v_ref[:, sl.kv(h)][:, head(j)]
+        return _dot(v_blk, p.astype(v_blk.dtype), _TN)
 
-    each_chunk = functools.partial(_each_chunk, sl, blk_q // rows)
+    def write(h, c, heads):
+        """o^T's rows of each head, [D, cols], as o's columns."""
+        out = heads[0] if pack == 1 else jnp.concatenate(heads)
+        o_ref[_chunk(c, cols), sl.cols(h)] = out.T.astype(o_ref.dtype)
+
+    def on_tile(contribute):
+        """``contribute(masked, keep, h, c)`` over the step's passes."""
+        def body(masked):
+            keep = _key_column(mask_ref)
+            _each_chunk(sl, blk_q // cols,
+                        functools.partial(contribute, masked, keep))
+
+        _on_tile(step, body)
 
     if one_pass:
-        def whole_rows(masked, h, c):
-            r = _chunk(c, rows)
-            out = None
+        def whole_columns(masked, keep, h, c):
+            r = _chunk(c, cols)
+            heads = []
             for j in range(pack):
-                s = scores(masked, h, c, j)
-                m = jnp.max(s, axis=1, keepdims=True)
+                s = scores(masked, keep, h, c, j)
+                m = jnp.max(s, axis=0, keepdims=True)
                 p = jnp.exp(s - m)
-                l = jnp.sum(p, axis=1, keepdims=True)  # >= 1: the row's max
-                o = values(h, j, p) / l  # zero in the other head's lanes
-                out = o if out is None else out + o
-                lse_ref[h, j:j + 1, r] = _as_row(m + jnp.log(l))
-            o_ref[r, sl.cols(h)] = out.astype(o_ref.dtype)
+                l = jnp.sum(p, axis=0, keepdims=True)  # >= 1: the max's
+                heads.append(values(h, j, p) / l)
+                lse_ref[h, j:j + 1, r] = m + jnp.log(l)
+            write(h, c, heads)
 
-        _on_tile(step, lambda masked: each_chunk(
-            functools.partial(whole_rows, masked)))
+        on_tile(whole_columns)
         return
 
     acc_ref, m_ref, l_ref = refs[5 + has_mask:]
@@ -881,42 +942,35 @@ def _flash_fwd_kernel(*refs, causal, sm_scale, has_mask, offset, one_pass,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def contribute(masked, h, c):
-        r = _chunk(c, rows)
-        acc = acc_ref[h, r]
+    def contribute(masked, keep, h, c):
+        r = _chunk(c, cols)
         for j in range(pack):
-            s = scores(masked, h, c, j)
-            m_prev = m_ref[h, j, r][:, :1]
-            l_prev = l_ref[h, j, r][:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            s = scores(masked, keep, h, c, j)
+            m_prev, l_prev = m_ref[h, j:j + 1, r], l_ref[h, j:j + 1, r]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            # Head j's lanes of the accumulator are rescaled and added to;
-            # the other head's keep what they hold.
-            acc = acc * _lanes_of(j, pack, jnp.broadcast_to(alpha, acc.shape),
-                                  1.0) + values(h, j, p)
-            m_ref[h, j, r] = jnp.broadcast_to(m_new, (rows, _LANES))
-            l_ref[h, j, r] = jnp.broadcast_to(l_new, (rows, _LANES))
-        acc_ref[h, r] = acc
+            l_ref[h, j:j + 1, r] = alpha * l_prev \
+                + jnp.sum(p, axis=0, keepdims=True)
+            m_ref[h, j:j + 1, r] = m_new
+            acc_ref[h, head(j), r] = acc_ref[h, head(j), r] * alpha \
+                + values(h, j, p)
 
-    _on_tile(step, lambda masked: each_chunk(
-        functools.partial(contribute, masked)))
+    on_tile(contribute)
 
     @pl.when(step.last())
     def _finalize():
         def finish(h, c):
-            r = _chunk(c, rows)
-            acc, out = acc_ref[h, r], None
+            r = _chunk(c, cols)
+            heads = []
             for j in range(pack):
-                l_safe = jnp.maximum(l_ref[h, j, r][:, :1], 1e-30)
-                o = _lanes_of(j, pack, acc / l_safe)
-                out = o if out is None else out + o
-                lse_ref[h, j:j + 1, r] = _as_row(m_ref[h, j, r][:, :1]
-                                                 + jnp.log(l_safe))
-            o_ref[r, sl.cols(h)] = out.astype(o_ref.dtype)
+                l_safe = jnp.maximum(l_ref[h, j:j + 1, r], 1e-30)
+                heads.append(acc_ref[h, head(j), r] / l_safe)
+                lse_ref[h, j:j + 1, r] = m_ref[h, j:j + 1, r] \
+                    + jnp.log(l_safe)
+            write(h, c, heads)
 
-        each_chunk(finish)
+        _each_chunk(sl, blk_q // cols, finish)
 
 
 def _head_blocks(Sq, Sk, H, Hkv, D, tiles):
@@ -1036,9 +1090,14 @@ def _flash_fwd(q, k, v, mask, causal, tiles, heads, interpret,
     # whole in one step (under a causal mask with Sq > Sk the first rows see
     # none, and the stepping path's skip gives them the zeros it always did).
     one_pass = Sk == blk_k and not (causal and offset < 0)
+    spare = VMEM_BUDGET - step_vmem_bytes(
+        "fwd", KernelTiles(blk_q, blk_k, slabs * pack), D, q.dtype.itemsize,
+        H != Hkv, pack, H // Hkv)
     kernel = functools.partial(_flash_fwd_kernel, causal=causal,
                                sm_scale=sm_scale, has_mask=mask is not None,
                                offset=offset, one_pass=one_pass,
+                               cols=_fwd_cols(blk_q, blk_k, q.dtype.itemsize,
+                                              spare),
                                structure=structure)
     out, lse = pl.pallas_call(
         kernel,
@@ -1052,9 +1111,9 @@ def _flash_fwd(q, k, v, mask, causal, tiles, heads, interpret,
                              on(_stat_at(R // hr))),
             ],
             scratch_shapes=[] if one_pass else [
-                pltpu.VMEM((slabs, blk_q, width), jnp.float32),
-                pltpu.VMEM((slabs, pack, blk_q, _LANES), jnp.float32),
-                pltpu.VMEM((slabs, pack, blk_q, _LANES), jnp.float32),
+                pltpu.VMEM((slabs, width, blk_q), jnp.float32),
+                pltpu.VMEM((slabs, pack, blk_q), jnp.float32),
+                pltpu.VMEM((slabs, pack, blk_q), jnp.float32),
             ],
         ),
         out_shape=[
@@ -1177,19 +1236,14 @@ def _flash_bwd_dkdv_kernel(*refs, causal, sm_scale, has_mask, offset,
     def contribute(masked, h, c):
         keys = _chunk(c, rows)
         k_blk, v_blk = k_ref[keys, sl.kv(h)], v_ref[keys, sl.kv(h)]
+        keep = _key_column(mask_ref, keys)
         dk = dv = 0.0
         for j in range(pack):
             q = _lanes_of(j, pack, q_ref[:, sl.cols(h)])
             do = _lanes_of(j, pack, do_ref[:, sl.cols(h)])
-            s = _dot(k_blk, q, _NT) * sm_scale
-            if causal and masked:
-                s = _causal_tile_mask(s, qi * blk_q, kb * blk_k + c * rows,
-                                      offset, q_axis=1)
-            if structure is not None and masked:
-                s = _structure_tile_mask(s, qi * blk_q, kb * blk_k + c * rows,
-                                         structure, q_axis=1)
-            if mask_ref is not None:  # the key mask as a [rows, 1] column
-                s = jnp.where(mask_ref[:, keys][0][:, None] != 0, s, NEG_INF)
+            s = _scores(q, k_blk, qi * blk_q, kb * blk_k + c * rows,
+                        causal and masked, sm_scale, offset, keep,
+                        structure if masked else None, q_axis=1)
             p = jnp.exp(s - lse_ref[h, j:j + 1])
             ds = p * (_dot(v_blk, do, _NT) - delta_ref[h, j:j + 1])
             dv += _dot(p.astype(do.dtype), do, _NN)  # head j's lanes alone
@@ -1248,12 +1302,13 @@ def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset,
     def contribute(masked, h, c):
         r = _chunk(c, rows)
         k_blk, v_blk = k_ref[:, sl.kv(h)], v_ref[:, sl.kv(h)]
+        keep = None if mask_ref is None else mask_ref[...]
         dq = 0.0
         for j in range(pack):
             q = _lanes_of(j, pack, q_ref[r, sl.cols(h)])
             do = _lanes_of(j, pack, do_ref[r, sl.cols(h)])
             s = _scores(q, k_blk, qi * blk_q + c * rows, kb * blk_k,
-                        causal and masked, sm_scale, offset, mask_ref,
+                        causal and masked, sm_scale, offset, keep,
                         structure if masked else None)
             # lane->sublane relayout of the compact [1, rows] statistics
             p = jnp.exp(s - lse_ref[h, j:j + 1, r][0][:, None])

@@ -249,9 +249,10 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: vectorized block), ``first_dispatch`` (epoch seconds of the trial's
 #: first step dispatch) and ``flash_plan``: the tiles of the Pallas flash
 #: kernels in the step program this trial traced, as
-#: `ops.attention.FlashPlan.describe` writes them (``fwd q512 k512 h4;
+#: `ops.attention.FlashPlan.describe` writes them (``fwd q512 k512 h4 kq;
 #: dkdv q512 k512 h4; dq q512 k512 h4``: per kernel the query and key tile
-#: and the heads a grid step covers; several shapes in one program are
+#: and the heads a grid step covers, and ``kq`` after the forward's: its
+#: scores are keys-major; several shapes in one program are
 #: joined by `` | ``; under a mask description the plan also says the mask
 #: kind, its block length, per kernel the tiles of a head that run of
 #: those there are, and what each kernel's grid walks for them (steps that

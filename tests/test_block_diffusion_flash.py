@@ -12,11 +12,13 @@ import pytest
 
 from maggy_tpu.ops import attention as att
 from maggy_tpu.ops.attention import (BlockDiffusionMask, FlashPlan,
-                                     attention_reference,
+                                     KernelTiles, attention_reference,
                                      multi_head_attention, tile_plan)
-from test_flash_tiles import WALK_TENSORS, walk_bits
+from test_flash_tiles import (WALK_TENSORS, flash_lse, reference_lse,
+                              walk_bits)
 
-TENSORS = ("out", "dq", "dk", "dv")
+TENSORS = ("out", "lse", "dq", "dk", "dv")
+GRADS = TENSORS[2:]
 CASES = {
     # name: data length L, block, H, Hkv, D, explicit tiles or None (planned)
     "block4_gqa_d128": (128, 4, 4, 2, 128, (128, 128)),
@@ -64,11 +66,16 @@ def _results(name):
     def reference(q, k, v):
         return attention_reference(q, k, v, causal=False, mask=mask.dense())
 
-    def all_of(fn):
+    def all_of(fn, lse):
         out, vjp = jax.vjp(fn, q, k, v)
-        return tuple(np.asarray(t) for t in (out,) + vjp(w))
+        return tuple(np.asarray(t) for t in (out, lse) + vjp(w))
 
-    return {"flash": all_of(flash), "reference": all_of(reference)}
+    fwd = KernelTiles(*tiles) if tiles else tile_plan(
+        S, S, D, H, Hkv, 4, False, False, mask).fwd
+    return {"flash": all_of(flash, flash_lse(q, k, v, None, False, fwd,
+                                             mask)),
+            "reference": all_of(reference, reference_lse(
+                q, k, False, mask.dense()))}
 
 
 @pytest.mark.parametrize("tensor", TENSORS)
@@ -209,7 +216,7 @@ def test_the_cell_skips_176_of_256_tiles():
     assert att._blocks_run(8192, 8192, 512, 512, False, mask) == 80
     plan = tile_plan(8192, 8192, 128, 32, 4, 2, False, False, mask)
     assert plan.describe() == \
-        "fwd q512 k512 h4; dkdv q512 k512 h4; dq q512 k512 h4"
+        "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4"
 
 
 @pytest.mark.parametrize("Sq,Sk,blk_q,blk_k", [
@@ -227,11 +234,11 @@ def test_causal_tiles_run_are_what_they_were(Sq, Sk, blk_q, blk_k):
 @pytest.mark.parametrize("shape,said", [
     # BERT-base's cell, Llama's causal GQA, the kept ASHA mix's S 128.
     ((512, 512, 64, 12, 12, 2, False, True),
-     "fwd q512 k512 h4; dkdv q512 k512 h4; dq q512 k512 h4"),
+     "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4"),
     ((2048, 2048, 128, 32, 8, 2, True, False),
-     "fwd q512 k512 h4; dkdv q512 k512 h4; dq q512 k512 h4"),
+     "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4"),
     ((128, 128, 64, 12, 12, 2, False, True),
-     "fwd q128 k128 h12; dkdv q128 k128 h12; dq q128 k128 h12"),
+     "fwd q128 k128 h12 kq; dkdv q128 k128 h12; dq q128 k128 h12"),
 ])
 def test_plans_without_a_description_are_unchanged(shape, said):
     assert tile_plan(*shape).describe() == said
@@ -251,7 +258,8 @@ def test_the_plan_says_the_mask_and_the_tiles_run():
     with att.plans_traced() as plans:
         multi_head_attention(q, q, q, causal=False,
                              mask=BlockDiffusionMask(128, 4), force="flash")
-    assert plans == ["fwd q256 k256 h2; dkdv q256 k256 h2; dq q256 k256 h2; "
+    assert plans == ["fwd q256 k256 h2 kq; dkdv q256 k256 h2; "
+                     "dq q256 k256 h2; "
                      "block_diffusion b4 L128 tiles fwd 1/1 dkdv 1/1 dq 1/1; "
                      "walk fwd 1+0 (1 partial) dkdv 1+0 (1 partial) "
                      "dq 1+0 (1 partial)"]
@@ -265,7 +273,7 @@ def test_the_plan_of_the_cell_says_its_walk(monkeypatch):
     att._remember(tile_plan(8192, 8192, 128, 32, 4, 2, False, False, mask),
                   8192, 8192, False, mask)
     assert said == [
-        "fwd q512 k512 h4; dkdv q512 k512 h4; dq q512 k512 h4; "
+        "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4; "
         "block_diffusion b4 L4096 tiles fwd 80/256 dkdv 80/256 dq 80/256; "
         "walk fwd 80+0 (24 partial) dkdv 80+0 (24 partial) "
         "dq 80+0 (24 partial)"]
@@ -357,10 +365,10 @@ def test_a_checkpoint_that_keeps_the_names_runs_the_forward_kernel_once(kind):
     assert found["policy"][1] == once
 
 
-@pytest.mark.parametrize("tensor", TENSORS[1:])
+@pytest.mark.parametrize("tensor", GRADS)
 @pytest.mark.parametrize("kind", sorted(REMAT_MASKS))
 def test_the_kept_values_are_bitwise_what_a_second_run_gives(kind, tensor):
-    i = TENSORS.index(tensor) - 1
+    i = GRADS.index(tensor)
     found = _under_checkpoint(kind)
     assert np.any(found["plain"][0][i] != 0)
     for name in ("remat", "policy"):

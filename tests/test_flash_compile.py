@@ -530,9 +530,8 @@ def test_the_combine_kernels_compile_at_the_cells_shapes(v5e_device, cell):
         assert _kernel_names(text) == ["moe_combine_" + which]
 
 
-def _mosaic_text(lowered):
-    """The Mosaic module of a lowered program's one kernel, as text, with
-    every number masked: what its body holds, whatever its shapes."""
+def _mosaic_module(lowered):
+    """The Mosaic module of a lowered program's one kernel, as text."""
     import base64
     import re
 
@@ -548,8 +547,15 @@ def _mosaic_text(lowered):
     tpu.register_dialect(ctx)
     ctx.allow_unregistered_dialects = True
     with ctx:
-        return re.sub(r"\d+", "0", str(ir.Module.parse(
-            base64.b64decode(body))))
+        return str(ir.Module.parse(base64.b64decode(body)))
+
+
+def _mosaic_text(lowered):
+    """`_mosaic_module` with every number masked: what its body holds,
+    whatever its shapes."""
+    import re
+
+    return re.sub(r"\d+", "0", _mosaic_module(lowered))
 
 
 def test_the_combine_kernel_does_not_grow_with_the_held_experts(v5e_device):
@@ -562,6 +568,76 @@ def test_the_combine_kernel_does_not_grow_with_the_held_experts(v5e_device):
         v5e_device, width, held, top_k, products, "fwd")) for held in (4, 16))
     assert len(four) == len(sixteen)
     assert four.count("\n") == sixteen.count("\n")
+
+
+#: The forward at the claimed kernel cells' own shapes: B, S, H, Hkv, D,
+#: causal, mask description.
+FORWARD_CELLS = {
+    "sdar_under_its_description": (2, 8192, 32, 4, 128, False, "bd"),
+    "smallthinker_windowed": (1, 16384, 28, 4, 128, True, "window"),
+    "smallthinker_global": (1, 16384, 28, 4, 128, True, None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FORWARD_CELLS))
+def test_the_forward_reduces_over_sublanes_not_lanes(v5e_device, name):
+    """The forward takes its scores keys-major, [keys, queries]: the
+    running max and sum of every pass reduce over dimension 0 of a 2-d
+    chunk, the sublanes, and no reduction in its Mosaic module runs over
+    the last dimension, the lanes."""
+    import re
+
+    from maggy_tpu.ops.attention import BlockDiffusionMask, CausalWindow
+
+    B, S, H, Hkv, D, causal, kind = FORWARD_CELLS[name]
+    mask = {"bd": BlockDiffusionMask(S // 2, 4),
+            "window": CausalWindow(4096)}.get(kind)
+    plan = tile_plan(S, S, D, H, Hkv, 2, causal, False, mask)
+    q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=v5e_device)
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16,
+                              sharding=v5e_device)
+    lowered = jax.jit(lambda q, k, v: flash_attention_planned(
+        q, k, v, None, causal, plan, False, mask)).lower(q, kv, kv)
+    text = _mosaic_module(lowered)
+    assert text.startswith("module @flash_fwd")
+    reductions = re.findall(
+        r"multi_reduction.*reduction_dims = array<i64: ([\d, ]+)>"
+        r".*: \(vector<(\d+)x(\d+)xf32>", text)
+    assert reductions  # the max and the sum of each pass and head
+    assert {dims for dims, _, _ in reductions} == {"0"}
+    blk_k = plan.fwd.blk_k
+    assert {int(keys) for _, keys, _ in reductions} == {blk_k}
+    assert plan.describe().startswith("fwd q{} k{} h{} kq;".format(
+        *plan.fwd))
+
+
+#: The kernel cells' shapes, as `tile_plan` takes them, and the tiles each
+#: takes: the same in all three kernels (the forward's keys-major scratch
+#: changed what it reckons of VMEM, not the tiles).
+CELL_PLANS = {
+    "bert-base": ((512, 512, 64, 12, 12, 2, False, True), (512, 512, 4)),
+    "sdar-30b-a3b": ((8192, 8192, 128, 32, 4, 2, False, False, "bd"),
+                     (512, 512, 4)),
+    "nemotron-3-nano-30b-a3b": ((8192, 8192, 128, 32, 2, 2, True, False),
+                                (1024, 1024, 1)),
+    "ouro-2.6b": ((4096, 4096, 128, 16, 16, 2, True, False), (1024, 1024, 1)),
+    "smallthinker-21b-a3b_windowed": (
+        (16384, 16384, 128, 28, 4, 2, True, False, "window"), (1024, 1024, 1)),
+    "smallthinker-21b-a3b_global": (
+        (16384, 16384, 128, 28, 4, 2, True, False), (1024, 1024, 1)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_PLANS))
+def test_the_kernel_cells_plans_keep_their_tiles(cell):
+    from maggy_tpu.ops.attention import (BlockDiffusionMask, CausalWindow,
+                                         KernelTiles)
+
+    shape, tiles = CELL_PLANS[cell]
+    mask = {"bd": BlockDiffusionMask(4096, 4),
+            "window": CausalWindow(4096)}.get(shape[8:] and shape[8])
+    assert tile_plan(*shape[:8], mask) \
+        == FlashPlan(*(KernelTiles(*tiles),) * 3)
 
 
 #: The kernels' operands and results by position (a walk's table, the first

@@ -17,8 +17,9 @@ from maggy_tpu.ops import attention as att
 from maggy_tpu.ops.attention import (BlockDiffusionMask, CausalWindow,
                                      attention_reference, lane_pack,
                                      multi_head_attention, tile_plan)
+from test_flash_tiles import flash_lse, reference_lse
 
-TENSORS = ("out", "dq", "dk", "dv")
+TENSORS = ("out", "lse", "dq", "dk", "dv")
 LAYOUTS = {
     # name: B, S, H, Hkv, D, causal, mask kind
     # BERT: MHA at D 64, two heads to a slab's 128 lanes, key padding.
@@ -65,19 +66,27 @@ def _flash(q, k, v, causal, mask):
 
 @functools.lru_cache(maxsize=None)
 def _results(name):
-    """{"flash" | "reference": (out, dq, dk, dv)} of one layout."""
+    """{"flash" | "reference": (out, lse, dq, dk, dv)} of one layout."""
     (q, k, v, w), causal, mask, dense = _inputs(name)
+    B, S, H, Hkv, D, _, kind = LAYOUTS[name]
+    structure = mask if kind in ("block", "window") else None
+    keep = dense[:, 0, 0] if kind == "keys" else None
 
     def reference(q, k, v):
         return attention_reference(q, k, v, causal=causal, mask=dense)
 
     found = {}
     with jax.default_matmul_precision("highest"):
+        lse = {"flash": flash_lse(q, k, v, keep, causal, tile_plan(
+                   S, S, D, H, Hkv, 4, causal, keep is not None,
+                   structure).fwd, structure),
+               "reference": reference_lse(q, k, causal, dense)}
         for label, fn in (("flash", lambda q, k, v: _flash(q, k, v, causal,
                                                            mask)),
                           ("reference", reference)):
             out, vjp = jax.vjp(fn, q, k, v)
-            found[label] = [np.asarray(x) for x in (out,) + vjp(w)]
+            found[label] = [np.asarray(x)
+                            for x in (out, lse[label]) + vjp(w)]
     return found
 
 
@@ -87,7 +96,8 @@ def test_in_place_kernels_match_the_reference(name, tensor):
     found, i = _results(name), TENSORS.index(tensor)
     got, want = found["flash"][i], found["reference"][i]
     assert got.shape == want.shape and np.isfinite(got).all()
-    limit = 1e-4 if tensor == "out" else 1e-3  # as tests/test_models.py
+    # As tests/test_models.py; the log-sum-exp to float32 rounding.
+    limit = {"out": 1e-4, "lse": 1e-5}.get(tensor, 1e-3)
     assert np.abs(got - want).max() <= limit * np.abs(want).max()
 
 

@@ -26,7 +26,7 @@ from test_flash_compile import CELL_SHAPE, SHAPE_CLASSES
 BF16_ULPS = 4
 BF16_TOL = BF16_ULPS * 2.0 ** -8
 
-TENSORS = ("out", "dq", "dk", "dv")
+TENSORS = ("out", "lse", "dq", "dk", "dv")
 CASES = {
     # name: B, Sq, Sk, H, Hkv, D, causal, masked, dtype
     "bert_s256_d64_masked": (2, 256, 256, 4, 4, 64, False, True, jnp.bfloat16),
@@ -40,7 +40,31 @@ CASES = {
     "h3_d64_causal": (1, 256, 256, 3, 3, 64, True, False, jnp.bfloat16),
     "float32_gqa_causal_masked": (1, 256, 256, 4, 2, 128, True, True,
                                   jnp.float32),
+    "gqa_sq256_sk512_causal": (1, 256, 512, 4, 2, 128, True, False,
+                               jnp.bfloat16),
+    "d72_causal_masked": (1, 256, 256, 2, 2, 72, True, True, jnp.bfloat16),
 }
+
+
+def reference_lse(q, k, causal, mask=None):
+    """[B, H, Sq] float32 log-sum-exp of the scaled logits over the keys a
+    query sees (``mask`` as `attention_reference` takes it)."""
+    B, Sq, H, D = q.shape
+    Sk, Hkv = k.shape[1], k.shape[2]
+    k = jnp.repeat(k.astype(jnp.float32), H // Hkv, axis=2)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                        k) / np.float32(np.sqrt(D))
+    seen = jnp.tril(jnp.ones((Sq, Sk), bool), k=Sk - Sq) if causal \
+        else jnp.ones((Sq, Sk), bool)
+    if mask is not None:
+        seen = seen & mask
+    return jax.nn.logsumexp(jnp.where(seen, logits, -jnp.inf), axis=-1)
+
+
+def flash_lse(q, k, v, keep, causal, tiles, structure=None):
+    """The forward kernel's log-sum-exp at ``tiles``, as [B, H, Sq]."""
+    _, lse = att._flash_fwd_4d(q, k, v, keep, causal, tiles, True, structure)
+    return np.asarray(lse).reshape(q.shape[0], q.shape[2], q.shape[1])
 
 
 @functools.lru_cache(maxsize=None)
@@ -72,6 +96,11 @@ def _results(name):
                                    v.astype(jnp.float32), causal=causal,
                                    mask=mask4)
 
+    lse = {"planned": flash_lse(q, k, v, keep, causal, tile_plan(
+               Sq, Sk, D, H, Hkv, jnp.dtype(dtype).itemsize, causal,
+               masked).fwd),
+           "explicit": flash_lse(q, k, v, keep, causal, KernelTiles(128, 128)),
+           "reference": reference_lse(q, k, causal, mask4)}
     found = {}
     for label, fn in (("planned", planned), ("explicit", explicit),
                       ("reference", reference)):
@@ -81,12 +110,17 @@ def _results(name):
 
         (_, out), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(
             q, k, v)
-        found[label] = [np.asarray(x, np.float32) for x in (out, *grads)]
+        found[label] = [np.asarray(x, np.float32)
+                        for x in (out, lse[label], *grads)]
     return found
 
 
 def _limits(name, tensor):
-    """(relative limit against the reference, against the explicit call)."""
+    """(relative limit against the reference, against the explicit call).
+    The log-sum-exp is float32 from the operands as they are, whatever
+    their dtype: it differs from the reference's by float32 rounding."""
+    if tensor == "lse":
+        return 1e-5, 1e-5
     if CASES[name][-1] == jnp.bfloat16:
         return BF16_TOL, BF16_TOL
     return (1e-4 if tensor == "out" else 1e-3,) * 2  # as tests/test_models.py
@@ -299,6 +333,14 @@ def test_a_causal_walk_writes_every_block(name):
     assert np.isfinite(lse).all()
     assert not out[:, ~seen].any() and not grads[0][:, ~seen].any()
     assert (~seen).sum() == max(0, Sq - Sk)
+    # The log-sum-exp of the rows that see a key is the reference's; a row
+    # that sees none takes that of no key, NEG_INF + log(1e-30).
+    lse = lse.reshape(2, H, Sq)
+    want = np.asarray(reference_lse(q, k, True))
+    assert np.abs(lse[..., seen] - want[..., seen]).max() \
+        <= 1e-5 * np.abs(want[..., seen]).max()
+    assert (lse[..., ~seen] == np.float32(att.NEG_INF)
+            + np.log(np.float32(1e-30))).all()
 
 
 # ------------------------------------------------------------- the plan
@@ -347,7 +389,7 @@ def test_the_cell_takes_the_whole_sequence_in_one_tile():
     plan = _plan_of(CELL_SHAPE)
     assert all((t.blk_q, t.blk_k) == (512, 512) and t.heads > 1 for t in plan)
     assert plan.describe() == "; ".join(
-        "{} q512 k512 h{}".format(n, t.heads)
+        "{} q512 k512 h{}{}".format(n, t.heads, " kq" * (n == "fwd"))
         for n, t in zip(("fwd", "dkdv", "dq"), plan))
 
 
@@ -410,6 +452,6 @@ def test_plans_traced_collects_each_plan_once_in_order():
         multi_head_attention(q, q, q, causal=False, force="reference")
     short = tile_plan(128, 128, 64, 2, 2, 4, True, False).describe()
     assert inner == [tile_plan(256, 256, 64, 2, 2, 4, False, False).describe()]
-    assert outer == [short] and short.startswith("fwd q128 k128 h2; dkdv ")
+    assert outer == [short] and short.startswith("fwd q128 k128 h2 kq; dkdv ")
     multi_head_attention(q, q, q, causal=False, force="flash")  # none open
     assert len(inner) == 1 and len(outer) == 1

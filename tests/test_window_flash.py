@@ -13,12 +13,14 @@ import pytest
 
 from maggy_tpu.ops import attention as att
 from maggy_tpu.ops.attention import (BlockDiffusionMask, CausalWindow,
-                                     FlashPlan, attention_reference,
+                                     FlashPlan, KernelTiles,
+                                     attention_reference,
                                      multi_head_attention, tile_plan)
 from test_block_diffusion_flash import _dense_grid_walk, _walk_steps
-from test_flash_tiles import WALK_TENSORS, walk_bits
+from test_flash_tiles import (WALK_TENSORS, flash_lse, reference_lse,
+                              walk_bits)
 
-TENSORS = ("out", "dq", "dk", "dv")
+TENSORS = ("out", "lse", "dq", "dk", "dv")
 CASES = {
     # name: S, window, H, Hkv, D, explicit tiles or None (planned)
     # A window that is no multiple of the tile: its edge crosses tiles.
@@ -62,12 +64,17 @@ def _results(name):
     def causal(q, k, v):
         return attention_reference(q, k, v, causal=True)
 
-    def all_of(fn):
+    def all_of(fn, lse):
         out, vjp = jax.vjp(fn, q, k, v)
-        return tuple(np.asarray(t) for t in (out,) + vjp(w))
+        return tuple(np.asarray(t) for t in (out, lse) + vjp(w))
 
-    return {"flash": all_of(flash), "reference": all_of(reference),
-            "causal": all_of(causal)}
+    fwd = KernelTiles(*tiles) if tiles else tile_plan(
+        S, S, D, H, Hkv, 4, True, False, mask).fwd
+    return {"flash": all_of(flash, flash_lse(q, k, v, None, True, fwd,
+                                             mask)),
+            "reference": all_of(reference, reference_lse(
+                q, k, True, mask.dense(S))),
+            "causal": all_of(causal, reference_lse(q, k, True))}
 
 
 @pytest.mark.parametrize("tensor", TENSORS)
@@ -128,7 +135,7 @@ def test_the_cell_walks_70_of_the_causal_136_steps():
     mask = CausalWindow(4096)
     plan = tile_plan(16384, 16384, 128, 28, 4, 2, True, False, mask)
     assert plan.describe() == \
-        "fwd q1024 k1024 h1; dkdv q1024 k1024 h1; dq q1024 k1024 h1"
+        "fwd q1024 k1024 h1 kq; dkdv q1024 k1024 h1; dq q1024 k1024 h1"
     assert tile_plan(16384, 16384, 128, 28, 4, 2, True, False) == plan
     for kernel in FlashPlan._fields:
         walk = att.tile_walk(kernel, 16384, 16384, 1024, 1024, True, mask)
@@ -145,7 +152,7 @@ def test_the_plan_says_the_window_and_its_walk(monkeypatch):
     att._remember(tile_plan(16384, 16384, 128, 28, 4, 2, True, False, mask),
                   16384, 16384, True, mask)
     assert said == [
-        "fwd q1024 k1024 h1; dkdv q1024 k1024 h1; dq q1024 k1024 h1; "
+        "fwd q1024 k1024 h1 kq; dkdv q1024 k1024 h1; dq q1024 k1024 h1; "
         "window w4096 tiles fwd 70/256 dkdv 70/256 dq 70/256; "
         "walk fwd 70+0 (28 partial) dkdv 70+0 (28 partial) "
         "dq 70+0 (28 partial)"]
@@ -157,18 +164,19 @@ def test_the_plan_says_the_window_and_its_walk(monkeypatch):
 #: positions do not tile.
 ACCEPTED = {
     "bert-base": ((512, 512, 64, 12, 12, 2, False, True, None),
-                  "fwd q512 k512 h4; dkdv q512 k512 h4; dq q512 k512 h4",
+                  "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4",
                   None),
     "sdar-30b-a3b": ((8192, 8192, 128, 32, 4, 2, False, False,
                       BlockDiffusionMask(4096, 4)),
-                     "fwd q512 k512 h4; dkdv q512 k512 h4; dq q512 k512 h4",
+                     "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4",
                      "80+0 (24 partial)"),
     "nemotron-3-nano-30b-a3b": (
         (8192, 8192, 128, 32, 2, 2, True, False, None),
-        "fwd q1024 k1024 h1; dkdv q1024 k1024 h1; dq q1024 k1024 h1",
+        "fwd q1024 k1024 h1 kq; dkdv q1024 k1024 h1; dq q1024 k1024 h1",
         "36+0 (8 partial)"),
     "ouro-2.6b": ((4096, 4096, 128, 16, 16, 2, True, False, None),
-                  "fwd q1024 k1024 h1; dkdv q1024 k1024 h1; dq q1024 k1024 h1",
+                  "fwd q1024 k1024 h1 kq; dkdv q1024 k1024 h1; "
+                  "dq q1024 k1024 h1",
                   "10+0 (4 partial)"),
 }
 
