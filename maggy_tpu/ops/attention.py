@@ -4,8 +4,10 @@ The reference framework has no attention code (SURVEY.md §5.7); this is the
 TPU-first hot-op design the BERT/Llama baseline configs need:
 
 - `flash_attention`: Pallas TPU kernels — tiled online-softmax forward and
-  a two-kernel backward (dK/dV streaming Q tiles, dQ streaming K/V tiles),
-  fp32 accumulators in VMEM scratch, O(tile) VMEM and no S x S
+  a fused backward (one walk of the visible tiles makes each tile's scores
+  once and from them dV, dK and dQ, accumulated in float32 VMEM over the
+  whole lengths; where those accumulators do not fit, a two-kernel
+  backward: dK/dV streaming Q tiles, dQ streaming K/V tiles), and no S x S
   materialization in either direction. Under a mask that empties tiles by
   their indices (causal, a description) a kernel's grid walks only the tiles
   that hold a visible pair (`tile_walk`: a schedule in SMEM), and a tile in
@@ -32,13 +34,14 @@ TPU-first hot-op design the BERT/Llama baseline configs need:
     * head_dim >= 64. At D 64 (BERT-base) two heads of an MHA layer share
       a tile's 128 lanes (`lane_pack`), so every tile moves in whole rows.
   Per-row statistics (log-sum-exp, and delta in the backward, which the
-  dQ kernel makes) are stored COMPACTLY as [B, H/pack, pack, Sq] fp32 with
+  backward makes) are stored COMPACTLY as [B, H/pack, pack, Sq] fp32 with
   q-rows on the lane dimension (one [pack, blk_q] tile per q-block) — not
-  broadcast to 128 lanes in HBM. The forward and the dK/dV kernel take
-  their scores keys-major, [keys, queries], so the statistics are used
-  and made as the rows they are stored as: the forward's running max and
-  sum are reductions over sublanes, its output accumulates transposed
-  (o^T += V^T p^T) and turns back once a q-block, as it is written.
+  broadcast to 128 lanes in HBM. The forward and the backward take their
+  scores keys-major, [keys, queries], so the statistics are used and made
+  as the rows they are stored as: the forward's running max and sum are
+  reductions over sublanes, its output accumulates transposed (o^T += V^T
+  p^T) and turns back once a q-block, as it is written, and the fused
+  backward's dQ accumulates transposed the same way.
 - `tile_plan`: what one grid step of each kernel covers, chosen from the
   shape: the [blk_q, blk_k] score tile (multiples of 128 that divide the
   lengths; the largest that a stated VMEM budget holds, smaller under a
@@ -353,22 +356,36 @@ class KernelTiles(NamedTuple):
 
 
 class FlashPlan(NamedTuple):
-    """The tiles of the three kernels, by the kernels' names."""
+    """The tiles of the kernels a layer runs, by the kernels' names: the
+    forward, and the fused backward (``bwd``) or, where its accumulators
+    over the whole lengths do not fit VMEM, the split pair (``dkdv``,
+    ``dq``). A kernel the plan does not run is None."""
     fwd: KernelTiles
-    dkdv: KernelTiles
-    dq: KernelTiles
+    dkdv: Optional[KernelTiles] = None
+    dq: Optional[KernelTiles] = None
+    bwd: Optional[KernelTiles] = None
 
     @classmethod
-    def explicit(cls, blk_q: int, blk_k: int) -> "FlashPlan":
-        """The caller's own tiles in all three kernels, one head a step."""
-        return cls(*(KernelTiles(blk_q, blk_k),) * 3)
+    def explicit(cls, blk_q: int, blk_k: int,
+                 fused: bool = True) -> "FlashPlan":
+        """The caller's own tiles in every kernel, one head a step: the
+        forward and the fused backward, or the split pair (``fused``
+        False)."""
+        tiles = KernelTiles(blk_q, blk_k)
+        return cls(tiles, bwd=tiles) if fused else cls(tiles, tiles, tiles)
+
+    def kernels(self) -> list:
+        """(name, tiles) of each kernel the plan runs, in order."""
+        return [(name, tiles) for name, tiles in zip(self._fields, self)
+                if tiles is not None]
 
     def describe(self) -> str:
-        """``fwd q512 k512 h4 kq; dkdv q512 k512 h2; dq q512 k512 h4``: the
-        forward's word ``kq`` says it takes its scores keys-major."""
+        """``fwd q512 k512 h4 kq; bwd q512 k512 h4`` (or ``...; dkdv q512
+        k512 h2; dq q512 k512 h4`` for the split pair): the forward's word
+        ``kq`` says it takes its scores keys-major."""
         return "; ".join("{} q{} k{} h{}".format(name, *tiles)
                          + " kq" * (name == "fwd")
-                         for name, tiles in zip(self._fields, self))
+                         for name, tiles in self.kernels())
 
 
 #: What a step's buffers may take of VMEM as `step_vmem_bytes` reckons them:
@@ -376,6 +393,11 @@ class FlashPlan(NamedTuple):
 #: leaves out what Mosaic adds (relayouts, spills, the temporaries of more
 #: than one pass where it overlaps unrolled passes).
 VMEM_BUDGET = 8 * 2 ** 20
+#: The VMEM the fused backward asks Mosaic for (`vmem_limit_bytes`; a v5e
+#: core has 128 MiB): its dQ, dK and dV accumulate over the whole lengths.
+#: The plan takes it where its step, accumulators included, leaves
+#: `VMEM_BUDGET` of this for what Mosaic adds.
+BWD_VMEM_LIMIT = 96 * 2 ** 20
 #: The cost of stepping the grid once (about 0.35 us: pipeline bookkeeping
 #: and the tiles' DMA descriptors), in the plan's unit: one score element
 #: of one head through one kernel (a 512 x 512 tile is 0.9-1.4 us). Read
@@ -434,7 +456,8 @@ def _pass_rows(rows: int, cols: int) -> int:
 
 def step_vmem_bytes(kernel: str, tiles: KernelTiles, D: int, itemsize: int,
                     kv_shared: bool, pack: int = 1,
-                    group: Optional[int] = None) -> int:
+                    group: Optional[int] = None,
+                    lengths: Optional[tuple] = None) -> int:
     """VMEM one grid step of ``kernel`` holds, from the shapes alone: every
     operand and result tile twice (the pipeline's double buffer), the
     float32 accumulators once, and the float32 scores, probabilities and
@@ -444,13 +467,25 @@ def step_vmem_bytes(kernel: str, tiles: KernelTiles, D: int, itemsize: int,
     lanes (``pack`` heads share them); a row of statistics or mask pads
     to 8 sublanes.
     Query heads that share K/V (``kv_shared``) hold one K/V head, or one a
-    ``group`` of them where a step holds whole groups (`_step_heads`)."""
+    ``group`` of them where a step holds whole groups (`_step_heads`).
+    The fused backward (``bwd``) keeps dQ^T, delta, dK and dV over the
+    whole (Sq, Sk) ``lengths`` and writes them through two staging tiles
+    of each side."""
     blk_q, blk_k, heads = tiles
     slabs = -(-heads // pack)  # [rows, lanes] tiles on the query side
     q_tile = slabs * blk_q * _lane_pad(pack * D)
     kv_slabs = max(1, heads // (group or heads)) if kv_shared else slabs
     kv_tile = kv_slabs * blk_k * _lane_pad(pack * D)
     rows = 2 * 8 * 4 * (slabs * blk_q + blk_k)  # lse (delta) and the mask
+    if kernel == "bwd":      # q, dO, o | k, v | staging | s, dp, ds, dQ^T's
+        Sq, Sk = lengths     # term | dQ^T, delta | dK, dV
+        tiles_io = 2 * itemsize * (3 * q_tile + 2 * kv_tile) + rows
+        staging = 2 * itemsize * (q_tile + kv_tile)
+        temps = _pass_rows(blk_k, blk_q) * blk_q * (3 * 4 + 2 * itemsize) \
+            + 4 * pack * D * blk_q
+        scratch = 4 * (slabs * (pack * D + 8) * Sq
+                       + 2 * kv_slabs * Sk * _lane_pad(pack * D))
+        return tiles_io + staging + temps + scratch
     if kernel == "fwd":      # q, o | k, v | o^T, m, l | s, p and p rounded
         tiles_io = 2 * itemsize * (2 * q_tile + 2 * kv_tile) + rows
         scratch = 4 * (q_tile + 2 * 8 * slabs * blk_q)
@@ -489,18 +524,23 @@ def _blocks_run(Sq: int, Sk: int, blk_q: int, blk_k: int, causal: bool,
 
 
 # A step of a walk, in its ``flags``: what its tile is, and whether the step
-# opens and closes its accumulator.
+# opens and closes its accumulator. The fused backward's walk also marks the
+# first step at each q-block of a rep block (`_OPEN`, where delta is made)
+# and, from bit `_HELD` up, the q-block whose o the step holds: the last
+# one opened.
 _EMPTY, _PARTIAL, _WHOLE, _KIND = 0, 1, 2, 3
-_FIRST, _LAST = 4, 8
+_FIRST, _LAST, _OPEN = 4, 8, 16
+_HELD = 5
 
 
 class TileWalk(NamedTuple):
     """The grid steps of one kernel under a mask that empties tiles: per
     step the q-block and k-block of its tile, the block of query heads it
-    belongs to (``rep``; the dK/dV kernel streams a K/V group's rep blocks
-    through one accumulator, the other two kernels have the rep axis on the
-    grid) and its ``flags`` (`_KIND`, `_FIRST`, `_LAST`). `table` is what
-    the kernel and its index maps read from SMEM."""
+    belongs to (``rep``; the dK/dV kernel and the fused backward stream a
+    K/V group's rep blocks through one accumulator, the forward and dQ
+    kernels have the rep axis on the grid) and its ``flags`` (`_KIND`,
+    `_FIRST`, `_LAST`; the fused backward's `_OPEN` and `_HELD`). `table`
+    is what the kernel and its index maps read from SMEM."""
     q_blk: tuple
     k_blk: tuple
     flags: tuple
@@ -539,17 +579,39 @@ def tile_walk(kernel: str, Sq: int, Sk: int, blk_q: int, blk_k: int,
     dense grid visits them**, so every accumulator sees the same terms in
     the same order: ``fwd`` and ``dq`` by q-block, then ascending k-block;
     ``dkdv`` by k-block, then rep block (``reps`` of them), then ascending
-    q-block. A tile in which every pair is visible (`_tile_whole`) is
-    marked whole, the others partial. An accumulator that no tile reaches
-    (under a causal mask with Sq > Sk the first query rows see no key) still
-    gets one step, marked empty, on the block the walk already holds, which
-    only initialises and finalises it: its ``out`` / ``dq`` block is written
-    (zeros, and the log-sum-exp of no key) as the dense grid wrote it. Every
-    k-block is seen by some query under both mask kinds."""
+    q-block; ``bwd`` (the fused backward) by rep block, then k-block, then
+    ascending q-block, so that each q-block's dQ still takes its k-blocks
+    in ascending order and each k-block's dK/dV its (rep block, q-block)
+    pairs in the order ``dkdv`` gives them. A tile in which every pair is
+    visible (`_tile_whole`) is marked whole, the others partial. An
+    accumulator that no tile reaches (under a causal mask with Sq > Sk the
+    first query rows see no key) still gets one step, marked empty, on the
+    block the walk already holds, which only initialises and finalises it:
+    its ``out`` / ``dq`` block is written (zeros, and the log-sum-exp of no
+    key) as the dense grid wrote it. Every k-block is seen by some query
+    under both mask kinds. The fused backward's accumulators span the whole
+    lengths and are written whole, zeros where no tile reached: its walk
+    has no empty step, its first and last flags open and close a rep
+    block, and it marks where a rep block first reaches each q-block
+    (`_OPEN`) and which q-block's o a step holds (`_HELD`)."""
     runs = _tiles_at(Sq, Sk, blk_q, blk_k, _tile_runs, causal, structure)
     if runs is None:
         return None
     whole = _tiles_at(Sq, Sk, blk_q, blk_k, _tile_whole, causal, structure)
+    if kernel == "bwd":
+        rows = []  # (q-block, k-block, flags, rep block)
+        tiles = list(zip(*runs.T.nonzero()))  # by k-block, then q-block
+        for rep in range(reps):
+            opened, held = set(), 0
+            for n, (kb, qi) in enumerate(tiles):
+                flags = (_WHOLE if whole[qi, kb] else _PARTIAL) \
+                    | _FIRST * (n == 0) | _LAST * (n == len(tiles) - 1)
+                if qi not in opened:
+                    opened.add(qi)
+                    held, flags = qi, flags | _OPEN
+                rows.append((int(qi), int(kb), flags | int(held) << _HELD,
+                             rep))
+        return TileWalk(*zip(*rows))
     by_k = kernel == "dkdv"
     if by_k:
         runs, whole = runs.T, whole.T
@@ -593,13 +655,16 @@ def tile_plan(Sq: int, Sk: int, D: int, H: int, Hkv: int, itemsize: int,
     Mosaic to move: `_step_heads`) until stepping is under ``STEP_SHARE``
     of it or VMEM is full. ``has_mask`` moves nothing yet: a key-padding
     row is 4 KB a step. A mask description (``structure``) weighs in as
-    the causal mask does, through the tiles it leaves to run."""
+    the causal mask does, through the tiles it leaves to run.
+
+    The backward is the fused kernel where it fits (`fused_fits`), else
+    the split pair, each at tiles chosen the same way."""
     del has_mask
     kv_shared = H != Hkv
     pack = lane_pack(H, Hkv, D)
     step_heads = _step_heads(H, Hkv, D)
-    plan = []
-    for kernel in FlashPlan._fields:
+
+    def tiles_of(kernel):
         def cost(pair):
             blk_q, blk_k = pair
             along = blk_q if kernel == "dkdv" else blk_k
@@ -607,12 +672,18 @@ def tile_plan(Sq: int, Sk: int, D: int, H: int, Hkv: int, itemsize: int,
                     * (STEP_COST + blk_q * blk_k), -along)
 
         def fits(blk_q, blk_k, heads):
-            return step_vmem_bytes(kernel, KernelTiles(blk_q, blk_k, heads),
-                                   D, itemsize, kv_shared, pack,
-                                   H // Hkv) <= VMEM_BUDGET
+            tiles = KernelTiles(blk_q, blk_k, heads)
+            if kernel == "bwd":
+                return fused_fits(Sq, Sk, D, H, Hkv, itemsize, tiles)
+            return step_vmem_bytes(kernel, tiles, D, itemsize, kv_shared,
+                                   pack, H // Hkv) <= VMEM_BUDGET
 
         pairs = [(bq, bk) for bq in _tiles_of(Sq) for bk in _tiles_of(Sk)
-                 if fits(bq, bk, step_heads[0])] or [(_LANES, _LANES)]
+                 if fits(bq, bk, step_heads[0])]
+        if not pairs:
+            if kernel == "bwd":
+                return None
+            pairs = [(_LANES, _LANES)]
         blk_q, blk_k = min(pairs, key=cost)
         heads = step_heads[0]
         for h in step_heads:
@@ -621,8 +692,31 @@ def tile_plan(Sq: int, Sk: int, D: int, H: int, Hkv: int, itemsize: int,
             heads = h
             if STEP_COST / h <= STEP_SHARE * blk_q * blk_k:
                 break
-        plan.append(KernelTiles(blk_q, blk_k, heads))
-    return FlashPlan(*plan)
+        return KernelTiles(blk_q, blk_k, heads)
+
+    fwd, bwd = tiles_of("fwd"), tiles_of("bwd")
+    if bwd is not None:
+        return FlashPlan(fwd, bwd=bwd)
+    return FlashPlan(fwd, tiles_of("dkdv"), tiles_of("dq"))
+
+
+def fused_fits(Sq: int, Sk: int, D: int, H: int, Hkv: int, itemsize: int,
+               tiles: KernelTiles) -> bool:
+    """Whether the fused backward runs at ``tiles``: its passes are the
+    dK/dV kernel's and one product more, so the dK/dV kernel's step at
+    these tiles fits `VMEM_BUDGET`, and its own step, with its
+    accumulators over the whole lengths, leaves `VMEM_BUDGET` of
+    `BWD_VMEM_LIMIT` for what Mosaic adds (S 131,072 in GQA at D 128
+    does not fit: dK and dV alone are 128 MiB of float32). Its results
+    leave by DMA, from windows of rows of the results in HBM, which Mosaic
+    cuts only where a row is whole 128-lane tiles: not at 4 heads of 72 or
+    3 of 64."""
+    if H * D % _LANES or Hkv * D % _LANES:
+        return False
+    args = (tiles, D, itemsize, H != Hkv, lane_pack(H, Hkv, D), H // Hkv)
+    return step_vmem_bytes("dkdv", *args) <= VMEM_BUDGET \
+        and step_vmem_bytes("bwd", *args, lengths=(Sq, Sk)) \
+        <= BWD_VMEM_LIMIT - VMEM_BUDGET
 
 
 def _remember(plan: FlashPlan, Sq: int, Sk: int, causal: bool,
@@ -632,8 +726,9 @@ def _remember(plan: FlashPlan, Sq: int, Sk: int, causal: bool,
         # The mask kind and its size (a window's, or the block length and
         # the data length), per kernel the tiles of a head that run of those
         # there are, and what its grid walks for them.
+        kernels = plan.kernels()
         walks = [tile_walk(name, Sq, Sk, t.blk_q, t.blk_k, causal, structure)
-                 for name, t in zip(plan._fields, plan)]
+                 for name, t in kernels]
         kind = "window w{}".format(structure.window) \
             if isinstance(structure, CausalWindow) \
             else "block_diffusion b{} L{}".format(structure.block,
@@ -643,9 +738,9 @@ def _remember(plan: FlashPlan, Sq: int, Sk: int, causal: bool,
             " ".join("{} {}/{}".format(
                 name, walk.steps - walk.count(_EMPTY),
                 (Sq // t.blk_q) * (Sk // t.blk_k))
-                for name, t, walk in zip(plan._fields, plan, walks)),
+                for (name, t), walk in zip(kernels, walks)),
             " ".join("{} {}".format(name, walk.describe())
-                     for name, walk in zip(plan._fields, walks)))
+                     for (name, _), walk in zip(kernels, walks)))
     remember_plan("flash", said)
 
 
@@ -776,20 +871,27 @@ class _Step(NamedTuple):
     (``last()``) its accumulator, and the tile's kind (`_KIND` of a walk's
     flags; None on the dense grid, where every tile runs under whatever
     mask the kernel has). ``first`` and ``last`` are asked where the kernel
-    needs them, and in that order."""
+    needs them, and in that order. The fused backward also asks for its
+    rep block (``rep()``) and whether the step is the first of its rep
+    block at its q-block (``opens()``)."""
     qi: jax.Array
     kb: jax.Array
     first: Callable[[], jax.Array]
     last: Callable[[], jax.Array]
     kind: Optional[jax.Array] = None
+    rep: Optional[Callable[[], jax.Array]] = None
+    opens: Optional[Callable[[], jax.Array]] = None
 
 
-def _dense_step(q_axis: int, k_axis: int, streamed: tuple) -> _Step:
+def _dense_step(q_axis: int, k_axis: int, streamed: tuple,
+                rep_axis: Optional[int] = None) -> _Step:
     """A step of the dense grid: the blocks are program ids, and an
     accumulator opens at the first and closes at the last program of the
-    ``streamed`` (sequential) axes. Ids, extents and comparisons are traced
-    where the kernels always traced them, so a kernel with nothing to walk
-    lowers to the Mosaic module it always did."""
+    ``streamed`` (sequential) axes; the rep block is program id
+    ``rep_axis``, and a q-block is first reached at k-block 0. Ids, extents
+    and comparisons are traced where the kernels always traced them, so a
+    kernel with nothing to walk lowers to the Mosaic module it always
+    did."""
     from jax.experimental import pallas as pl
 
     ids = {a: pl.program_id(a) for a in sorted({q_axis, k_axis, *streamed})}
@@ -804,7 +906,9 @@ def _dense_step(q_axis: int, k_axis: int, streamed: tuple) -> _Step:
         return functools.reduce(operator.and_, (
             ids[a] == n - 1 for a, n in zip(streamed, extents)))
 
-    return _Step(ids[q_axis], ids[k_axis], first, last)
+    rep = None if rep_axis is None else pl.program_id(rep_axis)
+    return _Step(ids[q_axis], ids[k_axis], first, last, rep=lambda: rep,
+                 opens=lambda: ids[k_axis] == 0)
 
 
 def _walk_step(walk_ref, axis: int) -> _Step:
@@ -816,19 +920,24 @@ def _walk_step(walk_ref, axis: int) -> _Step:
     flags = walk_ref[2 * steps + t]
     return _Step(walk_ref[t], walk_ref[steps + t],
                  lambda: flags & _FIRST != 0, lambda: flags & _LAST != 0,
-                 flags & _KIND)
+                 flags & _KIND, rep=lambda: walk_ref[3 * steps + t],
+                 opens=lambda: flags & _OPEN != 0)
 
 
-def _step_and_refs(refs, causal, structure, q_axis, k_axis, streamed):
+def _step_and_refs(refs, causal, structure, q_axis, k_axis, streamed,
+                   rep_axis=None):
     """(this grid step, the kernel's operand, result and scratch refs). A
     kernel walks where its mask kind empties tiles by their indices: the
     walk's table is then its first ref (scalar prefetch) and the walk's
     steps the grid axis that follows the parallel ones the dense grid
     begins with. Else the grid is dense, with the q-blocks, the k-blocks
-    and the ``streamed`` (sequential) axes where the arguments say."""
+    and the ``streamed`` (sequential) axes where the arguments say, and
+    the rep blocks on ``rep_axis`` where the walk carries them (the fused
+    backward)."""
     if causal or structure is not None:
-        return _walk_step(refs[0], min(q_axis, k_axis)), refs[1:]
-    return _dense_step(q_axis, k_axis, streamed), refs
+        axes = (q_axis, k_axis) + (() if rep_axis is None else (rep_axis,))
+        return _walk_step(refs[0], min(axes)), refs[1:]
+    return _dense_step(q_axis, k_axis, streamed, rep_axis), refs
 
 
 def _on_tile(step: _Step, body) -> None:
@@ -1136,15 +1245,25 @@ def flash_attention(q, k, v, mask=None, causal: bool = True, blk_q: int = 128,
                     blk_k: int = 128, interpret: bool = False):
     """Flash attention on q [B,Sq,H,D], k/v [B,Sk,Hkv,D] (Hkv divides H —
     GQA handled without materializing repeated K/V) at the caller's own
-    [blk_q, blk_k] tiles in all three kernels, one head a step (ring
-    attention, Ulysses and the tests; `multi_head_attention` plans its
-    tiles from the shape). ``mask``: optional [B, Sk] (or [B,1,Sk])
-    keep-mask over keys. A query row whose keys are ALL masked outputs the
-    uniform average of V (p = exp(NEG_INF-NEG_INF) per key — the same value
-    the reference's softmax-of-all-masked produces); such rows are padding
-    and must be excluded from the loss."""
+    [blk_q, blk_k] tiles in every kernel, one head a step (ring attention,
+    Ulysses and the tests; `multi_head_attention` plans its tiles from the
+    shape). ``mask``: optional [B, Sk] (or [B,1,Sk]) keep-mask over keys.
+    A query row whose keys are ALL masked outputs the uniform average of V
+    (p = exp(NEG_INF-NEG_INF) per key — the same value the reference's
+    softmax-of-all-masked produces); such rows are padding and must be
+    excluded from the loss."""
     return flash_attention_planned(q, k, v, mask, causal,
-                                   FlashPlan.explicit(blk_q, blk_k), interpret)
+                                   _explicit_plan(q, k, blk_q, blk_k),
+                                   interpret)
+
+
+def _explicit_plan(q, k, blk_q: int, blk_k: int) -> FlashPlan:
+    """The caller's own tiles, with the fused backward where it fits the
+    shape (`fused_fits`), else the split pair."""
+    _, Sq, H, D = q.shape
+    Sk, Hkv = k.shape[1], k.shape[2]
+    return FlashPlan.explicit(blk_q, blk_k, fused_fits(
+        Sq, Sk, D, H, Hkv, q.dtype.itemsize, KernelTiles(blk_q, blk_k)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -1328,14 +1447,244 @@ def _flash_bwd_dq_kernel(*refs, causal, sm_scale, has_mask, offset,
         sl.each(write)
 
 
+def _dma_out(n, fill, stages, flight, window, start=0) -> None:
+    """A result held in VMEM to HBM in ``n`` chunks: ``fill(c, stage)``
+    casts chunk c into one of the two staging refs ``stages``, and a DMA
+    takes it to ``window(c)``, the chunk's place in the result. Chunk c
+    takes slot ``(start + c) % 2``, so that a chunk's DMA runs while the
+    next one is cast; a loop round takes a pair of chunks, so each knows
+    its staging ref as it is traced (Mosaic slices no staging buffer whose
+    rows are not whole lane tiles). A DMA is waited for when its slot is
+    wanted again, here or in a later grid step (`_Flight`), so the last
+    chunks' DMAs run on while the kernel goes on."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def chunk(c, slot):
+        flight.wait(stages, window, slot)
+        fill(c, stages[slot])
+        pltpu.make_async_copy(stages[slot], window(c),
+                              flight.sem(slot)).start()
+        flight.pending[flight.base + slot] = 1
+
+    def pair(i, _):
+        for j in range(2):
+            chunk(2 * i + j, (start + j) % 2)
+
+    jax.lax.fori_loop(0, n // 2, pair, None)
+    if n % 2:
+        chunk(n - 1, (start + n - 1) % 2)
+
+
+class _Flight(NamedTuple):
+    """The DMAs in flight from one pair of staging slots: their semaphores
+    (``sems``) and whether each slot has one (``pending``, in SMEM), at
+    ``base`` and ``base + 1`` of both."""
+    sems: object
+    pending: object
+    base: int
+
+    def sem(self, slot):
+        return self.sems.at[self.base + slot]
+
+    def wait(self, stages, window, slot) -> None:
+        """Wait for the DMA in flight from ``slot``, if any (the wait's
+        window is any of the result's: only its size counts)."""
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        @pl.when(self.pending[self.base + slot] != 0)
+        def _():
+            pltpu.make_async_copy(stages[slot], window(0),
+                                  self.sem(slot)).wait()
+            self.pending[self.base + slot] = 0
+
+
+def _flash_bwd_kernel(*refs, causal, sm_scale, has_mask, offset, reps,
+                      structure=None):
+    """The fused backward: grid (B, head block, r, kb, qi), the three inner
+    dims sequential; under a causal mask or a description a `TileWalk`'s
+    steps in that order. A step makes the keys-major scores of its tile
+    once, s^T = K Q^T, and from p^T = exp(s^T - lse), dP^T = V dO^T and
+    dS^T = p^T (dP^T - delta) all three results: dV += p^T dO, dK += dS^T
+    Q (the dK/dV kernel's products, a chunk of key rows owning its rows of
+    dK and dV) and dQ^T += K^T dS^T, K taken transposed as the forward
+    takes V. Each accumulates in float32 VMEM over the whole length: dQ^T
+    [slabs, width, Sq] over a rep block's walk, so a q-block takes its
+    k-blocks in ascending order as the dQ kernel gave them; dK and dV
+    [kv_slabs, Sk, width] over the group's rep blocks, so a k-block takes
+    its (rep block, q-block) pairs in the dK/dV kernel's order. delta =
+    rowsum(dO o) is made where a rep block first reaches a q-block (o's
+    block index moves only there), into [slabs, pack, Sq]. A result is
+    written once, when its accumulator closes, cast a chunk at a time and
+    moved to HBM by DMA (`_dma_out`): dQ at the end of a rep block, dK and
+    dV at the end of the group. The last chunks' DMAs run on into the
+    steps that follow and are waited for where their staging slot is
+    wanted again, or at the kernel's last step (at BERT's shape, one tile a
+    head, a step that waited for its own took 1.94 ms a layer on a v5e
+    where this takes 1.64).
+
+    Refs: q/dO/o [BLK_Q, slabs*width]; k/v [BLK_K, kv_slabs*width]; lse
+    [slabs, pack, BLK_Q]; (mask [1, BLK_K] int32); dq, dk, dv in HBM
+    (memory space ANY); scratch dQ^T, dK, dV, delta, the two staging pairs,
+    their DMA semaphores and which of them has a DMA in flight."""
+    from jax.experimental import pallas as pl
+
+    step, refs = _step_and_refs(refs, causal, structure, 4, 3, (3, 4), 2)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref = refs[:6]
+    mask_ref = refs[6] if has_mask else None
+    (dq_hbm, dk_hbm, dv_hbm, dq_acc, dk_acc, dv_acc, delta_acc, *stages,
+     sems, pending) = refs[6 + has_mask:]
+    q_stages, kv_stages = stages[:2], stages[2:]
+    q_flight, kv_flight = _Flight(sems, pending, 0), _Flight(sems, pending, 2)
+
+    slabs, pack, blk_q = lse_ref.shape
+    sl = _Slabs.of(q_ref, k_ref, slabs)
+    blk_k = k_ref.shape[0]
+    qi, kb = step.qi, step.kb
+    rows = _pass_rows(blk_k, blk_q)
+    head = functools.partial(_chunk, rows=sl.width // pack)  # rows of dQ^T
+    queries = _chunk(qi, blk_q)  # the tile's lanes of dQ^T and delta
+    b, g, r = pl.program_id(0), pl.program_id(1), step.rep()
+
+    def zero(n, part):
+        """Each of ``n`` parts of an accumulator to zeros, in a loop."""
+        jax.lax.fori_loop(0, n, lambda c, _: part(c), None)
+
+    @pl.when(step.first() & (r == 0) & (b == 0) & (g == 0))
+    def _open_kernel():  # no DMA of a result is in flight yet
+        for i in range(4):
+            pending[i] = 0
+
+    @pl.when(step.first())
+    def _init():
+        def dq_part(c):
+            dq_acc[:, :, _chunk(c, blk_q)] = jnp.zeros(
+                dq_acc.shape[:2] + (blk_q,), dq_acc.dtype)
+
+        zero(dq_acc.shape[2] // blk_q, dq_part)
+
+        @pl.when(r == 0)
+        def _open_group():
+            def kv_part(c):
+                for acc in (dk_acc, dv_acc):
+                    acc[:, _chunk(c, blk_k)] = jnp.zeros(
+                        (acc.shape[0], blk_k, acc.shape[2]), acc.dtype)
+
+            zero(dk_acc.shape[1] // blk_k, kv_part)
+
+    @pl.when(step.opens())
+    def _delta():
+        d_rows = _pass_rows(blk_q, blk_k)
+
+        def make_delta(h, c):
+            part = _chunk(c, d_rows)
+            prod = do_ref[part, sl.cols(h)].astype(jnp.float32) \
+                * o_ref[part, sl.cols(h)].astype(jnp.float32)
+            at = _chunk(qi * (blk_q // d_rows) + c, d_rows)
+            for j in range(pack):
+                delta_acc[h, j:j + 1, at] = _as_row(jnp.sum(
+                    _lanes_of(j, pack, prod), axis=1, keepdims=True))
+
+        _each_chunk(sl, blk_q // d_rows, make_delta)
+
+    def contribute(masked, h, c):
+        keys = _chunk(c, rows)
+        k_blk, v_blk = k_ref[keys, sl.kv(h)], v_ref[keys, sl.kv(h)]
+        keep = _key_column(mask_ref, keys)
+        dk = dv = 0.0
+        for j in range(pack):
+            q = _lanes_of(j, pack, q_ref[:, sl.cols(h)])
+            do = _lanes_of(j, pack, do_ref[:, sl.cols(h)])
+            s = _scores(q, k_blk, qi * blk_q, kb * blk_k + c * rows,
+                        causal and masked, sm_scale, offset, keep,
+                        structure if masked else None, q_axis=1)
+            p = jnp.exp(s - lse_ref[h, j:j + 1])
+            ds = (p * (_dot(v_blk, do, _NT)
+                       - delta_acc[h, j:j + 1, queries])).astype(q.dtype)
+            dv += _dot(p.astype(do.dtype), do, _NN)  # head j's lanes alone
+            dk += _dot(ds, q, _NN)
+            dq_acc[h, head(j), queries] += _dot(k_blk[:, head(j)], ds, _TN)
+        at = _chunk(kb * (blk_k // rows) + c, rows)
+        dv_acc[h // sl.per, at] += dv
+        dk_acc[h // sl.per, at] += dk
+
+    _on_tile(step, lambda masked: _each_chunk(
+        sl, blk_k // rows, functools.partial(contribute, masked)))
+
+    @pl.when(step.last())
+    def _finalize():
+        # ds lacked the scale; dQ and dK take it once, as they are written.
+        def window(result, stages, block):
+            """Chunk c of a result: its rows as many as a staging ref's, in
+            the step's column block, as wide as a staging ref."""
+            rows, cols = stages[0].shape
+            return lambda c: result.at[b, _chunk(c, rows),
+                                       pl.ds(block * cols, cols)]
+
+        def fill_dq(c, stage):
+            def slab(h):
+                stage[:, sl.cols(h)] = (
+                    dq_acc[h, :, _chunk(c, blk_q)].T * sm_scale
+                ).astype(stage.dtype)
+
+            sl.each(slab)
+
+        dq_window = window(dq_hbm, q_stages, g * reps + r)
+        _dma_out(dq_acc.shape[2] // blk_q, fill_dq, q_stages, q_flight,
+                 dq_window)
+        kv_windows = [window(result, kv_stages, g)
+                      for result in (dk_hbm, dv_hbm)]
+        n_kv = dk_acc.shape[1] // blk_k
+
+        @pl.when(r == reps - 1)
+        def _close_group():
+            for acc, at, scale, start in ((dk_acc, kv_windows[0], sm_scale, 0),
+                                          (dv_acc, kv_windows[1], None, n_kv)):
+                def fill(c, stage, acc=acc, scale=scale):
+                    def slab(h):
+                        x = acc[h, _chunk(c, blk_k)]
+                        stage[:, sl.cols(h)] = (
+                            x if scale is None else x * scale
+                        ).astype(stage.dtype)
+
+                    sl.each(slab, acc.shape[0])
+
+                _dma_out(n_kv, fill, kv_stages, kv_flight, at, start)
+
+        @pl.when((r == reps - 1) & (b == pl.num_programs(0) - 1)
+                 & (g == pl.num_programs(1) - 1))
+        def _close_kernel():  # the last step: every DMA done
+            for slot in range(2):
+                q_flight.wait(q_stages, dq_window, slot)
+                kv_flight.wait(kv_stages, kv_windows[0], slot)
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "plan", "heads",
                                              "interpret", "structure"))
 def _flash_bwd(q, k, v, do, lse, out, mask, causal, plan, heads,
                interpret, structure=None):
-    """Pallas flash backward: dQ (which makes delta from dO and out), then
-    dK/dV. q/do/out: [B,Sq,H*D]; k/v: [B,Sk,Hkv*D] (`_columns`), ``heads``
-    (H, Hkv); lse: [B,H/pack,pack,Sq] fp32 (compact); mask: [B,1,Sk] int32
-    or None. Returns (dq [B,Sq,H*D], dk/dv [B,Sk,Hkv*D])."""
+    """Pallas flash backward: the fused kernel where the plan has it, else
+    dQ (which makes delta from dO and out), then dK/dV. q/do/out:
+    [B,Sq,H*D]; k/v: [B,Sk,Hkv*D] (`_columns`), ``heads`` (H, Hkv); lse:
+    [B,H/pack,pack,Sq] fp32 (compact); mask: [B,1,Sk] int32 or None.
+    Returns (dq [B,Sq,H*D], dk/dv [B,Sk,Hkv*D])."""
+    B, Sq, _ = q.shape
+    Sk = k.shape[1]
+    H, Hkv = heads
+    D = q.shape[2] // H
+    kernel_args = dict(causal=causal, sm_scale=1.0 / (D ** 0.5),
+                       has_mask=mask is not None, offset=Sk - Sq,
+                       structure=structure)
+    if plan.bwd is not None:
+        return _fused_bwd(q, k, v, do, lse, out, mask, plan.bwd, heads,
+                          interpret, kernel_args)
+    return _split_bwd(q, k, v, do, lse, out, mask, plan, heads, interpret,
+                      kernel_args)
+
+
+def _fused_bwd(q, k, v, do, lse, out, mask, tiles, heads, interpret,
+               kernel_args):
+    """The fused backward (`_flash_bwd_kernel`) as one `pallas_call`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1345,11 +1694,81 @@ def _flash_bwd(q, k, v, do, lse, out, mask, causal, plan, heads,
     D = q.shape[2] // H
     pack = lane_pack(H, Hkv, D)
     width = pack * D
-    offset = Sk - Sq
-    sm_scale = 1.0 / (D ** 0.5)
+    blk_q, blk_k, _ = tiles
+    G, R, hg, hr = _head_blocks(Sq, Sk, H, Hkv, D, tiles)
+    slabs, reps = hg * hr, R // hr
+    walk = tile_walk("bwd", Sq, Sk, blk_q, blk_k, kernel_args["causal"],
+                     kernel_args["structure"], reps)
+    grid, _, prefetch, on = _kernel_grid(
+        walk, (B, G // hg), (), (reps, Sk // blk_k, Sq // blk_q),
+        lambda b, g, r, kb, qi: (b, g, r, qi, kb))
+    if walk is None:  # o's block moves where a rep block opens a q-block
+        def o_at(b, g, r, kb, qi):
+            return b, jnp.where(kb == 0, qi, Sq // blk_q - 1), g * reps + r
+    else:
+        def o_at(b, g, t, table):
+            return (b, table[2 * walk.steps + t] >> _HELD,
+                    g * reps + table[3 * walk.steps + t])
+
+    q_spec = pl.BlockSpec((None, blk_q, slabs * width), on(_q_at(reps)))
+    kv_spec = pl.BlockSpec((None, blk_k, hg * width), on(_kv_at))
+    in_specs = [q_spec, kv_spec, kv_spec, q_spec,
+                pl.BlockSpec((None, slabs, pack, blk_q), on(_stat_at(reps))),
+                pl.BlockSpec((None, blk_q, slabs * width), o_at)]
+    operands = [q, k, v, do, lse, out]
+    if mask is not None:
+        in_specs.append(pl.BlockSpec((None, 1, blk_k), on(_mask_at)))
+        operands.append(mask)
+
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, reps=reps, **kernel_args),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+            scratch_shapes=[
+                pltpu.VMEM((slabs, width, Sq), jnp.float32),
+                pltpu.VMEM((hg, Sk, width), jnp.float32),
+                pltpu.VMEM((hg, Sk, width), jnp.float32),
+                pltpu.VMEM((slabs, pack, Sq), jnp.float32),
+                *[pltpu.VMEM((blk_q, slabs * width), q.dtype)] * 2,
+                *[pltpu.VMEM((blk_k, hg * width), k.dtype)] * 2,
+                pltpu.SemaphoreType.DMA((4,)),
+                pltpu.SMEM((4,), jnp.int32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            # In order, every axis: a group's rep blocks share its dK/dV
+            # accumulators, and a result's DMAs run on into the steps that
+            # follow, to the kernel's last.
+            dimension_semantics=("arbitrary",) * len(grid),
+            vmem_limit_bytes=BWD_VMEM_LIMIT),
+        interpret=interpret,
+        name="flash_bwd",
+    )(*prefetch, *operands)
+    return dq, dk, dv
+
+
+def _split_bwd(q, k, v, do, lse, out, mask, plan, heads, interpret,
+               kernel_args):
+    """The split backward: dQ (which makes delta from dO and out), then
+    dK/dV, two `pallas_call`s (the plan's ``dq`` and ``dkdv``), where the
+    fused kernel's accumulators do not fit."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, Sq, _ = q.shape
+    Sk = k.shape[1]
+    H, Hkv = heads
+    D = q.shape[2] // H
+    pack = lane_pack(H, Hkv, D)
+    width = pack * D
+    causal, structure = kernel_args["causal"], kernel_args["structure"]
     has_mask = mask is not None
-    kernel_args = dict(causal=causal, sm_scale=sm_scale, has_mask=has_mask,
-                       offset=offset, structure=structure)
 
     def specs(tiles, on, R, hg, hr):
         """The blocks of the query side, the K/V side and the statistics."""
@@ -1430,10 +1849,10 @@ def _flash_bwd(q, k, v, do, lse, out, mask, causal, plan, heads,
 
 
 def _flash_bwd_rule(causal, plan, interpret, structure, res, g):
-    """Flash backward as two Pallas kernels (dQ, which makes delta on the
-    way, then dK/dV), recomputing probabilities from the saved log-sum-exp
-    — the S x S matrix never materializes and VMEM holds one tile pair a
-    head at a time."""
+    """Flash backward as one Pallas kernel (or, where its accumulators do
+    not fit, two: dQ, which makes delta on the way, then dK/dV),
+    recomputing probabilities from the saved log-sum-exp — the S x S
+    matrix never materializes."""
     q, k, v, mask, out, lse = res
     mask3 = _canon_mask(mask, q.shape[0], k.shape[1])
     dq, dk, dv = _flash_bwd(
@@ -1477,7 +1896,7 @@ def flash_block_bwd(q, k, v, do, lse, out, causal: bool = True,
     dq, dk, dv = _flash_bwd(
         _columns(q), _columns(k), _columns(v), _columns(do),
         _slab_stats(lse, lane_pack(H, Hkv, q.shape[3])), _columns(out), None,
-        causal, FlashPlan.explicit(blk_q, blk_k), (H, Hkv), interpret)
+        causal, _explicit_plan(q, k, blk_q, blk_k), (H, Hkv), interpret)
     return (dq.reshape(q.shape).astype(jnp.float32),
             dk.reshape(k.shape).astype(jnp.float32),
             dv.reshape(v.shape).astype(jnp.float32))

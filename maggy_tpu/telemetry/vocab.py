@@ -250,16 +250,18 @@ ANNOTATION_NAMES = ("place_batch", "train_step", "report")
 #: first step dispatch) and ``flash_plan``: the tiles of the Pallas flash
 #: kernels in the step program this trial traced, as
 #: `ops.attention.FlashPlan.describe` writes them (``fwd q512 k512 h4 kq;
-#: dkdv q512 k512 h4; dq q512 k512 h4``: per kernel the query and key tile
-#: and the heads a grid step covers, and ``kq`` after the forward's: its
-#: scores are keys-major; several shapes in one program are
+#: bwd q512 k512 h4``: per kernel the query and key tile and the heads a
+#: grid step covers, and ``kq`` after the forward's: its scores are
+#: keys-major; ``bwd`` is the fused backward, where a shape too long for
+#: its accumulators runs the split pair, ``dkdv q.. k.. h..; dq q.. k..
+#: h..``; several shapes in one program are
 #: joined by `` | ``; under a mask description the plan also says the mask
 #: kind, its block length, per kernel the tiles of a head that run of
 #: those there are, and what each kernel's grid walks for them (steps that
 #: run a tile + steps that only open and close an accumulator, and the
 #: tiles that need the index mask): ``; block_diffusion b4 L4096 tiles fwd
-#: 80/256 dkdv 80/256 dq 80/256; walk fwd 80+0 (24 partial) dkdv 80+0 (24
-#: partial) dq 80+0 (24 partial)``). Absent where the trial traced
+#: 80/256 bwd 80/256; walk fwd 80+0 (24 partial) bwd 80+0 (24
+#: partial)``). Absent where the trial traced
 #: nothing (a warm trial) or the program holds no flash kernel.
 #: ``moe_plan``: what each
 #: dropless expert layer (`models.moe.ExpertShareMLP`) holds and how it

@@ -63,6 +63,14 @@ def _results(name):
         return att.flash_attention_planned(
             q, k, v, None, False, FlashPlan.explicit(*tiles), True, mask)
 
+    plan = FlashPlan.explicit(*tiles) if tiles else tile_plan(
+        S, S, D, H, Hkv, 4, False, False, mask)
+
+    def split(q, k, v):  # the same tiles, the backward as the split pair
+        return att.flash_attention_planned(
+            q, k, v, None, False, FlashPlan(plan.fwd, plan.bwd, plan.bwd),
+            True, mask)
+
     def reference(q, k, v):
         return attention_reference(q, k, v, causal=False, mask=mask.dense())
 
@@ -72,8 +80,8 @@ def _results(name):
 
     fwd = KernelTiles(*tiles) if tiles else tile_plan(
         S, S, D, H, Hkv, 4, False, False, mask).fwd
-    return {"flash": all_of(flash, flash_lse(q, k, v, None, False, fwd,
-                                             mask)),
+    lse = flash_lse(q, k, v, None, False, fwd, mask)
+    return {"flash": all_of(flash, lse), "split": all_of(split, lse),
             "reference": all_of(reference, reference_lse(
                 q, k, False, mask.dense()))}
 
@@ -81,9 +89,12 @@ def _results(name):
 @pytest.mark.parametrize("tensor", TENSORS)
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_flash_under_the_description_matches_the_dense_mask(name, tensor):
+    """The fused backward, and the split pair at the same tiles."""
     i = TENSORS.index(tensor)
-    got, want = (_results(name)[k][i] for k in ("flash", "reference"))
-    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+    want = _results(name)["reference"][i]
+    for kind in ("flash", "split"):
+        got = _results(name)[kind][i]
+        assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
 
 
 def _brute_force_tiles(L, block, blk_q, blk_k):
@@ -119,6 +130,9 @@ def _dense_grid_walk(kernel, visible, blk_q, blk_k, reps):
     if kernel == "dkdv":  # grid (kb, r, qi)
         groups = [[(qi, kb, r) for r in range(reps) for qi in range(nq)]
                   for kb in range(nk)]
+    elif kernel == "bwd":  # grid (r, kb, qi), dQ's accumulator a rep block
+        groups = [[(qi, kb, r) for kb in range(nk) for qi in range(nq)]
+                  for r in range(reps)]
     else:                 # grid (qi, kb), the rep blocks apart
         groups = [[(qi, kb, 0) for kb in range(nk)] for qi in range(nq)]
     steps = []
@@ -143,7 +157,7 @@ def test_the_walk_is_the_dense_grid_without_its_empty_steps(
     that straddles ``length`` among them: L 192 in 128-tiles), and each
     accumulator opened and closed once."""
     mask = BlockDiffusionMask(L, block)
-    reps = 2 if kernel == "dkdv" else 1
+    reps = 2 if kernel in ("dkdv", "bwd") else 1
     walk = att.tile_walk(kernel, 2 * L, 2 * L, blk_q, blk_k, False, mask, reps)
     want = _dense_grid_walk(kernel, np.asarray(mask.dense()), blk_q, blk_k,
                             reps)
@@ -167,11 +181,16 @@ def test_the_cell_walks_80_steps_a_head_24_of_them_partial(kernel):
             walk.count(att._EMPTY)) == (80, 24, 56, 0)
     assert walk.describe() == "80+0 (24 partial)"
     # dK/dV streams a K/V group's two blocks of four query heads through
-    # each k-block's accumulator: 160 steps, 80 a block of heads.
+    # each k-block's accumulator: 160 steps, 80 a block of heads; the fused
+    # backward streams them through its one accumulator over all keys.
     both = att.tile_walk("dkdv", 8192, 8192, 512, 512, False,
                          BlockDiffusionMask(4096, 4), 2)
     assert both.steps == 160 and sum(
         bool(f & att._FIRST) for f in both.flags) == 16
+    fused = att.tile_walk("bwd", 8192, 8192, 512, 512, False,
+                          BlockDiffusionMask(4096, 4), 2)
+    assert fused.steps == 160 and sum(
+        bool(f & att._FIRST) for f in fused.flags) == 2
 
 
 DESCRIBED_WALKS = {
@@ -205,8 +224,10 @@ def test_whole_tiles_without_the_mask_are_bitwise_the_masked_ones(name,
     assert att.tile_walk("fwd", 2 * L, 2 * L, blk_q, blk_k, False,
                          BlockDiffusionMask(L, block)).describe() == said
     found, i = _described_walk_bits(name), WALK_TENSORS.index(tensor)
-    assert np.isfinite(found["walk"][i]).all() and found["walk"][i].any()
-    np.testing.assert_array_equal(found["walk"][i], found["all_partial"][i])
+    for kind in ("", "fused_")[:1 + ("fused_walk" in found)]:
+        walk = found[kind + "walk"][i]
+        assert np.isfinite(walk).all() and walk.any()
+        np.testing.assert_array_equal(walk, found[kind + "all_partial"][i])
 
 
 def test_the_cell_skips_176_of_256_tiles():
@@ -215,8 +236,7 @@ def test_the_cell_skips_176_of_256_tiles():
     mask = BlockDiffusionMask(4096, 4)
     assert att._blocks_run(8192, 8192, 512, 512, False, mask) == 80
     plan = tile_plan(8192, 8192, 128, 32, 4, 2, False, False, mask)
-    assert plan.describe() == \
-        "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4"
+    assert plan.describe() == "fwd q512 k512 h4 kq; bwd q512 k512 h4"
 
 
 @pytest.mark.parametrize("Sq,Sk,blk_q,blk_k", [
@@ -234,11 +254,11 @@ def test_causal_tiles_run_are_what_they_were(Sq, Sk, blk_q, blk_k):
 @pytest.mark.parametrize("shape,said", [
     # BERT-base's cell, Llama's causal GQA, the kept ASHA mix's S 128.
     ((512, 512, 64, 12, 12, 2, False, True),
-     "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4"),
+     "fwd q512 k512 h4 kq; bwd q512 k512 h4"),
     ((2048, 2048, 128, 32, 8, 2, True, False),
-     "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4"),
+     "fwd q512 k512 h4 kq; bwd q512 k512 h4"),
     ((128, 128, 64, 12, 12, 2, False, True),
-     "fwd q128 k128 h12 kq; dkdv q128 k128 h12; dq q128 k128 h12"),
+     "fwd q128 k128 h12 kq; bwd q128 k128 h12"),
 ])
 def test_plans_without_a_description_are_unchanged(shape, said):
     assert tile_plan(*shape).describe() == said
@@ -258,11 +278,9 @@ def test_the_plan_says_the_mask_and_the_tiles_run():
     with att.plans_traced() as plans:
         multi_head_attention(q, q, q, causal=False,
                              mask=BlockDiffusionMask(128, 4), force="flash")
-    assert plans == ["fwd q256 k256 h2 kq; dkdv q256 k256 h2; "
-                     "dq q256 k256 h2; "
-                     "block_diffusion b4 L128 tiles fwd 1/1 dkdv 1/1 dq 1/1; "
-                     "walk fwd 1+0 (1 partial) dkdv 1+0 (1 partial) "
-                     "dq 1+0 (1 partial)"]
+    assert plans == ["fwd q256 k256 h2 kq; bwd q256 k256 h2; "
+                     "block_diffusion b4 L128 tiles fwd 1/1 bwd 1/1; "
+                     "walk fwd 1+0 (1 partial) bwd 1+0 (1 partial)"]
 
 
 def test_the_plan_of_the_cell_says_its_walk(monkeypatch):
@@ -273,10 +291,9 @@ def test_the_plan_of_the_cell_says_its_walk(monkeypatch):
     att._remember(tile_plan(8192, 8192, 128, 32, 4, 2, False, False, mask),
                   8192, 8192, False, mask)
     assert said == [
-        "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4; "
-        "block_diffusion b4 L4096 tiles fwd 80/256 dkdv 80/256 dq 80/256; "
-        "walk fwd 80+0 (24 partial) dkdv 80+0 (24 partial) "
-        "dq 80+0 (24 partial)"]
+        "fwd q512 k512 h4 kq; bwd q512 k512 h4; "
+        "block_diffusion b4 L4096 tiles fwd 80/256 bwd 80/256; "
+        "walk fwd 80+0 (24 partial) bwd 80+0 (24 partial)"]
 
 
 def test_a_description_never_falls_back_silently_on_a_tpu(monkeypatch):
@@ -349,7 +366,7 @@ def _under_checkpoint(kind):
         found[name] = (
             tuple(np.asarray(g) for g in grad(q, k, v)),
             {n: counts["pallas_call:" + n]
-             for n in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")})
+             for n in ("flash_fwd", "flash_bwd")})
     return found
 
 
@@ -359,7 +376,7 @@ def test_a_checkpoint_that_keeps_the_names_runs_the_forward_kernel_once(kind):
     forward kernel again for ``out`` and ``lse`` (what a rematerialised
     layer paid until PR 27); keeping `REMAT_KEEP` it does not."""
     found = _under_checkpoint(kind)
-    once = {"flash_fwd": 1, "flash_bwd_dkdv": 1, "flash_bwd_dq": 1}
+    once = {"flash_fwd": 1, "flash_bwd": 1}
     assert found["plain"][1] == once
     assert found["remat"][1] == dict(once, flash_fwd=2)
     assert found["policy"][1] == once
@@ -414,6 +431,6 @@ def test_the_three_kernels_compile_for_the_v5e_under_a_description():
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile() \
         .as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
-    for name in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"):
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in ("flash_fwd", "flash_bwd"):
         assert "%{}".format(name) in text

@@ -39,7 +39,7 @@ def test_flash_check_runs_every_shape_at_planned_and_explicit_tiles():
         ("bert_b1_s256_h4_d64_masked", 1, 256, 4, 4, 64, False, True),
         ("gqa_b1_s256_h4_kv2_d128_causal", 1, 256, 4, 2, 128, True, False)))
     assert report["bert_b1_s256_h4_d64_masked"]["plan"] == \
-        "fwd q256 k256 h4 kq; dkdv q256 k256 h4; dq q256 k256 h4"
+        "fwd q256 k256 h4 kq; bwd q256 k256 h4"
     for entry in report.values():
         for tiles in ("planned", "explicit_128"):
             assert set(entry[tiles]) == {"out", "dq", "dk", "dv"}

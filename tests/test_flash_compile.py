@@ -12,8 +12,18 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from maggy_tpu.ops.attention import (FlashPlan, flash_attention,
-                                     flash_attention_planned, tile_plan)
+from maggy_tpu.ops.attention import (FlashPlan, KernelTiles, flash_attention,
+                                     flash_attention_planned, fused_fits,
+                                     tile_plan)
+
+#: The names the kernels' `pallas_call`s carry, by the plan's names for them.
+KERNEL_NAMES = {"fwd": "flash_fwd", "bwd": "flash_bwd",
+                "dkdv": "flash_bwd_dkdv", "dq": "flash_bwd_dq"}
+
+
+def _plan_kernels(plan):
+    """The names of the kernels ``plan`` runs, sorted."""
+    return sorted(KERNEL_NAMES[name] for name, _ in plan.kernels())
 
 
 @pytest.fixture(scope="module")
@@ -52,8 +62,11 @@ def test_forward_and_both_backward_kernels_compile(v5e_device, shape, tiles):
     q = jax.ShapeDtypeStruct((B, Sq, H, D), dtype, sharding=v5e_device)
     kv = jax.ShapeDtypeStruct((B, Sk, Hkv, D), dtype, sharding=v5e_device)
     keep = jax.ShapeDtypeStruct((B, Sk), jnp.bool_, sharding=v5e_device)
-    plan = FlashPlan.explicit(128, 128) if tiles == "explicit_128" else \
-        tile_plan(Sq, Sk, D, H, Hkv, jnp.dtype(dtype).itemsize, causal, masked)
+    itemsize = jnp.dtype(dtype).itemsize
+    plan = FlashPlan.explicit(128, 128, fused_fits(
+        Sq, Sk, D, H, Hkv, itemsize, KernelTiles(128, 128))) \
+        if tiles == "explicit_128" else \
+        tile_plan(Sq, Sk, D, H, Hkv, itemsize, causal, masked)
 
     def loss(q, k, v, keep):
         out = flash_attention_planned(
@@ -63,10 +76,12 @@ def test_forward_and_both_backward_kernels_compile(v5e_device, shape, tiles):
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
         q, kv, kv, keep).compile()
-    # Forward, dK/dV and dQ: three Mosaic kernels in the executable, under
-    # the names the benchmark's readers find them by.
-    assert _kernel_names(compiled.as_text()) == [
-        "flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]
+    # The forward and the fused backward: two Mosaic kernels in the
+    # executable, under the names the benchmark's readers find them by;
+    # where a row is no whole 128-lane tiles (D 72) the split pair, dK/dV
+    # and dQ, in place of the fused kernel.
+    assert _kernel_names(compiled.as_text()) == _plan_kernels(plan)
+    assert (plan.bwd is None) == (name == "d72")
 
 
 WALKED = {
@@ -83,18 +98,22 @@ WALKED = {
     # a window of 4096 at S 16384 (70 of 256 tiles of 1024 run).
     "smallthinker_cell_gqa_28_4_window_s16384": (1, 16384, 28, 4, 128, True,
                                                  "window 4096"),
+    # Too long for the fused backward's accumulators (dK and dV alone are
+    # 128 MiB of float32): the split pair, dK/dV and dQ.
+    "llama_gqa_d128_causal_s131072": (1, 131072, 32, 8, 128, True, None),
 }
 
 
 @pytest.mark.parametrize("name", sorted(WALKED))
 def test_the_kernels_that_walk_their_tiles_compile(v5e_device, name):
-    """Under a mask that empties tiles the three kernels take a `TileWalk`'s
+    """Under a mask that empties tiles the kernels take a `TileWalk`'s
     table as a scalar-prefetch operand (SMEM) and hold two bodies, one for
     partial and one for whole tiles: both must pass Mosaic before a chip
     call. The table is the custom call's first operand, four rows of the
-    walk's steps."""
+    walk's steps. The fused backward runs wherever its accumulators fit,
+    the split pair only where they do not."""
     from maggy_tpu.ops.attention import (_PARTIAL, _WHOLE, BlockDiffusionMask,
-                                         CausalWindow, tile_walk)
+                                         CausalWindow, _head_blocks, tile_walk)
 
     B, S, H, Hkv, D, causal, described = WALKED[name]
     mask = described and (CausalWindow(int(described.split()[1]))
@@ -111,24 +130,25 @@ def test_the_kernels_that_walk_their_tiles_compile(v5e_device, name):
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
         ).as_text()
-    assert _kernel_names(text) == ["flash_bwd_dkdv", "flash_bwd_dq",
-                                   "flash_fwd"]
+    assert _kernel_names(text) == _plan_kernels(plan)
+    assert (plan.bwd is None) == (S > 16384)
     calls = {_kernel_names(line)[0]: line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line}
-    for kernel, tiles in zip(plan._fields, plan):
-        reps = (H // Hkv) // tiles.heads if kernel == "dkdv" else 1
+    for kernel, tiles in plan.kernels():
+        _, R, _, hr = _head_blocks(S, S, H, Hkv, D, tiles)
+        reps = R // hr if kernel in ("dkdv", "bwd") else 1
         walk = tile_walk(kernel, S, S, tiles.blk_q, tiles.blk_k, causal, mask,
                          reps)
         assert walk.count(_WHOLE) and walk.count(_PARTIAL)
-        call = calls[{"fwd": "flash_fwd"}.get(kernel, "flash_bwd_" + kernel)]
+        call = calls[KERNEL_NAMES[kernel]]
         assert "operand_layout_constraints={{s32[{}]".format(
             4 * walk.steps) in call
 
 
 def test_the_three_kernels_carry_their_names(v5e_device):
     """A trace names a kernel after its HLO instruction, which takes the
-    `pallas_call`'s ``name=``: the benchmark's readers find the forward,
-    dK/dV and dQ kernels by these names and by nothing positional."""
+    `pallas_call`'s ``name=``: the benchmark's readers find the forward and
+    the fused backward by these names and by nothing positional."""
     q = jax.ShapeDtypeStruct((2, 128, 12, 64), jnp.bfloat16,
                              sharding=v5e_device)
 
@@ -141,8 +161,7 @@ def test_the_three_kernels_carry_their_names(v5e_device):
     backward = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, q, q).compile()
 
     assert _kernel_names(forward.as_text()) == ["flash_fwd"]
-    assert _kernel_names(backward.as_text()) == [
-        "flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]
+    assert _kernel_names(backward.as_text()) == ["flash_bwd", "flash_fwd"]
 
 
 def test_the_convolution_kernels_compile_at_the_cells_shape(v5e_device):
@@ -208,8 +227,7 @@ def test_a_checkpointed_layer_compiles_with_one_forward_kernel(
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
         ).as_text()
-    assert _kernel_names(text) == (
-        ["flash_bwd_dkdv", "flash_bwd_dq"] + ["flash_fwd"] * forwards)
+    assert _kernel_names(text) == ["flash_bwd"] + ["flash_fwd"] * forwards
 
 
 def _kernel_names(text):
@@ -270,8 +288,8 @@ def test_a_bert_width_step_carries_the_scope_names(v5e_device, monkeypatch):
     text = step.lower(abstract(variables), abstract(opt_state),
                       batch).compile().as_text()
     assert text.startswith("HloModule jit_train_step")
-    assert _kernel_names(text) == (  # two layers x forward, dK/dV, dQ
-        ["flash_bwd_dkdv"] * 2 + ["flash_bwd_dq"] * 2 + ["flash_fwd"] * 2)
+    assert _kernel_names(text) == (  # two layers x forward, backward
+        ["flash_bwd"] * 2 + ["flash_fwd"] * 2)
     scopes = set()
     for op_name in re.findall(r'op_name="([^"]*)"', text):
         scopes.update(op_name.split("/"))
@@ -283,7 +301,8 @@ def test_a_rematerialised_sdar_step_holds_each_kernel_once_a_layer(
     """The model's own gradient through XLA:TPU, two layers at toy widths
     (heads of 128, GQA, a share of the experts): with ``remat=True`` the
     layers keep `models.sdar.REMAT_KEEP`, so the executable holds ONE
-    `flash_fwd` a layer beside its two backward kernels (two before PR 27);
+    `flash_fwd` a layer beside its fused backward (two where a layer
+    keeps nothing);
     the grouped products are what the expert layer's own VJP asks for
     either way (three forward, two of them again in the backward pass), and
     the combine is one kernel a pass (`moe_combine_fwd`, `moe_combine_bwd`),
@@ -323,7 +342,7 @@ def test_a_rematerialised_sdar_step_holds_each_kernel_once_a_layer(
     text = jax.jit(jax.grad(loss)).lower(
         params, tokens, targets, weights).compile().as_text()
     assert collections.Counter(_kernel_names(text)) == {
-        "flash_fwd": 2, "flash_bwd_dkdv": 2, "flash_bwd_dq": 2,
+        "flash_fwd": 2, "flash_bwd": 2,
         "moe_gmm_fwd": 10, "moe_gmm_dlhs": 6, "moe_gmm_drhs": 6,
         "moe_combine_fwd": 2, "moe_combine_bwd": 2}
 
@@ -365,7 +384,7 @@ def test_a_rematerialised_nemotron_step_holds_each_kernel_once_a_block(
     """The model's own gradient through XLA:TPU, one block of each kind at
     toy widths (heads of 128, GQA, a share of relu^2 experts, a scan the
     kernels tile): the blocks keep `models.nemotron_h.REMAT_KEEP`, so the
-    executable holds ONE `flash_fwd` beside its two backward kernels and ONE
+    executable holds ONE `flash_fwd` beside its fused backward and ONE
     `ssd_fwd` beside `ssd_states` and `ssd_bwd` (two with `ssd_out` out of
     what a block keeps: the rematerialised block runs it again), TWO
     `ssd_conv_fwd` beside `ssd_conv_bwd` (the convolution's output is made
@@ -415,7 +434,7 @@ def test_a_rematerialised_nemotron_step_holds_each_kernel_once_a_block(
     text = jax.jit(jax.grad(loss)).lower(
         params, tokens, tokens, weights).compile().as_text()
     assert collections.Counter(_kernel_names(text)) == {
-        "flash_fwd": 1, "flash_bwd_dkdv": 1, "flash_bwd_dq": 1,
+        "flash_fwd": 1, "flash_bwd": 1,
         "moe_gmm_fwd": 3, "moe_gmm_dlhs": 2, "moe_gmm_drhs": 2,
         "moe_combine_fwd": 1, "moe_combine_bwd": 1,
         "ssd_fwd": forwards, "ssd_states": 1, "ssd_bwd": 1,
@@ -437,9 +456,9 @@ def test_a_rematerialised_smallthinker_step_holds_each_kernel_once_a_layer(
     layer at toy widths (heads of 128, a group of 7, a share of ReGLU
     experts routed before attention): each layer keeps its flash kernel's
     out and lse and its routing, so the executable holds ONE `flash_fwd` a
-    layer beside its two backward kernels, and the experts' three matrices
+    layer beside its fused backward, and the experts' three matrices
     ask for three grouped products forward, two of them again in the
-    backward pass, and one combine kernel a pass. Each layer's three flash
+    backward pass, and one combine kernel a pass. Each layer's two flash
     kernels are under its kind's scope,
     ``attn_global`` or ``attn_window``, which the cell's readers take them
     by."""
@@ -476,14 +495,13 @@ def test_a_rematerialised_smallthinker_step_holds_each_kernel_once_a_layer(
     text = jax.jit(jax.grad(loss)).lower(params, tokens, tokens).compile() \
         .as_text()
     assert collections.Counter(_kernel_names(text)) == {
-        "flash_fwd": 2, "flash_bwd_dkdv": 2, "flash_bwd_dq": 2,
+        "flash_fwd": 2, "flash_bwd": 2,
         "moe_gmm_fwd": 10, "moe_gmm_dlhs": 6, "moe_gmm_drhs": 6,
         "moe_combine_fwd": 2, "moe_combine_bwd": 2}
     scopes = ops_by_scope(text, ("attn_global", "attn_window"))
     for scope in ("attn_global", "attn_window"):
         assert sorted(name.split(".")[0] for name in scopes[scope]
-                      if "flash_" in name) == [
-            "flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd"]
+                      if "flash_" in name) == ["flash_bwd", "flash_fwd"]
 
 
 #: The expert cells' layers: width, held experts, pairs a token, matrices
@@ -612,8 +630,10 @@ def test_the_forward_reduces_over_sublanes_not_lanes(v5e_device, name):
 
 
 #: The kernel cells' shapes, as `tile_plan` takes them, and the tiles each
-#: takes: the same in all three kernels (the forward's keys-major scratch
-#: changed what it reckons of VMEM, not the tiles).
+#: takes: the same in the forward and the fused backward, which every cell
+#: runs (the forward's keys-major scratch changed what it reckons of VMEM,
+#: not the tiles; the fused backward takes the tiles and heads the split
+#: pair took).
 CELL_PLANS = {
     "bert-base": ((512, 512, 64, 12, 12, 2, False, True), (512, 512, 4)),
     "sdar-30b-a3b": ((8192, 8192, 128, 32, 4, 2, False, False, "bd"),
@@ -637,15 +657,14 @@ def test_the_kernel_cells_plans_keep_their_tiles(cell):
     mask = {"bd": BlockDiffusionMask(4096, 4),
             "window": CausalWindow(4096)}.get(shape[8:] and shape[8])
     assert tile_plan(*shape[:8], mask) \
-        == FlashPlan(*(KernelTiles(*tiles),) * 3)
+        == FlashPlan(KernelTiles(*tiles), bwd=KernelTiles(*tiles))
 
 
 #: The kernels' operands and results by position (a walk's table, the first
 #: operand where a kernel walks, aside): what each one reads and writes.
 KERNEL_ROLES = {
     "flash_fwd": (("q", "k", "v"), ("o",)),
-    "flash_bwd_dq": (("q", "k", "v", "do", "lse", "o"), ("dq",)),
-    "flash_bwd_dkdv": (("q", "k", "v", "do", "lse", "delta"), ("dk", "dv")),
+    "flash_bwd": (("q", "k", "v", "do", "lse", "o"), ("dq", "dk", "dv")),
 }
 
 
@@ -687,7 +706,7 @@ def _boundary_relayouts(text):
 
     kernels, found = collections.Counter(), collections.Counter()
     for name, (typ, op, operands, _, _) in inst.items():
-        kernel = re.match(r"(?:\w*?_)?(flash_(?:fwd|bwd_dq|bwd_dkdv))"
+        kernel = re.match(r"(?:\w*?_)?(flash_(?:fwd|bwd))"
                           r"(?:\.\d+)?$", name)
         if op != "custom-call" or not kernel:
             continue
@@ -761,7 +780,7 @@ def _cell_layer_step(name):
 def test_the_cells_steps_hold_no_relayout_at_the_kernels(
         v5e_device, monkeypatch, name):
     """Each kernel cell's model at its layer's width (hidden size, heads,
-    head size), its gradient through XLA:TPU with its own remat: the three
+    head size), its gradient through XLA:TPU with its own remat: the two
     kernels read q, k, v and dO where the projections wrote them and write
     o, dQ, dK and dV where the model reads them, so no copy or transpose
     stands between them and the model on v, o, dO or dV, nor on the row

@@ -109,7 +109,7 @@ def test_the_plan_takes_more_than_one_head_a_step(name):
     _, causal, mask, _ = _inputs(name)
     structure = mask if kind in ("block", "window") else None
     plan = tile_plan(S, S, D, H, Hkv, 4, causal, kind == "keys", structure)
-    assert max(t.heads for t in plan) > lane_pack(H, Hkv, D)
+    assert max(t.heads for _, t in plan.kernels()) > lane_pack(H, Hkv, D)
 
 
 def _eqns(jaxpr):
@@ -129,10 +129,10 @@ def _eqns(jaxpr):
 
 @pytest.mark.parametrize("name", sorted(LAYOUTS))
 def test_no_transpose_outside_the_kernels(name):
-    """Forward and backward, between the caller's arrays and the three
+    """Forward and backward, between the caller's arrays and the two
     kernels there is no transpose: every operand and result is a view of
     [B, S, H*D] (the row statistics are made and read in their own layout,
-    and delta is made by the dQ kernel)."""
+    and delta is made by the fused backward)."""
     (q, k, v, w), causal, mask, _ = _inputs(name)
 
     def loss(q, k, v):
@@ -140,7 +140,7 @@ def test_no_transpose_outside_the_kernels(name):
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v).jaxpr
     names = [e.primitive.name for e in _eqns(jaxpr)]
-    assert names.count("pallas_call") == 3
+    assert names.count("pallas_call") == 2
     assert "transpose" not in names
 
 

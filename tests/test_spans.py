@@ -250,7 +250,7 @@ def test_the_flash_plan_of_the_traced_step_lands_on_the_compiled_record(
     clear_warm()
     plan = attention.tile_plan(128, 128, 64, 2, 2, 2, False, True).describe()
     assert cold["flash_plan"] == plan == \
-        "fwd q128 k128 h2 kq; dkdv q128 k128 h2; dq q128 k128 h2"
+        "fwd q128 k128 h2 kq; bwd q128 k128 h2"
     assert cold["trace_ms"] > 0 and cold["warm"] is False
     assert warm_trial["warm"] is True and "trace_ms" not in warm_trial
     assert "flash_plan" not in warm_trial

@@ -58,6 +58,14 @@ def _results(name):
         return att.flash_attention_planned(
             q, k, v, None, True, FlashPlan.explicit(*tiles), True, mask)
 
+    plan = FlashPlan.explicit(*tiles) if tiles else tile_plan(
+        S, S, D, H, Hkv, 4, True, False, mask)
+
+    def split(q, k, v):  # the same tiles, the backward as the split pair
+        return att.flash_attention_planned(
+            q, k, v, None, True, FlashPlan(plan.fwd, plan.bwd, plan.bwd),
+            True, mask)
+
     def reference(q, k, v):
         return attention_reference(q, k, v, causal=True, mask=mask.dense(S))
 
@@ -70,8 +78,8 @@ def _results(name):
 
     fwd = KernelTiles(*tiles) if tiles else tile_plan(
         S, S, D, H, Hkv, 4, True, False, mask).fwd
-    return {"flash": all_of(flash, flash_lse(q, k, v, None, True, fwd,
-                                             mask)),
+    lse = flash_lse(q, k, v, None, True, fwd, mask)
+    return {"flash": all_of(flash, lse), "split": all_of(split, lse),
             "reference": all_of(reference, reference_lse(
                 q, k, True, mask.dense(S))),
             "causal": all_of(causal, reference_lse(q, k, True))}
@@ -80,10 +88,13 @@ def _results(name):
 @pytest.mark.parametrize("tensor", TENSORS)
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_flash_under_the_window_matches_the_dense_mask(name, tensor):
+    """The fused backward, and the split pair at the same tiles."""
     i = TENSORS.index(tensor)
     found = _results(name)
-    got, want = found["flash"][i], found["reference"][i]
-    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+    want = found["reference"][i]
+    for kind in ("flash", "split"):
+        got = found[kind][i]
+        assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
     S, window = CASES[name][:2]
     apart = np.abs(found["causal"][i] - want).max() / np.abs(want).max()
     if window >= S:
@@ -118,7 +129,7 @@ def test_the_walk_is_the_dense_grid_without_its_empty_steps(
     accumulator opened and closed once, and as many steps as `_tile_runs`
     keeps."""
     mask = CausalWindow(window)
-    reps = 7 if kernel == "dkdv" else 1
+    reps = 7 if kernel in ("dkdv", "bwd") else 1
     walk = att.tile_walk(kernel, S, S, blk_q, blk_k, True, mask, reps)
     want = _dense_grid_walk(kernel, np.asarray(mask.dense(S)), blk_q, blk_k,
                             reps)
@@ -134,8 +145,7 @@ def test_the_cell_walks_70_of_the_causal_136_steps():
     global layers holds 136 tiles."""
     mask = CausalWindow(4096)
     plan = tile_plan(16384, 16384, 128, 28, 4, 2, True, False, mask)
-    assert plan.describe() == \
-        "fwd q1024 k1024 h1 kq; dkdv q1024 k1024 h1; dq q1024 k1024 h1"
+    assert plan.describe() == "fwd q1024 k1024 h1 kq; bwd q1024 k1024 h1"
     assert tile_plan(16384, 16384, 128, 28, 4, 2, True, False) == plan
     for kernel in FlashPlan._fields:
         walk = att.tile_walk(kernel, 16384, 16384, 1024, 1024, True, mask)
@@ -152,31 +162,30 @@ def test_the_plan_says_the_window_and_its_walk(monkeypatch):
     att._remember(tile_plan(16384, 16384, 128, 28, 4, 2, True, False, mask),
                   16384, 16384, True, mask)
     assert said == [
-        "fwd q1024 k1024 h1 kq; dkdv q1024 k1024 h1; dq q1024 k1024 h1; "
-        "window w4096 tiles fwd 70/256 dkdv 70/256 dq 70/256; "
-        "walk fwd 70+0 (28 partial) dkdv 70+0 (28 partial) "
-        "dq 70+0 (28 partial)"]
+        "fwd q1024 k1024 h1 kq; bwd q1024 k1024 h1; "
+        "window w4096 tiles fwd 70/256 bwd 70/256; "
+        "walk fwd 70+0 (28 partial) bwd 70+0 (28 partial)"]
 
 
 #: The flash plans and the forward walks of the accepted cells' shapes, as
 #: they were before the window: BERT-base (key padding), SDAR (the
 #: block-diffusion description), Nemotron-H and Ouro (causal). ViT's 197
-#: positions do not tile.
+#: positions do not tile. Since the fused backward, each runs it at the
+#: tiles and heads its two split kernels took, over the same walk.
 ACCEPTED = {
     "bert-base": ((512, 512, 64, 12, 12, 2, False, True, None),
-                  "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4",
+                  "fwd q512 k512 h4 kq; bwd q512 k512 h4",
                   None),
     "sdar-30b-a3b": ((8192, 8192, 128, 32, 4, 2, False, False,
                       BlockDiffusionMask(4096, 4)),
-                     "fwd q512 k512 h4 kq; dkdv q512 k512 h4; dq q512 k512 h4",
+                     "fwd q512 k512 h4 kq; bwd q512 k512 h4",
                      "80+0 (24 partial)"),
     "nemotron-3-nano-30b-a3b": (
         (8192, 8192, 128, 32, 2, 2, True, False, None),
-        "fwd q1024 k1024 h1 kq; dkdv q1024 k1024 h1; dq q1024 k1024 h1",
+        "fwd q1024 k1024 h1 kq; bwd q1024 k1024 h1",
         "36+0 (8 partial)"),
     "ouro-2.6b": ((4096, 4096, 128, 16, 16, 2, True, False, None),
-                  "fwd q1024 k1024 h1 kq; dkdv q1024 k1024 h1; "
-                  "dq q1024 k1024 h1",
+                  "fwd q1024 k1024 h1 kq; bwd q1024 k1024 h1",
                   "10+0 (4 partial)"),
 }
 
@@ -187,7 +196,7 @@ def test_the_accepted_cells_plans_and_walks_are_what_they_were(cell):
     plan = tile_plan(*shape)
     assert plan.describe() == said
     Sq, Sk, causal, structure = shape[0], shape[1], shape[6], shape[8]
-    for kernel, tiles in zip(plan._fields, plan):
+    for kernel, tiles in plan.kernels():
         walk = att.tile_walk(kernel, Sq, Sk, tiles.blk_q, tiles.blk_k, causal,
                              structure)
         assert (walk and walk.describe()) == walked
@@ -218,8 +227,10 @@ def test_whole_tiles_without_the_mask_are_bitwise_the_masked_ones(name,
     assert att.tile_walk("fwd", S, S, blk_q, blk_k, True,
                          CausalWindow(window)).describe() == said
     found, i = _described_walk_bits(name), WALK_TENSORS.index(tensor)
-    assert np.isfinite(found["walk"][i]).all() and found["walk"][i].any()
-    np.testing.assert_array_equal(found["walk"][i], found["all_partial"][i])
+    for kind in ("", "fused_")[:1 + ("fused_walk" in found)]:
+        walk = found[kind + "walk"][i]
+        assert np.isfinite(walk).all() and walk.any()
+        np.testing.assert_array_equal(walk, found[kind + "all_partial"][i])
 
 
 @pytest.mark.parametrize("causal,S_k,match", [
@@ -275,6 +286,6 @@ def test_the_three_kernels_compile_for_the_v5e_under_a_window():
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile() \
         .as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
-    for name in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"):
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in ("flash_fwd", "flash_bwd"):
         assert "%{}".format(name) in text
